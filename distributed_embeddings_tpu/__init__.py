@@ -10,7 +10,6 @@ Top-level API parity with the reference package
 ``__version__``.
 """
 
-from distributed_embeddings_tpu import compat  # noqa: F401  (installs jax shims)
 from distributed_embeddings_tpu.ops.embedding_lookup import embedding_lookup
 from distributed_embeddings_tpu.ops.ragged import RaggedBatch, SparseIds, row_to_split
 

@@ -38,7 +38,7 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 # (tests/test_obs.py `_runtime_sources`), so the migration can never
 # narrow enforcement.  tests/ are deliberately excluded — fixtures seed
 # violations on purpose.
-_RUNTIME_TOP_FILES = ('bench.py', '__graft_entry__.py')
+_RUNTIME_TOP_FILES = ('bench.py', 'chip_smoke.py', '__graft_entry__.py')
 _RUNTIME_DIRS = ('distributed_embeddings_tpu', 'tools', 'examples')
 
 

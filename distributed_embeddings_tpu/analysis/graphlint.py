@@ -924,8 +924,7 @@ def build_programs(tier: str = 'flagship') -> List[Program]:
     h = jnp.concatenate(list(emb_outs), axis=-1)
     return jnp.mean((h @ dense_params['kernel'] - hb) ** 2)
 
-  kernel = jnp.asarray(
-      rng.standard_normal((8 * len(cfg2), 1)).astype(np.float32) * 0.1)
+  kernel = rng.standard_normal((8 * len(cfg2), 1)).astype(np.float32) * 0.1
   weights = [rng.normal(size=(c.input_dim, c.output_dim))
              .astype(np.float32) * 0.1 for c in cfg2]
   labels = jnp.asarray(rng.normal(size=(batch, 1)).astype(np.float32))
@@ -937,7 +936,7 @@ def build_programs(tier: str = 'flagship') -> List[Program]:
     opt = SparseAdagrad(learning_rate=0.05)
     state = init_hybrid_train_state(
         dist, {'embedding': set_weights(dist, weights),
-               'kernel': kernel}, optax.sgd(0.05), opt)
+               'kernel': jnp.asarray(kernel)}, optax.sgd(0.05), opt)
     step = make_hybrid_train_step(dist, head_loss, optax.sgd(0.05),
                                   opt)
     traced = step.jitted.trace(state, cats_t, labels)

@@ -36,7 +36,7 @@ from distributed_embeddings_tpu.analysis.core import Context, Finding
 _JIT_WRAPPERS = frozenset({
     'jax.jit', 'jit', 'jax.pjit', 'pjit',
     'jax.experimental.pjit.pjit',
-    'shard_map', 'jax.experimental.shard_map.shard_map',
+    'shard_map', 'jax.shard_map',
 })
 _TRACE_MOD = 'distributed_embeddings_tpu.obs.trace'
 

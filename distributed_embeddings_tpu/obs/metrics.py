@@ -156,7 +156,7 @@ REGISTERED_STATS_KEYS = frozenset({
 REGISTERED_ARTIFACT_KEYS = frozenset({
     # core artifact line (bench.py)
     'metric', 'value', 'unit', 'vs_baseline', 'comparable', 'warmup_s',
-    'window_ms', 'loadavg', 'sha', 'prior_chip_evidence', 'recorded_at',
+    'window_ms', 'loadavg', 'sha',
     # hot-cache counters (parallel/hotcache.py)
     'alltoall_rows_sent', 'alltoall_rows_sent_off', 'unique_cold_rows',
     'hot_hit_rate', 'cold_occurrence_fraction', 'scatter_rows_per_step',

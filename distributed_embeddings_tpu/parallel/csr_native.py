@@ -47,7 +47,7 @@ class NativeBuilderError(RuntimeError):
 def build(quiet: bool = True) -> bool:
   """Builds the shared library with make; returns success."""
   global _load_failed
-  ok = nativebuild.build(target=_SO_NAME, quiet=quiet)
+  ok = nativebuild.build(_SO_NAME, _SRC_NAMES, quiet=quiet)
   if ok:
     _load_failed = False  # a later explicit build may succeed: retry load
   return ok

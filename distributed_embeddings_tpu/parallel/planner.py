@@ -1313,6 +1313,12 @@ class ExchangeCostModel:
   ratio is CONFIGURABLE and JOURNALED (``journal()``, event
   ``exchange_cost_model``) so every priced claim names its assumption.
 
+  The rates only PRICE JOURNAL LINES (``price_exchange``,
+  ``reconcile_exchange``): no placement, slicing or dispatch decision
+  reads them, so the one default (not keyed by ``device_kind``) cannot
+  steer a plan on any device — it can only mislabel a journaled
+  microsecond figure, which names the rate it assumed.
+
   Attributes:
     ici_gbps: per-device ICI injection bandwidth, GB/s.
     dcn_ici_ratio: how many times slower a DCN byte is than an ICI

@@ -1,0 +1,34 @@
+"""Where JAX's persistent compilation cache lives: decided OUTSIDE the
+program.
+
+The directory is part of every cache key, so a directory that moves
+never hits; and the machine a program runs on (a CI box, the chip tool's
+sealed copy) is the only party that knows which directory survives the
+process.  One rule for every entry point that compiles (bench.py,
+chip_smoke.py, tests/conftest.py, examples/dlrm, examples/benchmarks):
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it; set nothing.
+- unset: ``<checkout>/.jax_cache`` (git-ignored), a fixed path with no
+  pid, time or temp component in it.
+"""
+
+from __future__ import annotations
+
+import os
+
+ENV_VAR = 'JAX_COMPILATION_CACHE_DIR'
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def configure() -> str:
+  """Point JAX at the cache directory by the rule above; returns the
+  directory in use.  Call before the first compile."""
+  from_env = os.environ.get(ENV_VAR)
+  if from_env:
+    return from_env
+  import jax
+  path = os.path.join(_CHECKOUT, '.jax_cache')
+  jax.config.update('jax_compilation_cache_dir', path)
+  return path

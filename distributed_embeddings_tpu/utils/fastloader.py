@@ -29,14 +29,14 @@ _lib = None
 
 def build(quiet: bool = True) -> bool:
   """Builds the shared library with make; returns success."""
-  return nativebuild.build(target=_SO_NAME, quiet=quiet)
+  return nativebuild.build(_SO_NAME, _SRC_NAMES, quiet=quiet)
 
 
 def _load():
   global _lib
   if _lib is not None:
     return _lib
-  # build on demand (first use, or source newer than the binary — a stale
+  # build on demand (first use, or source edited since the build — a stale
   # binary must NOT shadow edited source); unavailable falls back to the
   # Python loader (shared lifecycle: utils/nativebuild.py)
   lib = nativebuild.load(_SO_NAME, _SRC_NAMES)

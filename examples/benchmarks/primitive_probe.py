@@ -33,8 +33,6 @@ def main():
   args = parser.parse_args()
 
   import jax
-  if os.environ.get('JAX_PLATFORMS') == 'cpu':
-    jax.config.update('jax_platforms', 'cpu')
   import jax.numpy as jnp
 
   rng = np.random.default_rng(0)

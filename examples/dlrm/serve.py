@@ -99,6 +99,8 @@ def main():
 
   import jax
   from distributed_embeddings_tpu import serving
+  from distributed_embeddings_tpu.utils import compile_cache
+  compile_cache.configure()
   from distributed_embeddings_tpu.parallel import TableConfig, hotcache
 
   bundle = args.bundle

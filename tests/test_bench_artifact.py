@@ -1,31 +1,26 @@
 """Artifact-robustness helpers in bench.py: the driver parses ONE JSON
-line per round, so the provenance/evidence/watchdog machinery around it
-needs pinning (VERDICT r4 items 1/9: sha provenance, prior chip
-evidence, self-bounded wall time)."""
+line per run, so the provenance/watchdog/exit-code machinery around it
+needs pinning (sha provenance, self-bounded wall time, and no failure
+that leaves exit code 0)."""
 
 import importlib.util
 import json
 import os
-import time
+import subprocess
+import sys
 
 import pytest
 
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture()
-def bench(tmp_path, monkeypatch):
+def bench():
   spec = importlib.util.spec_from_file_location(
-      'bench_for_test',
-      os.path.join(os.path.dirname(__file__), '..', 'bench.py'))
+      'bench_for_test', os.path.join(_ROOT, 'bench.py'))
   mod = importlib.util.module_from_spec(spec)
   spec.loader.exec_module(mod)
-  # isolate the journal from real sweep state
-  mod.CHIP_LINES = str(tmp_path / 'lines.jsonl')
   return mod
-
-
-def _stamp(offset_s=0.0):
-  return time.strftime('%Y-%m-%dT%H:%M:%SZ',
-                       time.gmtime(time.time() + offset_s))
 
 
 def test_repo_sha_prefers_snapshot_file_then_git(bench):
@@ -34,47 +29,44 @@ def test_repo_sha_prefers_snapshot_file_then_git(bench):
   assert sha and len(sha) >= 7
 
 
-def test_chip_evidence_age_filter(bench):
-  with open(bench.CHIP_LINES, 'w') as f:
-    f.write(json.dumps({'value': 1, 'recorded_at': _stamp(-20 * 3600)}) +
-            '\n')
-  assert bench.chip_evidence() is None  # stale: older than a round
-  with open(bench.CHIP_LINES, 'a') as f:
-    f.write(json.dumps({'value': 2, 'recorded_at': _stamp(-3600)}) + '\n')
-  assert bench.chip_evidence()['value'] == 2
-  # a malformed line never raises: the whole journal is treated as
-  # unreadable (evidence is an optional extra, not a failure source)
-  with open(bench.CHIP_LINES, 'a') as f:
-    f.write('not json\n')
-  assert bench.chip_evidence() is None
+def test_bench_command_refuses_a_cpu():
+  """`JAX_PLATFORMS=cpu python bench.py` times nothing: exit code 1 and
+  a plain reason, no JSON line with a value."""
+  proc = subprocess.run(
+      [sys.executable, os.path.join(_ROOT, 'bench.py')],
+      env={**os.environ, 'JAX_PLATFORMS': 'cpu'}, cwd=_ROOT,
+      capture_output=True, text=True, timeout=120)
+  assert proc.returncode == 1, (proc.returncode, proc.stderr[-500:])
+  assert 'no TPU' in proc.stderr
+  assert proc.stdout.strip() == ''
 
 
-def test_chip_evidence_skips_bad_timestamps(bench):
-  with open(bench.CHIP_LINES, 'w') as f:
-    f.write(json.dumps({'value': 7, 'recorded_at': 'garbage'}) + '\n')
-    f.write(json.dumps({'value': 8, 'recorded_at': _stamp()}) + '\n')
-  assert bench.chip_evidence()['value'] == 8
+def test_raised_phase_makes_exit_code_nonzero(bench, capsys):
+  """A secondary phase that raised still prints its `*_error` key, and
+  the run exits non-zero; a clean line exits through `run` as 0."""
+  with pytest.raises(SystemExit) as exc:
+    bench.run(lambda: bench.finish(
+        {'metric': 'm', 'value': 1.5, 'serving_error': 'ValueError: x'}))
+  assert exc.value.code not in (0, None)
+  assert 'serving_error' in str(exc.value.code)
+  line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+  assert line['serving_error'] == 'ValueError: x'  # still on the line
+  assert bench.run(lambda: bench.finish({'metric': 'm', 'value': 1.5})) == 0
 
 
-def test_emit_journals_only_tpu_measurements(bench, capsys):
-  bench.emit({'value': 1.5, 'metric': 'm'}, on_tpu=False)
-  assert not os.path.exists(bench.CHIP_LINES)
-  bench.emit({'value': 1.5, 'metric': 'm'}, on_tpu=True)
-  bench.emit({'value': None, 'metric': 'failed'}, on_tpu=True)
-  with open(bench.CHIP_LINES) as f:
-    lines = [json.loads(l) for l in f]
-  assert len(lines) == 1  # failures are never journaled as evidence
-  assert 'recorded_at' in lines[0]
-  out = capsys.readouterr().out.strip().splitlines()
-  assert all(json.loads(l) for l in out)  # stdout stays parseable JSON
+def test_exception_and_watchdog_exit_one(bench, capsys):
+  """An exception in main() — the SIGALRM watchdog's included — prints
+  the labelled failure line and is exit code 1, never 0."""
+  def boom():
+    raise RuntimeError('compile exploded')
 
+  def slow():
+    raise bench._Watchdog('wall time exceeded 1s')
 
-def test_fold_prior_evidence_attaches_fresh_line(bench):
-  with open(bench.CHIP_LINES, 'w') as f:
-    f.write(json.dumps({'value': 3, 'recorded_at': _stamp()}) + '\n')
-  result = {'metric': 'x'}
-  bench._fold_prior_evidence(result)
-  assert result['prior_chip_evidence']['value'] == 3
+  for fn, text in ((boom, 'compile exploded'), (slow, 'wall time')):
+    assert bench.run(fn) == 1
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line['value'] is None and text in line['error']
 
 
 def test_watchdog_arm_disarm_cycle(bench, monkeypatch):
@@ -96,30 +88,6 @@ def test_watchdog_disabled_by_zero(bench, monkeypatch):
   bench._arm_watchdog()
   assert signal.getitimer(signal.ITIMER_REAL)[0] == 0
   assert 'timer' not in bench._WATCHDOG_STATE
-
-
-def test_chip_evidence_utc_parse_is_dst_immune(bench, monkeypatch):
-  """recorded_at is UTC; the parse must be timegm (its exact inverse).
-  The old mktime(...) - time.timezone conversion shifted the epoch by
-  an hour whenever the LOCAL zone was in DST, silently staling lines
-  near the 14h cutoff (ADVICE.md round 5, low #1).  Pin a DST locale
-  and a line 13.5h old: it must stay fresh."""
-  monkeypatch.setenv('TZ', 'America/New_York')
-  time.tzset()
-  try:
-    with open(bench.CHIP_LINES, 'w') as f:
-      f.write(json.dumps({'value': 5,
-                          'recorded_at': _stamp(-13.5 * 3600)}) + '\n')
-    ev = bench.chip_evidence()
-    assert ev is not None and ev['value'] == 5
-    # and a genuinely stale line still filters
-    with open(bench.CHIP_LINES, 'w') as f:
-      f.write(json.dumps({'value': 6,
-                          'recorded_at': _stamp(-14.5 * 3600)}) + '\n')
-    assert bench.chip_evidence() is None
-  finally:
-    monkeypatch.delenv('TZ')
-    time.tzset()
 
 
 def test_hot_cache_counters_present_and_consistent():

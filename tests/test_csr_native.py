@@ -167,3 +167,27 @@ def test_resolve_builder_numpy_fallback_without_native(monkeypatch):
   assert sparsecore.resolve_builder('auto') == 'numpy'
   with pytest.raises(RuntimeError, match='native CSR builder'):
     sparsecore.resolve_builder('native')
+
+
+def test_staleness_is_decided_by_content_not_by_modification_time(built):
+  """A copied tree does not keep modification times meaningful: the
+  build records its sources' digest beside the binary, and only a
+  digest that matches the present sources makes the binary current."""
+  import os
+  so, srcs = csr_native._SO_NAME, csr_native._SRC_NAMES
+  stamp = nativebuild.so_path(so) + '.srcsum'
+  assert not nativebuild.stale(so, srcs)
+  with open(stamp, encoding='ascii') as f:
+    digest = f.read()
+  try:
+    # sources "older" than the binary by mtime, but not the ones it was
+    # built from: stale
+    with open(stamp, 'w', encoding='ascii') as f:
+      f.write('0' * 64)
+    assert nativebuild.stale(so, srcs)
+    os.remove(stamp)  # a binary of unknown provenance: stale
+    assert nativebuild.stale(so, srcs)
+  finally:
+    with open(stamp, 'w', encoding='ascii') as f:
+      f.write(digest)
+  assert not nativebuild.stale(so, srcs)

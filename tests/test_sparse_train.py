@@ -502,3 +502,29 @@ def test_bf16_accumulator_checkpoint_roundtrip():
     for k in a:
       np.testing.assert_array_equal(np.asarray(a[k], dtype=np.float32),
                                     np.asarray(b[k], dtype=np.float32))
+
+
+def test_dispatch_says_which_path_each_group_takes_on_tpu(monkeypatch,
+                                                          caplog):
+  """`use_segwalk_apply=True` is a request: on a TPU a group the kernel
+  cannot serve says so (and why) instead of taking XLA in silence, and a
+  served group says that too; off the chip nothing is said."""
+  import logging
+  import jax
+  import jax.numpy as jnp
+  from distributed_embeddings_tpu.parallel import SparseAdagrad, sparse
+  opt = SparseAdagrad(use_segwalk_apply=True)
+  good = jax.ShapeDtypeStruct((1024, 128), jnp.float32)
+  odd = jax.ShapeDtypeStruct((1024, 24), jnp.float32)
+  with caplog.at_level(logging.INFO, logger=sparse.__name__):
+    assert not sparse._use_segwalk(opt, good, group='group_0')
+    assert not caplog.records  # CPU backend: not asked here, not said
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert sparse._use_segwalk(opt, good, group='group_0')
+    assert not sparse._use_segwalk(opt, odd, group='group_1')
+    assert not sparse._use_segwalk(SparseAdagrad(), odd, group='group_1')
+  said = [(r.levelname, r.getMessage()) for r in caplog.records]
+  assert said[0][0] == 'INFO' and 'group_0 takes the segment-walk' in said[0][1]
+  assert said[1][0] == 'WARNING' and 'group_1 takes the XLA apply' in said[1][1]
+  assert 'not a kernel shape' in said[1][1]
+  assert len(said) == 2  # the kernel was not asked for: nothing to say

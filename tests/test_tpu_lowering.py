@@ -12,11 +12,10 @@ hardware-gated in tests/test_pallas_tpu.py.
 Covers every kernel configuration AND the full 4-chip hybrid train
 step (flat and two-axis meshes) compiled for v5e 2x2.
 
-Marked ``slow``: the abstract-topology compile stack costs ~10 minutes
-of host XLA time on this image's 2-core CI host (and most cases still
-need a newer jax/libtpu than the image carries), which does not fit
-the tier-1 time budget — run with ``pytest -m slow`` where the stack
-is available.
+Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 49 cases pass
+in about 40 s on 8 host cores.  This is the free gate to run
+(``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
 """
 
 import numpy as np
