@@ -37,8 +37,8 @@ Passes (each a callable ``(programs) -> findings`` in ``PASSES``):
   ``compile_count`` pin generalized from serving to every path.
 - ``hostsync``   — no host callback primitive inside a traced hot-path
   program, and no ``jax.device_get`` observed from the monitored step
-  hot loop (trace-time obs spans are the sanctioned instrument, as in
-  the purity pass; the cold tier's documented host leg is exempt).
+  hot loop (``obs.trace.phase`` scopes are the sanctioned instrument, as
+  in the purity pass; the cold tier's documented host leg is exempt).
 - ``hbm``        — per-program memory estimate from the compiled
   executable's memory analysis, journaled next to
   ``device_hbm_budget`` and gated against it where a plan declares one
@@ -586,7 +586,7 @@ def _hostsync_pass(programs: List[Program]) -> List[Finding]:
             message=f'host callback primitive {prim!r} inside the '
             'traced program — every execution pays a device->host '
             'rendezvous, and under shard_map a per-device callback '
-            'can wedge the mesh (trace-time obs spans are the '
+            'can wedge the mesh (obs.trace.phase scopes are the '
             'sanctioned instrument; they insert no primitive)'))
     if prog.hostsync is not None:
       for site in sorted(set(prog.hostsync.sites)):

@@ -12,12 +12,13 @@ Roots: functions wrapped by ``jax.jit`` / ``pjit`` / ``shard_map``
 ``jax.jit(fn)`` where ``fn`` resolves lexically).  Reachability walks
 the intra-repo call graph from each root.
 
-Deliberately exempt: ``obs.trace`` spans.  Trace-time spans
-(``fwd/exchange`` & co) are the SANCTIONED trace-time instrument — they
-run at trace time by design, insert zero operations, and attribute
-trace/compile wall time (obs/trace.py docstring).  The walk therefore
-never descends into ``obs.trace``; everything else on the banned list
-is flagged at its call site.
+``obs.trace`` HOST SPANS (``span``/``begin``/``end``/...) are on the
+banned list: inside traced code a span would last as long as Python's
+tracing did and say nothing of the device.  The sanctioned instrument
+there is ``obs.trace.phase`` (``jax.named_scope``: metadata on the
+operations, no host effect), so the walk never descends into
+``obs.trace``; everything on the banned list is flagged at its call
+site.
 
 Rule: ``purity/host-effect-in-traced`` — symbol is
 ``<root>-><offending function>:<effect>`` so the id survives line
@@ -49,6 +50,12 @@ _BANNED_PREFIXES: Tuple[Tuple[str, str], ...] = (
     ('distributed_embeddings_tpu.obs.metrics.set_gauge', 'metrics'),
     ('distributed_embeddings_tpu.obs.metrics.journal_snapshot',
      'metrics'),
+    ('distributed_embeddings_tpu.obs.trace.span', 'span'),
+    ('distributed_embeddings_tpu.obs.trace.begin', 'span'),
+    ('distributed_embeddings_tpu.obs.trace.end', 'span'),
+    ('distributed_embeddings_tpu.obs.trace.complete', 'span'),
+    ('distributed_embeddings_tpu.obs.trace.async_span', 'span'),
+    ('distributed_embeddings_tpu.obs.trace.instant', 'span'),
     ('time.', 'time'),
     ('numpy.random.', 'global-rng'),
     ('random.', 'global-rng'),

@@ -7,6 +7,8 @@ precise, alias-aware resolution — and goes strictly beyond them:
 - call sites the regexes matched (``journal('x')``, ``trace.span('x')``,
   ``obs_trace.begin(...)``, ``metrics.inc('y')``) are still checked by
   surface shape, so enforcement can never be weaker than the scans;
+  device phases (``obs_trace.phase('x')``, context manager or
+  decorator) are held to ``REGISTERED_PHASES`` the same way;
 - call sites the regexes MISSED are now covered: a direct import
   (``from ...resilience import journal as j; j('x')``) resolves through
   the module's import aliases;
@@ -23,6 +25,7 @@ by a string literal somewhere in the runtime sources).
 Rules:
   registry/journal-unregistered   journal() name not in REGISTERED_EVENTS
   registry/span-unregistered      trace name not in REGISTERED_SPANS
+  registry/phase-unregistered     phase() name not in REGISTERED_PHASES
   registry/metric-unregistered    metric name not in REGISTERED_METRICS
   registry/unverifiable-name      derived/non-literal name argument
   registry/stats-key-unregistered stats() key not in REGISTERED_STATS_KEYS
@@ -40,6 +43,7 @@ from distributed_embeddings_tpu.analysis.core import Context, Finding
 
 _SPAN_FUNCS = frozenset({'span', 'begin', 'complete', 'async_span',
                          'instant'})
+_PHASE_FUNCS = frozenset({'phase'})
 _METRIC_FUNCS = frozenset({'inc', 'observe', 'set_gauge'})
 _TRACE_MOD = 'distributed_embeddings_tpu.obs.trace'
 _METRICS_MOD = 'distributed_embeddings_tpu.obs.metrics'
@@ -48,7 +52,7 @@ _JOURNAL_TARGET = 'distributed_embeddings_tpu.utils.resilience.journal'
 
 def _classify(mod: core.Module, call: ast.Call
               ) -> Tuple[Optional[str], bool]:
-  """(kind, confident) — kind is 'journal' | 'span' | 'metric' for a
+  """(kind, confident) — kind is 'journal' | 'span' | 'phase' | 'metric' for a
   registry-surface call, else None.  Surface shape (what the regexes
   matched) OR a resolved alias target qualifies — shape-only matches
   keep enforcement no weaker than the scans, resolution adds the
@@ -65,6 +69,8 @@ def _classify(mod: core.Module, call: ast.Call
     head, _, leaf = resolved.rpartition('.')
     if head == _TRACE_MOD and leaf in _SPAN_FUNCS:
       return 'span', True
+    if head == _TRACE_MOD and leaf in _PHASE_FUNCS:
+      return 'phase', True
     if head == _METRICS_MOD and leaf in _METRIC_FUNCS:
       return 'metric', True
   if isinstance(fn, ast.Name) and fn.id == 'journal':
@@ -76,6 +82,8 @@ def _classify(mod: core.Module, call: ast.Call
       return 'journal', base_leaf == 'resilience'
     if fn.attr in _SPAN_FUNCS and base_leaf in ('trace', 'obs_trace'):
       return 'span', True
+    if fn.attr in _PHASE_FUNCS and base_leaf in ('trace', 'obs_trace'):
+      return 'phase', True
     if fn.attr in _METRIC_FUNCS and base_leaf in ('metrics',
                                                   'obs_metrics'):
       return 'metric', True
@@ -103,11 +111,13 @@ def run(ctx: Context) -> List[Finding]:
       'journal': (resilience.REGISTERED_EVENTS,
                   'resilience.REGISTERED_EVENTS'),
       'span': (obs_trace.REGISTERED_SPANS, 'obs.trace.REGISTERED_SPANS'),
+      'phase': (obs_trace.REGISTERED_PHASES,
+                'obs.trace.REGISTERED_PHASES'),
       'metric': (obs_metrics.REGISTERED_METRICS,
                  'obs.metrics.REGISTERED_METRICS'),
   }
   findings: List[Finding] = []
-  sites = {'journal': 0, 'span': 0, 'metric': 0}
+  sites = {'journal': 0, 'span': 0, 'phase': 0, 'metric': 0}
   # string constants that can count as a key's PRODUCER: docstrings
   # are excluded (a key named in prose is not a producer), and so is
   # the registry-definition module itself — its frozenset literals

@@ -3,27 +3,33 @@
 One instrumentation contract across training and serving, replacing the
 per-component ``stats()`` islands with two shared primitives:
 
-- ``obs.trace``: a lightweight span tracer emitting Chrome-trace-event
-  JSON (loads directly in Perfetto / ``chrome://tracing``).  Named
-  phases thread through the whole step — host CSR build and feed queue
-  wait, cold-tier pre-pass/fetch/write-back, the dp<->mp exchange and
-  lookup/combine/apply (trace-time spans), auditor calls, checkpoint
-  save/restore, and the per-request submit->enqueue->dispatch->demux
-  path in serving.  ``tools/trace_report.py`` turns a trace into the
-  per-step phase breakdown and stall-attribution table.
+- ``obs.trace``: one vocabulary for where a step's time goes.  HOST
+  SPANS thread through the host side of the step — CSR build and feed
+  queue wait, cold-tier pre-pass/fetch/write-back, auditor calls,
+  checkpoint save/restore, and the per-request
+  submit->enqueue->dispatch->demux path in serving — into a
+  Chrome-trace-event JSON (Perfetto / ``chrome://tracing``) and, as
+  ``TraceAnnotation``s, into the JAX profiler's own trace.  DEVICE
+  PHASES (``obs.trace.phase`` = ``jax.named_scope``) name the sections
+  of the compiled step — route, exchange, lookup/combine, head, and the
+  sparse apply's dedup/read/update/write per table group — on the
+  profiler's device ops.  ``obs.trace.profile(dir)`` captures both;
+  ``tools/trace_report.py`` turns either file into the per-step phase
+  breakdown, ``--profile`` into device ms per phase.
 - ``obs.metrics``: a process-global registry of counters / gauges /
   fixed-bucket histograms under ONE documented name schema
   (``REGISTERED_METRICS``), with periodic snapshots journaled through
   the existing ``resilience.journal`` sink and a Prometheus-text
   exporter.
 
-Both are DISABLED by default and their disabled path is a single flag
-check returning a shared no-op — the instrumented program is
-program-identical to the uninstrumented one (the spans inside traced
-jax code run at Python trace time and insert zero operations either
-way; ``bench.py`` journals the measured on/off ``obs_overhead_pct``).
+Spans and metrics are DISABLED by default and their disabled path is a
+single flag check returning a shared no-op; device phases are metadata
+of the compiled program and insert zero operations, so the instrumented
+program is program-identical to the uninstrumented one (``bench.py``
+journals the measured on/off ``obs_overhead_pct``).
 
-Every span name must come from ``REGISTERED_SPANS`` and every metric
+Every span name must come from ``REGISTERED_SPANS``, every phase from
+``REGISTERED_PHASES`` and every metric
 name from ``REGISTERED_METRICS`` — pinned by the source-scan tests in
 ``tests/test_obs.py`` (the same schema discipline as
 ``resilience.REGISTERED_EVENTS``): a typo'd phase name fails tier-1
@@ -32,7 +38,8 @@ instead of silently vanishing from every report.
 
 from distributed_embeddings_tpu.obs import devprof, metrics, trace
 from distributed_embeddings_tpu.obs.metrics import REGISTERED_METRICS
-from distributed_embeddings_tpu.obs.trace import REGISTERED_SPANS
+from distributed_embeddings_tpu.obs.trace import (REGISTERED_PHASES,
+                                                  REGISTERED_SPANS)
 
 
 def enable(trace_path=None):
@@ -96,4 +103,5 @@ def measure_overhead(step_ms: float, reps: int = 2000) -> dict:
 
 
 __all__ = ['trace', 'metrics', 'devprof', 'REGISTERED_SPANS',
+           'REGISTERED_PHASES',
            'REGISTERED_METRICS', 'enable', 'disable', 'reset']

@@ -1,11 +1,12 @@
 """Per-phase device-time attribution: the segmented-dispatch profiler
 (docs/design.md §19).
 
-The §15 tracer is honest about its blind spot: trace-time program
-spans attribute trace/compile wall and mark program structure,
-explicitly NOT per-step device time — so ``trace_report``'s critical
-path ends at an unattributed remainder of "device + untraced host".
-This module is the device-side half: it runs the real step's phases as
+The §15 host tracer times host code, so the obs file's critical path
+ends at an unattributed remainder of "device + untraced host".  (The
+device time of the step that RUNS is read from the profiler's trace by
+phase: ``obs.trace.phase`` + ``tools/trace_report.py --profile``.)
+This module predates that and estimates the device side from outside
+the step: it runs the real step's phases as
 INDIVIDUALLY SYNCED sub-programs on the live backend (emulation/XLA on
 this host, the same programs on TPU) and attributes per-phase device
 milliseconds:

@@ -79,6 +79,8 @@ METRIC_TYPES: Dict[str, str] = {
     'serve.batches': 'counter',
     'serve.batch_fill': 'gauge',
     'serve.latency_ms': 'histogram',
+    # admission -> dispatch, the 'serve/enqueue' span's own measurement
+    'serve.queue_wait_ms': 'histogram',
     # pipelined dispatch stages (design §16)
     'serve.merge_ms': 'histogram',
     'serve.demux_ms': 'histogram',
@@ -130,7 +132,8 @@ REGISTERED_STATS_KEYS = frozenset({
     'queue_depth', 'queue_dropped',
     # DynamicBatcher (serving/batcher.py)
     'submitted', 'completed', 'max_batch', 'max_delay_ms', 'batch_fill',
-    'p50_ms', 'p99_ms', 'bucket_ladder', 'buckets', 'bucket_launches',
+    'p50_ms', 'p99_ms', 'queue_wait_p50_ms', 'queue_wait_p99_ms',
+    'bucket_ladder', 'buckets', 'bucket_launches',
     'rows_launched', 'pad_rows', 'pad_waste_pct', 'pipeline',
     'merge_demux_ms', 'csr_feed',
     # SLO-aware admission + replica pool (serving/batcher.py,

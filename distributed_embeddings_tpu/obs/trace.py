@@ -1,11 +1,25 @@
-"""Span tracer: named step phases -> Chrome-trace-event JSON.
+"""Span tracer and device phases: one vocabulary for where a step's time goes.
 
-The capture half of the observability layer (docs/design.md §15).  Call
-sites wrap host-side phases in ``with span('feed/build'): ...`` (or the
-``begin``/``end`` token pair where a ``with`` block would force a
-re-indent of traced jax code); each completed span becomes one
-complete-duration event (``ph='X'``) in an in-memory buffer, and
-``save()`` writes the standard wrapper object
+The capture half of the observability layer (docs/design.md §15).  Two
+kinds of name, both registered here:
+
+- a HOST SPAN (``REGISTERED_SPANS``) times host code.  Call sites wrap a
+  phase in ``with span('feed/build'): ...`` or the ``begin``/``end``
+  token pair; each completed span becomes one complete-duration event
+  (``ph='X'``) in an in-memory buffer and, for as long as it is open,
+  one ``jax.profiler.TraceAnnotation`` — so the same span shows in the
+  JAX profiler's own trace, on the thread that ran it and on the clock
+  of the device planes;
+- a DEVICE PHASE (``REGISTERED_PHASES``) names a section of a compiled
+  program.  ``with phase('fwd/exchange'): ...`` is ``jax.named_scope``:
+  metadata on every operation traced inside it, no operation added, so
+  the profiler's device ops carry the phase in their ``tf_op`` path.
+  It scopes whether the tracer is enabled or not (it costs Python time
+  only while jit traces the program).
+
+``obs.trace.profile(directory)`` starts a capture of both;
+``tools/trace_report.py --profile <directory>`` reads it.  ``save()``
+writes the tracer's own buffer as the standard wrapper object
 
     {"traceEvents": [...], "displayTimeUnit": "ms", "otherData": {...}}
 
@@ -13,12 +27,12 @@ that Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` open
 directly, and ``tools/trace_report.py`` parses for the stall
 attribution tables.
 
-Disabled (the default) every entry point is ONE module-flag check
-returning a shared no-op object — no allocation, no lock, no event.
-Spans placed inside jit-traced code run at Python trace time in either
-mode and never insert operations into the program, so the disabled path
-is program-identical (the bench's off/on A/B journals the measured
-overhead of the enabled path).
+Disabled (the default) ``span`` is ONE module-flag check returning a
+shared no-op object — no allocation, no lock, no event, no annotation;
+``begin`` is that check plus one clock read (its token is then the bare
+start time, so ``end`` can still hand the caller's stats the seconds).
+Spans never go inside jit-traced code: there they would time Python's
+tracing, not the device — that is what ``phase`` is for.
 
 Span-name discipline: every runtime call site must use a name from
 ``REGISTERED_SPANS`` (source-scanned by tests/test_obs.py, mirroring
@@ -28,27 +42,31 @@ names surface in ``tools/trace_report.py --strict``.
 
 Three event shapes:
 
-- ``span``/``begin``+``end``/``complete``: a synchronous phase on one
-  thread (``ph='X'``).  Same-thread spans follow ``with``-statement
-  stack discipline, so per-track events are always properly nested.
+- ``span``/``begin``+``end``: a synchronous phase on one thread
+  (``ph='X'``).  Same-thread spans follow ``with``-statement stack
+  discipline, so per-track events are always properly nested.
+  ``end`` returns the seconds it measured: a site that also feeds a
+  stats counter takes them from there, one measurement for both.
+  ``complete`` emits an interval measured elsewhere (the devprof lane)
+  into the tracer's own file only: it cannot be annotated after the
+  fact.
 - ``async_span``: a logical interval not owned by any one thread — a
   serving request's queue residency (``serve/enqueue``) overlaps its
   neighbours arbitrarily — emitted as a ``ph='b'``/``'e'`` pair keyed
   by ``id`` (Perfetto renders each id on its own async track).
 - ``instant``: a point marker (``ph='i'``).
 
-Timestamps are microseconds on the ``time.perf_counter`` clock,
-re-based to ``enable()``; producers that measure an interval themselves
-(a queue wait already being timed for ``stats()``) emit it with
-``complete(name, start_s, dur_s)`` using ``now()`` for the start so the
-trace and the stats agree on the SAME measurement instead of timing the
-phase twice.
+Timestamps in the tracer's own file are microseconds on the
+``time.perf_counter`` clock, re-based to ``enable()``.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import os
+import re
 import threading
 import time
 
@@ -57,6 +75,7 @@ from typing import Any, Dict, List, Optional
 # The complete span taxonomy (docs/design.md §15).  Add a name HERE in
 # the same change that introduces the call site — tests/test_obs.py
 # source-scans every span()/begin()/complete()/async_span() literal.
+# No span goes inside jit-traced code (see REGISTERED_PHASES).
 REGISTERED_SPANS = frozenset({
     # training driver (parallel/grad.py fit)
     'train/step', 'train/sync',
@@ -65,11 +84,6 @@ REGISTERED_SPANS = frozenset({
     # cold tier (parallel/coldtier.py)
     'coldtier/prepass', 'coldtier/wait', 'coldtier/fetch',
     'coldtier/writeback',
-    # trace-time phases of the compiled step
-    # (parallel/dist_embedding.py / parallel/sparse.py): emitted while
-    # python traces the jitted program — they attribute TRACE/compile
-    # wall time and mark program structure, not per-step device time
-    'fwd/exchange', 'fwd/lookup_combine', 'bwd/exchange', 'apply/update',
     # state-integrity auditor (parallel/audit.py)
     'audit/check',
     # checkpoints (parallel/checkpoint.py)
@@ -98,15 +112,56 @@ REGISTERED_SPANS = frozenset({
     'dev/bwd/exchange/ici', 'dev/bwd/exchange/dcn',
 })
 
+# Device phases of the compiled step (docs/design.md §15): the value is
+# the layer of PERF.md section 3 the phase belongs to.  Add a name HERE
+# in the same change that introduces its ``phase()`` call site (same
+# source scan as the spans).  A phase's LEAF may not be, or start with,
+# a primitive that trace reductions class ops by (``PRIMITIVE_LEAVES``):
+# some device ops carry a ``tf_op`` that ends at the scope, and a
+# reduction that reads the last path component would book them to that
+# class.  Hence ``read_rows``/``write_rows``, not ``gather``/``scatter``.
+REGISTERED_PHASES: Dict[str, str] = {
+    # send buffers, slot selection, owner-side id routing, sort/unique
+    # of ids; backward: cotangent send buffers, per-unique-row sums
+    'fwd/route': 'route + exchange',
+    'bwd/route': 'route + exchange',
+    # the all-to-alls with their pack/unpack (ids out, rows back; the
+    # cross-slice DCN pair; backward: cotangents, hot-row psum) and the
+    # reorder of what came back
+    'fwd/exchange': 'route + exchange',
+    'bwd/exchange': 'route + exchange',
+    # row gather + combine over the hotness axis, one child scope per
+    # plan group (``fwd/lookup_combine/g<index>`` = params ``group_<index>``)
+    'fwd/lookup_combine': 'gather + combine',
+    # head_loss_fn under the step's vjp (its backward reads
+    # ``transpose(jvp(head))``) and the optax update of the dense params
+    'head': 'dense head',
+    'dense_update': 'dense head',
+    # the sparse optimizer step, each under a child scope per group:
+    # the update stream's assembly and cross-slice merge ...
+    'apply/stream': 'sparse apply',
+    # ... sort + segment sums + compaction of duplicate rows ...
+    'apply/dedup': 'sparse apply',
+    # ... rows and optimizer state fetched for the rows updated ...
+    'apply/read_rows': 'sparse apply',
+    # ... the optimizer's arithmetic ...
+    'apply/update': 'sparse apply',
+    # ... and the write back into table and state
+    'apply/write_rows': 'sparse apply',
+}
+
+PRIMITIVE_LEAVES = ('gather', 'scatter', 'sort', 'cumsum',
+                    'reduce_window_sum', 'cumlogsumexp', 'dot_general',
+                    'conv_general', 'all_to_all', 'psum', 'all_gather',
+                    'copy')
+
 # Report classification (tools/trace_report.py): 'wait' spans are
-# blocked time (the stall-attribution numerator), 'trace' spans are
-# trace-time program phases, 'device' spans are measured device time on
-# the devprof lane (design §19), everything else is measured host work.
+# blocked time (the stall-attribution numerator), 'device' spans are
+# measured device time on the devprof lane (design §19), everything
+# else is measured host work.
 SPAN_CATEGORIES: Dict[str, str] = {
     'feed/wait': 'wait', 'coldtier/wait': 'wait', 'train/sync': 'wait',
     'serve/enqueue': 'wait', 'serve/shed': 'wait',
-    'fwd/exchange': 'trace', 'fwd/lookup_combine': 'trace',
-    'bwd/exchange': 'trace', 'apply/update': 'trace',
     'dev/fwd/exchange': 'device', 'dev/fwd/lookup_combine': 'device',
     'dev/bwd/exchange': 'device', 'dev/bwd/grad': 'device',
     'dev/apply/update': 'device', 'dev/serve/execute': 'device',
@@ -159,8 +214,9 @@ def enabled() -> bool:
 
 
 def now() -> float:
-  """The tracer's clock (seconds) — use for ``complete()`` starts so a
-  self-timed interval lands on the same timeline as live spans."""
+  """The tracer's clock (seconds): starts for ``complete()`` and
+  ``async_span()``, so their intervals land on the timeline of the live
+  spans."""
   return time.perf_counter()
 
 
@@ -283,12 +339,29 @@ def _emit(event: Dict[str, Any]):
     _events.append(event)
 
 
+def _annotation(name: str, args: Optional[Dict[str, Any]]):
+  """An entered ``jax.profiler.TraceAnnotation`` for one open span
+  (``StepTraceAnnotation`` for ``train/step``, so profiler tools group
+  the device work by step).  Outside a profiler session it costs the
+  TraceMe's own flag check."""
+  import jax
+  args = args or {}
+  if name == 'train/step':
+    ann = jax.profiler.StepTraceAnnotation(
+        name, step_num=int(args.get('step', 0)), **args)
+  else:
+    ann = jax.profiler.TraceAnnotation(name, **args)
+  ann.__enter__()
+  return ann
+
+
 class _Span:
-  __slots__ = ('name', 'args', 't0')
+  __slots__ = ('name', 'args', 'ann', 't0')
 
   def __init__(self, name: str, args: Optional[Dict[str, Any]]):
     self.name = name
     self.args = args
+    self.ann = _annotation(name, args)
     self.t0 = time.perf_counter()
 
   def __enter__(self):
@@ -308,33 +381,148 @@ def span(name: str, **args):
 
 
 def begin(name: str, **args):
-  """Token form of ``span`` for blocks where a ``with`` would force a
-  re-indent (the traced-forward sections).  Returns None disabled —
-  ``end(None)`` is a no-op, so call sites never branch."""
+  """Token form of ``span``, for a block that cannot be a ``with`` and
+  for a site whose stats counter wants the seconds ``end`` returns.
+  Disabled, the token is the bare start time: no span, no annotation,
+  and ``end`` still measures."""
   if not _enabled:
-    return None
+    return time.perf_counter()
   return _Span(name, args or None)
 
 
-def end(tok):
-  if tok is None or not _enabled:
-    return
+def end(tok) -> float:
+  """Close ``tok`` on the thread that opened it; returns the seconds
+  from ``begin`` to now — the one measurement the event, the profiler's
+  annotation and the caller's counter share."""
   t1 = time.perf_counter()
-  ev = {
-      'name': tok.name, 'cat': span_category(tok.name), 'ph': 'X',
-      'ts': (tok.t0 - _t0) * 1e6, 'dur': (t1 - tok.t0) * 1e6,
-      'pid': _pid,
-  }
-  if tok.args:
-    ev['args'] = tok.args
-  _emit(ev)
+  if not isinstance(tok, _Span):
+    return t1 - tok if tok is not None else 0.0
+  tok.ann.__exit__(None, None, None)
+  if _enabled:
+    ev = {
+        'name': tok.name, 'cat': span_category(tok.name), 'ph': 'X',
+        'ts': (tok.t0 - _t0) * 1e6, 'dur': (t1 - tok.t0) * 1e6,
+        'pid': _pid,
+    }
+    if tok.args:
+      ev['args'] = tok.args
+    _emit(ev)
+  return t1 - tok.t0
+
+
+_group_scope = threading.local()
+
+
+class phase(contextlib.ContextDecorator):
+  """Context manager (or decorator) naming a DEVICE phase: every
+  operation traced inside carries ``name`` in its name stack
+  (``jax.named_scope``), under a child scope ``name/<group>`` inside a
+  ``phase_group``.  Scopes whether or not the tracer is enabled; adds
+  no operation to the program.  The group is read when the scope opens,
+  so a decorated function picks up the group of each call."""
+
+  def __init__(self, name: str):
+    self.name = name
+
+  def _recreate_cm(self):
+    return phase(self.name)  # one scope object per call of a decorated fn
+
+  def __enter__(self):
+    import jax
+    group = getattr(_group_scope, 'name', None)
+    self._scope = jax.named_scope(
+        f'{self.name}/{group}' if group else self.name)
+    self._scope.__enter__()
+    return self
+
+  def __exit__(self, *exc):
+    return self._scope.__exit__(*exc)
+
+
+_TRANSFORM = re.compile(r'^(?:transpose|jvp|vmap)\((.*)\)$')
+_GROUP = re.compile(r'^g\d+$')  # what ``phase_group`` callers pass
+_PHASE_PATHS = [tuple(p.split('/')) for p in REGISTERED_PHASES]
+
+
+@functools.lru_cache(maxsize=None)   # a trace repeats each op every step
+def phase_of(op_name: str):
+  """The registered phase an operation belongs to, read off its name
+  stack (HLO ``op_name``, the profiler's ``tf_op``): ``(phase, child)``
+  for the INNERMOST registered phase in the path, ``child`` the table
+  group one level down (``g<index>``) or None; None when the path holds
+  no registered phase.  ``jvp(x)`` and ``transpose(jvp(x))`` unwrap to
+  ``x``: a phase's backward belongs to the phase."""
+  parts, depth, cur = [], 0, ''
+  for ch in op_name.rstrip(':'):
+    depth += (ch == '(') - (ch == ')')
+    if ch == '/' and depth == 0:
+      parts.append(cur)
+      cur = ''
+    else:
+      cur += ch
+  parts.append(cur)
+  path = []
+  for part in parts:
+    m = _TRANSFORM.match(part)
+    while m:
+      part = m.group(1)
+      m = _TRANSFORM.match(part)
+    # a function's name (``jit(head)``) is no scope
+    path += [part] if '(' in part else part.split('/')
+  for end in range(len(path), 0, -1):
+    for p in _PHASE_PATHS:
+      if end >= len(p) and tuple(path[end - len(p):end]) == p:
+        child = path[end] if end < len(path) else ''
+        return '/'.join(p), (child if _GROUP.match(child) else None)
+  return None
+
+
+@contextlib.contextmanager
+def phase_group(group: str):
+  """While open (on this thread), every ``phase`` opened gets the child
+  scope ``<phase>/<group>`` — the per-table-group level of the phase
+  table, set once by the loop that walks the plan's groups so the
+  optimizers' own ``phase`` sites need not know their group."""
+  prev = getattr(_group_scope, 'name', None)
+  _group_scope.name = group
+  try:
+    yield
+  finally:
+    _group_scope.name = prev
+
+
+@contextlib.contextmanager
+def profile(directory: str, path: Optional[str] = None):
+  """Capture one profile under ``directory``: enables the tracer (its
+  own file goes to ``path`` on ``save()``), starts the JAX profiler with
+  Python's call tracer off (it slows every host call severalfold) and
+  stops both on exit.  Read it with ``tools/trace_report.py --profile
+  <directory>``.
+
+  The phases of a compiled program are metadata of its executable, and
+  the persistent compile cache's key leaves metadata out: a program
+  served from a cache filled before its phases existed shows none.
+  Capture from an empty cache directory (docs/userguide.md)."""
+  import jax
+  options = jax.profiler.ProfileOptions()
+  options.python_tracer_level = 0
+  was_enabled = _enabled
+  enable(path)
+  jax.profiler.start_trace(directory, profiler_options=options)
+  try:
+    yield directory
+  finally:
+    jax.profiler.stop_trace()
+    if not was_enabled:
+      disable()
 
 
 def complete(name: str, start_s: float, dur_s: float,
              tid: Optional[int] = None, **args):
-  """Emit an already-measured interval (``start_s`` from ``now()``) —
-  the single-measurement contract: stats counters and the trace report
-  the same number."""
+  """Emit an interval measured elsewhere (``start_s`` from ``now()``)
+  into the tracer's own file: the devprof lane's offline measurements.
+  Host code that times itself uses ``begin``/``end``, which also reach
+  the profiler's trace."""
   if not _enabled:
     return
   ev = {
@@ -358,6 +546,7 @@ def async_span(name: str, span_id, start_s: float, end_s: float, **args):
     return
   base = {'name': name, 'cat': span_category(name), 'pid': _pid,
           'id': str(span_id)}
+  start_s = max(start_s, _t0)  # an interval begun before enable()
   b = dict(base, ph='b', ts=(start_s - _t0) * 1e6)
   if args:
     b['args'] = args

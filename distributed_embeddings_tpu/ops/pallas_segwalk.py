@@ -66,6 +66,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_embeddings_tpu.obs import trace as obs_trace
+
 # Test hook: engage the kernel in interpreter mode on any backend so
 # CI exercises the real producers.
 FORCE_INTERPRET = False
@@ -689,51 +691,52 @@ def segwalk_apply(table: jax.Array,
                              pair=pair,
                              sideband=sideband,
                              op=op)
-  outs = pl.pallas_call(
-      kernel,
-      grid=(num_tiles,),
-      in_specs=[
-          pl.BlockSpec((_SMEM_BLOCK,),
-                       lambda t, _tl=tile: ((t * _tl) // _SMEM_BLOCK,),
-                       memory_space=pltpu.SMEM),   # ids (scalar walks)
-          pl.BlockSpec((_SMEM_BLOCK,),
-                       lambda t, _tl=tile: ((t * _tl) // _SMEM_BLOCK,),
-                       memory_space=pltpu.SMEM),   # is_last (walks)
-          pl.BlockSpec((tile, 128 if sideband else kw), lambda t: (t, 0),
-                       memory_space=pltpu.VMEM),   # grads (+ id sideband)
-          (pl.BlockSpec(memory_space=pltpu.SMEM) if sideband else
-           pl.BlockSpec((tile, 1), lambda t: (t, 0),
-                        memory_space=pltpu.VMEM)),  # ids (vector, w=128)
-          pl.BlockSpec(memory_space=pltpu.SMEM),   # [lr, eps]
-          pl.BlockSpec(memory_space=pl.ANY),       # table
-          pl.BlockSpec(memory_space=pl.ANY),       # acc (or dummy)
-      ],
-      out_specs=[
-          pl.BlockSpec(memory_space=pl.ANY),
-          pl.BlockSpec(memory_space=pl.ANY),
-      ],
-      out_shape=[
-          jax.ShapeDtypeStruct(table_k.shape, table_k.dtype),
-          jax.ShapeDtypeStruct(acc_operand.shape, acc_operand.dtype),
-      ],
-      # REQUIRED for correctness, not just memory: rows the kernel never
-      # touches must retain their input values, which only the aliased
-      # output buffer provides
-      input_output_aliases={5: 0, 6: 1},
-      scratch_shapes=[
-          pltpu.VMEM(stage, table_k.dtype),        # tbuf (parity pair)
-          pltpu.VMEM(stage, acc_operand.dtype),    # abuf (parity pair)
-          pltpu.VMEM((2, pair * kw), jnp.float32),  # carry (sum, sum_sq)
-          pltpu.SMEM((1, 1), jnp.int32),           # carry id
-          pltpu.SMEM((2, 1), jnp.int32),           # in-flight write counts
-          pltpu.SemaphoreType.DMA,                 # read semaphore
-          pltpu.SemaphoreType.DMA((2,)),           # write semaphores
-      ],
-      compiler_params=pltpu.CompilerParams(
-          dimension_semantics=('arbitrary',)),
-      interpret=interpret,
-  )(sid1d, is_last, g_operand, idv_operand, lr_arr, table_k,
-    acc_operand)
+  with obs_trace.phase('apply/update'):
+    outs = pl.pallas_call(
+        kernel,
+        grid=(num_tiles,),
+        in_specs=[
+            pl.BlockSpec((_SMEM_BLOCK,),
+                         lambda t, _tl=tile: ((t * _tl) // _SMEM_BLOCK,),
+                         memory_space=pltpu.SMEM),   # ids (scalar walks)
+            pl.BlockSpec((_SMEM_BLOCK,),
+                         lambda t, _tl=tile: ((t * _tl) // _SMEM_BLOCK,),
+                         memory_space=pltpu.SMEM),   # is_last (walks)
+            pl.BlockSpec((tile, 128 if sideband else kw), lambda t: (t, 0),
+                         memory_space=pltpu.VMEM),   # grads (+ id sideband)
+            (pl.BlockSpec(memory_space=pltpu.SMEM) if sideband else
+             pl.BlockSpec((tile, 1), lambda t: (t, 0),
+                          memory_space=pltpu.VMEM)),  # ids (vector, w=128)
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # [lr, eps]
+            pl.BlockSpec(memory_space=pl.ANY),       # table
+            pl.BlockSpec(memory_space=pl.ANY),       # acc (or dummy)
+        ],
+        out_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(table_k.shape, table_k.dtype),
+            jax.ShapeDtypeStruct(acc_operand.shape, acc_operand.dtype),
+        ],
+        # REQUIRED for correctness, not just memory: rows the kernel never
+        # touches must retain their input values, which only the aliased
+        # output buffer provides
+        input_output_aliases={5: 0, 6: 1},
+        scratch_shapes=[
+            pltpu.VMEM(stage, table_k.dtype),        # tbuf (parity pair)
+            pltpu.VMEM(stage, acc_operand.dtype),    # abuf (parity pair)
+            pltpu.VMEM((2, pair * kw), jnp.float32),  # carry (sum, sum_sq)
+            pltpu.SMEM((1, 1), jnp.int32),           # carry id
+            pltpu.SMEM((2, 1), jnp.int32),           # in-flight write counts
+            pltpu.SemaphoreType.DMA,                 # read semaphore
+            pltpu.SemaphoreType.DMA((2,)),           # write semaphores
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=interpret,
+    )(sid1d, is_last, g_operand, idv_operand, lr_arr, table_k,
+      acc_operand)
   new_table, new_acc = outs[0], outs[1]
   if pair == 2:
     new_table = new_table.reshape(prows, kw)

@@ -561,7 +561,7 @@ class StateAuditor:
     # rank reaches this cadence point or the mesh was already split
     # (design §22)
     commsan.record('audit/run', audit=self.audits)
-    t0 = time.perf_counter()
+    tok = obs_trace.begin('audit/check', step=step)
     findings: List[AuditFinding] = []
     leaves = self._collect_leaves(params, opt_state)
     if leaves:
@@ -610,8 +610,7 @@ class StateAuditor:
     self.findings_total += len(findings)
     # ONE measurement feeds both the span and the histogram (the
     # trace-vs-stats agreement contract, obs/trace.py)
-    call_ms = (time.perf_counter() - t0) * 1000.0
-    obs_trace.complete('audit/check', t0, call_ms / 1000.0, step=step)
+    call_ms = obs_trace.end(tok) * 1000.0
     obs_metrics.inc('audit.calls')
     obs_metrics.observe('audit.call_ms', call_ms)
     if findings:

@@ -1215,13 +1215,11 @@ def save_train_npz(path: str,
   """
   # ONE measurement feeds both the span and the histogram (the
   # trace-vs-stats agreement contract, obs/trace.py)
-  t0 = obs_trace.now()
+  tok = obs_trace.begin('ckpt/save', path=os.path.basename(path))
   try:
     _save_train_npz(path, weights, table_states, extras, plan)
   finally:
-    save_ms = (obs_trace.now() - t0) * 1000.0
-    obs_trace.complete('ckpt/save', t0, save_ms / 1000.0,
-                       path=os.path.basename(path))
+    save_ms = obs_trace.end(tok) * 1000.0
   obs_metrics.inc('ckpt.saves')
   obs_metrics.observe('ckpt.save_ms', save_ms)
   # the periodic save is a natural rank-uniform barrier: cross-check
@@ -1376,13 +1374,11 @@ def restore_train_state(dist: DistributedEmbedding, state, source: str,
 
   Returns ``(state, path)`` — the restored state and the file used.
   """
-  t0 = obs_trace.now()
+  tok = obs_trace.begin('ckpt/restore', source=os.path.basename(source))
   try:
     out = _restore_train_state(dist, state, source, quarantine)
   finally:
-    restore_ms = (obs_trace.now() - t0) * 1000.0
-    obs_trace.complete('ckpt/restore', t0, restore_ms / 1000.0,
-                       source=os.path.basename(source))
+    restore_ms = obs_trace.end(tok) * 1000.0
   obs_metrics.inc('ckpt.restores')
   obs_metrics.observe('ckpt.restore_ms', restore_ms)
   # record WITHOUT a barrier check: a restore can legitimately run on

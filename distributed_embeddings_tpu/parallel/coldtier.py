@@ -634,12 +634,10 @@ class ColdFetchPipeline:
         for cats in cats_iter:
           if stop.is_set():
             return
-          t0 = time.perf_counter()
           tok = obs_trace.begin('coldtier/prepass')
           prepped, _, _ = dist._prepare_inputs(list(cats))
           rows = compute_fetch_rows(dist, prepped)
-          obs_trace.end(tok)
-          prepass_ms = (time.perf_counter() - t0) * 1000.0
+          prepass_ms = obs_trace.end(tok) * 1000.0
           live = ref()
           if live is not None:
             live._overlap.add_build(prepass_ms)
@@ -660,17 +658,18 @@ class ColdFetchPipeline:
     return self
 
   def __next__(self):
-    t0 = time.perf_counter()
-    while True:
-      try:
-        item = self._q.get(timeout=0.1)
-        break
-      except queue.Empty:
-        if self._stop.is_set():
-          raise StopIteration from None
-    blocked_ms = (time.perf_counter() - t0) * 1000.0
+    tok = obs_trace.begin('coldtier/wait')
+    try:
+      while True:
+        try:
+          item = self._q.get(timeout=0.1)
+          break
+        except queue.Empty:
+          if self._stop.is_set():
+            raise StopIteration from None
+    finally:
+      blocked_ms = obs_trace.end(tok) * 1000.0
     self._overlap.add_blocked(blocked_ms)
-    obs_trace.complete('coldtier/wait', t0, blocked_ms / 1000.0)
     obs_metrics.observe('coldtier.blocked_ms', blocked_ms)
     if item is None:
       if self._err_box:

@@ -334,7 +334,6 @@ class CsrFeed:
           return True
         self._cursor = (seq, item)
       try:
-        t0 = time.perf_counter()
         tok = obs_trace.begin('feed/build', seq=seq)
         try:
           csrs = self._retry(
@@ -347,8 +346,7 @@ class CsrFeed:
           # a FAILED build still emits its span: the retry-inclusive
           # wall of a poison batch is exactly what stall attribution
           # must not lose when the feed misbehaves
-          obs_trace.end(tok)
-        build_ms = (time.perf_counter() - t0) * 1000.0
+          build_ms = obs_trace.end(tok) * 1000.0
         obs_metrics.observe('feed.build_ms', build_ms)
       except Exception as e:  # poison batch (or exhausted retries)
         if self._on_batch_error == 'skip':
@@ -395,7 +393,26 @@ class CsrFeed:
   def __next__(self) -> FedBatch:
     if self._closed:
       raise StopIteration
-    t0 = time.perf_counter()
+    # the wait that followed batch ``after``; ONE measurement feeds the
+    # span, the profiler's annotation and the histogram
+    tok = obs_trace.begin('feed/wait', after=self._last_seq)
+    try:
+      msg = self._next_message()
+    finally:
+      blocked_ms = obs_trace.end(tok) * 1000.0
+    obs_metrics.observe('feed.blocked_ms', blocked_ms)
+    obs_metrics.inc('feed.batches')
+    if self._queue_source is not None:
+      obs_metrics.set_gauge('feed.queue_depth', self._queue_source.qsize())
+    self._last_seq = msg.seq
+    self._overlap.count_batch()
+    self._overlap.add_build(msg.fed.build_ms)
+    self._overlap.add_blocked(blocked_ms)
+    return msg.fed
+
+  def _next_message(self):
+    """Block until the ring yields the next undelivered batch; raises
+    what the producer raised, ``StopIteration`` at the end."""
     while True:
       try:
         msg = self._ring.get(timeout=0.1)
@@ -427,18 +444,7 @@ class CsrFeed:
         raise msg.exc
       if msg.seq <= self._last_seq:
         continue  # duplicate re-built after a respawn: already delivered
-      break
-    blocked_ms = (time.perf_counter() - t0) * 1000.0
-    obs_trace.complete('feed/wait', t0, blocked_ms / 1000.0, seq=msg.seq)
-    obs_metrics.observe('feed.blocked_ms', blocked_ms)
-    obs_metrics.inc('feed.batches')
-    if self._queue_source is not None:
-      obs_metrics.set_gauge('feed.queue_depth', self._queue_source.qsize())
-    self._last_seq = msg.seq
-    self._overlap.count_batch()
-    self._overlap.add_build(msg.fed.build_ms)
-    self._overlap.add_blocked(blocked_ms)
-    return msg.fed
+      return msg
 
   def __enter__(self):
     return self

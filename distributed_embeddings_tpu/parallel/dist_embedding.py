@@ -541,6 +541,7 @@ class DistributedEmbedding:
     # until its function's first call
     self._lookup_plans: Dict[Any, Any] = {}
 
+  @obs_trace.phase('fwd/lookup_combine')
   def _lookup(self, table: jax.Array, routed: jax.Array,
               combiner: Optional[str], pack: int = 1,
               scale: Optional[jax.Array] = None) -> jax.Array:
@@ -1338,8 +1339,9 @@ class DistributedEmbedding:
       for m, inp in enumerate(sub.merge_inputs):
         partial = out_ext[mslot[m]]  # [GB, w]; zeros when not an owner
         if D > 1:
-          partial = jax.lax.psum_scatter(partial, self.axis_name,
-                                         scatter_dimension=0, tiled=True)
+          with obs_trace.phase('fwd/exchange'):
+            partial = jax.lax.psum_scatter(partial, self.axis_name,
+                                           scatter_dimension=0, tiled=True)
         merge_out[(si, inp)] = partial  # [B, w], already summed
       if not sub.out_n_cap:
         return None
@@ -1463,6 +1465,13 @@ class DistributedEmbedding:
     ledger rows and commlint emission all report wire truth by
     construction), and the collective count is untouched.
     """
+    scope = (obs_trace.phase('bwd/exchange') if name.startswith('bwd/')
+             else obs_trace.phase('fwd/exchange'))
+    with scope:
+      return self._exchange_legs(bufs, name, plan, axis)
+
+  def _exchange_legs(self, bufs, name, plan, axis):
+    """``_exchange`` inside its phase scope."""
     axis = axis or self.axis_name
     D = self.mesh.shape[axis]
     out = list(bufs)
@@ -1589,22 +1598,23 @@ class DistributedEmbedding:
       # (dev, s) holds the ids destined for device dev's s-th request of
       # the class; distinct inputs are traced once and slots select
       # statically (_gather_slots) ----
-      sends = []
-      for sub in subs:
-        h = sub.hotness
+      with obs_trace.phase('fwd/route'):
+        sends = []
+        for sub in subs:
+          h = sub.hotness
 
-        def _ids(k, h=h):
-          if k == -1:
-            return jnp.full((local_batch, h), _SENTINEL, jnp.int32)
-          x = inputs[k]
-          x = x[:, None] if x.ndim == 1 else x
-          return x.astype(jnp.int32)
+          def _ids(k, h=h):
+            if k == -1:
+              return jnp.full((local_batch, h), _SENTINEL, jnp.int32)
+            x = inputs[k]
+            x = x[:, None] if x.ndim == 1 else x
+            return x.astype(jnp.int32)
 
-        sends.append(_gather_slots(
-            D, sub.n_cap,
-            lambda dev, s, sub=sub: (sub.requests[dev][s].input_id
-                                     if s < len(sub.requests[dev]) else -1),
-            _ids))
+          sends.append(_gather_slots(
+              D, sub.n_cap,
+              lambda dev, s, sub=sub: (sub.requests[dev][s].input_id
+                                       if s < len(sub.requests[dev]) else -1),
+              _ids))
       routed_parts = [[] for _ in subs]
       back_parts = [[] for _ in subs]
 
@@ -1625,26 +1635,28 @@ class DistributedEmbedding:
             continue
           lo, hi = bounds[si][k]
           h = sub.hotness
-          # [n_cap, D*B, h]: the slice's batch in source-major order
-          # (the reference's [world_size * local] reshape, :405-410)
-          ids_c = recvs[si].transpose(1, 0, 2, 3).reshape(
-              hi - lo, slice_batch, h)
-          rows_cap = self.plan.groups[sub.gi].rows_cap
-          routed_c = _route_ids(
-              ids_c, jnp.asarray(sub.offsets)[me, lo:hi],
-              jnp.asarray(sub.vocab)[me, lo:hi], rows_cap,
-              jnp.asarray(sub.row_lo)[me, lo:hi],
-              jnp.asarray(sub.row_hi)[me, lo:hi],
-              (jnp.asarray(sub.row_stride)[me, lo:hi]
-               if sub.has_mod_windows else None))
+          with obs_trace.phase('fwd/route'):
+            # [n_cap, D*B, h]: the slice's batch in source-major order
+            # (the reference's [world_size * local] reshape, :405-410)
+            ids_c = recvs[si].transpose(1, 0, 2, 3).reshape(
+                hi - lo, slice_batch, h)
+            rows_cap = self.plan.groups[sub.gi].rows_cap
+            routed_c = _route_ids(
+                ids_c, jnp.asarray(sub.offsets)[me, lo:hi],
+                jnp.asarray(sub.vocab)[me, lo:hi], rows_cap,
+                jnp.asarray(sub.row_lo)[me, lo:hi],
+                jnp.asarray(sub.row_hi)[me, lo:hi],
+                (jnp.asarray(sub.row_stride)[me, lo:hi]
+                 if sub.has_mod_windows else None))
           routed_parts[si].append(routed_c)
           if self.dcn_sharding:
             hier.append((si, sub, routed_c, ids_c))
             continue
-          out_c = self._lookup(params[f'group_{sub.gi}'][0], routed_c,
-                               sub.lookup_combiner,
-                               pack=self.plan.groups[sub.gi].storage_pack,
-                               scale=self._scale_of(params, sub.gi))
+          with obs_trace.phase_group(f'g{sub.gi}'):
+            out_c = self._lookup(params[f'group_{sub.gi}'][0], routed_c,
+                                 sub.lookup_combiner,
+                                 pack=self.plan.groups[sub.gi].storage_pack,
+                                 scale=self._scale_of(params, sub.gi))
           staged[si] = (out_c, ids_c)
         if hier:
           # gather stage, hierarchical override (§20): every subgroup's
@@ -1659,57 +1671,49 @@ class DistributedEmbedding:
           if staged[si] is None:
             continue
           out_c, ids_c = staged[si]
-          if sub.mean_row_sliced:
-            # mean row shards look up with 'sum'; divide by the TRUE
-            # per-sample id count HERE, where the full raw ids are in
-            # hand (each owner received them all) - the divided
-            # partials then simply sum at assembly
-            out_c = out_c / _valid_count(ids_c)[..., None].astype(
-                out_c.dtype)
-          if n_rounds == 1:
-            pre[si] = self._emit_outputs(sub, si, out_c, me, local_batch,
-                                         merge_out)
-          else:
-            lo, hi = bounds[si][k]
-            pre[si] = out_c.reshape(hi - lo, D, local_batch,
-                                    sub.group.width).transpose(1, 0, 2, 3)
+          with (obs_trace.phase_group(f'g{sub.gi}'),
+                obs_trace.phase('fwd/lookup_combine')):
+            if sub.mean_row_sliced:
+              # mean row shards look up with 'sum'; divide by the TRUE
+              # per-sample id count HERE, where the full raw ids are in
+              # hand (each owner received them all) - the divided
+              # partials then simply sum at assembly
+              out_c = out_c / _valid_count(ids_c)[..., None].astype(
+                  out_c.dtype)
+            if n_rounds == 1:
+              pre[si] = self._emit_outputs(sub, si, out_c, me, local_batch,
+                                           merge_out)
+            else:
+              lo, hi = bounds[si][k]
+              pre[si] = out_c.reshape(hi - lo, D, local_batch,
+                                      sub.group.width).transpose(1, 0, 2, 3)
         # exchange stage, mp->dp leg (reference 'out_mp_to_dp', :434)
         backs = self._exchange(pre, 'fwd/rows', plan=lplan)
         for si in range(len(subs)):
           if backs[si] is not None:
             back_parts[si].append(backs[si])
 
-      if n_rounds == 1:
-        tok = obs_trace.begin('fwd/exchange')
-        recvs = issue(0)
-        obs_trace.end(tok)
-        tok = obs_trace.begin('fwd/lookup_combine')
-        process(0, recvs)
-        obs_trace.end(tok)
-      else:
-        # one 'fwd/exchange' span over the whole software-pipelined
-        # chunk loop: exchange and lookup/combine legs interleave by
-        # design, so they are not separable phases here (trace-time
-        # span — obs/trace.py; zero ops inserted either way)
-        tok = obs_trace.begin('fwd/exchange', chunks=n_rounds)
-        pending = None
-        for k in range(n_rounds):
-          recvs = issue(k)
-          if pending is not None:
-            process(*pending)
-          pending = (k, recvs)
-        process(*pending)
-        obs_trace.end(tok)
-      sub_back, residuals = [], []
-      for si in range(len(subs)):
-        bp = back_parts[si]
-        sub_back.append(None if not bp else
-                        (bp[0] if len(bp) == 1
-                         else jnp.concatenate(bp, axis=1)))
-        rp = routed_parts[si]
-        residuals.append((rp[0] if len(rp) == 1
-                          else jnp.concatenate(rp, axis=0))[None])
-      outs = self._assemble(subs, sub_back, merge_out)
+      # round k's collective is issued before round k-1's
+      # route/gather/return leg is traced, so the legs of the chunk
+      # loop interleave; each carries its own phase
+      pending = None
+      for k in range(n_rounds):
+        recvs = issue(k)
+        if pending is not None:
+          process(*pending)
+        pending = (k, recvs)
+      process(*pending)
+      with obs_trace.phase('fwd/exchange'):
+        sub_back, residuals = [], []
+        for si in range(len(subs)):
+          bp = back_parts[si]
+          sub_back.append(None if not bp else
+                          (bp[0] if len(bp) == 1
+                           else jnp.concatenate(bp, axis=1)))
+          rp = routed_parts[si]
+          residuals.append((rp[0] if len(rp) == 1
+                            else jnp.concatenate(rp, axis=0))[None])
+        outs = self._assemble(subs, sub_back, merge_out)
       if with_residuals:
         return outs + tuple(residuals)
       return outs
@@ -1767,11 +1771,12 @@ class DistributedEmbedding:
         x = x[:, None] if x.ndim == 1 else x
         return x.astype(jnp.int32)
 
-      stacked = _gather_slots(
-          D, sub.n_cap,
-          lambda dev, s: (pos_of[(dev, sub.requests[dev][s].input_id)]
-                          if s < len(sub.requests[dev]) else -1),
-          _ids)
+      with obs_trace.phase('fwd/route'):
+        stacked = _gather_slots(
+            D, sub.n_cap,
+            lambda dev, s: (pos_of[(dev, sub.requests[dev][s].input_id)]
+                            if s < len(sub.requests[dev]) else -1),
+            _ids)
       return jax.lax.with_sharding_constraint(
           stacked,
           NamedSharding(self.mesh,
@@ -1784,27 +1789,31 @@ class DistributedEmbedding:
       residuals = []
       pre = []
       for si, (sub, canon) in enumerate(zip(subs, canonicals)):
-        ids = canon[0]  # [n_cap, GB, h]
-        rows_cap = self.plan.groups[sub.gi].rows_cap
-        routed = _route_ids(ids, jnp.asarray(sub.offsets)[me],
-                            jnp.asarray(sub.vocab)[me], rows_cap,
-                            jnp.asarray(sub.row_lo)[me],
-                            jnp.asarray(sub.row_hi)[me],
-                            (jnp.asarray(sub.row_stride)[me]
-                             if sub.has_mod_windows else None))
-        out = self._lookup(params[f'group_{sub.gi}'][0], routed,
-                           sub.lookup_combiner,
-                           pack=self.plan.groups[sub.gi].storage_pack,
-                           scale=self._scale_of(params, sub.gi))
-        if sub.mean_row_sliced:
-          # owner-side division by the true count (see the dp path)
-          out = out / _valid_count(ids)[..., None].astype(out.dtype)
-        residuals.append(routed[None])
-        pre.append(self._emit_outputs(sub, si, out, me, local_batch,
-                                      merge_out))
+        with obs_trace.phase('fwd/route'):
+          ids = canon[0]  # [n_cap, GB, h]
+          rows_cap = self.plan.groups[sub.gi].rows_cap
+          routed = _route_ids(ids, jnp.asarray(sub.offsets)[me],
+                              jnp.asarray(sub.vocab)[me], rows_cap,
+                              jnp.asarray(sub.row_lo)[me],
+                              jnp.asarray(sub.row_hi)[me],
+                              (jnp.asarray(sub.row_stride)[me]
+                               if sub.has_mod_windows else None))
+        with obs_trace.phase_group(f'g{sub.gi}'):
+          out = self._lookup(params[f'group_{sub.gi}'][0], routed,
+                             sub.lookup_combiner,
+                             pack=self.plan.groups[sub.gi].storage_pack,
+                             scale=self._scale_of(params, sub.gi))
+          with obs_trace.phase('fwd/lookup_combine'):
+            if sub.mean_row_sliced:
+              # owner-side division by the true count (see the dp path)
+              out = out / _valid_count(ids)[..., None].astype(out.dtype)
+            residuals.append(routed[None])
+            pre.append(self._emit_outputs(sub, si, out, me, local_batch,
+                                          merge_out))
       # the mp path has no dp->mp leg; only the return exchange fuses
       sub_back = self._exchange(pre, 'fwd/rows', plan=lplan)
-      outs = self._assemble(subs, sub_back, merge_out)
+      with obs_trace.phase('fwd/exchange'):
+        outs = self._assemble(subs, sub_back, merge_out)
       if with_residuals:
         return outs + tuple(residuals)
       return outs
@@ -1968,36 +1977,35 @@ class DistributedEmbedding:
     def local_fn(*d_outs):
       lplan.legs.clear()
       me = jax.lax.axis_index(self.axis_name)
-      # trace-time span (obs/trace.py): the whole cotangent exchange
-      tok = obs_trace.begin('bwd/exchange')
       dt = d_outs[0].dtype
       # --- route stage: canonical cotangent send buffers.  Distinct
       # (input, column range) cotangent slices are traced once and
       # slots select statically (_gather_slots).  all_to_all is
       # self-transpose, so the forward's return leg transposes by the
       # same exchange. ---
-      sends = []
-      for si, sub in enumerate(subs):
-        if not slots_of[si]:
-          sends.append(None)
-          continue
-        w = sub.group.width
-        sel = sub.out_sel if sub.merge_inputs else None
+      with obs_trace.phase('bwd/route'):
+        sends = []
+        for si, sub in enumerate(subs):
+          if not slots_of[si]:
+            sends.append(None)
+            continue
+          w = sub.group.width
+          sel = sub.out_sel if sub.merge_inputs else None
 
-        def key_of(dev, p, sub=sub, sel=sel):
-          rs = sub.requests[dev]
-          s = int(sel[dev, p]) if sel is not None else p
-          if s < len(rs):
-            r = rs[s]
-            return (r.input_id, r.col_start, r.col_end)
-          return -1
+          def key_of(dev, p, sub=sub, sel=sel):
+            rs = sub.requests[dev]
+            s = int(sel[dev, p]) if sel is not None else p
+            if s < len(rs):
+              r = rs[s]
+              return (r.input_id, r.col_start, r.col_end)
+            return -1
 
-        def val_of(k, w=w):
-          if k == -1:
-            return jnp.zeros((local_batch, w), dt)
-          return d_outs[k[0]][:, k[1]:k[2]]
+          def val_of(k, w=w):
+            if k == -1:
+              return jnp.zeros((local_batch, w), dt)
+            return d_outs[k[0]][:, k[1]:k[2]]
 
-        sends.append(_gather_slots(D, slots_of[si], key_of, val_of))
+          sends.append(_gather_slots(D, slots_of[si], key_of, val_of))
       # --- exchange stage: ONE fused cotangent all_to_all per chunk
       # round (design §11 x §21: chunk rounds split the FUSED buffer
       # along the slot axis into independent collectives the scheduler
@@ -2012,47 +2020,47 @@ class DistributedEmbedding:
         for si in range(len(subs)):
           if recvs[si] is not None:
             recv_parts[si].append(recvs[si])
-      gsubs = []
-      for si, sub in enumerate(subs):
-        w = sub.group.width
-        drecv = None
-        if slots_of[si]:
-          rp = recv_parts[si]
-          drecv = rp[0] if len(rp) == 1 else jnp.concatenate(rp, axis=1)
-          drecv = drecv.transpose(1, 0, 2, 3).reshape(
-              slots_of[si], slice_batch, w)
-        if not sub.merge_inputs:
-          gsubs.append(drecv[None])
-          continue
-        # Row-shard slots: every owner needs the FULL [GB, w] cotangent
-        # (transpose of the forward psum_scatter) — ONE all_gather per
-        # merged input, shared by all its owners, instead of one a2a
-        # slot per shard.  Reconstruct the per-slot [n_cap, GB, w] grads
-        # by a per-device static index into the concatenated sources.
-        M = len(sub.merge_inputs)
-        parts = []
-        if sub.out_n_cap:
-          parts.append(drecv)
-        for inp in sub.merge_inputs:
-          dloc = d_outs[inp]  # [B, w]: row shards span the full width
-          g_full = (jax.lax.all_gather(dloc, self.axis_name, axis=0,
-                                       tiled=True) if D > 1 else dloc)
-          parts.append(g_full[None].astype(dt))
-        parts.append(jnp.zeros((1, slice_batch, w), dt))
-        cat = jnp.concatenate(parts, axis=0)
-        zero_row = sub.out_n_cap + M
-        recon = np.full((D, sub.n_cap), zero_row, np.int32)
-        for dev, rs in enumerate(sub.requests):
-          for s, r in enumerate(rs):
-            pos = sub.out_pos.get((dev, s))
-            if pos is not None:
-              recon[dev, s] = pos
-            else:
-              recon[dev, s] = sub.out_n_cap + sub.merge_inputs.index(
-                  r.input_id)
-        g = cat[jnp.asarray(recon)[me]]
-        gsubs.append(g[None])
-      obs_trace.end(tok)
+      with obs_trace.phase('bwd/exchange'):
+        gsubs = []
+        for si, sub in enumerate(subs):
+          w = sub.group.width
+          drecv = None
+          if slots_of[si]:
+            rp = recv_parts[si]
+            drecv = rp[0] if len(rp) == 1 else jnp.concatenate(rp, axis=1)
+            drecv = drecv.transpose(1, 0, 2, 3).reshape(
+                slots_of[si], slice_batch, w)
+          if not sub.merge_inputs:
+            gsubs.append(drecv[None])
+            continue
+          # Row-shard slots: every owner needs the FULL [GB, w] cotangent
+          # (transpose of the forward psum_scatter) — ONE all_gather per
+          # merged input, shared by all its owners, instead of one a2a
+          # slot per shard.  Reconstruct the per-slot [n_cap, GB, w] grads
+          # by a per-device static index into the concatenated sources.
+          M = len(sub.merge_inputs)
+          parts = []
+          if sub.out_n_cap:
+            parts.append(drecv)
+          for inp in sub.merge_inputs:
+            dloc = d_outs[inp]  # [B, w]: row shards span the full width
+            g_full = (jax.lax.all_gather(dloc, self.axis_name, axis=0,
+                                         tiled=True) if D > 1 else dloc)
+            parts.append(g_full[None].astype(dt))
+          parts.append(jnp.zeros((1, slice_batch, w), dt))
+          cat = jnp.concatenate(parts, axis=0)
+          zero_row = sub.out_n_cap + M
+          recon = np.full((D, sub.n_cap), zero_row, np.int32)
+          for dev, rs in enumerate(sub.requests):
+            for s, r in enumerate(rs):
+              pos = sub.out_pos.get((dev, s))
+              if pos is not None:
+                recon[dev, s] = pos
+              else:
+                recon[dev, s] = sub.out_n_cap + sub.merge_inputs.index(
+                    r.input_id)
+          g = cat[jnp.asarray(recon)[me]]
+          gsubs.append(g[None])
       return tuple(gsubs)
 
     fn = jax.jit(
@@ -2201,32 +2209,34 @@ class DistributedEmbedding:
       lplan.legs.clear()
       me = jax.lax.axis_index(self.axis_name)
       # hot_split stage (design §21): hot ids leave the exchange here
-      mem = self._hot_membership(inputs, hotness)
+      with obs_trace.phase('fwd/route'):
+        mem = self._hot_membership(inputs, hotness)
       piece: Dict[tuple, Any] = {}
       residuals = []
       routing_aux = []
       # --- route stage: per-subgroup deduplicated cold send buffers.
       # Sort-unique per (dest device, slot): each distinct cold row
       # crosses the wire once; inv maps every occurrence back ---
-      sends, invs = [], []
-      for sub in subs:
-        h = sub.hotness
-        U = local_batch * h
+      with obs_trace.phase('fwd/route'):
+        sends, invs = [], []
+        for sub in subs:
+          h = sub.hotness
+          U = local_batch * h
 
-        def _cold(k, h=h):
-          if k == -1:
-            return jnp.full((local_batch, h), _SENTINEL, jnp.int32)
-          return mem[k]['cold']
+          def _cold(k, h=h):
+            if k == -1:
+              return jnp.full((local_batch, h), _SENTINEL, jnp.int32)
+            return mem[k]['cold']
 
-        send = _gather_slots(
-            D, sub.n_cap,
-            lambda dev, s, sub=sub: (sub.requests[dev][s].input_id
-                                     if s < len(sub.requests[dev]) else -1),
-            _cold)
-        uniq, inv = _unique_with_inverse(
-            send.reshape(D * sub.n_cap, U), U)
-        sends.append(uniq.reshape(D, sub.n_cap, U))
-        invs.append(inv)
+          send = _gather_slots(
+              D, sub.n_cap,
+              lambda dev, s, sub=sub: (sub.requests[dev][s].input_id
+                                       if s < len(sub.requests[dev]) else -1),
+              _cold)
+          uniq, inv = _unique_with_inverse(
+              send.reshape(D * sub.n_cap, U), U)
+          sends.append(uniq.reshape(D, sub.n_cap, U))
+          invs.append(inv)
       routed_parts = [[] for _ in subs]
       comb_parts = [[] for _ in subs]
 
@@ -2243,22 +2253,23 @@ class DistributedEmbedding:
       def process(k, recvs):
         routed_c = [None] * len(subs)
         rows_c = [None] * len(subs)
-        for si, sub in enumerate(subs):
-          if k >= len(bounds[si]):
-            continue
-          lo, hi = bounds[si][k]
-          U = local_batch * sub.hotness
-          ids_c = recvs[si].transpose(1, 0, 2).reshape(hi - lo, D * U)
-          rc = _route_ids(ids_c[..., None],
-                          jnp.asarray(sub.offsets)[me, lo:hi],
-                          jnp.asarray(sub.vocab)[me, lo:hi],
-                          plan.groups[sub.gi].rows_cap,
-                          jnp.asarray(sub.row_lo)[me, lo:hi],
-                          jnp.asarray(sub.row_hi)[me, lo:hi],
-                          (jnp.asarray(sub.row_stride)[me, lo:hi]
-                           if sub.has_mod_windows else None))
-          routed_c[si] = rc
-          routed_parts[si].append(rc)
+        with obs_trace.phase('fwd/route'):
+          for si, sub in enumerate(subs):
+            if k >= len(bounds[si]):
+              continue
+            lo, hi = bounds[si][k]
+            U = local_batch * sub.hotness
+            ids_c = recvs[si].transpose(1, 0, 2).reshape(hi - lo, D * U)
+            rc = _route_ids(ids_c[..., None],
+                            jnp.asarray(sub.offsets)[me, lo:hi],
+                            jnp.asarray(sub.vocab)[me, lo:hi],
+                            plan.groups[sub.gi].rows_cap,
+                            jnp.asarray(sub.row_lo)[me, lo:hi],
+                            jnp.asarray(sub.row_hi)[me, lo:hi],
+                            (jnp.asarray(sub.row_stride)[me, lo:hi]
+                             if sub.has_mod_windows else None))
+            routed_c[si] = rc
+            routed_parts[si].append(rc)
         # gather stage: one row gather per distinct id (combiner=None ==
         # masked row fetch); out-of-window ids of row shards return
         # zero, so slot partials sum to the whole at the source.
@@ -2275,75 +2286,70 @@ class DistributedEmbedding:
         else:
           for si, sub in enumerate(subs):
             if routed_c[si] is not None:
-              rows_c[si] = self._make_cold_gather(
-                  params, fetch, sub.gi)(routed_c[si])
-        pre = [None] * len(subs)
-        for si, sub in enumerate(subs):
-          if rows_c[si] is None:
-            continue
-          lo, hi = bounds[si][k]
-          U = local_batch * sub.hotness
-          pre[si] = rows_c[si].reshape(hi - lo, D, U,
-                                       sub.group.width).transpose(
-                                           1, 0, 2, 3)
+              with obs_trace.phase_group(f'g{sub.gi}'):
+                rows_c[si] = self._make_cold_gather(
+                    params, fetch, sub.gi)(routed_c[si])
+        with obs_trace.phase('fwd/exchange'):
+          pre = [None] * len(subs)
+          for si, sub in enumerate(subs):
+            if rows_c[si] is None:
+              continue
+            lo, hi = bounds[si][k]
+            U = local_batch * sub.hotness
+            pre[si] = rows_c[si].reshape(hi - lo, D, U,
+                                         sub.group.width).transpose(
+                                             1, 0, 2, 3)
         # exchange stage, cold-row return leg (one fused a2a)
         backs = self._exchange(pre, 'fwd/cold_rows', plan=lplan)
         # combine stage: inverse-permutation scatter + h-axis fold
         for si, sub in enumerate(subs):
           if backs[si] is None:
             continue
-          lo, hi = bounds[si][k]
-          h = sub.hotness
-          U = local_batch * h
-          w = sub.group.width
-          back_c = backs[si]
-          rows_ext_c = jnp.concatenate(
-              [back_c, jnp.zeros((D, hi - lo, 1, w), back_c.dtype)],
-              axis=2)
-          inv3 = invs[si].reshape(D, sub.n_cap, U)
-          occ_c = jnp.take_along_axis(rows_ext_c,
-                                      inv3[:, lo:hi][..., None],
-                                      axis=2)
-          comb_parts[si].append(
-              jnp.sum(
-                  occ_c.reshape(D, hi - lo, local_batch, h, w).astype(
-                      jnp.float32), axis=3))
+          with (obs_trace.phase_group(f'g{sub.gi}'),
+                obs_trace.phase('fwd/lookup_combine')):
+            lo, hi = bounds[si][k]
+            h = sub.hotness
+            U = local_batch * h
+            w = sub.group.width
+            back_c = backs[si]
+            rows_ext_c = jnp.concatenate(
+                [back_c, jnp.zeros((D, hi - lo, 1, w), back_c.dtype)],
+                axis=2)
+            inv3 = invs[si].reshape(D, sub.n_cap, U)
+            occ_c = jnp.take_along_axis(rows_ext_c,
+                                        inv3[:, lo:hi][..., None],
+                                        axis=2)
+            comb_parts[si].append(
+                jnp.sum(
+                    occ_c.reshape(D, hi - lo, local_batch, h, w).astype(
+                        jnp.float32), axis=3))
 
-      if n_rounds == 1:
-        tok = obs_trace.begin('fwd/exchange')
-        recvs = issue(0)
-        obs_trace.end(tok)
-        tok = obs_trace.begin('fwd/lookup_combine')
-        process(0, recvs)
-        obs_trace.end(tok)
-      else:
-        # one 'fwd/exchange' trace-time span over the pipelined chunk
-        # loop (exchange and combine legs interleave by design): round
-        # k's fused a2a is issued before round k-1's
-        # gather/inverse-scatter/combine is traced
-        tok = obs_trace.begin('fwd/exchange', chunks=n_rounds)
-        pending = None
-        for k in range(n_rounds):
-          recvs = issue(k)
-          if pending is not None:
-            process(*pending)
-          pending = (k, recvs)
-        process(*pending)
-        obs_trace.end(tok)
+      # round k's fused a2a is issued before round k-1's
+      # gather/inverse-scatter/combine is traced, so the legs of the
+      # chunk loop interleave; each carries its own phase
+      pending = None
+      for k in range(n_rounds):
+        recvs = issue(k)
+        if pending is not None:
+          process(*pending)
+        pending = (k, recvs)
+      process(*pending)
 
       for si, sub in enumerate(subs):
-        if with_residuals:
-          rp = routed_parts[si]
-          residuals.append((rp[0] if len(rp) == 1
-                            else jnp.concatenate(rp, axis=0))[None])
-          routing_aux.append(invs[si][None])
-        cp = comb_parts[si]
-        comb = cp[0] if len(cp) == 1 else jnp.concatenate(cp, axis=1)
-        for dev in range(D):
-          for s, r in enumerate(sub.requests[dev]):
-            k = (r.input_id, r.col_start, r.col_end)
-            piece[k] = (comb[dev, s] if k not in piece
-                        else piece[k] + comb[dev, s])
+        with (obs_trace.phase_group(f'g{sub.gi}'),
+              obs_trace.phase('fwd/lookup_combine')):
+          if with_residuals:
+            rp = routed_parts[si]
+            residuals.append((rp[0] if len(rp) == 1
+                              else jnp.concatenate(rp, axis=0))[None])
+            routing_aux.append(invs[si][None])
+          cp = comb_parts[si]
+          comb = cp[0] if len(cp) == 1 else jnp.concatenate(cp, axis=1)
+          for dev in range(D):
+            for s, r in enumerate(sub.requests[dev]):
+              k = (r.input_id, r.col_start, r.col_end)
+              piece[k] = (comb[dev, s] if k not in piece
+                          else piece[k] + comb[dev, s])
 
       # hot partials: local gather from the replicated buffers
       for i, chunks in enumerate(meta['input_chunks']):
@@ -2351,32 +2357,35 @@ class DistributedEmbedding:
         if hotm is None:
           continue
         for gi, cs, ce, off in chunks:
-          buf = params[f'hot_group_{gi}']
-          ext = jnp.concatenate(
-              [buf, jnp.zeros((1, buf.shape[1]), buf.dtype)])
-          idx = jnp.where(hotm >= 0, off + hotm, buf.shape[0])
-          rows_h = ext[idx].astype(jnp.float32)
-          if self.quant is not None:
-            # quantized hot buffer: dequantize at the gather (§12)
-            hs = params[f'hot_scale_group_{gi}']
-            hs_ext = jnp.concatenate(
-                [hs, jnp.ones((1, 1), jnp.float32)])
-            rows_h = rows_h * hs_ext[idx]
-          hp = jnp.sum(rows_h, axis=1)
-          k = (i, cs, ce)
-          piece[k] = hp if k not in piece else piece[k] + hp
+          with (obs_trace.phase_group(f'g{gi}'),
+                obs_trace.phase('fwd/lookup_combine')):
+            buf = params[f'hot_group_{gi}']
+            ext = jnp.concatenate(
+                [buf, jnp.zeros((1, buf.shape[1]), buf.dtype)])
+            idx = jnp.where(hotm >= 0, off + hotm, buf.shape[0])
+            rows_h = ext[idx].astype(jnp.float32)
+            if self.quant is not None:
+              # quantized hot buffer: dequantize at the gather (§12)
+              hs = params[f'hot_scale_group_{gi}']
+              hs_ext = jnp.concatenate(
+                  [hs, jnp.ones((1, 1), jnp.float32)])
+              rows_h = rows_h * hs_ext[idx]
+            hp = jnp.sum(rows_h, axis=1)
+            k = (i, cs, ce)
+            piece[k] = hp if k not in piece else piece[k] + hp
 
-      outs = []
-      for i in range(self.num_inputs):
-        tid = plan.input_table_map[i]
-        ranges = sorted({(r.col_start, r.col_end)
-                         for r in plan.input_requests[i]})
-        parts = [piece[(i, cs, ce)] for cs, ce in ranges]
-        out = parts[0] if len(parts) == 1 else jnp.concatenate(parts,
-                                                               axis=-1)
-        if plan.table_configs[tid].combiner == 'mean':
-          out = out / _valid_count(mem[i]['x2'])[:, None]
-        outs.append(out.astype(self.compute_dtype))
+      with obs_trace.phase('fwd/lookup_combine'):
+        outs = []
+        for i in range(self.num_inputs):
+          tid = plan.input_table_map[i]
+          ranges = sorted({(r.col_start, r.col_end)
+                           for r in plan.input_requests[i]})
+          parts = [piece[(i, cs, ce)] for cs, ce in ranges]
+          out = parts[0] if len(parts) == 1 else jnp.concatenate(parts,
+                                                                 axis=-1)
+          if plan.table_configs[tid].combiner == 'mean':
+            out = out / _valid_count(mem[i]['x2'])[:, None]
+          outs.append(out.astype(self.compute_dtype))
       if with_residuals:
         return tuple(outs) + tuple(residuals) + tuple(routing_aux)
       return tuple(outs)
@@ -2433,10 +2442,15 @@ class DistributedEmbedding:
       return lambda routed: self._lookup(table, routed, None,
                                          pack=g.storage_pack, scale=scale)
     f = fetch[gi]
-    return lambda routed: _tiered_gather(
-        table, scale, routed, f['rows'][0], f['payload'][0],
-        f['scale'][0] if 'scale' in f else None, g.rows_cap,
-        self.compute_dtype)
+
+    def tiered(routed):
+      with obs_trace.phase('fwd/lookup_combine'):
+        return _tiered_gather(
+            table, scale, routed, f['rows'][0], f['payload'][0],
+            f['scale'][0] if 'scale' in f else None, g.rows_cap,
+            self.compute_dtype)
+
+    return tiered
 
   def _param_specs(self):
     """shard_map in_specs for the params pytree: fused group shards on
@@ -2521,19 +2535,24 @@ class DistributedEmbedding:
     most once per source slice — the dedup-at-the-boundary contract
     the §20 counters audit.
     """
-    pre = [self._hier_dcn_send(gi, uniq) for gi, uniq in items]
+    with obs_trace.phase('fwd/route'):
+      pre = [self._hier_dcn_send(gi, uniq) for gi, uniq in items]
     recvs = self._exchange([p[0] for p in pre], 'dcn/ids', plan=plan,
                            axis=self.dcn_axis)
-    rows = [self._hier_owner_rows(params, gi, recv)
-            for (gi, _), recv in zip(items, recvs)]
+    rows = []
+    for (gi, _), recv in zip(items, recvs):
+      with (obs_trace.phase_group(f'g{gi}'),
+            obs_trace.phase('fwd/lookup_combine')):
+        rows.append(self._hier_owner_rows(params, gi, recv))
     backs = self._exchange(rows, 'dcn/rows', plan=plan,
                            axis=self.dcn_axis)
-    out = []
-    for back, (_, owner, valid) in zip(backs, pre):
-      sel = jnp.broadcast_to(owner[None, ..., None].astype(jnp.int32),
-                             (1,) + owner.shape + (back.shape[-1],))
-      rows_u = jnp.take_along_axis(back, sel, axis=0)[0]
-      out.append(jnp.where(valid[..., None], rows_u, 0))
+    with obs_trace.phase('fwd/exchange'):
+      out = []
+      for back, (_, owner, valid) in zip(backs, pre):
+        sel = jnp.broadcast_to(owner[None, ..., None].astype(jnp.int32),
+                               (1,) + owner.shape + (back.shape[-1],))
+        rows_u = jnp.take_along_axis(back, sel, axis=0)[0]
+        out.append(jnp.where(valid[..., None], rows_u, 0))
     return out
 
   def _hier_fetch_unique(self, params, gi, uniq):
@@ -2552,30 +2571,33 @@ class DistributedEmbedding:
     flat.  ``pairs``: list of ``(sub, routed)`` with ``routed``
     ``[n_cap, GB, h]`` flat fused-space ids, sentinel ``rows_cap``.
     """
-    pre = []
-    for sub, routed in pairs:
-      rows_cap = self.plan.groups[sub.gi].rows_cap
-      n_cap, gb, h = routed.shape
-      vr = jnp.where(routed < rows_cap, routed, -1)
-      vr = vr.reshape(n_cap, gb * h).astype(jnp.int32)
-      uniq, inv = _unique_with_inverse(vr, gb * h)
-      pre.append((sub, routed, uniq, inv))
+    with obs_trace.phase('fwd/route'):
+      pre = []
+      for sub, routed in pairs:
+        rows_cap = self.plan.groups[sub.gi].rows_cap
+        n_cap, gb, h = routed.shape
+        vr = jnp.where(routed < rows_cap, routed, -1)
+        vr = vr.reshape(n_cap, gb * h).astype(jnp.int32)
+        uniq, inv = _unique_with_inverse(vr, gb * h)
+        pre.append((sub, routed, uniq, inv))
     fetched = self._hier_fetch_unique_many(
         params, [(sub.gi, uniq) for sub, _, uniq, _ in pre], plan=plan)
     outs = []
     for (sub, routed, uniq, inv), rows_u in zip(pre, fetched):
-      n_cap, gb, h = routed.shape
-      w = rows_u.shape[-1]
-      rows_ext = jnp.concatenate(
-          [rows_u, jnp.zeros((n_cap, 1, w), rows_u.dtype)], axis=1)
-      occ = jnp.take_along_axis(
-          rows_ext,
-          jnp.broadcast_to(inv[..., None], (n_cap, gb * h, w)), axis=1)
-      occ = occ.reshape(n_cap, gb, h, w)
-      mask = routed < self.plan.groups[sub.gi].rows_cap
-      tdt = jnp.float32 if self.quant is not None else occ.dtype
-      outs.append(_combine_rows(occ, mask, sub.lookup_combiner, tdt,
-                                self.compute_dtype))
+      with (obs_trace.phase_group(f'g{sub.gi}'),
+            obs_trace.phase('fwd/lookup_combine')):
+        n_cap, gb, h = routed.shape
+        w = rows_u.shape[-1]
+        rows_ext = jnp.concatenate(
+            [rows_u, jnp.zeros((n_cap, 1, w), rows_u.dtype)], axis=1)
+        occ = jnp.take_along_axis(
+            rows_ext,
+            jnp.broadcast_to(inv[..., None], (n_cap, gb * h, w)), axis=1)
+        occ = occ.reshape(n_cap, gb, h, w)
+        mask = routed < self.plan.groups[sub.gi].rows_cap
+        tdt = jnp.float32 if self.quant is not None else occ.dtype
+        outs.append(_combine_rows(occ, mask, sub.lookup_combiner, tdt,
+                                  self.compute_dtype))
     return outs
 
   def _hier_lookup(self, params, sub, routed):
@@ -2596,30 +2618,33 @@ class DistributedEmbedding:
     ``[n_cap, M, w]`` combiner-None rows in compute_dtype.
     ``items``: list of ``(gi, routed)``, ``routed`` ``[n_cap, M, 1]``.
     """
-    pre = []
-    for gi, routed in items:
-      rows_cap = self.plan.groups[gi].rows_cap
-      r = routed[..., 0]
-      n_cap, m = r.shape
-      vr = jnp.where(r < rows_cap, r, -1).astype(jnp.int32)
-      uniq, inv = _unique_with_inverse(vr, m)
-      pre.append((gi, r, uniq, inv))
+    with obs_trace.phase('fwd/route'):
+      pre = []
+      for gi, routed in items:
+        rows_cap = self.plan.groups[gi].rows_cap
+        r = routed[..., 0]
+        n_cap, m = r.shape
+        vr = jnp.where(r < rows_cap, r, -1).astype(jnp.int32)
+        uniq, inv = _unique_with_inverse(vr, m)
+        pre.append((gi, r, uniq, inv))
     fetched = self._hier_fetch_unique_many(
         params, [(gi, uniq) for gi, _, uniq, _ in pre], plan=plan)
     outs = []
     for (gi, r, uniq, inv), rows_u in zip(pre, fetched):
-      n_cap, m = r.shape
-      w = rows_u.shape[-1]
-      rows_ext = jnp.concatenate(
-          [rows_u, jnp.zeros((n_cap, 1, w), rows_u.dtype)], axis=1)
-      occ = jnp.take_along_axis(
-          rows_ext, jnp.broadcast_to(inv[..., None], (n_cap, m, w)),
-          axis=1)
-      tdt = jnp.float32 if self.quant is not None else occ.dtype
-      rows_cap = self.plan.groups[gi].rows_cap
-      outs.append(
-          _combine_rows(occ[:, :, None, :], (r < rows_cap)[:, :, None],
-                        None, tdt, self.compute_dtype))
+      with (obs_trace.phase_group(f'g{gi}'),
+            obs_trace.phase('fwd/lookup_combine')):
+        n_cap, m = r.shape
+        w = rows_u.shape[-1]
+        rows_ext = jnp.concatenate(
+            [rows_u, jnp.zeros((n_cap, 1, w), rows_u.dtype)], axis=1)
+        occ = jnp.take_along_axis(
+            rows_ext, jnp.broadcast_to(inv[..., None], (n_cap, m, w)),
+            axis=1)
+        tdt = jnp.float32 if self.quant is not None else occ.dtype
+        rows_cap = self.plan.groups[gi].rows_cap
+        outs.append(
+            _combine_rows(occ[:, :, None, :], (r < rows_cap)[:, :, None],
+                          None, tdt, self.compute_dtype))
     return outs
 
   def _hier_cold_gather(self, params, gi, routed):
@@ -2676,79 +2701,77 @@ class DistributedEmbedding:
 
     def local_fn(*args):
       lplan.legs.clear()
-      # trace-time span (obs/trace.py): the deduplicated cold-cotangent
-      # exchange + the replicated hot-grad psum
-      tok = obs_trace.begin('bwd/exchange')
       d_outs = args[:self.num_inputs]
       inputs = args[self.num_inputs:2 * self.num_inputs]
       routing = args[2 * self.num_inputs:]
-      mem = self._hot_membership(inputs, hotness)
-      cot = []
-      for i in range(self.num_inputs):
-        c = d_outs[i].astype(jnp.float32)
-        tid = plan.input_table_map[i]
-        if plan.table_configs[tid].combiner == 'mean':
-          c = c / _valid_count(mem[i]['x2'])[:, None]
-        cot.append(c)
+      with obs_trace.phase('bwd/route'):
+        mem = self._hot_membership(inputs, hotness)
+        cot = []
+        for i in range(self.num_inputs):
+          c = d_outs[i].astype(jnp.float32)
+          tid = plan.input_table_map[i]
+          if plan.table_configs[tid].combiner == 'mean':
+            c = c / _valid_count(mem[i]['x2'])[:, None]
+          cot.append(c)
 
-      grads = []
-      for si, sub in enumerate(subs):
-        h = sub.hotness
-        U = local_batch * h
-        w = sub.group.width
-        wc = 2 * w if with_sq else w
+        grads = []
+        for si, sub in enumerate(subs):
+          h = sub.hotness
+          U = local_batch * h
+          w = sub.group.width
+          wc = 2 * w if with_sq else w
 
-        if with_routing:
-          # residual-reuse (design §21): the forward's inverse
-          # permutation arrives as routing aux — no send gather, no
-          # re-sort
-          inv3 = routing[si][0].reshape(D, sub.n_cap, U)
-        else:
-          def _cold(k, h=h):
+          if with_routing:
+            # residual-reuse (design §21): the forward's inverse
+            # permutation arrives as routing aux — no send gather, no
+            # re-sort
+            inv3 = routing[si][0].reshape(D, sub.n_cap, U)
+          else:
+            def _cold(k, h=h):
+              if k == -1:
+                return jnp.full((local_batch, h), _SENTINEL, jnp.int32)
+              return mem[k]['cold']
+
+            send = _gather_slots(
+                D, sub.n_cap,
+                lambda dev, s, sub=sub: (sub.requests[dev][s].input_id
+                                         if s < len(sub.requests[dev])
+                                         else -1),
+                _cold)
+            _, inv = _unique_with_inverse(send.reshape(D * sub.n_cap, U),
+                                          U)
+            inv3 = inv.reshape(D, sub.n_cap, U)
+          occ_idx = jnp.repeat(
+              jnp.arange(local_batch, dtype=jnp.int32), h)
+          first_slot = {}
+          for dev in range(D):
+            for s, r in enumerate(sub.requests[dev]):
+              first_slot.setdefault(
+                  (r.input_id, r.col_start, r.col_end), (dev, s))
+
+          def key_of(dev, s, sub=sub):
+            rs = sub.requests[dev]
+            if s < len(rs):
+              r = rs[s]
+              return (r.input_id, r.col_start, r.col_end)
+            return -1
+
+          def val_of(k, U=U, wc=wc, w=w, inv3=inv3, occ_idx=occ_idx,
+                     first_slot=first_slot):
             if k == -1:
-              return jnp.full((local_batch, h), _SENTINEL, jnp.int32)
-            return mem[k]['cold']
+              return jnp.zeros((U, wc), jnp.float32)
+            inp, cs, ce = k
+            # all slots sharing an input ship the same cold ids, so one
+            # slot's inverse serves every shard request of the input
+            dev, s = first_slot[k]
+            payload = cot[inp][:, cs:ce]
+            if with_sq:
+              payload = jnp.concatenate([payload, payload * payload],
+                                        axis=1)
+            return _dense_segment_sum(inv3[dev, s], payload, U,
+                                      row_index=occ_idx)
 
-          send = _gather_slots(
-              D, sub.n_cap,
-              lambda dev, s, sub=sub: (sub.requests[dev][s].input_id
-                                       if s < len(sub.requests[dev])
-                                       else -1),
-              _cold)
-          _, inv = _unique_with_inverse(send.reshape(D * sub.n_cap, U),
-                                        U)
-          inv3 = inv.reshape(D, sub.n_cap, U)
-        occ_idx = jnp.repeat(
-            jnp.arange(local_batch, dtype=jnp.int32), h)
-        first_slot = {}
-        for dev in range(D):
-          for s, r in enumerate(sub.requests[dev]):
-            first_slot.setdefault(
-                (r.input_id, r.col_start, r.col_end), (dev, s))
-
-        def key_of(dev, s, sub=sub):
-          rs = sub.requests[dev]
-          if s < len(rs):
-            r = rs[s]
-            return (r.input_id, r.col_start, r.col_end)
-          return -1
-
-        def val_of(k, U=U, wc=wc, w=w, inv3=inv3, occ_idx=occ_idx,
-                   first_slot=first_slot):
-          if k == -1:
-            return jnp.zeros((U, wc), jnp.float32)
-          inp, cs, ce = k
-          # all slots sharing an input ship the same cold ids, so one
-          # slot's inverse serves every shard request of the input
-          dev, s = first_slot[k]
-          payload = cot[inp][:, cs:ce]
-          if with_sq:
-            payload = jnp.concatenate([payload, payload * payload],
-                                      axis=1)
-          return _dense_segment_sum(inv3[dev, s], payload, U,
-                                    row_index=occ_idx)
-
-        grads.append(_gather_slots(D, sub.n_cap, key_of, val_of))
+          grads.append(_gather_slots(D, sub.n_cap, key_of, val_of))
 
       # chunked deduplicated-gradient exchange (design §11/§21): the
       # per-slot segment sums above are slot-local, so the slot axis
@@ -2765,13 +2788,14 @@ class DistributedEmbedding:
           if p is not None:
             recv_parts[si].append(p)
 
-      gsubs = []
-      for si, sub in enumerate(subs):
-        U = local_batch * sub.hotness
-        wc = 2 * sub.group.width if with_sq else sub.group.width
-        g = jnp.concatenate(recv_parts[si], axis=1)
-        gsubs.append(
-            g.transpose(1, 0, 2, 3).reshape(sub.n_cap, D * U, wc)[None])
+      with obs_trace.phase('bwd/exchange'):
+        gsubs = []
+        for si, sub in enumerate(subs):
+          U = local_batch * sub.hotness
+          wc = 2 * sub.group.width if with_sq else sub.group.width
+          g = jnp.concatenate(recv_parts[si], axis=1)
+          gsubs.append(
+              g.transpose(1, 0, 2, 3).reshape(sub.n_cap, D * U, wc)[None])
 
       hot_out = []
       for gi in plan.hot_groups:
@@ -2788,49 +2812,49 @@ class DistributedEmbedding:
         # per-chunk sum would rebuild (and re-add) the [K, w] dense
         # buffer once per input, multiplying the dominant memory
         # traffic by the hot-input count
-        segs, rows, idxs = [], [], []
-        base = 0
-        for i, chunks in enumerate(meta['input_chunks']):
-          hotm = mem[i]['hot']
-          for cgi, cs, ce, off in chunks:
-            if cgi != gi or hotm is None:
-              continue
-            b, h = hotm.shape
-            segs.append(jnp.where(hotm >= 0, off + hotm, K).reshape(-1))
-            payload = cot[i][:, cs:ce]
-            if with_sq:
-              payload = jnp.concatenate([payload, payload * payload],
-                                        axis=1)
-            if with_touch:
-              payload = jnp.concatenate(
-                  [payload, jnp.ones((b, 1), jnp.float32)], axis=1)
-            rows.append(payload)
-            idxs.append(base + jnp.repeat(
-                jnp.arange(b, dtype=jnp.int32), h))
-            base += b
-        if segs:
-          total = _dense_segment_sum(
-              jnp.concatenate(segs),
-              jnp.concatenate(rows), K,
-              row_index=jnp.concatenate(idxs))
-        else:
-          total = jnp.zeros((K, wch), jnp.float32)
-        if D > 1 or self.dcn_axis:
-          n_chunks = effective_chunks(self.overlap_chunks, K)
-          if n_chunks > 1:
-            # chunked hot-grad replication (design §11): the one psum
-            # per group splits along the row axis so chunk k's psum can
-            # overlap chunk k-1's dense apply_hot; per-chunk psums of
-            # row slices perform the identical adds — bit-exact
-            total = jnp.concatenate([
-                jax.lax.psum(total[lo:hi], psum_axes)
-                for lo, hi in chunk_bounds(K, n_chunks)
-            ], axis=0)
+        with obs_trace.phase('bwd/route'):
+          segs, rows, idxs = [], [], []
+          base = 0
+          for i, chunks in enumerate(meta['input_chunks']):
+            hotm = mem[i]['hot']
+            for cgi, cs, ce, off in chunks:
+              if cgi != gi or hotm is None:
+                continue
+              b, h = hotm.shape
+              segs.append(jnp.where(hotm >= 0, off + hotm, K).reshape(-1))
+              payload = cot[i][:, cs:ce]
+              if with_sq:
+                payload = jnp.concatenate([payload, payload * payload],
+                                          axis=1)
+              if with_touch:
+                payload = jnp.concatenate(
+                    [payload, jnp.ones((b, 1), jnp.float32)], axis=1)
+              rows.append(payload)
+              idxs.append(base + jnp.repeat(
+                  jnp.arange(b, dtype=jnp.int32), h))
+              base += b
+          if segs:
+            total = _dense_segment_sum(
+                jnp.concatenate(segs),
+                jnp.concatenate(rows), K,
+                row_index=jnp.concatenate(idxs))
           else:
-            total = jax.lax.psum(total, psum_axes)
+            total = jnp.zeros((K, wch), jnp.float32)
+        with obs_trace.phase('bwd/exchange'):
+          if D > 1 or self.dcn_axis:
+            n_chunks = effective_chunks(self.overlap_chunks, K)
+            if n_chunks > 1:
+              # chunked hot-grad replication (design §11): the one psum
+              # per group splits along the row axis so chunk k's psum can
+              # overlap chunk k-1's dense apply_hot; per-chunk psums of
+              # row slices perform the identical adds — bit-exact
+              total = jnp.concatenate([
+                  jax.lax.psum(total[lo:hi], psum_axes)
+                  for lo, hi in chunk_bounds(K, n_chunks)
+              ], axis=0)
+            else:
+              total = jax.lax.psum(total, psum_axes)
         hot_out.append(total)
-
-      obs_trace.end(tok)
       return tuple(gsubs) + tuple(hot_out)
 
     bax = self._batch_axes

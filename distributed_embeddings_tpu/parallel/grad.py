@@ -313,15 +313,16 @@ def fit(step_fn: Callable,
     the trace report attributes (docs/design.md §15)."""
     stacked = jnp.stack(window)
     window.clear()
-    t0 = obs_trace.now()
-    if step_timeout_s is None:
-      host = np.asarray(stacked)
-    else:
-      host = resilience.call_with_timeout(
-          lambda: np.asarray(jax.block_until_ready(stacked)),
-          step_timeout_s, what=f'device-step sync at step {i}')
-    sync_s = obs_trace.now() - t0
-    obs_trace.complete('train/sync', t0, sync_s, step=i)
+    tok = obs_trace.begin('train/sync', step=i)
+    try:
+      if step_timeout_s is None:
+        host = np.asarray(stacked)
+      else:
+        host = resilience.call_with_timeout(
+            lambda: np.asarray(jax.block_until_ready(stacked)),
+            step_timeout_s, what=f'device-step sync at step {i}')
+    finally:
+      sync_s = obs_trace.end(tok)
     obs_metrics.observe('train.sync_ms', sync_s * 1000.0)
     return host
 

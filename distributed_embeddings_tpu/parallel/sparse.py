@@ -53,6 +53,7 @@ from distributed_embeddings_tpu.parallel.overlap import (chunk_bounds,
 _LOG = logging.getLogger(__name__)
 
 
+@obs_trace.phase('apply/dedup')
 def compact_segments(ids: jax.Array,
                      grads: jax.Array,
                      cap: int,
@@ -200,6 +201,7 @@ def _sorted_segments(sid: jax.Array):
   return is_first, is_last, first_pos, seg_total
 
 
+@obs_trace.phase('apply/dedup')
 def dedup_rows(ids: jax.Array, grads: jax.Array,
                sentinel: int) -> Tuple[jax.Array, jax.Array]:
   """Sum rows of ``grads`` sharing an id; static shapes throughout.
@@ -303,7 +305,8 @@ class SparseSGD:
     the quantized adapter (``_QuantizedTableOptimizer``) requants.  ONE
     definition per optimizer so the two paths can never drift."""
     del sum_sq, limit
-    return -lr * sum_g, state
+    with obs_trace.phase('apply/update'):
+      return -lr * sum_g, state
 
   def tier_leaf_specs(self):
     """Optimizer-state leaves the host cold tier must carry per tail
@@ -317,10 +320,11 @@ class SparseSGD:
     # compacted ids are ascending; _distinct_oob makes them strictly
     # unique so the hints let XLA vectorise the scatter instead of
     # serialising for duplicates
-    uids = _distinct_oob(uids, table.shape[0])
-    return table.at[uids].add(delta.astype(table.dtype), mode='drop',
-                              unique_indices=True,
-                              indices_are_sorted=True), state
+    with obs_trace.phase('apply/write_rows'):
+      uids = _distinct_oob(uids, table.shape[0])
+      return table.at[uids].add(delta.astype(table.dtype), mode='drop',
+                                unique_indices=True,
+                                indices_are_sorted=True), state
 
   def apply_hot(self, hot, state, sum_g, sum_sq, lr, count=None):
     """DENSE step on a replicated hot-cache buffer (design §10):
@@ -441,34 +445,45 @@ class SparseAdagrad:
     # _rounded_square: pins `acc + g*g` to mul-then-add rounding so the
     # accumulator is layout-independent (design §20 bit-parity; the
     # compacted operand is small, so the severed fusion costs nothing)
-    add = _rounded_square(sum_g) if self.dedup else sum_sq
-    safe = jnp.clip(uids, 0, limit - 1)
+    # (the phases below keep the operations in the order they always
+    # had: the program is the same, only its metadata is new)
+    with obs_trace.phase('apply/update'):
+      add = _rounded_square(sum_g) if self.dedup else sum_sq
     # compacted ids are ascending; _distinct_oob makes them strictly
     # unique (clipped sentinel gathers may duplicate the last row, hence
     # unique_indices=False there): the hints let XLA vectorise the
     # gather/scatters instead of serialising for duplicates
-    dids = _distinct_oob(uids, limit)
+    with obs_trace.phase('apply/read_rows'):
+      safe = jnp.clip(uids, 0, limit - 1)
+    with obs_trace.phase('apply/write_rows'):
+      dids = _distinct_oob(uids, limit)
     # low-precision accumulators: gather up-casts, arithmetic (add +
     # rsqrt) stays f32, only the store rounds to accum_dtype — the
     # update this step uses the EXACT f32 running value
-    acc_rows = state['acc'].at[safe].get(
-        unique_indices=False,
-        indices_are_sorted=True).astype(jnp.float32) + add
-    acc = state['acc'].at[dids].set(acc_rows.astype(state['acc'].dtype),
-                                    mode='drop',
-                                    unique_indices=True,
-                                    indices_are_sorted=True)
-    delta = -lr * sum_g * jax.lax.rsqrt(acc_rows + self.epsilon)
+    with obs_trace.phase('apply/read_rows'):
+      old_rows = state['acc'].at[safe].get(
+          unique_indices=False,
+          indices_are_sorted=True).astype(jnp.float32)
+    with obs_trace.phase('apply/update'):
+      acc_rows = old_rows + add
+    with obs_trace.phase('apply/write_rows'):
+      acc = state['acc'].at[dids].set(acc_rows.astype(state['acc'].dtype),
+                                      mode='drop',
+                                      unique_indices=True,
+                                      indices_are_sorted=True)
+    with obs_trace.phase('apply/update'):
+      delta = -lr * sum_g * jax.lax.rsqrt(acc_rows + self.epsilon)
     return delta, {'acc': acc}
 
   def apply_unique(self, table, state, uids, sum_g, sum_sq, lr):
     """One step at COMPACTED unique rows (see ``row_updates``)."""
     delta, state = self.row_updates(state, uids, sum_g, sum_sq, lr,
                                     table.shape[0])
-    uids = _distinct_oob(uids, table.shape[0])
-    return table.at[uids].add(delta.astype(table.dtype), mode='drop',
-                              unique_indices=True,
-                              indices_are_sorted=True), state
+    with obs_trace.phase('apply/write_rows'):
+      uids = _distinct_oob(uids, table.shape[0])
+      return table.at[uids].add(delta.astype(table.dtype), mode='drop',
+                                unique_indices=True,
+                                indices_are_sorted=True), state
 
   def apply_hot(self, hot, state, sum_g, sum_sq, lr, count=None):
     """DENSE Adagrad step on a replicated hot-cache buffer: the same
@@ -569,34 +584,48 @@ class SparseAdam:
     were segment-summed by ``compact_segments``, the same dedup the old
     path did internally."""
     del sum_sq
-    safe = jnp.clip(uids, 0, limit - 1)
-    valid = (uids < limit)[:, None]
-    ids, g = _distinct_oob(uids, limit), sum_g
+    g = sum_g
     # strictly unique ascending ids; see SparseAdagrad.row_updates
     hints = dict(unique_indices=True, indices_are_sorted=True)
     ghints = dict(unique_indices=False, indices_are_sorted=True)
-    t = state['t'].at[ids].add(1, mode='drop', **hints)
-    m_rows = self.b1 * state['m'].at[safe].get(**ghints) + (1 - self.b1) * g
-    v_rows = (self.b2 * state['v'].at[safe].get(**ghints) +
-              (1 - self.b2) * g * g)
-    m = state['m'].at[ids].set(jnp.where(valid, m_rows, 0), mode='drop',
-                               **hints)
-    v = state['v'].at[ids].set(jnp.where(valid, v_rows, 0), mode='drop',
-                               **hints)
-    t_rows = t.at[safe].get(**ghints).astype(jnp.float32)[:, None]
-    mhat = m_rows / (1 - self.b1**t_rows)
-    vhat = v_rows / (1 - self.b2**t_rows)
-    delta = -lr * mhat / (jnp.sqrt(vhat) + self.epsilon)
+    # (operations in the order they always had; see SparseAdagrad)
+    with obs_trace.phase('apply/read_rows'):
+      safe = jnp.clip(uids, 0, limit - 1)
+    with obs_trace.phase('apply/update'):
+      valid = (uids < limit)[:, None]
+    with obs_trace.phase('apply/write_rows'):
+      ids = _distinct_oob(uids, limit)
+      t = state['t'].at[ids].add(1, mode='drop', **hints)
+    with obs_trace.phase('apply/read_rows'):
+      m_old = state['m'].at[safe].get(**ghints)
+    with obs_trace.phase('apply/update'):
+      m_rows = self.b1 * m_old + (1 - self.b1) * g
+    with obs_trace.phase('apply/read_rows'):
+      v_old = state['v'].at[safe].get(**ghints)
+    with obs_trace.phase('apply/update'):
+      v_rows = self.b2 * v_old + (1 - self.b2) * g * g
+    with obs_trace.phase('apply/write_rows'):
+      m = state['m'].at[ids].set(jnp.where(valid, m_rows, 0), mode='drop',
+                                 **hints)
+      v = state['v'].at[ids].set(jnp.where(valid, v_rows, 0), mode='drop',
+                                 **hints)
+    with obs_trace.phase('apply/read_rows'):
+      t_rows = t.at[safe].get(**ghints).astype(jnp.float32)[:, None]
+    with obs_trace.phase('apply/update'):
+      mhat = m_rows / (1 - self.b1**t_rows)
+      vhat = v_rows / (1 - self.b2**t_rows)
+      delta = -lr * mhat / (jnp.sqrt(vhat) + self.epsilon)
     return delta, {'m': m, 'v': v, 't': t}
 
   def apply_unique(self, table, state, uids, sum_g, sum_sq, lr):
     """One lazy-Adam step at COMPACTED unique rows (``row_updates``)."""
     delta, state = self.row_updates(state, uids, sum_g, sum_sq, lr,
                                     table.shape[0])
-    ids = _distinct_oob(uids, table.shape[0])
-    return table.at[ids].add(delta.astype(table.dtype), mode='drop',
-                             unique_indices=True,
-                             indices_are_sorted=True), state
+    with obs_trace.phase('apply/write_rows'):
+      ids = _distinct_oob(uids, table.shape[0])
+      return table.at[ids].add(delta.astype(table.dtype), mode='drop',
+                               unique_indices=True,
+                               indices_are_sorted=True), state
 
   def apply_hot(self, hot, state, sum_g, sum_sq, lr, count=None):
     """DENSE lazy-Adam step on a replicated hot-cache buffer.
@@ -665,15 +694,18 @@ class _QuantizedTableOptimizer:
     delta, state2 = self.inner.row_updates(state, uids, sum_g, sum_sq,
                                            lr, limit)
     ghints = dict(unique_indices=False, indices_are_sorted=True)
-    safe = jnp.clip(uids, 0, limit - 1)
-    old = (payload.at[safe].get(**ghints).astype(jnp.float32)
-           * scale.at[safe].get(**ghints))
-    npay, nscale = quantization.quantize_jnp(old + delta, self.spec)
+    with obs_trace.phase('apply/read_rows'):
+      safe = jnp.clip(uids, 0, limit - 1)
+      old = (payload.at[safe].get(**ghints).astype(jnp.float32)
+             * scale.at[safe].get(**ghints))
+    with obs_trace.phase('apply/update'):
+      npay, nscale = quantization.quantize_jnp(old + delta, self.spec)
     hints = dict(mode='drop', unique_indices=True,
                  indices_are_sorted=True)
-    dids = _distinct_oob(uids, limit)
-    return (payload.at[dids].set(npay, **hints),
-            scale.at[dids].set(nscale, **hints)), state2
+    with obs_trace.phase('apply/write_rows'):
+      dids = _distinct_oob(uids, limit)
+      return (payload.at[dids].set(npay, **hints),
+              scale.at[dids].set(nscale, **hints)), state2
 
   def apply_hot(self, pt, state, sum_g, sum_sq, lr, count=None):
     """Dense step on a quantized replicated hot buffer: dequantize the
@@ -690,6 +722,7 @@ class _QuantizedTableOptimizer:
     return (npay, nscale), state2
 
 
+@obs_trace.phase('apply/dedup')
 def _lane_pack(uids, sum_g, sum_sq, pack: int, rows_cap: int,
                exact: bool = False):
   """Re-compact per-row updates at packed-row granularity.
@@ -861,17 +894,19 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
     # narrow groups — the documented cost of pairing Adam with
     # packed_storage; disable packed_storage on the layer to avoid it.
     packed_shape = table.shape
-    tn = table.reshape(rows_cap, w)
-    sn = {k: (v.reshape(rows_cap, w) if v.shape == packed_shape else v)
-          for k, v in state.items()}
+    with obs_trace.phase('apply/read_rows'):
+      tn = table.reshape(rows_cap, w)
+      sn = {k: (v.reshape(rows_cap, w) if v.shape == packed_shape else v)
+            for k, v in state.items()}
     t2, s2 = _dedup_and_apply(optimizer, tn, sn, flat_ids, flat_g, lr,
                               rows_cap, cap_rows=cap_rows, flat_sq=flat_sq,
                               g_index=g_index, n_chunks=n_chunks,
                               max_seg=max_seg)
-    return t2.reshape(packed_shape), {
-        k: (v.reshape(packed_shape) if v.shape == (rows_cap, w) else v)
-        for k, v in s2.items()
-    }
+    with obs_trace.phase('apply/write_rows'):
+      return t2.reshape(packed_shape), {
+          k: (v.reshape(packed_shape) if v.shape == (rows_cap, w) else v)
+          for k, v in s2.items()
+      }
   if storage_packed:
     pack, packable = storage_pack, False
   else:
@@ -884,13 +919,15 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
     pack = 128 // w if packable else 1
     packable = packable and rows_cap // pack + 2 < cap
 
-  order = jnp.argsort(flat_ids) if cap < cap_safe else None
+  with obs_trace.phase('apply/dedup'):
+    order = jnp.argsort(flat_ids) if cap < cap_safe else None
   if with_sq and flat_sq is not None:
     # squares arrive pre-accumulated: segment-sum them as an extra
     # payload column block instead of squaring the (pre-summed) grads
-    payload = jnp.concatenate(
-        [flat_g.astype(jnp.float32),
-         flat_sq.astype(jnp.float32)], axis=1)
+    with obs_trace.phase('apply/dedup'):
+      payload = jnp.concatenate(
+          [flat_g.astype(jnp.float32),
+           flat_sq.astype(jnp.float32)], axis=1)
     uids, tot, _, num_unique = compact_segments(
         flat_ids, payload, cap, sentinel, order=order, max_seg=max_seg)
     sum_g, sum_sq = tot[:, :w], tot[:, w:]
@@ -907,14 +944,16 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
   elif packable:
     pids, g_p, sq_p = _lane_pack(uids, sum_g, sum_sq, pack, rows_cap,
                                  exact=max_seg is not None)
-    ptable = table.reshape(rows_cap // pack, pack * w)
-    pstate = {
-        k: v.reshape(rows_cap // pack, pack * w) for k, v in state.items()
-    }
+    with obs_trace.phase('apply/read_rows'):
+      ptable = table.reshape(rows_cap // pack, pack * w)
+      pstate = {
+          k: v.reshape(rows_cap // pack, pack * w) for k, v in state.items()
+      }
     t2, s2 = _apply_unique_chunked(optimizer, ptable, pstate, pids, g_p,
                                    sq_p, lr, n_chunks)
-    t2 = t2.reshape(rows_cap, w)
-    s2 = {k: v.reshape(rows_cap, w) for k, v in s2.items()}
+    with obs_trace.phase('apply/write_rows'):
+      t2 = t2.reshape(rows_cap, w)
+      s2 = {k: v.reshape(rows_cap, w) for k, v in s2.items()}
   else:
     t2, s2 = _apply_unique_chunked(optimizer, table, state, uids, sum_g,
                                    sum_sq, lr, n_chunks)
@@ -927,27 +966,28 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
     # the guaranteed bound so the branch's scatters stay O(rows_cap)
     # rather than O(n) when the fused table is smaller than the stream
     t3, s3 = args
-    sid = flat_ids[order]
-    sg = (flat_g[order] if g_index is None else
-          flat_g[jnp.take(g_index, order)]).astype(jnp.float32)
-    is_first, is_last, first_pos_c, seg_total = _sorted_segments(sid)
-    if max_seg is not None:
-      # the bounded exact fold of the main wave (layout-independent
-      # totals, design §20) — the correction must sum identically
-      seg_total = lambda x: _seg_fold_bounded(x, first_pos_c, max_seg)
-    rank = jnp.cumsum(is_first.astype(jnp.int32)) - 1
-    keep = is_last & (rank >= cap)
-    key2 = jnp.where(keep, rank, n)
-    order3 = jnp.argsort(key2)[:cap_safe]
-    valid3 = key2[order3] < n
-    uids2 = jnp.where(valid3, sid[order3], sentinel)
-    tot_g = jnp.where(valid3[:, None], seg_total(sg)[order3], 0.0)
-    if with_sq:
-      sq_src = (flat_sq[order].astype(jnp.float32)
-                if flat_sq is not None else sg * sg)
-      tot_sq = jnp.where(valid3[:, None], seg_total(sq_src)[order3], 0.0)
-    else:
-      tot_sq = None
+    with obs_trace.phase('apply/dedup'):
+      sid = flat_ids[order]
+      sg = (flat_g[order] if g_index is None else
+            flat_g[jnp.take(g_index, order)]).astype(jnp.float32)
+      is_first, is_last, first_pos_c, seg_total = _sorted_segments(sid)
+      if max_seg is not None:
+        # the bounded exact fold of the main wave (layout-independent
+        # totals, design §20) — the correction must sum identically
+        seg_total = lambda x: _seg_fold_bounded(x, first_pos_c, max_seg)
+      rank = jnp.cumsum(is_first.astype(jnp.int32)) - 1
+      keep = is_last & (rank >= cap)
+      key2 = jnp.where(keep, rank, n)
+      order3 = jnp.argsort(key2)[:cap_safe]
+      valid3 = key2[order3] < n
+      uids2 = jnp.where(valid3, sid[order3], sentinel)
+      tot_g = jnp.where(valid3[:, None], seg_total(sg)[order3], 0.0)
+      if with_sq:
+        sq_src = (flat_sq[order].astype(jnp.float32)
+                  if flat_sq is not None else sg * sg)
+        tot_sq = jnp.where(valid3[:, None], seg_total(sq_src)[order3], 0.0)
+      else:
+        tot_sq = None
     if storage_packed:
       # correction rows lane-pack too (uids2 is ascending-with-sentinels
       # like the main wave's compacted buffer, so _lane_pack's
@@ -1021,13 +1061,19 @@ def _sc_apply(optimizer, dist, table, state, flat_ids, flat_g, lr,
   num_sc = getattr(dist.plan, 'num_sc', 4)
   if dist._resolve_sc_backend() == 'custom_call':
     n = flat_ids.shape[0]
-    csr = sparsecore.csr_from_routed(flat_ids.reshape(1, n, 1),
-                                     table.shape[0], num_sc, 'sum')
-    return sparsecore.custom_call_grad_apply(optimizer, table, state, csr,
-                                             flat_g, lr, num_sc,
-                                             g_index=g_index)
-  return sparsecore.sc_grad_apply(optimizer, table, state, flat_ids,
-                                  flat_g, lr, num_sc, g_index=g_index)
+    with obs_trace.phase('apply/dedup'):
+      csr = sparsecore.csr_from_routed(flat_ids.reshape(1, n, 1),
+                                       table.shape[0], num_sc, 'sum')
+    # one custom call sums, reads, updates and writes
+    with obs_trace.phase('apply/update'):
+      return sparsecore.custom_call_grad_apply(optimizer, table, state,
+                                               csr, flat_g, lr, num_sc,
+                                               g_index=g_index)
+  # the emulation: CSR round trip as part of the dedup, then the
+  # compact_segments + apply_unique pair under their own phases
+  with obs_trace.phase('apply/dedup'):
+    return sparsecore.sc_grad_apply(optimizer, table, state, flat_ids,
+                                    flat_g, lr, num_sc, g_index=g_index)
 
 
 def _use_segwalk(optimizer, table, group: str = '') -> bool:
@@ -1065,11 +1111,15 @@ def _use_segwalk(optimizer, table, group: str = '') -> bool:
                            or pallas_segwalk.ASSUME_TPU)
 
 
+@obs_trace.phase('apply/dedup')
 def _segwalk_apply(optimizer, table, state, flat_ids, flat_g, lr,
                    storage_pack: int = 1, g_index=None):
   """Sort the raw stream and hand it to the fused segment-walk kernel
   (ops/pallas_segwalk.py) — no compaction, no capacity, no correction
-  wave: every segment is applied exactly once.  ``storage_pack > 1``:
+  wave: every segment is applied exactly once.  (Phases: the wrapper's
+  sort and operand assembly are this path's ``apply/dedup``; the kernel,
+  which sums, reads, updates and writes in one pass, opens
+  ``apply/update`` inside.)  ``storage_pack > 1``:
   the table arrives (and returns) in the physical packed layout; the
   kernel runs its packed path on the operand itself.  ``g_index``:
   ``flat_g`` holds COMPACT per-(sample, bag) rows and ``g_index`` maps
@@ -1157,294 +1207,299 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
     writeback = {}
     fence = lr  # serialisation token threaded through the group applies
     for gi, group in enumerate(dist.plan.groups):
-      ids_list, grad_list, gidx_list = [], [], []
-      rows_cap = group.rows_cap
-      # hier: downstream applies run in the OWNER's hier-local row
-      # space ([rows_cap_h, w] shards, sentinel rows_cap_h); the
-      # pre-compaction above stays in flat fused space (sentinel
-      # rows_cap), exactly like the flat path
-      rows_cap_apply = (hier.groups[gi].rows_cap_h if hier is not None
-                        else rows_cap)
-      w = group.width
-      slots = [(si, sub) for si, sub in enumerate(subs) if sub.gi == gi]
-      if not slots:
-        continue
-      # Multi-hot bags broadcast ONE cotangent row to every occurrence.
-      # When duplication is real (n >= 2m), keep the compact
-      # [n_cap*GB, w] rows plus an [n] position->row index instead of
-      # materialising the h-fold broadcast (the 12.6 GiB-class stream
-      # temps of the jumbo memory audit); the segwalk path consumes the
-      # indirection natively, the XLA paths gather it back below.
-      # Below 2x duplication the indirection LOSES: the compact rows
-      # are a materialised array (the lazy broadcast fuses into its
-      # consumer) and w<128 rows store T(8,128) lane-padded — at m ~ n
-      # that re-buys the round-4 padding blowup (+3.3 GiB measured on
-      # medium@32) — so those groups keep the fused broadcast.
-      # hot-cache streams are already per-(source, slot) deduplicated
-      # h=1 rows whose cotangents were pre-divided (mean) and, for
-      # per-occurrence-squares optimizers, carry the squared channel as
-      # trailing columns — segment-summed additively, never re-squared
-      wc = 2 * w if (cached and needs_sq) else w
-      n_total = sum(residuals[si][0].size for si, _ in slots)
-      m_total = sum(residuals[si][0].shape[0] * residuals[si][0].shape[1]
-                    for si, _ in slots)
-      use_idx = n_total >= 2 * m_total
-      row_off = 0
-      for si, sub in slots:
-        ids = residuals[si][0]            # [n_cap, GB, h]
-        gg = gs[si][0].astype(jnp.float32)  # [n_cap, GB, w]
-        if group.combiner == 'mean' and not sub.mean_row_sliced \
-            and not cached:
-          cnt = jnp.sum(ids < rows_cap, axis=2).astype(jnp.float32)
-          gg = gg / jnp.maximum(cnt, 1.0)[..., None]
-        # mean_row_sliced: the cotangent arrives pre-divided by the TRUE
-        # per-sample count (make_hybrid_train_step), and the shard-local
-        # count here would be the window count - no division
-        n_cap, gb, h = ids.shape
-        ids_list.append(ids.reshape(-1))
-        if use_idx:
-          grad_list.append(gg.reshape(-1, wc))
-          gidx_list.append(
-              row_off + jnp.repeat(
-                  jnp.arange(n_cap * gb, dtype=jnp.int32), h))
-          row_off += n_cap * gb
-        else:
-          pos_g = jnp.broadcast_to(gg[:, :, None, :], ids.shape + (wc,))
-          grad_list.append(pos_g.reshape(-1, wc))
-      flat_ids = jnp.concatenate(ids_list) if len(ids_list) > 1 \
-          else ids_list[0]
-      g_rows = jnp.concatenate(grad_list) if len(grad_list) > 1 \
-          else grad_list[0]
-      g_idx = None
-      if use_idx:
-        g_idx = jnp.concatenate(gidx_list) if len(gidx_list) > 1 \
-            else gidx_list[0]
-      key = f'group_{gi}'
-      # serialise the per-group applies: without a data dependency XLA may
-      # schedule every group's sort/gather/scatter pipeline concurrently,
-      # keeping all their multi-hundred-MB compaction temporaries live at
-      # once — on a chip already holding params + accumulator that tips
-      # peak HBM over the edge (docs/perf_notes.md, train-step section).
-      # Only the IDS pass the barrier: everything downstream (sort,
-      # gathers, applies) depends on them, which orders the pipelines,
-      # while the gradient stream stays fusible into its consumer (a
-      # barriered flat_g materialises as a full lane-padded narrow temp
-      # — 2 GiB at synthetic-small scale, round-4 memory audit)
-      (flat_ids, fence) = jax.lax.optimization_barrier((flat_ids, fence))
-      state_g = {k: v[0] for k, v in opt_state[key].items()}
-      cap_rows = None
-      caps = getattr(optimizer, 'capacity_rows', None)
-      if caps is not None and gi < len(caps):
-        cap_rows = caps[gi]
-      flat_sq = None
-      flat_g = None  # materialised lazily: only the XLA paths need the
-      #                per-occurrence stream; segwalk consumes (g_rows,
-      #                g_idx) without ever broadcasting the bags
-      if dist.num_slices > 1:
-        # Cross-slice update exchange — the DP-gradient step for the
-        # slice-REPLICATED table shards (each slice computed updates
-        # from its own sub-batch; every replica must apply them all,
-        # identically).  Streams pre-compact to unique rows per slice,
-        # bounding the DCN gather to the fused table's row count
-        # instead of the raw batch*hotness stream; per-occurrence-
-        # squares optimizers (needs_sq) ship the squares as their own
-        # additive channel (squares of pre-summed rows would be wrong).
-        # After the gather every slice holds the identical combined
-        # stream, so the applies (and replicas) stay in sync.
-        # Pre-compaction capacity must be the GUARANTEED bound
-        # (uniques + sentinel <= rows_cap + 2): a fraction/calibrated
-        # cap could silently drop segments here, where no correction
-        # wave runs (the wave guards only the post-gather apply).
-        pcap = _guaranteed_cap(flat_ids.shape[0], rows_cap)
-        # cached streams carry squares as trailing payload columns —
-        # they segment-sum additively with the grads and split at the
-        # same column offsets after the gather
-        uids_s, sum_g_s, sum_sq_s, _ = compact_segments(
-            flat_ids, g_rows, pcap, rows_cap,
-            with_sq=needs_sq and not cached, g_index=g_idx)
-        if hier is not None:
-          # Hierarchical update exchange (design §20): each compacted
-          # row maps through the static interval tables to its owner
-          # (slice, hier row); ONE DCN all_to_all per group ships every
-          # per-slice sum to its owner cell (same inner device index —
-          # pure cross-slice traffic), with non-owned positions at the
-          # hier sentinel so the apply drops them.  The receiver
-          # flattens slice-major, reproducing the flat all_gather's
-          # position order — so per-row segment sums add in the same
-          # sequence and the applied updates stay bit-exact vs flat.
-          hl = hier.groups[gi]
-          S = dist.num_slices
-          cap_h = hl.rows_cap_h
-          me_d = jax.lax.axis_index(ax)
-          cut_lo = jnp.asarray(hl.cut_lo)[me_d]
-          cut_sl = jnp.asarray(hl.cut_slice)[me_d]
-          cut_h = jnp.asarray(hl.cut_hier)[me_d]
-          valid = (uids_s >= 0) & (uids_s < rows_cap)
-          safe = jnp.clip(uids_s, 0, rows_cap - 1)
-          k2 = jnp.clip(
-              jnp.searchsorted(cut_lo, safe, side='right') - 1,
-              0, cut_lo.shape[0] - 1)
-          owner = cut_sl[k2]
-          hrow = safe - cut_lo[k2] + cut_h[k2]
-          dest = jax.lax.broadcasted_iota(jnp.int32,
-                                          (S,) + uids_s.shape, 0)
-          hids = jnp.where(valid[None] & (owner[None] == dest),
-                           hrow[None], cap_h).astype(jnp.int32)
-          packed = [
-              jax.lax.bitcast_convert_type(hids, jnp.float32)[..., None],
-              jnp.broadcast_to(sum_g_s[None], (S,) + sum_g_s.shape)
-          ]
-          if needs_sq and not cached:
-            packed.append(
-                jnp.broadcast_to(sum_sq_s[None], (S,) + sum_sq_s.shape))
-          gathered = jax.lax.all_to_all(
-              jnp.concatenate(packed, axis=2), dist.dcn_axis, 0, 0)
-          gathered = gathered.reshape(-1, gathered.shape[2])
-        else:
-          # ONE DCN collective per group: ids ride as a bitcast f32
-          # column alongside the grad (and square) payload
-          packed = [
-              jax.lax.bitcast_convert_type(uids_s, jnp.float32)[:, None],
-              sum_g_s
-          ]
-          if needs_sq and not cached:
-            packed.append(sum_sq_s)
-          gathered = jax.lax.all_gather(jnp.concatenate(packed, axis=1),
-                                        dist.dcn_axis, axis=0, tiled=True)
-        flat_ids = jax.lax.bitcast_convert_type(gathered[:, 0], jnp.int32)
-        flat_g = gathered[:, 1:1 + w]
-        if needs_sq:
-          flat_sq = gathered[:, 1 + w:]
-      if cached and needs_sq and flat_g is None:
-        # single-slice cached stream: split the additive squared-grad
-        # channel off the payload columns for the flat_sq apply path
-        flat_g = g_rows[:, :w]
-        flat_sq = g_rows[:, w:]
-      spack = getattr(group, 'storage_pack', 1)
-      if quant is not None or gi in tiered:
-        # quantized and/or tiered group (design §12): the table operand
-        # is the (payload, scale) pair; cold-tier groups concatenate
-        # the batch's fetched tail rows and return the updated rows as
-        # writeback.  Streaming kernels (segwalk/SparseCore apply) do
-        # not serve these groups — XLA adapter path only.
-        table_op = params[key][0]
-        scale_op = (params[f'scale_group_{gi}'][0]
-                    if quant is not None else None)
-        rows_eff = rows_cap_apply
-        res = group.device_rows
-        if gi in tiered:
-          f = fetch[gi]
-          frows = f['rows'][0]
-          cap_f = frows.shape[0]
-          # remap tail ids into the concatenated [res + cap_f] space:
-          # resident ids pass through, fetched tail ids land at
-          # res + fetch position, everything else (sentinel; a tail id
-          # the pre-pass missed, impossible by contract) drops at the
-          # new sentinel res + cap_f
-          pos = jnp.searchsorted(frows, flat_ids).astype(jnp.int32)
-          safe_pos = jnp.minimum(pos, cap_f - 1)
-          hit = ((flat_ids >= res) & (flat_ids < rows_cap)
-                 & (frows[safe_pos] == flat_ids))
-          flat_ids = jnp.where(
-              flat_ids < res, flat_ids,
-              jnp.where(hit, res + safe_pos, res + cap_f))
-          rows_eff = res + cap_f
-          table_op = jnp.concatenate([table_op, f['payload'][0]])
-          if scale_op is not None:
-            scale_op = jnp.concatenate([scale_op, f['scale'][0]])
-          state_g = {
-              k: jnp.concatenate([v, f['opt'][k][0]])
-              for k, v in state_g.items()
-          }
-        operand = ((table_op, scale_op) if quant is not None
-                   else table_op)
-        if flat_g is None:
-          t2, state2 = _dedup_and_apply(opt_q, operand, state_g,
-                                        flat_ids, g_rows, lr, rows_eff,
-                                        cap_rows=cap_rows,
-                                        g_index=g_idx,
-                                        n_chunks=n_chunks)
-        else:
-          # post-gather merge: each row appears at most once per slice,
-          # so the bounded exact fold keeps the merged totals
-          # layout-independent (flat-vs-hier bit-parity, design §20)
-          t2, state2 = _dedup_and_apply(opt_q, operand, state_g,
-                                        flat_ids, flat_g, lr, rows_eff,
-                                        cap_rows=cap_rows,
-                                        flat_sq=flat_sq,
-                                        n_chunks=n_chunks,
-                                        max_seg=dist.num_slices)
-        pay2, sc2 = t2 if quant is not None else (t2, None)
-        if gi in tiered:
-          wb = {'payload': pay2[res:][None]}
+      with obs_trace.phase_group(f'g{gi}'):
+        ids_list, grad_list, gidx_list = [], [], []
+        rows_cap = group.rows_cap
+        # hier: downstream applies run in the OWNER's hier-local row
+        # space ([rows_cap_h, w] shards, sentinel rows_cap_h); the
+        # pre-compaction above stays in flat fused space (sentinel
+        # rows_cap), exactly like the flat path
+        rows_cap_apply = (hier.groups[gi].rows_cap_h if hier is not None
+                          else rows_cap)
+        w = group.width
+        slots = [(si, sub) for si, sub in enumerate(subs) if sub.gi == gi]
+        if not slots:
+          continue
+        # Multi-hot bags broadcast ONE cotangent row to every occurrence.
+        # When duplication is real (n >= 2m), keep the compact
+        # [n_cap*GB, w] rows plus an [n] position->row index instead of
+        # materialising the h-fold broadcast (the 12.6 GiB-class stream
+        # temps of the jumbo memory audit); the segwalk path consumes the
+        # indirection natively, the XLA paths gather it back below.
+        # Below 2x duplication the indirection LOSES: the compact rows
+        # are a materialised array (the lazy broadcast fuses into its
+        # consumer) and w<128 rows store T(8,128) lane-padded — at m ~ n
+        # that re-buys the round-4 padding blowup (+3.3 GiB measured on
+        # medium@32) — so those groups keep the fused broadcast.
+        # hot-cache streams are already per-(source, slot) deduplicated
+        # h=1 rows whose cotangents were pre-divided (mean) and, for
+        # per-occurrence-squares optimizers, carry the squared channel as
+        # trailing columns — segment-summed additively, never re-squared
+        with obs_trace.phase('apply/stream'):
+          wc = 2 * w if (cached and needs_sq) else w
+          n_total = sum(residuals[si][0].size for si, _ in slots)
+          m_total = sum(residuals[si][0].shape[0] * residuals[si][0].shape[1]
+                        for si, _ in slots)
+          use_idx = n_total >= 2 * m_total
+          row_off = 0
+          for si, sub in slots:
+            ids = residuals[si][0]            # [n_cap, GB, h]
+            gg = gs[si][0].astype(jnp.float32)  # [n_cap, GB, w]
+            if group.combiner == 'mean' and not sub.mean_row_sliced \
+                and not cached:
+              cnt = jnp.sum(ids < rows_cap, axis=2).astype(jnp.float32)
+              gg = gg / jnp.maximum(cnt, 1.0)[..., None]
+            # mean_row_sliced: the cotangent arrives pre-divided by the TRUE
+            # per-sample count (make_hybrid_train_step), and the shard-local
+            # count here would be the window count - no division
+            n_cap, gb, h = ids.shape
+            ids_list.append(ids.reshape(-1))
+            if use_idx:
+              grad_list.append(gg.reshape(-1, wc))
+              gidx_list.append(
+                  row_off + jnp.repeat(
+                      jnp.arange(n_cap * gb, dtype=jnp.int32), h))
+              row_off += n_cap * gb
+            else:
+              pos_g = jnp.broadcast_to(gg[:, :, None, :], ids.shape + (wc,))
+              grad_list.append(pos_g.reshape(-1, wc))
+          flat_ids = jnp.concatenate(ids_list) if len(ids_list) > 1 \
+              else ids_list[0]
+          g_rows = jnp.concatenate(grad_list) if len(grad_list) > 1 \
+              else grad_list[0]
+          g_idx = None
+          if use_idx:
+            g_idx = jnp.concatenate(gidx_list) if len(gidx_list) > 1 \
+                else gidx_list[0]
+          key = f'group_{gi}'
+          # serialise the per-group applies: without a data dependency XLA may
+          # schedule every group's sort/gather/scatter pipeline concurrently,
+          # keeping all their multi-hundred-MB compaction temporaries live at
+          # once — on a chip already holding params + accumulator that tips
+          # peak HBM over the edge (docs/perf_notes.md, train-step section).
+          # Only the IDS pass the barrier: everything downstream (sort,
+          # gathers, applies) depends on them, which orders the pipelines,
+          # while the gradient stream stays fusible into its consumer (a
+          # barriered flat_g materialises as a full lane-padded narrow temp
+          # — 2 GiB at synthetic-small scale, round-4 memory audit)
+          (flat_ids, fence) = jax.lax.optimization_barrier((flat_ids, fence))
+          state_g = {k: v[0] for k, v in opt_state[key].items()}
+          cap_rows = None
+          caps = getattr(optimizer, 'capacity_rows', None)
+          if caps is not None and gi < len(caps):
+            cap_rows = caps[gi]
+          flat_sq = None
+          flat_g = None  # materialised lazily: only the XLA paths need the
+          #                per-occurrence stream; segwalk consumes (g_rows,
+          #                g_idx) without ever broadcasting the bags
+          if dist.num_slices > 1:
+            # Cross-slice update exchange — the DP-gradient step for the
+            # slice-REPLICATED table shards (each slice computed updates
+            # from its own sub-batch; every replica must apply them all,
+            # identically).  Streams pre-compact to unique rows per slice,
+            # bounding the DCN gather to the fused table's row count
+            # instead of the raw batch*hotness stream; per-occurrence-
+            # squares optimizers (needs_sq) ship the squares as their own
+            # additive channel (squares of pre-summed rows would be wrong).
+            # After the gather every slice holds the identical combined
+            # stream, so the applies (and replicas) stay in sync.
+            # Pre-compaction capacity must be the GUARANTEED bound
+            # (uniques + sentinel <= rows_cap + 2): a fraction/calibrated
+            # cap could silently drop segments here, where no correction
+            # wave runs (the wave guards only the post-gather apply).
+            pcap = _guaranteed_cap(flat_ids.shape[0], rows_cap)
+            # cached streams carry squares as trailing payload columns —
+            # they segment-sum additively with the grads and split at the
+            # same column offsets after the gather
+            uids_s, sum_g_s, sum_sq_s, _ = compact_segments(
+                flat_ids, g_rows, pcap, rows_cap,
+                with_sq=needs_sq and not cached, g_index=g_idx)
+            if hier is not None:
+              # Hierarchical update exchange (design §20): each compacted
+              # row maps through the static interval tables to its owner
+              # (slice, hier row); ONE DCN all_to_all per group ships every
+              # per-slice sum to its owner cell (same inner device index —
+              # pure cross-slice traffic), with non-owned positions at the
+              # hier sentinel so the apply drops them.  The receiver
+              # flattens slice-major, reproducing the flat all_gather's
+              # position order — so per-row segment sums add in the same
+              # sequence and the applied updates stay bit-exact vs flat.
+              hl = hier.groups[gi]
+              S = dist.num_slices
+              cap_h = hl.rows_cap_h
+              me_d = jax.lax.axis_index(ax)
+              cut_lo = jnp.asarray(hl.cut_lo)[me_d]
+              cut_sl = jnp.asarray(hl.cut_slice)[me_d]
+              cut_h = jnp.asarray(hl.cut_hier)[me_d]
+              valid = (uids_s >= 0) & (uids_s < rows_cap)
+              safe = jnp.clip(uids_s, 0, rows_cap - 1)
+              k2 = jnp.clip(
+                  jnp.searchsorted(cut_lo, safe, side='right') - 1,
+                  0, cut_lo.shape[0] - 1)
+              owner = cut_sl[k2]
+              hrow = safe - cut_lo[k2] + cut_h[k2]
+              dest = jax.lax.broadcasted_iota(jnp.int32,
+                                              (S,) + uids_s.shape, 0)
+              hids = jnp.where(valid[None] & (owner[None] == dest),
+                               hrow[None], cap_h).astype(jnp.int32)
+              packed = [
+                  jax.lax.bitcast_convert_type(hids, jnp.float32)[..., None],
+                  jnp.broadcast_to(sum_g_s[None], (S,) + sum_g_s.shape)
+              ]
+              if needs_sq and not cached:
+                packed.append(
+                    jnp.broadcast_to(sum_sq_s[None], (S,) + sum_sq_s.shape))
+              gathered = jax.lax.all_to_all(
+                  jnp.concatenate(packed, axis=2), dist.dcn_axis, 0, 0)
+              gathered = gathered.reshape(-1, gathered.shape[2])
+            else:
+              # ONE DCN collective per group: ids ride as a bitcast f32
+              # column alongside the grad (and square) payload
+              packed = [
+                  jax.lax.bitcast_convert_type(uids_s, jnp.float32)[:, None],
+                  sum_g_s
+              ]
+              if needs_sq and not cached:
+                packed.append(sum_sq_s)
+              gathered = jax.lax.all_gather(jnp.concatenate(packed, axis=1),
+                                            dist.dcn_axis, axis=0, tiled=True)
+            flat_ids = jax.lax.bitcast_convert_type(gathered[:, 0], jnp.int32)
+            flat_g = gathered[:, 1:1 + w]
+            if needs_sq:
+              flat_sq = gathered[:, 1 + w:]
+          if cached and needs_sq and flat_g is None:
+            # single-slice cached stream: split the additive squared-grad
+            # channel off the payload columns for the flat_sq apply path
+            flat_g = g_rows[:, :w]
+            flat_sq = g_rows[:, w:]
+        spack = getattr(group, 'storage_pack', 1)
+        if quant is not None or gi in tiered:
+          # quantized and/or tiered group (design §12): the table operand
+          # is the (payload, scale) pair; cold-tier groups concatenate
+          # the batch's fetched tail rows and return the updated rows as
+          # writeback.  Streaming kernels (segwalk/SparseCore apply) do
+          # not serve these groups — XLA adapter path only.
+          table_op = params[key][0]
+          scale_op = (params[f'scale_group_{gi}'][0]
+                      if quant is not None else None)
+          rows_eff = rows_cap_apply
+          res = group.device_rows
+          if gi in tiered:
+            with obs_trace.phase('apply/stream'):
+              f = fetch[gi]
+              frows = f['rows'][0]
+              cap_f = frows.shape[0]
+              # remap tail ids into the concatenated [res + cap_f] space:
+              # resident ids pass through, fetched tail ids land at
+              # res + fetch position, everything else (sentinel; a tail id
+              # the pre-pass missed, impossible by contract) drops at the
+              # new sentinel res + cap_f
+              pos = jnp.searchsorted(frows, flat_ids).astype(jnp.int32)
+              safe_pos = jnp.minimum(pos, cap_f - 1)
+              hit = ((flat_ids >= res) & (flat_ids < rows_cap)
+                     & (frows[safe_pos] == flat_ids))
+              flat_ids = jnp.where(
+                  flat_ids < res, flat_ids,
+                  jnp.where(hit, res + safe_pos, res + cap_f))
+              rows_eff = res + cap_f
+            with obs_trace.phase('apply/read_rows'):
+              table_op = jnp.concatenate([table_op, f['payload'][0]])
+              if scale_op is not None:
+                scale_op = jnp.concatenate([scale_op, f['scale'][0]])
+              state_g = {
+                  k: jnp.concatenate([v, f['opt'][k][0]])
+                  for k, v in state_g.items()
+              }
+          operand = ((table_op, scale_op) if quant is not None
+                     else table_op)
+          if flat_g is None:
+            t2, state2 = _dedup_and_apply(opt_q, operand, state_g,
+                                          flat_ids, g_rows, lr, rows_eff,
+                                          cap_rows=cap_rows,
+                                          g_index=g_idx,
+                                          n_chunks=n_chunks)
+          else:
+            # post-gather merge: each row appears at most once per slice,
+            # so the bounded exact fold keeps the merged totals
+            # layout-independent (flat-vs-hier bit-parity, design §20)
+            t2, state2 = _dedup_and_apply(opt_q, operand, state_g,
+                                          flat_ids, flat_g, lr, rows_eff,
+                                          cap_rows=cap_rows,
+                                          flat_sq=flat_sq,
+                                          n_chunks=n_chunks,
+                                          max_seg=dist.num_slices)
+          pay2, sc2 = t2 if quant is not None else (t2, None)
+          if gi in tiered:
+            with obs_trace.phase('apply/write_rows'):
+              wb = {'payload': pay2[res:][None]}
+              if sc2 is not None:
+                wb['scale'] = sc2[res:][None]
+              wb['opt'] = {k: v[res:][None] for k, v in state2.items()}
+              writeback[gi] = wb
+              pay2 = pay2[:res]
+              if sc2 is not None:
+                sc2 = sc2[:res]
+              state2 = {k: v[:res] for k, v in state2.items()}
+          new_params[key] = pay2[None]
           if sc2 is not None:
-            wb['scale'] = sc2[res:][None]
-          wb['opt'] = {k: v[res:][None] for k, v in state2.items()}
-          writeback[gi] = wb
-          pay2 = pay2[:res]
-          if sc2 is not None:
-            sc2 = sc2[:res]
-          state2 = {k: v[:res] for k, v in state2.items()}
-        new_params[key] = pay2[None]
-        if sc2 is not None:
-          new_params[f'scale_group_{gi}'] = sc2[None]
-        new_state[key] = {k: v[None] for k, v in state2.items()}
-        fence = pay2[0, 0]
-        continue
-      if flat_sq is None and _use_sparsecore(optimizer, dist,
-                                             params[key][0], spack):
-        # SparseCore grad+optimizer path (docs/design.md §8): the
-        # stream executes through the partition-sorted CSR buffers.
-        # flat_sq present (multi-slice per-occurrence Adagrad) means
-        # pre-accumulated squares the CSR grad op cannot consume —
-        # that case keeps the XLA path, like segwalk.
-        if flat_g is None:  # single-slice: compact rows + index
-          table, state2 = _sc_apply(optimizer, dist, params[key][0],
-                                    state_g, flat_ids, g_rows, lr,
-                                    g_index=g_idx)
-        else:  # multi-slice: the DCN exchange already compacted
-          table, state2 = _sc_apply(optimizer, dist, params[key][0],
-                                    state_g, flat_ids, flat_g, lr)
-      elif flat_sq is None and _use_segwalk(optimizer, params[key][0],
-                                            group=key):
-        # fused segment-walk path (flat_sq present means the stream
-        # carries pre-accumulated squares the kernel cannot consume —
-        # multi-slice per-occurrence Adagrad falls back to XLA).
-        # Single-slice: hand over the compact rows + index — the
-        # kernel's one [n, 128] operand gathers straight from them
-        if flat_g is None:
-          table, state2 = _segwalk_apply(optimizer, params[key][0],
-                                         state_g, flat_ids, g_rows, lr,
-                                         storage_pack=spack,
-                                         g_index=g_idx)
-        else:  # multi-slice: the DCN exchange already compacted
-          table, state2 = _segwalk_apply(optimizer, params[key][0],
-                                         state_g, flat_ids, flat_g, lr,
-                                         storage_pack=spack)
-      else:
-        if flat_g is None:  # single-slice: the compact rows + index go
-          #                   straight through (g_idx None = h1 stream)
-          table, state2 = _dedup_and_apply(optimizer, params[key][0],
+            new_params[f'scale_group_{gi}'] = sc2[None]
+          new_state[key] = {k: v[None] for k, v in state2.items()}
+          fence = pay2[0, 0]
+          continue
+        if flat_sq is None and _use_sparsecore(optimizer, dist,
+                                               params[key][0], spack):
+          # SparseCore grad+optimizer path (docs/design.md §8): the
+          # stream executes through the partition-sorted CSR buffers.
+          # flat_sq present (multi-slice per-occurrence Adagrad) means
+          # pre-accumulated squares the CSR grad op cannot consume —
+          # that case keeps the XLA path, like segwalk.
+          if flat_g is None:  # single-slice: compact rows + index
+            table, state2 = _sc_apply(optimizer, dist, params[key][0],
+                                      state_g, flat_ids, g_rows, lr,
+                                      g_index=g_idx)
+          else:  # multi-slice: the DCN exchange already compacted
+            table, state2 = _sc_apply(optimizer, dist, params[key][0],
+                                      state_g, flat_ids, flat_g, lr)
+        elif flat_sq is None and _use_segwalk(optimizer, params[key][0],
+                                              group=key):
+          # fused segment-walk path (flat_sq present means the stream
+          # carries pre-accumulated squares the kernel cannot consume —
+          # multi-slice per-occurrence Adagrad falls back to XLA).
+          # Single-slice: hand over the compact rows + index — the
+          # kernel's one [n, 128] operand gathers straight from them
+          if flat_g is None:
+            table, state2 = _segwalk_apply(optimizer, params[key][0],
                                            state_g, flat_ids, g_rows, lr,
-                                           rows_cap, cap_rows=cap_rows,
                                            storage_pack=spack,
-                                           g_index=g_idx,
-                                           n_chunks=n_chunks)
-        else:  # multi-slice: the DCN exchange already compacted; each
-          #       row appears at most once per slice, so the bounded
-          #       exact fold keeps the merged totals layout-independent
-          #       (flat-vs-hier bit-parity, design §20)
-          table, state2 = _dedup_and_apply(optimizer, params[key][0],
+                                           g_index=g_idx)
+          else:  # multi-slice: the DCN exchange already compacted
+            table, state2 = _segwalk_apply(optimizer, params[key][0],
                                            state_g, flat_ids, flat_g, lr,
-                                           rows_cap_apply,
-                                           cap_rows=cap_rows,
-                                           flat_sq=flat_sq,
-                                           storage_pack=spack,
-                                           n_chunks=n_chunks,
-                                           max_seg=dist.num_slices)
-      new_params[key] = table[None]
-      new_state[key] = {k: v[None] for k, v in state2.items()}
-      fence = table[0, 0]
+                                           storage_pack=spack)
+        else:
+          if flat_g is None:  # single-slice: the compact rows + index go
+            #                   straight through (g_idx None = h1 stream)
+            table, state2 = _dedup_and_apply(optimizer, params[key][0],
+                                             state_g, flat_ids, g_rows, lr,
+                                             rows_cap, cap_rows=cap_rows,
+                                             storage_pack=spack,
+                                             g_index=g_idx,
+                                             n_chunks=n_chunks)
+          else:  # multi-slice: the DCN exchange already compacted; each
+            #       row appears at most once per slice, so the bounded
+            #       exact fold keeps the merged totals layout-independent
+            #       (flat-vs-hier bit-parity, design §20)
+            table, state2 = _dedup_and_apply(optimizer, params[key][0],
+                                             state_g, flat_ids, flat_g, lr,
+                                             rows_cap_apply,
+                                             cap_rows=cap_rows,
+                                             flat_sq=flat_sq,
+                                             storage_pack=spack,
+                                             n_chunks=n_chunks,
+                                             max_seg=dist.num_slices)
+        new_params[key] = table[None]
+        new_state[key] = {k: v[None] for k, v in state2.items()}
+        fence = table[0, 0]
 
     # hot-cache buffers: ONE dense elementwise step per hot group on
     # the mesh-psummed gradient sums — the dense add that replaces K
@@ -1471,36 +1526,37 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
         return ((op[0][lo:hi], op[1][lo:hi]) if quant is not None
                 else op[lo:hi])
 
-      if kch == 1:
-        hot_new, hstate = opt_q.apply_hot(hot_op, opt_state[hk],
-                                          sum_g, sum_sq, lr,
-                                          count=count)
-      else:
-        # chunked dense hot apply (design §11): apply_hot is
-        # elementwise per row, so row-range chunks are bit-exact — and
-        # chunk k's step can execute while chunk k+1's psummed
-        # gradient slice is still in flight (the backward psums the
-        # hot grads in the same row chunks).  Quantized buffers chunk
-        # identically: the per-row requant is row-local.
-        pieces, spieces = [], []
-        for lo, hi in chunk_bounds(K, kch):
-          hp, hs = opt_q.apply_hot(
-              slice_op(hot_op, lo, hi),
-              {kk: vv[lo:hi] for kk, vv in opt_state[hk].items()},
-              sum_g[lo:hi],
-              None if sum_sq is None else sum_sq[lo:hi], lr,
-              count=None if count is None else count[lo:hi])
-          pieces.append(hp)
-          spieces.append(hs)
-        if quant is not None:
-          hot_new = (jnp.concatenate([p[0] for p in pieces], axis=0),
-                     jnp.concatenate([p[1] for p in pieces], axis=0))
+      with obs_trace.phase_group(f'g{gi}'), obs_trace.phase('apply/update'):
+        if kch == 1:
+          hot_new, hstate = opt_q.apply_hot(hot_op, opt_state[hk],
+                                            sum_g, sum_sq, lr,
+                                            count=count)
         else:
-          hot_new = jnp.concatenate(pieces, axis=0)
-        hstate = ({} if not spieces[0] else {
-            kk: jnp.concatenate([s[kk] for s in spieces], axis=0)
-            for kk in spieces[0]
-        })
+          # chunked dense hot apply (design §11): apply_hot is
+          # elementwise per row, so row-range chunks are bit-exact — and
+          # chunk k's step can execute while chunk k+1's psummed
+          # gradient slice is still in flight (the backward psums the
+          # hot grads in the same row chunks).  Quantized buffers chunk
+          # identically: the per-row requant is row-local.
+          pieces, spieces = [], []
+          for lo, hi in chunk_bounds(K, kch):
+            hp, hs = opt_q.apply_hot(
+                slice_op(hot_op, lo, hi),
+                {kk: vv[lo:hi] for kk, vv in opt_state[hk].items()},
+                sum_g[lo:hi],
+                None if sum_sq is None else sum_sq[lo:hi], lr,
+                count=None if count is None else count[lo:hi])
+            pieces.append(hp)
+            spieces.append(hs)
+          if quant is not None:
+            hot_new = (jnp.concatenate([p[0] for p in pieces], axis=0),
+                       jnp.concatenate([p[1] for p in pieces], axis=0))
+          else:
+            hot_new = jnp.concatenate(pieces, axis=0)
+          hstate = ({} if not spieces[0] else {
+              kk: jnp.concatenate([s[kk] for s in spieces], axis=0)
+              for kk in spieces[0]
+          })
       if quant is not None:
         new_params[hk], new_params[hsk] = hot_new
       else:
@@ -1562,11 +1618,7 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
                 P(None, None) for _ in hot_gis),
         out_specs=(param_specs, state_spec, wb_spec),
         check_vma=False)
-    # trace-time span (obs/trace.py): the sparse optimizer apply
-    tok = obs_trace.begin('apply/update')
-    out = fn(params, opt_state, lr, fetch, *res_and_g)
-    obs_trace.end(tok)
-    return out
+    return fn(params, opt_state, lr, fetch, *res_and_g)
 
   dist._fn_cache[key] = apply
   return apply
@@ -1683,15 +1735,19 @@ def make_hybrid_train_step(dist: DistributedEmbedding,
           dist.forward_with_residuals(emb_params, cats,
                                       cold_fetch=cold_fetch))
 
+    # the scope opens inside the function vjp differentiates, so the
+    # head's backward carries it too (``transpose(jvp(head))``)
     loss, pull = jax.vjp(
-        lambda dp, eo: head_loss_fn(dp, eo, batch), dense_params,
+        obs_trace.phase('head')(
+            lambda dp, eo: head_loss_fn(dp, eo, batch)), dense_params,
         tuple(emb_outs))
     d_dense, d_emb = pull(jnp.ones((), loss.dtype))
 
-    updates, dense_opt_state = dense_optimizer.update(
-        d_dense, dense_opt_state, dense_params)
-    new_dense = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                             dense_params, updates)
+    with obs_trace.phase('dense_update'):
+      updates, dense_opt_state = dense_optimizer.update(
+          d_dense, dense_opt_state, dense_params)
+      new_dense = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                               dense_params, updates)
 
     if hot_on:
       # hot-cache layers: the backward consumes the forward's routing

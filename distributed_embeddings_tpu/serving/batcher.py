@@ -189,9 +189,10 @@ class _Slot:
     self.priority = priority
     # absolute monotonic shed deadline (None: never sheds on age)
     self.deadline = deadline
-    # queue-residency start on the TRACE clock (the 'serve/enqueue'
-    # async span the dispatcher closes); 0.0 when tracing is off
-    self.t0p = obs_trace.now() if obs_trace.enabled() else 0.0
+    # admission on the TRACE clock: the wait from here to dispatch is
+    # ONE measurement for stats() (queue_wait_p50/p99_ms), the
+    # serve.queue_wait_ms histogram and the 'serve/enqueue' async span
+    self.t0p = obs_trace.now()
 
 
 _CLOSE = object()
@@ -277,6 +278,7 @@ class DynamicBatcher:
     # the shared bounded exact-latency primitive (obs/metrics.py
     # LatencyWindow) — stats() keys and percentile arithmetic unchanged
     self._latencies = obs_metrics.LatencyWindow()
+    self._queue_waits = obs_metrics.LatencyWindow()
     self.bucket_ladder = bool(bucket_ladder) and not csr_feed
     self._feed = None
     self._queue_source = None
@@ -427,12 +429,12 @@ class DynamicBatcher:
                          reason=reason, shed_class=n_class,
                          shed_total=shed_total, admitted=admitted)
     obs_metrics.inc('serve.shed')
-    if obs_trace.enabled() and slot.t0p:
-      t1 = obs_trace.now()
-      obs_trace.complete('serve/shed', slot.t0p,
-                         max(0.0, t1 - slot.t0p),
-                         priority=slot.priority, reason=reason,
-                         samples=slot.n)
+    if obs_trace.enabled():
+      # queue residency of a request that left unserved: no thread owns
+      # it, so it is an async interval of the obs file like serve/enqueue
+      obs_trace.async_span('serve/shed', id(slot), slot.t0p,
+                           obs_trace.now(), priority=slot.priority,
+                           reason=reason, samples=slot.n)
     if reason == 'closed':
       msg = 'batcher closed before the request was served'
     else:
@@ -506,21 +508,22 @@ class DynamicBatcher:
           break
         batch.append(nxt)
         n += nxt.n
+      # each merged request's wait from admission to dispatch, read once
+      t1 = obs_trace.now()
+      waits = [(t1 - slot.t0p) * 1000.0 for slot in batch]
       with self._lock:
         for slot in batch:
           self._depth[slot.priority] -= 1
+        self._queue_waits.extend(waits)
+      for wait_ms in waits:
+        obs_metrics.observe('serve.queue_wait_ms', wait_ms)
       if obs_trace.enabled():
-        # close each merged request's queue-residency interval: an
-        # ASYNC span (b/e pair) because neighbours overlap arbitrarily
-        # — no one thread's track could hold them nested.  Slots
-        # admitted BEFORE the tracer was armed carry t0p=0.0 (the raw
-        # clock epoch, hours in the past) and are skipped rather than
-        # rendered as a machine-uptime-long wait.
-        t1 = obs_trace.now()
+        # the same interval as an ASYNC span (b/e pair): neighbours
+        # overlap arbitrarily, so no one thread's track could hold them
+        # nested, and no thread owns a wait to annotate it
         for slot in batch:
-          if slot.t0p:
-            obs_trace.async_span('serve/enqueue', id(slot), slot.t0p,
-                                 t1, samples=slot.n)
+          obs_trace.async_span('serve/enqueue', id(slot), slot.t0p, t1,
+                               samples=slot.n)
       try:
         with obs_trace.span('serve/dispatch', requests=len(batch),
                             samples=n):
@@ -607,11 +610,12 @@ class DynamicBatcher:
     eng = self.engine
     bucket = (eng.bucket_for(n) if self.bucket_ladder
               else eng.batch_size)
-    t0 = obs_trace.now()
-    merged = self._merge(batch, bucket)
-    merge_ms = (obs_trace.now() - t0) * 1000.0
-    obs_trace.complete('serve/merge', t0, merge_ms / 1000.0,
-                       requests=len(batch), samples=n, bucket=bucket)
+    tok = obs_trace.begin('serve/merge', requests=len(batch), samples=n,
+                          bucket=bucket)
+    try:
+      merged = self._merge(batch, bucket)
+    finally:
+      merge_ms = obs_trace.end(tok) * 1000.0
     obs_metrics.observe('serve.merge_ms', merge_ms)
     if self._queue_source is not None:
       # csr_feed mode: the merged batch rides the in-memory queue into
@@ -919,7 +923,10 @@ class DynamicBatcher:
 
   def stats(self) -> dict:
     """Latency / fill accounting: ``p50_ms``/``p99_ms``/``p999_ms``
-    over resolved request latencies (submit -> demux), the per-class
+    over resolved request latencies (submit -> demux),
+    ``queue_wait_p50_ms``/``queue_wait_p99_ms`` over the waits from
+    admission to dispatch (the part of the latency spent queued, the
+    ``serve/enqueue`` span's own measurement), the per-class
     admission ledger (``classes`` + the per-reason ``shed`` block;
     docs/design.md §23), mean ``batch_fill`` (samples /
     ``max_batch``), the bucket-ladder padding accounting
@@ -931,6 +938,8 @@ class DynamicBatcher:
       p50 = self._latencies.percentile(50)
       p99 = self._latencies.percentile(99)
       p999 = self._latencies.percentile(99.9)
+      wait50 = self._queue_waits.percentile(50)
+      wait99 = self._queue_waits.percentile(99)
       launched = self._rows_launched
       classes = self._class_stats()
       out = {
@@ -944,6 +953,10 @@ class DynamicBatcher:
           'p50_ms': round(p50, 3) if p50 is not None else None,
           'p99_ms': round(p99, 3) if p99 is not None else None,
           'p999_ms': round(p999, 3) if p999 is not None else None,
+          'queue_wait_p50_ms': (round(wait50, 3) if wait50 is not None
+                                else None),
+          'queue_wait_p99_ms': (round(wait99, 3) if wait99 is not None
+                                else None),
           'classes': classes,
           'shed': dict(self._shed_reason),
           'low_queue_depth': self.low_queue_depth,

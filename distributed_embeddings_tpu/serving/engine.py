@@ -374,14 +374,12 @@ class ServingEngine:
       raise ValueError(f'samples {real} outside [0, bucket {b}]')
     # ONE measurement feeds both the span and the histogram (the
     # trace-vs-stats agreement contract, obs/trace.py)
-    t0 = obs_trace.now()
+    tok = obs_trace.begin('serve/lookup', batch=b)
     try:
       padded = [self._pad_input(i, x, b) for i, x in enumerate(cats)]
       outs = self.dist.apply(self.params, padded)
     finally:
-      lookup_ms = (obs_trace.now() - t0) * 1000.0
-      obs_trace.complete('serve/lookup', t0, lookup_ms / 1000.0,
-                         batch=b)
+      lookup_ms = obs_trace.end(tok) * 1000.0
     with self._lock:
       self._batches_served += 1
       self._samples_served += real

@@ -186,18 +186,14 @@ class ServingEnginePool:
     degraded = self._note_submit(req)
     if degraded and priority == 'low' \
         and self.engines[idx].hot_filter_available:
-      t0 = obs_trace.now()
-      cats2, dropped, total = self.engines[idx].hot_only_filter(
-          req.cats)
+      with obs_trace.span('serve/degraded'):
+        cats2, dropped, total = self.engines[idx].hot_only_filter(
+            req.cats)
       req.cats = cats2
       req.degraded = True
       req.dropped = int(dropped)
       req.total = int(total)
       obs_metrics.inc('serve.degraded')
-      if obs_trace.enabled():
-        obs_trace.complete('serve/degraded', t0,
-                           max(0.0, obs_trace.now() - t0),
-                           dropped=req.dropped, total=req.total)
     self._dispatch(req, idx, raise_errors=True)
     return req.future
 
@@ -390,10 +386,11 @@ class ServingEnginePool:
         self._batchers[payload].close()
         continue
       req = payload
-      t0 = obs_trace.now() if obs_trace.enabled() else 0.0
-      wall0 = time.monotonic()
+      tok = obs_trace.begin('serve/failover', retries=req.retries,
+                            priority=req.priority)
       idx = self._pick_replica()
       if idx is None:
+        obs_trace.end(tok)
         self._finish(req, err=ReplicaLostError(
             'every replica is quarantined: nothing left to retry the '
             'request on (design §23)'))
@@ -404,11 +401,7 @@ class ServingEnginePool:
                          retries=req.retries, priority=req.priority)
       obs_metrics.inc('serve.failover')
       self._dispatch(req, idx, raise_errors=False)
-      failover_ms = (time.monotonic() - wall0) * 1000.0
-      obs_metrics.observe('serve.failover_ms', failover_ms)
-      if obs_trace.enabled() and t0:
-        obs_trace.complete('serve/failover', t0, failover_ms / 1000.0,
-                           replica=idx, retries=req.retries)
+      obs_metrics.observe('serve.failover_ms', obs_trace.end(tok) * 1000.0)
 
   # ----------------------------------------------------------- lifecycle
 

@@ -1,4 +1,13 @@
-"""Trace ONE steady-state hybrid sparse step (after layout stabilisation).
+"""Capture a few steady-state hybrid sparse steps and print where the
+chip's time went, phase by phase: the one capture script.
+
+Wraps its measured steps in ``obs.trace.profile`` (the JAX profiler with
+Python's call tracer off, plus the program's host spans on the same
+clock) and prints ``tools/trace_report.py --profile`` on what it wrote,
+together with the compiled step's memory analysis.  Run it from an EMPTY
+compile cache (``JAX_COMPILATION_CACHE_DIR=$(mktemp -d)``): the cache's
+key leaves metadata out, so a step served from a cache filled before its
+phases existed shows none.
 
 Usage: python examples/benchmarks/trace_step.py [--trace /tmp/trace_step]
        [--segwalk_apply] [--param_dtype bfloat16] [--model tiny]
@@ -7,16 +16,19 @@ Usage: python examples/benchmarks/trace_step.py [--trace /tmp/trace_step]
 import argparse
 import os
 import sys
-import time
+import tempfile
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..', '..'))
+ROOT = os.path.join(os.path.dirname(__file__), '..', '..')
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, 'tools'))
 
 
 def main():
   p = argparse.ArgumentParser()
   p.add_argument('--batch', type=int, default=65536)
   p.add_argument('--model', default='tiny')
-  p.add_argument('--trace', default='')
+  p.add_argument('--trace', default='',
+                 help='profile directory (default: a temporary one)')
   p.add_argument('--param_dtype', default='float32')
   p.add_argument('--segwalk_apply', action='store_true')
   p.add_argument('--capacity_fraction', type=float, default=0.5)
@@ -33,9 +45,11 @@ def main():
                                                            InputGenerator,
                                                            SyntheticModel)
   from distributed_embeddings_tpu.models.dlrm import bce_with_logits
+  from distributed_embeddings_tpu.obs import trace as obs_trace
   from distributed_embeddings_tpu.parallel import (SparseAdagrad, create_mesh,
                                                    init_hybrid_train_state,
                                                    make_hybrid_train_step)
+  import trace_report
 
   mesh = create_mesh(jax.devices())
   config = SYNTHETIC_MODELS[args.model]
@@ -45,7 +59,7 @@ def main():
   gen = InputGenerator(config, args.batch, alpha=1.05, num_batches=1, seed=0)
   (num0, cats0), labels0 = gen.pool[0]
   num0 = jnp.asarray(num0)
-  cats0 = tuple(jnp.asarray(c) for c in cats0)
+  cats0 = [jnp.asarray(c) for c in cats0]
   labels0 = jnp.asarray(labels0)
   dist = model.dist_embedding
 
@@ -68,28 +82,32 @@ def main():
     from distributed_embeddings_tpu.utils.apply_eligibility import (
         eligibility_line)
     print(eligibility_line(dist, args.param_dtype, args.segwalk_apply))
-  step = jax.jit(make_hybrid_train_step(dist, head_loss_fn, opt, emb_opt,
-                                        jit=False), donate_argnums=(0,))
+  step = make_hybrid_train_step(dist, head_loss_fn, opt, emb_opt)
   state = init_hybrid_train_state(dist, params, opt, emb_opt)
+  batch = (num0, labels0)
 
-  for i in range(2):
-    t0 = time.perf_counter()
-    state, loss = step(state, list(cats0), (num0, labels0))
-    loss.block_until_ready()
-    print(f'warmup {i}: {time.perf_counter() - t0:.1f}s')
+  compiled = step.jitted.lower(state, cats0, batch).compile()
+  memory = compiled.memory_analysis()
+  if memory is not None:
+    print('compiled step: arguments '
+          f'{memory.argument_size_in_bytes / 2**30:.2f} GiB + temporaries '
+          f'{memory.temp_size_in_bytes / 2**30:.2f} GiB '
+          f'(aliased {memory.alias_size_in_bytes / 2**30:.2f} GiB)')
+  for _ in range(2):  # the second absorbs a re-layout of the new state
+    state, loss = compiled(state, cats0, batch)
+  jax.block_until_ready((state, loss))
 
-  import contextlib
-  times = []
-  cm = (jax.profiler.trace(args.trace) if args.trace
-        else contextlib.nullcontext())
-  with cm:
+  directory = args.trace or tempfile.mkdtemp(prefix='trace_step_')
+  with obs_trace.profile(directory):
     for i in range(args.calls):
-      t0 = time.perf_counter()
-      state, loss = step(state, list(cats0), (num0, labels0))
-      loss.block_until_ready()
-      times.append(time.perf_counter() - t0)
-  print(f'steady-state step: {min(times)*1e3:.1f} ms '
-        f'(all: {[round(t*1e3) for t in times]})')
+      with obs_trace.span('train/step', step=i + 1):
+        state, loss = compiled(state, cats0, batch)
+        loss.block_until_ready()
+  print(f'profile written under {directory}')
+  report = trace_report.profile_report(
+      trace_report.load_profile(trace_report.find_profile(directory)),
+      program='jit_step')
+  print(trace_report.format_profile(report, children=True))
 
 
 if __name__ == '__main__':

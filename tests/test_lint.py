@@ -500,9 +500,11 @@ def test_fixture_transitive_impurity_and_call_form(tmp_path):
       [f.brief() for f in res.findings]
 
 
-def test_fixture_trace_spans_are_sanctioned(tmp_path):
-  """obs.trace spans inside traced code are the deliberate trace-time
-  instrument (design §15) — never a purity finding."""
+def test_fixture_phases_are_sanctioned_spans_are_not(tmp_path):
+  """Inside traced code ``obs.trace.phase`` (a named scope: metadata,
+  no host effect) is the sanctioned instrument (design §15) and never a
+  purity finding; a host span there would time Python's tracing, and
+  is one."""
   root = _fixture_tree(tmp_path, {
       'distributed_embeddings_tpu/okay.py': """
           import jax
@@ -511,11 +513,28 @@ def test_fixture_trace_spans_are_sanctioned(tmp_path):
 
           @jax.jit
           def step(x):
-            with obs_trace.span('fwd/exchange'):
+            with obs_trace.phase('fwd/exchange'):
               return x + 1
           """})
   res = run_passes(root, passes=['purity'])
   assert not res.findings, [f.brief() for f in res.findings]
+  root = _fixture_tree(tmp_path / 'bad', {
+      'distributed_embeddings_tpu/bad.py': """
+          import jax
+
+          from distributed_embeddings_tpu.obs import trace as obs_trace
+
+          @jax.jit
+          def step(x):
+            tok = obs_trace.begin('train/step')
+            y = x + 1
+            obs_trace.end(tok)
+            return y
+          """})
+  res = run_passes(root, passes=['purity'])
+  assert sum(f.rule == 'purity/host-effect-in-traced'
+             and ':span:' in f.symbol for f in res.findings) == 2, \
+      [f.brief() for f in res.findings]
 
 
 # --------------------------------------------------------------------------

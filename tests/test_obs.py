@@ -55,11 +55,11 @@ def test_trace_round_trip_is_valid_chrome_trace(tmp_path):
   tool validates (names/ph/ts, X durations, b/e async pairing)."""
   obs.enable()
   with obs_trace.span('train/step', step=1):
-    tok = obs_trace.begin('fwd/exchange')
-    obs_trace.end(tok)
+    tok = obs_trace.begin('feed/build', seq=0)
+    assert obs_trace.end(tok) >= 0.0   # the seconds it measured
     with obs_trace.span('audit/check'):
       pass
-  obs_trace.complete('feed/wait', obs_trace.now() - 0.003, 0.003, seq=0)
+  obs_trace.complete('dev/fwd/exchange', obs_trace.now() - 0.003, 0.003)
   obs_trace.async_span('serve/enqueue', 42, obs_trace.now() - 0.001,
                        obs_trace.now(), samples=2)
   obs_trace.instant('train/step', note='marker')
@@ -91,7 +91,7 @@ def test_trace_round_trip_is_valid_chrome_trace(tmp_path):
     if ev['ph'] == 'X':
       assert ev['dur'] >= 0
   assert names <= obs.REGISTERED_SPANS
-  assert {'train/step', 'fwd/exchange', 'audit/check', 'feed/wait',
+  assert {'train/step', 'dev/fwd/exchange', 'audit/check',
           'serve/enqueue', 'feed/build'} <= names
   # the report tool's validator accepts the same file (one schema)
   tr = _load_trace_report()
@@ -198,9 +198,13 @@ def test_disabled_spans_and_counters_are_noops(tmp_path, monkeypatch):
   resilience.clear_recent()
   # every disabled span is ONE shared object: nothing allocated
   assert obs_trace.span('train/step', step=1) is obs_trace.span('feed/wait')
-  assert obs_trace.begin('fwd/exchange') is None
-  obs_trace.end(None)
-  obs_trace.complete('feed/wait', 0.0, 1.0)
+  # a disabled begin is the bare start time, so end() still hands the
+  # caller's stats counter its seconds: no span, no annotation
+  tok = obs_trace.begin('feed/wait')
+  assert isinstance(tok, float)
+  assert 0.0 <= obs_trace.end(tok) < 1.0
+  assert obs_trace.end(None) == 0.0
+  obs_trace.complete('dev/fwd/exchange', 0.0, 1.0)
   obs_trace.async_span('serve/enqueue', 1, 0.0, 1.0)
   obs_trace.instant('train/step')
   assert obs_trace.event_count() == 0
@@ -360,7 +364,7 @@ def test_trace_report_attribution_and_gates(tmp_path):
   base = obs_trace.now() - 0.1
   for k in range(3):
     with obs_trace.span('train/step', step=k + 1):
-      tok = obs_trace.begin('fwd/exchange')
+      tok = obs_trace.begin('feed/build')
       obs_trace.end(tok)
     # three DISJOINT 2 ms syncs (3 ms apart): blocked union must be 6
     obs_trace.complete('train/sync', base + k * 0.003, 0.002,
@@ -376,7 +380,7 @@ def test_trace_report_attribution_and_gates(tmp_path):
   assert rep['phases']['train/step']['count'] == 3
   assert len(rep['steps']) == 3
   assert [s['step'] for s in rep['steps']] == [1, 2, 3]
-  assert all('fwd/exchange' in s['phases'] for s in rep['steps'])
+  assert all('feed/build' in s['phases'] for s in rep['steps'])
   # union semantics: 3 disjoint 2 ms + 2 fully-overlapped extras = ~6.5
   assert rep['critical_path']['blocked_ms'] == pytest.approx(6.5,
                                                              abs=0.5)
@@ -385,7 +389,10 @@ def test_trace_report_attribution_and_gates(tmp_path):
   text = tr.format_report(rep)
   assert 'per-step breakdown' in text and 'train/step' in text
   assert tr.main([path]) == 0
-  assert tr.main([path, '--require', 'train/step,fwd/exchange']) == 0
+  assert tr.main([path, '--require', 'train/step,feed/build']) == 0
+  # a device phase is no host span: the obs file never holds one
+  assert tr.main([path, '--require', 'fwd/exchange']) == 4
+  assert 'trace_time_ms' not in rep['critical_path']
   assert tr.main([path, '--require', 'coldtier/fetch']) == 4
 
 
@@ -571,11 +578,13 @@ def test_span_and_metric_names_registered_detlint():
   from distributed_embeddings_tpu.analysis import run_passes
   res = run_passes(str(ROOT), passes=['registry'])
   bad = [f for f in (res.findings + res.unverifiable + res.waived)
-         if f.rule.startswith(('registry/span', 'registry/metric'))
+         if f.rule.startswith(('registry/span', 'registry/phase',
+                               'registry/metric'))
          or f.rule == 'registry/unverifiable-name']
   assert not bad, '\n'.join(f.brief() for f in bad)
   # the scan-not-broken guard the regex tests carried: real sites seen
   assert res.meta['registry_sites']['span'] > 10
+  assert res.meta['registry_sites']['phase'] > 40
   assert res.meta['registry_sites']['metric'] > 10
 
 
@@ -594,12 +603,15 @@ def test_span_and_metric_enforcement_no_weaker(tmp_path):
       "  tok = obs_trace.begin('typo/phase')\n"
       "  obs_trace.end(tok)\n"
       "  with obs_trace.span('another/typo'):\n"
-      "    metrics.inc('typo.metric')\n")
+      "    metrics.inc('typo.metric')\n"
+      "  with obs_trace.phase('fwd/gather'):\n"
+      "    pass\n")
   res = run_passes(str(tmp_path), passes=['registry'])
   caught = {(f.rule, f.symbol) for f in res.findings}
   assert ('registry/span-unregistered', 'typo/phase') in caught
   assert ('registry/span-unregistered', 'another/typo') in caught
   assert ('registry/metric-unregistered', 'typo.metric') in caught
+  assert ('registry/phase-unregistered', 'fwd/gather') in caught
 
 
 # --------------------------------------------------------------------------
@@ -608,10 +620,12 @@ def test_span_and_metric_enforcement_no_weaker(tmp_path):
 
 
 def test_traced_training_plus_serving_single_file(tmp_path):
-  """A traced 3-step training run (host CSR build through a CsrFeed,
-  exchange, lookup/combine, apply) plus one batched serving request
-  produce ONE Perfetto-loadable trace whose phase set covers the whole
-  step and stays inside the registered taxonomy."""
+  """A traced 3-step training run (host CSR build through a CsrFeed)
+  plus one batched serving request produce ONE Perfetto-loadable trace
+  whose span set covers the host side of the whole step and stays
+  inside the registered taxonomy.  The compiled step's own sections are
+  device phases (tests/test_phases.py): tracing the step emits no host
+  span for them."""
   import optax
   from distributed_embeddings_tpu import serving
   from distributed_embeddings_tpu.parallel import (
@@ -666,13 +680,14 @@ def test_traced_training_plus_serving_single_file(tmp_path):
   tr = _load_trace_report()
   rep = tr.report(tr.load_trace(path))
   required = {'train/step', 'train/sync', 'feed/build', 'feed/wait',
-              'fwd/exchange', 'fwd/lookup_combine', 'bwd/exchange',
-              'apply/update', 'serve/submit', 'serve/enqueue',
+              'serve/submit', 'serve/enqueue',
               'serve/dispatch', 'serve/lookup', 'serve/execute',
               'serve/demux'}
   have = set(rep['phases'])
   assert required <= have, f'missing spans: {required - have}'
   assert have <= obs.REGISTERED_SPANS, have - obs.REGISTERED_SPANS
+  assert not have & set(obs_trace.REGISTERED_PHASES), \
+      'a device phase leaked into the host timeline'
   assert rep['unregistered'] == []
   assert tr.main([path, '--strict',
                   '--require', ','.join(sorted(required))]) == 0

@@ -1,12 +1,36 @@
 #!/usr/bin/env python3
-"""Stall-attribution report over an obs trace file (design §15).
+"""Where a step's time goes: the report an operator reads (design §15).
 
-Reads a Chrome-trace-event JSON written by
-``distributed_embeddings_tpu.obs.trace.save()`` and prints:
+Two inputs, one vocabulary (``obs.trace.REGISTERED_SPANS`` for host
+code, ``REGISTERED_PHASES`` for sections of the compiled step).
+
+``--profile <dir>`` reads the JAX profiler's ``*.trace.json.gz`` that
+``obs.trace.profile(dir)`` (or any ``jax.profiler`` session) left under
+``dir`` and prints, per step of the named program on the busiest chip:
+
+- device ms per phase.  An op belongs to the innermost registered phase
+  in its ``tf_op`` path (``jvp(x)`` / ``transpose(jvp(x))`` unwrapped
+  to ``x``); ``--children`` splits each phase by table group, ``--ops
+  N`` lists each phase's N longest ops;
+- two remainders, kept apart: ``unscoped`` (the op has a ``tf_op`` and
+  no registered phase in it: a hole in the program's coverage) and
+  ``no_source`` (XLA made the op, ``tf_op`` empty: listed by instruction
+  name with its HLO text, so a whole-shard copy's operand can be read);
+- the step period on the device's clock, and every idle gap over 100 us
+  on that chip named by the innermost program or caller host span over
+  its middle.
+
+Phases and the two remainders are SELF times (a ``while`` or
+``conditional`` does not count its body twice), so they sum to the
+chip's busy time.  A program served from a compile cache filled before
+its phases existed shows none: capture from an empty cache directory
+(docs/userguide.md).
+
+Without ``--profile`` the argument is a Chrome-trace-event JSON written
+by ``distributed_embeddings_tpu.obs.trace.save()``, and the report is:
 
 - the per-phase totals table (count / total / mean ms, grouped by the
-  span taxonomy's category: host work, wait = blocked time, trace-time
-  program phases);
+  span taxonomy's category: host work, wait = blocked time);
 - the per-step breakdown: for every ``train/step`` span, the host
   phases and blocked time that landed inside its window plus the step's
   own wall — generalizing the consumer-blocked-time accounting
@@ -17,19 +41,26 @@ Reads a Chrome-trace-event JSON written by
 
 Usable as a CI gate: exits nonzero on a malformed or truncated trace
 (rc 2), on unregistered span names under ``--strict`` (rc 3), and on
-missing required spans under ``--require`` (rc 4) — a pipeline step
-that produces a trace can assert its phase coverage instead of
-trusting it.
+missing required spans (``--profile``: phases or host spans) under
+``--require`` (rc 4) — a pipeline step that produces a trace can assert
+its phase coverage instead of trusting it.  ``--profile --strict``
+exits 3 when ``unscoped`` passes 2% of the busy time or a host span of
+the program's families is not registered.
 
     python tools/trace_report.py /tmp/trace.json
     python tools/trace_report.py trace.json --strict \
-        --require train/step,fwd/exchange --json
+        --require train/step,feed/wait --json
+    python tools/trace_report.py --profile /tmp/prof --children \
+        --require fwd/lookup_combine,apply/write_rows
 """
 
 from __future__ import annotations
 
+import glob
+import gzip
 import json
 import os
+import re
 import sys
 
 from typing import Any, Dict, List, Optional
@@ -41,7 +72,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import _cli  # noqa: E402
 
 from distributed_embeddings_tpu.obs.trace import (  # noqa: E402
-    REGISTERED_SPANS, span_category)
+    REGISTERED_PHASES, REGISTERED_SPANS, phase_of, span_category)
 
 _KNOWN_PH = {'X', 'B', 'E', 'b', 'e', 'i', 'M'}
 
@@ -221,8 +252,6 @@ def report(events) -> Dict[str, Any]:
               union_ms([r for r in rows if r['cat'] == 'host']), 3),
           'blocked_ms': round(
               union_ms([r for r in rows if r['cat'] == 'wait']), 3),
-          'trace_time_ms': round(
-              union_ms([r for r in rows if r['cat'] == 'trace']), 3),
           # wall not covered by any host/wait span: device execution
           # and untraced host code — the honest remainder, never
           # claimed as attributed
@@ -250,7 +279,6 @@ def format_report(rep: Dict[str, Any]) -> str:
   out.append('critical path: '
              f"host {cp['host_ms']:.1f} ms, "
              f"blocked {cp['blocked_ms']:.1f} ms, "
-             f"trace-time {cp['trace_time_ms']:.1f} ms, "
              f"unattributed (device + untraced host) "
              f"{cp['unattributed_ms']:.1f} ms")
   if cp.get('device_ms'):
@@ -275,19 +303,287 @@ def format_report(rep: Dict[str, Any]) -> str:
   return '\n'.join(out)
 
 
+# --------------------------------------------------------------------------
+# --profile: the JAX profiler's trace, device ops by phase
+# --------------------------------------------------------------------------
+
+IDLE_GAP_US = 100.0
+UNSCOPED_LIMIT = 0.02
+# a caller's own annotation follows the program's convention
+# (``bench/window``); the runtime's internal TraceMe names do not
+_CALLER_SPAN = re.compile(r'^[a-z][a-z0-9_]*(/[a-z0-9_]+)+$')
+_SPAN_FAMILIES = frozenset(n.split('/')[0] for n in REGISTERED_SPANS)
+
+
+def find_profile(directory: str) -> str:
+  """Newest ``*.trace.json.gz`` under ``directory`` (a file is itself)."""
+  if os.path.isfile(directory):
+    return directory
+  paths = sorted(glob.glob(os.path.join(directory, '**', '*.trace.json.gz'),
+                           recursive=True), key=os.path.getmtime)
+  if not paths:
+    raise TraceFormatError(f'{directory}: no *.trace.json.gz under it')
+  return paths[-1]
+
+
+def load_profile(path: str) -> List[Dict[str, Any]]:
+  try:
+    with gzip.open(path, 'rt', encoding='utf-8') as f:
+      payload = json.load(f)
+  except (OSError, EOFError) as e:
+    raise TraceFormatError(f'{path}: unreadable/truncated: {e}') from e
+  except json.JSONDecodeError as e:
+    raise TraceFormatError(f'{path}: malformed/truncated JSON: {e}') from e
+  events = payload.get('traceEvents') if isinstance(payload, dict) else None
+  if not isinstance(events, list):
+    raise TraceFormatError(f'{path}: no traceEvents list (not a trace file)')
+  return events
+
+
+def _self_times(ops):
+  """``[(event, self_us)]`` for one thread's X events: an op that
+  encloses others (``while``, ``conditional``) keeps only the time its
+  children do not cover, so the self times sum to the union."""
+  out, stack = [], []   # stack of [event, end, child_us]
+
+  def pop():
+    ev, _, child = stack.pop()
+    out.append((ev, max(0.0, ev['dur'] - child)))
+
+  for ev in sorted(ops, key=lambda e: (e['ts'], -e['dur'])):
+    while stack and ev['ts'] >= stack[-1][1]:
+      pop()
+    if stack:
+      stack[-1][2] += ev['dur']
+    stack.append([ev, ev['ts'] + ev['dur'], 0.0])
+  while stack:
+    pop()
+  return out
+
+
+def profile_report(events, program: Optional[str] = None, ops_n: int = 0
+                   ) -> Dict[str, Any]:
+  """The analysis dict of one profiler trace (``format_profile`` renders
+  it, ``--json`` emits it).  Times are ms per step on the busiest chip."""
+  proc, thread = {}, {}
+  for e in events:
+    if e.get('ph') == 'M' and e.get('name') == 'process_name':
+      proc[e['pid']] = e['args']['name']
+    elif e.get('ph') == 'M' and e.get('name') == 'thread_name':
+      thread[(e['pid'], e['tid'])] = e['args']['name']
+  xs = [e for e in events if e.get('ph') == 'X' and 'dur' in e]
+  host = [e for e in xs if proc.get(e['pid'], '').startswith('/host:')]
+  spans = [e for e in host if e['name'] in REGISTERED_SPANS
+           or _CALLER_SPAN.match(str(e['name']))]
+  host_spans: Dict[str, Dict[str, Any]] = {}
+  for e in spans:
+    h = host_spans.setdefault(e['name'], {'count': 0, 'total_ms': 0.0})
+    h['count'] += 1
+    h['total_ms'] += e['dur'] / 1000.0
+  for h in host_spans.values():
+    h['total_ms'] = round(h['total_ms'], 3)
+  rep: Dict[str, Any] = {
+      'chip': None, 'chips': [], 'program': None, 'steps': 0,
+      'step_period_ms': None, 'busy_ms': 0.0, 'phases': {},
+      'unscoped': {'ms': 0.0, 'share': 0.0, 'ops': []},
+      'no_source': {'ms': 0.0, 'share': 0.0, 'ops': []},
+      'idle_gaps': [], 'host_spans': dict(sorted(host_spans.items())),
+      'unregistered': sorted(
+          n for n in host_spans if n not in REGISTERED_SPANS
+          and n.split('/')[0] in _SPAN_FAMILIES),
+  }
+  chips = sorted(p for p, n in proc.items() if n.startswith('/device:'))
+  per_chip = {}
+  for pid in chips:
+    ops = [e for e in xs if e['pid'] == pid
+           and thread.get((pid, e['tid'])) == 'XLA Ops']
+    if ops:
+      per_chip[pid] = _self_times(ops)
+  rep['chips'] = [proc[p] for p in per_chip]
+  if not per_chip:
+    return rep
+  busiest = max(per_chip, key=lambda p: sum(s for _, s in per_chip[p]))
+  rep['chip'] = proc[busiest]
+  timed = per_chip[busiest]
+  runs_of: Dict[str, list] = {}
+  for e in xs:
+    if e['pid'] == busiest and thread.get((busiest, e['tid'])) \
+        == 'XLA Modules':
+      runs_of.setdefault(re.sub(r'\(\d+\)$', '', e['name']), []).append(e)
+  device_us = {n: sum(e['dur'] for e in r) for n, r in runs_of.items()}
+  pick = None
+  if program:
+    pick = max((n for n in runs_of if program in n), key=device_us.get,
+               default=None)
+  if pick is None and runs_of:
+    pick = max(runs_of, key=device_us.get)
+  if pick is not None:
+    runs = sorted(runs_of[pick], key=lambda e: e['ts'])
+    rep['program'] = pick
+    rep['steps'] = len(runs)
+    rep['step_period_ms'] = round(
+        ((runs[-1]['ts'] - runs[0]['ts']) / (len(runs) - 1)
+         if len(runs) > 1 else runs[0]['dur']) / 1000.0, 4)
+    # only what ran inside a run of the program counts as the step
+    bounds = [(r['ts'], r['ts'] + r['dur']) for r in runs]
+
+    def in_step(e):
+      mid = e['ts'] + e['dur'] / 2.0
+      return any(lo <= mid <= hi for lo, hi in bounds)
+
+    timed = [(e, s) for e, s in timed if in_step(e)]
+  steps = max(1, rep['steps'])
+  per_ms = 1.0 / 1000.0 / steps
+  busy = sum(s for _, s in timed)
+  rep['busy_ms'] = round(busy * per_ms, 4)
+  phases: Dict[str, Dict[str, Any]] = {}
+  loose = {'unscoped': {}, 'no_source': {}}
+  produced_in: Dict[str, str] = {}   # instruction -> the phase that made it
+  for e, self_us in timed:
+    args = e.get('args') or {}
+    tf_op = args.get('tf_op', '')
+    found = phase_of(tf_op) if tf_op else None
+    if found is None:
+      kind = 'unscoped' if tf_op else 'no_source'
+      row = loose[kind].setdefault(
+          e['name'], {'name': e['name'], 'ms': 0.0, 'tf_op': tf_op,
+                      'hlo': args.get('long_name', '')})
+      row['ms'] += self_us * per_ms
+      continue
+    name, child = found
+    produced_in[e['name']] = f'{name}/{child}' if child else name
+    p = phases.setdefault(name, {
+        'layer': REGISTERED_PHASES[name], 'ms': 0.0, 'children': {},
+        'by_primitive': {}, 'ops': {}})
+    ms = self_us * per_ms
+    p['ms'] += ms
+    key = child or '-'
+    p['children'][key] = p['children'].get(key, 0.0) + ms
+    prim = tf_op.rstrip(':').rsplit('/', 1)[-1]
+    p['by_primitive'][prim] = p['by_primitive'].get(prim, 0.0) + ms
+    p['ops'][e['name']] = p['ops'].get(e['name'], 0.0) + ms
+
+  def top(d, n):
+    return {k: round(v, 4) for k, v in
+            sorted(d.items(), key=lambda kv: -kv[1])[:n or None]}
+
+  for name in sorted(phases, key=lambda n: -phases[n]['ms']):
+    p = phases[name]
+    rep['phases'][name] = {
+        'layer': p['layer'], 'ms': round(p['ms'], 4),
+        'share': round(p['ms'] / (busy * per_ms), 5) if busy else 0.0,
+        'children': top(p['children'], 0),
+        'by_primitive': top(p['by_primitive'], 0),
+        'ops': top(p['ops'], ops_n) if ops_n else {},
+    }
+  # an op XLA made has no source, but its operands may: name the phases
+  # that produced what it reads (a copy of a scatter's output)
+  for row in loose['no_source'].values():
+    operands = re.findall(r'%([\w.\-]+)', row['hlo'])[1:]
+    row['reads'] = {o: produced_in[o] for o in operands if o in produced_in}
+  for kind, rows in loose.items():
+    total = sum(r['ms'] for r in rows.values())
+    rep[kind] = {
+        'ms': round(total, 4),
+        'share': round(total / (busy * per_ms), 5) if busy else 0.0,
+        'ops': [dict(r, ms=round(r['ms'], 4)) for r in
+                sorted(rows.values(), key=lambda r: -r['ms'])],
+    }
+  # idle gaps between two ops on the chip, each named by the innermost
+  # host span over its middle
+  all_ops = sorted(per_chip[busiest], key=lambda es: es[0]['ts'])
+  edge = None
+  for e, _ in all_ops:
+    if edge is not None and e['ts'] - edge > IDLE_GAP_US:
+      mid = (edge + e['ts']) / 2.0
+      cover = [s for s in spans
+               if s['ts'] <= mid <= s['ts'] + s['dur']]
+      inner = min(cover, key=lambda s: s['dur'], default=None)
+      rep['idle_gaps'].append({
+          'ms': round((e['ts'] - edge) / 1000.0, 4),
+          'span': inner['name'] if inner else 'no span',
+          'before': e['name']})
+    edge = max(edge or 0.0, e['ts'] + e['dur'])
+  rep['idle_gaps'].sort(key=lambda g: -g['ms'])
+  return rep
+
+
+def format_profile(rep: Dict[str, Any], children: bool = False) -> str:
+  if rep['chip'] is None:
+    lines = ['profile: no device plane with XLA ops in this trace '
+             '(a CPU session has none); host spans only']
+  else:
+    lines = [
+        f"profile: {rep['program']} x {rep['steps']} step(s) on "
+        f"{rep['chip']} (busiest of {len(rep['chips'])}); step period "
+        f"{rep['step_period_ms']} ms, busy {rep['busy_ms']:.3f} ms/step",
+        '',
+        f"{'phase':<24} {'layer':<18} {'ms/step':>10} {'share':>8}"]
+    for name, p in rep['phases'].items():
+      lines.append(f"{name:<24} {p['layer']:<18} {p['ms']:>10.3f} "
+                   f"{100 * p['share']:>7.2f}%")
+      if children:
+        for child, ms in p['children'].items():
+          lines.append(f"  {child:<22} {'':<18} {ms:>10.3f}")
+      for op, ms in p['ops'].items():
+        lines.append(f"    {op:<38} {ms:>10.3f}")
+    for kind in ('unscoped', 'no_source'):
+      r = rep[kind]
+      lines.append(f"{kind:<24} {'-':<18} {r['ms']:>10.3f} "
+                   f"{100 * r['share']:>7.2f}%")
+    for kind, what in (('unscoped', 'tf_op'), ('no_source', 'hlo')):
+      if rep[kind]['ops']:
+        lines += ['', f'{kind} ops (ms/step, name, {what}):']
+        for r in rep[kind]['ops'][:20]:
+          reads = ''.join(f' [{o} <- {ph}]'
+                          for o, ph in r.get('reads', {}).items())
+          lines.append(f"  {r['ms']:>9.3f}  {r['name']}{reads}  "
+                       f"{r[what][:160]}")
+    lines += ['', f"idle gaps over {IDLE_GAP_US:.0f} us: "
+              f"{len(rep['idle_gaps'])}"]
+    for g in rep['idle_gaps'][:20]:
+      lines.append(f"  {g['ms']:>9.3f} ms under {g['span']} "
+                   f"(before {g['before']})")
+  if rep['host_spans']:
+    lines += ['', f"{'host span':<24} {'count':>6} {'total_ms':>10}"]
+    for name, h in rep['host_spans'].items():
+      lines.append(f"{name:<24} {h['count']:>6} {h['total_ms']:>10.3f}")
+  if rep['unregistered']:
+    lines += ['', 'WARNING: unregistered span name(s): '
+              + ', '.join(rep['unregistered'])]
+  return '\n'.join(lines)
+
+
 def main(argv: Optional[List[str]] = None) -> int:
   ap = _cli.make_parser(
       'trace_report',
-      description='Per-step phase breakdown + stall attribution over an '
-      'obs Chrome-trace file; nonzero exit on a malformed trace '
-      '(pipeline-gate friendly).',
+      description='Per-step phase breakdown: device ms per registered '
+      'phase from a profiler trace (--profile), or host spans + stall '
+      'attribution over an obs Chrome-trace file; nonzero exit on a '
+      'malformed trace (pipeline-gate friendly).',
       strict_help='exit 3 when any span name is not in '
-      'obs.REGISTERED_SPANS')
-  ap.add_argument('trace', help='trace JSON written by obs.trace.save()')
+      'obs.REGISTERED_SPANS (--profile: also when unscoped device time '
+      'passes 2%% of busy)')
+  ap.add_argument('trace', nargs='?', default=None,
+                  help='trace JSON written by obs.trace.save()')
+  ap.add_argument('--profile', default=None, metavar='DIR',
+                  help="a JAX profiler directory (obs.trace.profile's) or "
+                  'its *.trace.json.gz: report device time by phase')
+  ap.add_argument('--program', default=None,
+                  help='--profile: substring of the module whose runs are '
+                  'the steps (default: the one with most device time)')
+  ap.add_argument('--children', action='store_true',
+                  help='--profile: split each phase by table group')
+  ap.add_argument('--ops', type=int, default=0, metavar='N',
+                  help="--profile: list each phase's N longest ops")
   ap.add_argument('--require', default=None,
-                  help='comma-separated span names that must appear; '
-                  'exit 4 otherwise')
+                  help='comma-separated span (--profile: phase or span) '
+                  'names that must appear; exit 4 otherwise')
   args = ap.parse_args(argv)
+  if (args.trace is None) == (args.profile is None):
+    ap.error('give a trace file or --profile DIR (one of them)')
+  if args.profile is not None:
+    return _main_profile(args)
   try:
     events = load_trace(args.trace)
   except TraceFormatError as e:
@@ -303,6 +599,31 @@ def main(argv: Optional[List[str]] = None) -> int:
     if missing:
       return _cli.fail('trace_report', 'REQUIRE',
                        f'missing span(s) {missing}')
+  return _cli.EXIT_OK
+
+
+def _main_profile(args) -> int:
+  try:
+    events = load_profile(find_profile(args.profile))
+  except TraceFormatError as e:
+    return _cli.fail('trace_report', 'MALFORMED', e)
+  rep = profile_report(events, program=args.program, ops_n=args.ops)
+  _cli.emit(rep, args.json, lambda: format_profile(rep, args.children))
+  if args.strict and rep['unregistered']:
+    return _cli.fail('trace_report', 'STRICT',
+                     f"unregistered span name(s) {rep['unregistered']}")
+  if args.strict and rep['unscoped']['share'] > UNSCOPED_LIMIT:
+    return _cli.fail(
+        'trace_report', 'STRICT',
+        f"unscoped device time is {100 * rep['unscoped']['share']:.2f}% "
+        f'of busy (limit {100 * UNSCOPED_LIMIT:.0f}%): a section of the '
+        'program has no registered phase')
+  if args.require:
+    have = set(rep['phases']) | set(rep['host_spans'])
+    missing = [n for n in args.require.split(',') if n and n not in have]
+    if missing:
+      return _cli.fail('trace_report', 'REQUIRE',
+                       f'missing phase(s)/span(s) {missing}')
   return _cli.EXIT_OK
 
 
