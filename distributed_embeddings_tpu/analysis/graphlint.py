@@ -29,7 +29,12 @@ Passes (each a callable ``(programs) -> findings`` in ``PASSES``):
   flake wedges a test — attribution instead of a rerun note.
 - ``donation``   — every param/optimizer leaf of the sparse train step
   must be donated AND actually input-output aliased in the compiled
-  executable (an undonated table shard is a silent 2x HBM tax).
+  executable (an undonated table shard is a silent 2x HBM tax), and no
+  embedding-state leaf may pass a ``cond``: XLA gives each branch of a
+  conditional its own operand, so a table that enters one is copied
+  whole once per branch, every step, taken or not (ISSUE 25: 47% of
+  dlrm-train-4chip's step).  A zero-or-one-trip ``while`` carries the
+  same rare work on one buffer.
 - ``retrace``    — hash (shape, dtype, weak_type, static-arg)
   signatures per compiled function; zero retraces across a 3-step fit
   and a warmed serving ladder, naming the drifting leaf (weak_type
@@ -60,6 +65,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 import re
 
@@ -179,6 +185,10 @@ class Program:
   # dcn-replicated grad all_gather) as (primitive, axis) pairs
   plan_expect: Optional[List[Dict[str, Any]]] = None
   sync_allowance: Tuple[Tuple[str, str], ...] = ()
+  # the embedding-state leaves (tables, scales, optimizer slots) as ONE
+  # device holds them — ``device_state_leaves`` — which no ``cond`` of
+  # the program may take or return (donation pass)
+  state_leaves: Optional[List['StateLeaf']] = None
   # memoized derived facts: the HLO alias parse (a full as_text dump)
   # and the jaxpr walk are each needed by a pass AND the meta ledger —
   # computed once per program, not once per consumer
@@ -215,6 +225,54 @@ def measure_resident_bytes(tree) -> int:
     dev = shards[0].device
     total += sum(int(s.data.nbytes) for s in shards if s.device == dev)
   return total
+
+
+@dataclasses.dataclass(frozen=True)
+class StateLeaf:
+  """One embedding-state leaf as a single device holds it: the shard's
+  shape without its leading unit axes (``shard_map`` hands the apply
+  ``[1, rows, w]``; ``_dedup_and_apply`` works on ``[rows, w]``)."""
+  label: str
+  shape: Tuple[int, ...]
+  dtype: str
+
+  def held_by(self, aval) -> bool:
+    """Whether ``aval`` is big enough to BE this leaf: the shard
+    itself, the shard with rows appended (the cold tier concatenates
+    the batch's fetched tail rows onto it), or a reshaped view of it
+    (packed <-> natural storage)."""
+    shape = getattr(aval, 'shape', None)
+    if shape is None or str(getattr(aval, 'dtype', '')) != self.dtype:
+      return False
+    shape = _squeeze_leading(shape)
+    if (len(shape) == len(self.shape) and shape[1:] == self.shape[1:]
+        and shape[0] >= self.shape[0]):
+      return True
+    return math.prod(shape) == math.prod(self.shape)
+
+
+def _squeeze_leading(shape) -> Tuple[int, ...]:
+  shape = tuple(int(d) for d in shape)
+  while len(shape) > 1 and shape[0] == 1:
+    shape = shape[1:]
+  return shape
+
+
+def device_state_leaves(tree) -> List[StateLeaf]:
+  """The table-shaped leaves (two or more axes on a device) of a
+  sharded embedding-state pytree, each as ``StateLeaf``."""
+  import jax
+  out = []
+  for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+    shape = tuple(leaf.shape)
+    sharding = getattr(leaf, 'sharding', None)
+    if sharding is not None:
+      shape = sharding.shard_shape(shape)
+    shape = _squeeze_leading(shape)
+    if len(shape) >= 2:
+      out.append(StateLeaf(jax.tree_util.keystr(path), shape,
+                           str(leaf.dtype)))
+  return out
 
 
 # --------------------------------------------------------------------------
@@ -307,6 +365,29 @@ def _callback_sites(jaxpr) -> List[str]:
   inner = getattr(jaxpr, 'jaxpr', jaxpr)
   return [eqn.primitive.name for eqn, _ in _walk_eqns(inner)
           if eqn.primitive.name in HOST_CALLBACK_PRIMITIVES]
+
+
+def state_carriers(jaxpr, leaves: Sequence[StateLeaf],
+                   primitive: str = 'cond') -> List[Tuple[int, str]]:
+  """``(index, leaf label)`` for every ``primitive`` eqn (counted in
+  program order over the jaxpr and its sub-jaxprs) that takes or
+  returns a value holding one of ``leaves``.  ``'cond'`` is what the
+  donation pass refuses; ``'while'`` is how the overflow correction of
+  ``parallel/sparse._dedup_and_apply`` carries its shards instead."""
+  inner = getattr(jaxpr, 'jaxpr', jaxpr)
+  out = []
+  idx = 0
+  for eqn, _ in _walk_eqns(inner):
+    if eqn.primitive.name != primitive:
+      continue
+    avals = [getattr(v, 'aval', None)
+             for v in list(eqn.invars) + list(eqn.outvars)]
+    hit = next((leaf.label for leaf in leaves
+                if any(leaf.held_by(a) for a in avals)), None)
+    if hit is not None:
+      out.append((idx, hit))
+    idx += 1
+  return out
 
 
 # --------------------------------------------------------------------------
@@ -528,6 +609,21 @@ def _schedule_pass(programs: List[Program]) -> List[Finding]:
 @_register('donation')
 def _donation_pass(programs: List[Program]) -> List[Finding]:
   findings: List[Finding] = []
+  for prog in programs:
+    if prog.state_leaves and prog.jaxpr is not None:
+      for idx, leaf in state_carriers(prog.jaxpr, prog.state_leaves):
+        findings.append(Finding(
+            rule='donation/state-leaf-in-cond', path=prog.name, line=0,
+            symbol=f'cond#{idx}',
+            message=f'cond #{idx} takes or returns a value the size of '
+            f'embedding-state leaf {leaf} — XLA hands each branch of a '
+            'conditional its own operand, so the whole shard is copied '
+            'once per branch on every step, taken or not (ISSUE 25: '
+            '%copy.346/%copy.347 of f32[20025088,128], 62 of '
+            "dlrm-train-4chip's 132 ms).  Keep the shard outside: carry "
+            'it through a zero-or-one-trip while_loop (one buffer '
+            'through init, body and result), or let the cond yield '
+            'only the rows to write'))
   for prog in programs:
     if prog.donate_expected is None or prog.compiled is None:
       continue
@@ -956,7 +1052,9 @@ def build_programs(tier: str = 'flagship') -> List[Program]:
                    resident_state_bytes=measure_resident_bytes(
                        (state.params['embedding'],
                         state.opt_state[1])),
-                   plan_expect=plan_expectation(dist, ('dp', 'bwd')))
+                   plan_expect=plan_expectation(dist, ('dp', 'bwd')),
+                   state_leaves=device_state_leaves(
+                       (state.params['embedding'], state.opt_state[1])))
     if chunks == 1:
       # the 3-step-fit retrace + host-sync proof rides on the
       # monolithic step: execute the AOT executable (no second trace),
@@ -1022,6 +1120,8 @@ def build_programs(tier: str = 'flagship') -> List[Program]:
                          (state.params['embedding'],
                           state.opt_state[1])),
                      plan_expect=plan_expectation(dist, ('dp', 'bwd')),
+                     state_leaves=device_state_leaves(
+                         (state.params['embedding'], state.opt_state[1])),
                      # the apply stage syncs grads across slices with a
                      # collective the plan records no leg for — the
                      # sharded arm's per-group DCN update all_to_all
@@ -1088,4 +1188,33 @@ def build_programs(tier: str = 'flagship') -> List[Program]:
   fetch = d_tier.build_cold_fetch(cats_c)
   forward_program('serve/coldfetch', d_tier, p_tier, cats_c,
                   fetch=de._forward_fetch(fetch.device))
+
+  # ---- the train step of that int8 cold-tier layer (trace-only) ------
+  # the apply's operand here is the (payload, scale) PAIR with the
+  # batch's fetched tail rows concatenated on, optimizer rows likewise
+  # (design §12): the widest thing the overflow correction ever carries.
+  # capacity_rows=8 keeps the correction in the program at this size.
+  opt_t = SparseAdagrad(learning_rate=0.05,
+                        capacity_rows=(8,) * len(d_tier.plan.groups))
+  kernel_t = jnp.asarray(np.full((8 * len(cfg_t), 1), 0.1, np.float32))
+
+  def head_loss_t(dense_params, emb_outs, hb):
+    h = jnp.concatenate([o.reshape(o.shape[0], -1) for o in emb_outs],
+                        axis=-1)
+    return jnp.mean((h @ dense_params['kernel'] - hb) ** 2)
+
+  state_t = init_hybrid_train_state(
+      d_tier, {'embedding': p_tier, 'kernel': kernel_t}, optax.sgd(0.05),
+      opt_t)
+  step_t = make_hybrid_train_step(d_tier, head_loss_t, optax.sgd(0.05),
+                                  opt_t, donate=False)
+  # a fetch built now carries the accumulator rows the tier just gained
+  traced_t = step_t.jitted.trace(
+      state_t, cats_c, labels, d_tier.build_cold_fetch(cats_c).device)
+  programs.append(Program(
+      'train/tiered-int8', jaxpr=traced_t.jaxpr,
+      state_leaves=device_state_leaves(
+          (state_t.params['embedding'], state_t.opt_state[1])),
+      note='trace-only: the donation pass walks its jaxpr for state '
+      'leaves inside a cond'))
   return programs

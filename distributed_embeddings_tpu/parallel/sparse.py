@@ -836,7 +836,7 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
   ``g_index``: optional ``[n]`` position->row map into COMPACT
   ``flat_g`` (``[m, w]``; the ``compact_segments`` contract) — the
   multi-hot broadcast never materialises, in the main wave or the
-  overflow correction's ``cond`` branch (whose temps count toward peak
+  overflow correction's loop body (whose temps count toward peak
   HBM even untaken).  Mutually exclusive with ``flat_sq`` (that path's
   stream is already per-occurrence-compacted by the DCN exchange).
 
@@ -849,7 +849,7 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
   fed by millions of update rows), while the fraction covers big-vocab
   groups, whose duplicate factor comes from the power-law id distribution.
   When the fraction bound is exceeded (traced unique count > capacity),
-  a ``lax.cond``-gated correction wave applies the dropped segments —
+  a correction wave that runs only then applies the dropped segments —
   always correct, never silently dropping updates (overflow structure
   below).
 
@@ -865,16 +865,41 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
   bit-exact, because compacted rows are disjoint — so the apply's
   scatters pipeline against the chunked gradient exchange instead of
   forming one monolithic tail.  The correction wave stays monolithic
-  (it is the rare ``lax.cond`` branch; chunking it would only grow the
-  untaken branch's traced program).
+  (it is the rare path; chunking it would only grow a traced program
+  that almost never runs).
 
-  Overflow structure: the capped apply runs UNconditionally and a
-  ``lax.cond`` wraps only the rare *correction* wave for the segments
-  the cap dropped.  The waves touch disjoint unique rows, so applying
-  them separately is exact for every optimizer here.  (An earlier
-  formulation put the whole apply inside a two-branch cond; XLA then
-  materialised a full accumulator copy for the branches — +4.5 GB of
-  temps at synthetic-tiny scale, measured via memory_analysis.)
+  Overflow structure: the capped apply runs UNconditionally and only
+  the rare *correction* wave for the segments the cap dropped is
+  conditional.  The waves touch disjoint unique rows, so applying them
+  separately is exact for every optimizer here.  The condition is a
+  ``lax.while_loop`` that runs zero times or once with ``(table,
+  state)`` as its carried value — NOT a ``lax.cond``: no table or
+  state leaf may pass an XLA conditional.  XLA gives each branch of a
+  conditional its own operand, so the shard is copied whole once per
+  branch, on every step, whether or not the cap overflowed.  Compiled
+  for v5e:2x2 with the correction as ``lax.cond(num_unique > cap,
+  correction, identity, (t2, s2))`` (ISSUE 25)::
+
+      %copy.346 = f32[20025088,128] copy(%fusion.27)   # the scatter-add
+      %copy.347 = f32[20025088,128] copy(%copy.346)
+      %tuple.61 = (..., f32[20025088,128]) tuple(..., %copy.347)
+      %conditional.1 = (f32[1,20025088,128]) conditional(%bitcast.96,
+          %copy.346, %tuple.61), branch_computations={...},
+          op_name="jit(step)/shard_map/cond"
+
+  31.2 ms each, 47% of dlrm-train-4chip's step; four such copies (table
+  and accumulator, two each) in synthetic-tiny, which also pushed the
+  compiler into rematerialising both scatters (+1.32 GiB of temps).
+  A while loop is the construct XLA aliases by design — one buffer
+  through init, body and result — and compiles to ``%fusion.27 ->
+  %tuple -> %while -> get-tuple-element -> output`` with the
+  correction's own scatter writing the carried element in place.  (An
+  earlier formulation put the WHOLE apply inside a two-branch cond:
+  the same lesson, +4.5 GB of temps at synthetic-tiny scale.)
+  ``analysis/graphlint``'s donation pass holds every train program to
+  this (``donation/state-leaf-in-cond``), and
+  ``tests/test_tpu_lowering.py`` holds the compiled step to no
+  shard-shaped copy.
   """
   if g_index is not None and flat_sq is not None:
     raise ValueError('g_index and flat_sq are mutually exclusive (the '
@@ -997,8 +1022,11 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
       return optimizer.apply_unique(t3, s3, pids2, g_p2, sq_p2, lr)
     return optimizer.apply_unique(t3, s3, uids2, tot_g, tot_sq, lr)
 
-  return jax.lax.cond(num_unique > cap, correction, lambda args: args,
-                      (t2, s2))
+  (t2, s2), _ = jax.lax.while_loop(
+      lambda carry: carry[1],
+      lambda carry: (correction(carry[0]), False),
+      ((t2, s2), num_unique > cap))
+  return t2, s2
 
 
 # Ceiling on the POTENTIAL lane-padded parameter size a packed-view
@@ -1959,7 +1987,7 @@ def calibrate_capacity_rows(dist: DistributedEmbedding, cats,
   proportionally — e.g. synthetic-tiny's big fused group carries 859k
   uniques per 65536-batch against a 1.44M default cap.  Power-law id
   streams are stationary, so one batch plus ``margin`` headroom is
-  representative; if a later batch still overflows, the ``lax.cond``
+  representative; if a later batch still overflows, the overflow
   correction wave applies the dropped segments (slower, never wrong).
 
   With ``prefer_cpu`` (the default) and a non-CPU mesh, the measurement

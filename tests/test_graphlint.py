@@ -12,9 +12,12 @@ The load-bearing claims pinned here:
   warmed serving ladder (the generalized ``compile_count`` pin), and
   the parity groups (ladder rungs; chunked vs monolithic step) share
   one collapsed collective schedule;
+- no embedding-state leaf of a train step enters a ``lax.cond`` (XLA
+  copies the whole shard once per branch, ISSUE 25), with the overflow
+  correction IN every train program so the proof is not vacuous;
 - one seeded TRUE-POSITIVE fixture per pass: an undonated state leaf,
-  a parity pair with divergent collective order, a collective under a
-  divergent ``lax.cond``, a forced retrace via weak_type drift plus a
+  a state leaf inside a cond, a parity pair with divergent collective
+  order, a collective under a divergent ``lax.cond``, a forced retrace via weak_type drift plus a
   recompile, an injected hot-loop ``jax.device_get``, a host-callback
   primitive inside a traced program, and an over-budget resident
   state;
@@ -109,6 +112,35 @@ def test_donation_proves_all_train_state_leaves_aliased(live):
     assert d['aliased'] == d['expected'], (name, d)
 
 
+TRAIN_PROGRAMS = ('train/monolithic', 'train/chunked',
+                  'train/hier-flat-twin', 'train/hierarchical',
+                  'train/tiered-int8')
+
+
+@pytest.mark.parametrize('name', TRAIN_PROGRAMS)
+def test_no_state_leaf_enters_a_cond(live, flagship, name):
+  """The in-place-update proof, jaxpr half (ISSUE 25): every train
+  step of the catalog (flat, chunked, both hierarchical arms, and the
+  int8 cold-tier layer whose operand is the (payload, scale) pair with
+  fetched rows appended) is built with a capacity that leaves the
+  overflow correction in, carries its shards through a ``while``, and
+  has no ``cond`` that takes or returns a value the size of a state
+  leaf (the clean-tree gate enforces the last; asserted here by
+  name so that dropping ``state_leaves`` from the catalog fails)."""
+  prog = {p.name: p for p in flagship}[name]
+  assert prog.state_leaves, name
+  assert {l.dtype for l in prog.state_leaves} >= {'float32'}
+  # the correction is in the program: a loop carries a state leaf
+  assert graphlint.state_carriers(prog.jaxpr, prog.state_leaves,
+                                  'while'), name
+  assert not graphlint.state_carriers(prog.jaxpr, prog.state_leaves)
+  assert not any(f.rule == 'donation/state-leaf-in-cond'
+                 for f in live.findings + live.waived)
+  if name == 'train/tiered-int8':
+    # the pair and the tier really are in it
+    assert {'int8', 'float32'} == {l.dtype for l in prog.state_leaves}
+
+
 def test_retrace_zero_across_fit_and_warmed_ladder(live):
   """The retrace acceptance proof: the monitored 3-step fit and the
   one-request-per-rung warmed-ladder window both saw zero
@@ -187,6 +219,64 @@ def test_fixture_undonated_leaf():
   assert not any('fixture/donated' in i for i in ids), ids
   # the donated twin is PROVEN aliased, not just unflagged
   assert graphlint.aliased_param_indices(good.compiled) >= {0, 1}
+
+
+@pytest.mark.parametrize('operand', ['shard', 'shard_plus_rows',
+                                     'reshaped_view', 'update_rows'])
+def test_fixture_state_leaf_in_cond(operand):
+  """The shape ISSUE 25 removed: a rare correction under a two-branch
+  ``lax.cond`` that carries the table.  Flagged for the shard itself,
+  the shard with fetched rows concatenated on (cold tier) and a
+  reshaped view of it (packed <-> natural); the same work in a
+  zero-or-one-trip ``while_loop``, or a cond that yields only the
+  rows to write, is clean."""
+  leaf = graphlint.StateLeaf("['group_0']", (64, 16), 'float32')
+  shape = {'shard': (1, 64, 16), 'shard_plus_rows': (72, 16),
+           'reshaped_view': (8, 128), 'update_rows': (8, 16)}[operand]
+
+  def correction(t):
+    return t.at[0].add(1.0)
+
+  def cond_form(t, n):
+    return jax.lax.cond(n > 3, correction, lambda t: t, t)
+
+  def loop_form(t, n):
+    return jax.lax.while_loop(lambda c: c[1],
+                              lambda c: (correction(c[0]), False),
+                              (t, n > 3))[0]
+
+  t, n = jnp.zeros(shape, jnp.float32), jnp.int32(5)
+  progs = [graphlint.Program(f'fixture/{form.__name__}',
+                             jaxpr=jax.make_jaxpr(form)(t, n),
+                             state_leaves=[leaf])
+           for form in (cond_form, loop_form)]
+  res = graphlint.run_programs(progs, passes=['donation'])
+  ids = [f.id for f in res.findings]
+  if operand == 'update_rows':
+    assert not ids, ids
+  else:
+    assert ids == ['donation/state-leaf-in-cond@fixture/cond_form::cond#0']
+  assert graphlint.state_carriers(progs[1].jaxpr, [leaf], 'while') == (
+      [] if operand == 'update_rows' else [(0, "['group_0']")])
+
+
+def test_device_state_leaves_reads_one_devices_share():
+  mesh = _mesh()
+  world = mesh.devices.size
+  tree = {
+      'table': jax.device_put(
+          np.zeros((world, 40, 8), np.float32),
+          jax.sharding.NamedSharding(mesh, P('data', None, None))),
+      'scale': jax.device_put(
+          np.zeros((world, 40, 1), np.float32),
+          jax.sharding.NamedSharding(mesh, P('data', None, None))),
+      'step': jnp.zeros((), jnp.int32),      # no table: left out
+      'per_row': jnp.zeros((40,), jnp.int32),
+  }
+  got = {l.label: (l.shape, l.dtype)
+         for l in graphlint.device_state_leaves(tree)}
+  assert got == {"['table']": ((40, 8), 'float32'),
+                 "['scale']": ((40, 1), 'float32')}
 
 
 def test_fixture_divergent_parity_schedule():

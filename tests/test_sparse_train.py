@@ -294,17 +294,67 @@ def test_compact_segments_unit():
   assert int(nuniq) == len(set(ids[ids < vocab].tolist())) + 1
 
 
-@pytest.mark.parametrize('frac', [0.02, 1.0])
-def test_capacity_fraction_overflow_fallback(frac):
-  # frac=0.02 forces the traced unique count over the compaction capacity,
-  # exercising the lax.cond full-capacity fallback; frac=1.0 never
-  # overflows.  Both must match the dense keras-adagrad oracle exactly
-  # (dedup=True -> the oracle's sum-then-square semantics).
-  dist, params_emb, gen_inputs, kernel, labels, head_loss_fn = build(seed=5)
+def _while_as_cond(cond_fun, body_fun, init):
+  """The overflow correction as it stood before ISSUE 25: the SAME body
+  under a two-branch ``lax.cond`` whose other branch is the identity.
+  Only valid for the zero-or-one-trip loop ``_dedup_and_apply`` builds."""
+  return jax.lax.cond(cond_fun(init), body_fun, lambda c: c, init)
+
+
+def _overflow_step(monkeypatch, form, opt_fn, seed, donate=False, steps=1):
+  """``steps`` hybrid steps on a fresh ``build(seed)`` (a fresh layer, so
+  no traced function is shared between forms) with the correction in its
+  ``form``.  Returns ``(state, jaxpr text of the step)``."""
+  dist, params_emb, gen_inputs, kernel, labels, head_loss_fn = build(
+      seed=seed)
   cats = gen_inputs()
-  opt = SparseAdagrad(learning_rate=LR, dedup=True,
-                      initial_accumulator_value=0.1,
-                      capacity_fraction=frac)
+  opt = opt_fn(dist, cats)
+  with monkeypatch.context() as m:
+    if form == 'cond':
+      m.setattr(jax.lax, 'while_loop', _while_as_cond)
+    step = make_hybrid_train_step(dist, head_loss_fn, optax.sgd(LR), opt,
+                                  donate=donate)
+    state = init_hybrid_train_state(
+        dist, {'embedding': jax.tree.map(jnp.copy, params_emb),
+               'kernel': jnp.copy(kernel)}, optax.sgd(LR), opt)
+    text = str(step.jitted.trace(state, cats, labels).jaxpr)
+    for _ in range(steps):
+      state, loss = step(state, cats, labels)
+    assert np.isfinite(float(loss))
+  return state, text
+
+
+def _assert_same_bits(a, b):
+  """Tables AND sparse optimizer state, bit for bit."""
+  for x, y in zip(jax.tree.leaves((a.params['embedding'], a.opt_state[1])),
+                  jax.tree.leaves((b.params['embedding'], b.opt_state[1])),
+                  strict=True):
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _check_overflow_forms(monkeypatch, check, opt_fn, seed):
+  """``check='cond_form'``: the zero-or-one-trip loop gives the bits of
+  the two-branch cond it replaced.  ``check='donated'``: two steps on a
+  donated state (the loop updates the shards in place) give the bits of
+  two steps on a kept one."""
+  got, text = _overflow_step(monkeypatch, 'loop', opt_fn, seed,
+                             donate=check == 'donated', steps=2)
+  # the construct under test is in the program, and in its new form
+  assert ' while[' in text and ' cond[' not in text
+  want, wtext = _overflow_step(
+      monkeypatch, 'cond' if check == 'cond_form' else 'loop', opt_fn,
+      seed, steps=2)
+  if check == 'cond_form':
+    assert ' cond[' in wtext and ' while[' not in wtext
+  _assert_same_bits(got, want)
+
+
+def _check_overflow_oracle(opt_fn, seed):
+  """One step against the dense keras-adagrad oracle (dedup=True -> the
+  oracle's sum-then-square semantics)."""
+  dist, params_emb, gen_inputs, kernel, labels, head_loss_fn = build(seed=seed)
+  cats = gen_inputs()
+  opt = opt_fn(dist, cats)
   g = dense_grads(dist, params_emb, kernel, cats, labels,
                   head_loss_fn)['embedding']
   acc0 = jax.tree.map(lambda x: jnp.full_like(x, 0.1), params_emb)
@@ -323,38 +373,109 @@ def test_capacity_fraction_overflow_fallback(frac):
                                np.asarray(want[k]), rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize('mode', ['calibrated', 'too_small'])
-def test_capacity_rows_calibration(mode):
-  # calibrated per-group capacities must reproduce the dense oracle; a
-  # deliberately under-sized capacity_rows must stay correct through the
-  # overflow correction wave
+@pytest.mark.parametrize('frac,check', [(0.02, 'oracle'), (1.0, 'oracle'),
+                                        (0.02, 'cond_form'),
+                                        (0.02, 'donated')])
+def test_capacity_fraction_overflow_fallback(frac, check, monkeypatch):
+  # frac=0.02 forces the traced unique count over the compaction capacity,
+  # exercising the overflow correction (taken on every device); frac=1.0
+  # never overflows.  Both must match the dense keras-adagrad oracle
+  # exactly (dedup=True -> the oracle's sum-then-square semantics).
+  opt_fn = lambda dist, cats: SparseAdagrad(
+      learning_rate=LR, dedup=True, initial_accumulator_value=0.1,
+      capacity_fraction=frac)
+  if check == 'oracle':
+    return _check_overflow_oracle(opt_fn, seed=5)
+  _check_overflow_forms(monkeypatch, check, opt_fn, seed=5)
+
+
+def _calibration_caps(mode, dist, cats):
   from distributed_embeddings_tpu.parallel import calibrate_capacity_rows
-  dist, params_emb, gen_inputs, kernel, labels, head_loss_fn = build(seed=7)
-  cats = gen_inputs()
   if mode == 'calibrated':
     caps = calibrate_capacity_rows(dist, cats, margin=1.3)
     assert len(caps) == len(dist.plan.groups)
     assert all(isinstance(c, int) and c >= 8 for c in caps)
-  else:
-    caps = tuple(8 for _ in dist.plan.groups)
-  opt = SparseAdagrad(learning_rate=LR, dedup=True,
-                      initial_accumulator_value=0.1, capacity_rows=caps)
-  g = dense_grads(dist, params_emb, kernel, cats, labels,
-                  head_loss_fn)['embedding']
-  acc0 = jax.tree.map(lambda x: jnp.full_like(x, 0.1), params_emb)
-  want, _ = _keras_adagrad_dense(params_emb, g, acc0, LR)
+    return caps
+  return tuple(8 for _ in dist.plan.groups)
 
-  step = make_hybrid_train_step(dist, head_loss_fn, optax.sgd(LR), opt,
-                                donate=False)
-  state = init_hybrid_train_state(dist, {
-      'embedding': params_emb,
-      'kernel': kernel
-  }, optax.sgd(LR), opt)
-  state, loss = step(state, cats, labels)
-  assert np.isfinite(float(loss))
-  for k in params_emb:
-    np.testing.assert_allclose(np.asarray(state.params['embedding'][k]),
-                               np.asarray(want[k]), rtol=2e-5, atol=2e-6)
+
+@pytest.mark.parametrize('mode,check', [('calibrated', 'oracle'),
+                                        ('too_small', 'oracle'),
+                                        ('calibrated', 'cond_form'),
+                                        ('too_small', 'cond_form'),
+                                        ('calibrated', 'donated'),
+                                        ('too_small', 'donated')])
+def test_capacity_rows_calibration(mode, check, monkeypatch):
+  # calibrated per-group capacities must reproduce the dense oracle (the
+  # correction is in the program and NOT taken); a deliberately
+  # under-sized capacity_rows must stay correct through the overflow
+  # correction wave (taken)
+  opt_fn = lambda dist, cats: SparseAdagrad(
+      learning_rate=LR, dedup=True, initial_accumulator_value=0.1,
+      capacity_rows=_calibration_caps(mode, dist, cats))
+  if check == 'oracle':
+    return _check_overflow_oracle(opt_fn, seed=7)
+  _check_overflow_forms(monkeypatch, check, opt_fn, seed=7)
+
+
+@pytest.mark.parametrize('taken', [True, False])
+@pytest.mark.parametrize('max_seg', [None, 2])
+@pytest.mark.parametrize('storage', ['wide', 'packed_view', 'packed'])
+@pytest.mark.parametrize('opt_name', ['sgd', 'adagrad'])
+def test_overflow_loop_gives_the_cond_forms_bits(monkeypatch, opt_name,
+                                                 storage, max_seg, taken):
+  """ISSUE 25: the table and state leaves ride a zero-or-one-trip
+  ``lax.while_loop`` instead of a two-branch ``lax.cond`` (XLA copied
+  the whole shard for each branch).  Same segments, same sums, same
+  ``apply_unique`` call: the bits of the cond form, with the correction
+  taken (301 segments against a capacity of 128) and not (101), for the
+  natural width-128 operand, the packed VIEW of a natural width-16 one,
+  the physically packed one, and the bounded exact fold (``max_seg``,
+  with pre-summed squares for Adagrad as the cross-slice merge sends)."""
+  from distributed_embeddings_tpu.parallel import sparse as sparse_mod
+  rows_cap, n, cap_rows = 512, 1024, 128
+  w = 128 if storage == 'wide' else 16
+  pack = 8 if storage == 'packed' else 1
+  rng = np.random.default_rng(3)
+  k = 300 if taken else 100
+  rows = rng.permutation(rows_cap)[:k]
+  ids = np.full(n, rows_cap, np.int32)   # the rest is padding (sentinel)
+  ids[:2 * k] = np.concatenate([rows, rows])  # each row twice: max_seg 2
+  ids = rng.permutation(ids)
+  g = rng.normal(size=(n, w)).astype(np.float32)
+  table = rng.normal(size=(rows_cap // pack, w * pack)).astype(np.float32)
+  if opt_name == 'sgd':
+    opt, state = SparseSGD(learning_rate=LR), {}
+  else:
+    opt = SparseAdagrad(learning_rate=LR, dedup=False)
+    state = {'acc': np.full_like(table, 0.1)}
+  sq = g * g * 0.5 if (max_seg and opt_name == 'adagrad') else None
+
+  def run(form):
+    def fn(table, state, ids, g, sq):
+      return sparse_mod._dedup_and_apply(
+          opt, table, state, ids, g, LR, rows_cap, cap_rows=cap_rows,
+          flat_sq=sq, storage_pack=pack, max_seg=max_seg)
+
+    with monkeypatch.context() as m:
+      if form == 'cond':
+        m.setattr(jax.lax, 'while_loop', _while_as_cond)
+      text = str(jax.make_jaxpr(fn)(table, state, ids, g, sq))
+      return jax.jit(fn)(table, state, ids, g, sq), text
+
+  (t_loop, s_loop), text = run('loop')
+  assert ' while[' in text and ' cond[' not in text
+  (t_cond, s_cond), text = run('cond')
+  assert ' cond[' in text and ' while[' not in text
+  np.testing.assert_array_equal(np.asarray(t_loop), np.asarray(t_cond))
+  assert set(s_loop) == set(s_cond)
+  for name in s_loop:
+    np.testing.assert_array_equal(np.asarray(s_loop[name]),
+                                  np.asarray(s_cond[name]))
+  # every one of the k rows moved: by the main wave alone where they fit
+  # the capacity, by the correction for the segments past it
+  moved = (np.asarray(t_loop) != table).reshape(rows_cap, -1).any(axis=1)
+  assert moved.sum() == k
 
 
 def test_hybrid_step_with_lr_schedule():

@@ -10,10 +10,11 @@ the first healthy chip; only RUNTIME behavior (DMA timing/races) stays
 hardware-gated in tests/test_pallas_tpu.py.
 
 Covers every kernel configuration AND the full 4-chip hybrid train
-step (flat and two-axis meshes) compiled for v5e 2x2.
+step (flat and two-axis meshes) compiled for v5e 2x2, and holds the
+default XLA apply's compiled step to no whole-shard copy (ISSUE 25).
 
 Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
-spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 49 cases pass
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 51 cases pass
 in about 40 s on 8 host cores.  This is the free gate to run
 (``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
 """
@@ -190,6 +191,58 @@ def test_full_hybrid_train_step_compiles_for_v5e(v5e, two_axis,
     temps = getattr(ma, 'temp_size_in_bytes', 0) or 0
     args_b = getattr(ma, 'argument_size_in_bytes', 0) or 0
     assert temps + args_b < 16 * 2**30, (temps, args_b)
+
+
+@pytest.mark.parametrize('rows,width,batch,cap', [
+    (4096, 128, 512, 64),       # natural storage: shards f32[4096,128]
+    (262144, 16, 2048, 256),    # packed storage (x8): shards f32[32768,128]
+])
+def test_overflow_correction_copies_no_shard_for_v5e(v5e, rows, width,
+                                                     batch, cap):
+  """ISSUE 25: the 4-chip hybrid step with Adagrad and a capacity below
+  the guaranteed one (so the overflow correction is in the program)
+  compiles for v5e 2x2 with NO ``copy`` whose result has a table or
+  accumulator shard's shape.  While the correction was a two-branch
+  ``lax.cond`` over ``(table, state)``, XLA copied each leaf once per
+  branch after the apply's scatters: four such copies at BOTH sizes
+  here (four tables of ``rows`` x ``width``, two ids a sample, global
+  batch ``batch``, ``capacity_rows`` ``cap``), which are the smallest
+  tried; 47% of dlrm-train-4chip's step at its own size.  A bare
+  scatter followed by a cond does NOT reproduce them: it takes the
+  real step.  (A width-16 table much smaller than this is also copied
+  whole into fast memory by the forward lookup: another copy, not this
+  one, and the reason for the second size.)"""
+  import re
+  import optax
+  from jax.experimental import topologies
+  from distributed_embeddings_tpu.parallel import (DistributedEmbedding,
+                                                   SparseAdagrad,
+                                                   TableConfig,
+                                                   make_hybrid_train_step)
+  mesh = topologies.make_mesh(v5e, (4,), ('data',))
+  configs = [TableConfig(rows, width, 'sum') for _ in range(4)]
+  dist = DistributedEmbedding(configs, mesh=mesh)
+  opt = SparseAdagrad(learning_rate=0.01,
+                      capacity_rows=(cap,) * len(dist.plan.groups))
+  dense_opt = optax.sgd(0.01)
+
+  def head(dp, eo, b):
+    h = jnp.concatenate(list(eo), axis=-1)
+    return jnp.mean((h @ dp['kernel'] - b)**2)
+
+  step = make_hybrid_train_step(dist, head, dense_opt, opt, donate=False,
+                                jit=False)
+  state, cats, labels = _step_avals(dist, mesh, configs, batch, dense_opt)
+  hlo = jax.jit(step, donate_argnums=(0,)).lower(state, cats,
+                                                 labels).compile().as_text()
+  # the correction is in the program, as a loop
+  assert ' while(' in hlo and ' conditional(' not in hlo
+  shards = {f'f32[{g.param_rows},{g.param_width}]'
+            for g in dist.plan.groups}
+  copies = [m.group(0) for m in re.finditer(
+      r'%?\S+ = (\S+) copy\([^)]*\)', hlo)
+            if m.group(1).split('{')[0] in shards]
+  assert not copies, copies
 
 
 @pytest.mark.parametrize('op', ['sgd', 'adagrad_sq'])
