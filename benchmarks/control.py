@@ -3,9 +3,11 @@ cell's own size (by hand, when a cell's limits are set):
 
   python3 benchmarks/control.py --workload <cell> --seeds 1,2,3
 
-For each seed: the plain reference as the configuration states it, then,
-put in the program's place, the reference one step of precision lower
-(the control) and with each fault planted that the cell can have.  Prints
+For each seed: the plain reference as the configuration states it (its
+class, traffic shape and optimizer found by the names in the cell's data
+files), then, put in the program's place, the reference one step of
+precision lower (the control) and with each fault planted that the cell
+can have.  Prints
 the numbers that decide ``correct`` for each; needs no measured window
 (training's readings come from the first steps alone).  Refuses anything
 but a TPU: the cell's own size is the point.

@@ -6,14 +6,14 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
-from benchmarks.lib import builders, weights, program_state
+from benchmarks.lib import names, weights, program_state
 from distributed_embeddings_tpu.parallel import make_hybrid_train_step, TrainState
 
-config = json.load(open('benchmarks/configs/dlrm-mlperf.json'))
+config = names.load_json('benchmarks', 'configs', 'dlrm-mlperf')
 topo = topologies.get_topology_desc('v5e:2x2', 'tpu')
 tdevs = np.asarray(topo.devices).ravel()
 mesh = Mesh(tdevs[:4], ('data',))
-model = builders.dlrm(config, mesh, 1)
+model = names.resolve(config['builder'])(config, mesh, 1)
 dist = model.dist
 print(dist.plan.describe()[:1500], flush=True)
 W, GB = 4, 65536
@@ -43,7 +43,7 @@ with unittest.mock.patch.object(jax, 'jit', spy_jit):
 g = dist.plan.groups[0]
 one = SingleDeviceSharding(tdevs[0])
 lanes = g.param_width; rows_total = g.param_rows
-read = program_state._reader('sgd', 0.003, 0.0)
+read = program_state._reader('sgd', 0.003, 0.0, 0.0)
 s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
 t0 = time.time()
 rc = read.lower(s((1, rows_total, lanes), jnp.float32), None, s((), jnp.int32), s((), jnp.int32), s((), jnp.uint32), s((), jnp.uint32), (s((), jnp.uint32), s((), jnp.uint32)), s((), jnp.float32)).compile()
@@ -52,7 +52,7 @@ print(f'reader compiled {time.time()-t0:.0f}s: temp {ma.temp_size_in_bytes/2**30
 
 # 3. the step, default capacities
 emb_opt = model.emb_optimizer_cls(**model.emb_optimizer_kwargs)
-step = make_hybrid_train_step(dist, model.head_loss_fn, model.dense_optimizer, emb_opt, donate=False, jit=False)
+step = make_hybrid_train_step(dist, model.head_loss_fn, model.dense_optimizer, emb_opt, donate=False, jit=False, **model.step_kwargs)
 emb = {f'group_{gi}': sds((W, gg.param_rows, gg.param_width), jnp.float32, tsh) for gi, gg in enumerate(dist.plan.groups)}
 dense = jax.tree.map(lambda x: sds(x.shape, x.dtype, rep), model.dense_params)
 dstate = jax.tree.map(lambda x: sds(x.shape, x.dtype, rep), jax.eval_shape(model.dense_optimizer.init, model.dense_params))

@@ -1,14 +1,22 @@
 """Find a cell's files by name and run it.  Everything that belongs to
-one configuration, one traffic mix, one cell's limits or one per-layer
-metric is a file of its own under the benchmark's directory:
+one configuration, one traffic mix, one cell's limits, one kind of
+runner or one per-layer metric is a file of its own under the
+benchmark's directory, found by the name the manifest or a data file
+gives:
 
   configs/<config>.json   traffic/<mix>.json   limits/<cell>.json
+  runners/<kind>.py       (the mix's ``kind``; one function: ``run``)
   metrics/<metric>.py     (one function: ``read(context) -> number|None``)
 
-so a later cell or metric is new files and a manifest entry, no edit.
+and what belongs to a model class or a traffic shape is a function the
+configuration or the mix names as ``module:function`` (``lib/builders``,
+``lib/traffic``).  So a later cell, class, shape, kind or metric is new
+files and a manifest entry, no edit.  A reader sees the reduced trace
+(``context['trace']``: ``class_s``, ``phase_s``, ``phase_class_s``, ...)
+and the profiler's own files under ``context['trace_dir']``, which are
+deleted only after every reader has run.
 """
 
-import importlib
 import importlib.util
 import os
 import shutil
@@ -18,7 +26,6 @@ import time
 from benchmarks.lib import names, xtrace
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RUNNERS = {'train_pool': 'benchmarks.lib.train'}
 
 
 class CompileCount:
@@ -59,13 +66,18 @@ def memory_peak_bytes(devices):
                  for d in devices))
 
 
-def _reader(name):
-  path = os.path.join(BENCH_DIR, 'metrics', f'{name}.py')
+def _function(directory, name, function):
+  """``function`` of ``<directory>/<name>.py`` under the benchmark's
+  directory; ``None`` where there is no such file."""
+  path = os.path.join(BENCH_DIR, directory, f'{name}.py')
+  if not os.path.exists(path):
+    return None
   spec = importlib.util.spec_from_file_location(
-      'benchmarks_metric_' + name.replace('.', '_').replace('-', '_'), path)
+      f'benchmarks_{directory}_' + name.replace('.', '_').replace('-', '_'),
+      path)
   module = importlib.util.module_from_spec(spec)
   spec.loader.exec_module(module)
-  return module.read
+  return getattr(module, function)
 
 
 def run_cell(manifest, root, cell_name, args, devices, started, cache_dir):
@@ -82,23 +94,26 @@ def run_cell(manifest, root, cell_name, args, devices, started, cache_dir):
   per_layer = [m for m in manifest['per_layer']
                if cell_name in m.get('workloads', [cell_name])]
 
-  if mix.get('kind') not in RUNNERS:
-    raise SystemExit(f'traffic/{cell["traffic"]}.json: unknown kind '
-                     f'{mix.get("kind")!r}; the harness drives {sorted(RUNNERS)}')
-  runner = importlib.import_module(RUNNERS[mix['kind']])
-  result, end_to_end, context = runner.run(cell, config, mix, limits, args,
-                                           devices, started, cache_dir)
+  run = _function('runners', str(mix.get('kind')), 'run')
+  if run is None:
+    raise SystemExit(f'traffic/{cell["traffic"]}.json: kind '
+                     f'{mix.get("kind")!r} has no benchmarks/runners/'
+                     f'{mix.get("kind")}.py')
+  result, end_to_end, context = run(cell, config, mix, limits, args, devices,
+                                    started, cache_dir)
   result['device'] = {
       'platform': devices[0].platform, 'kind': devices[0].device_kind,
       'count': len(devices),
       'memory_peak_bytes': result.pop('memory_peak_bytes')}
   if args.trace:
-    reduced = xtrace.reduce_trace(xtrace.find_trace(context['trace_dir']),
-                                  program=context['program'])
-    shutil.rmtree(context['trace_dir'], ignore_errors=True)
-    context['trace'] = reduced
-    values = {m['name']: (_reader(m['name'])(context), m['unit'])
-              for m in per_layer}
+    try:
+      reduced = xtrace.reduce_trace(xtrace.find_trace(context['trace_dir']),
+                                    program=context['program'])
+      context['trace'] = reduced
+      values = {m['name']: (_function('metrics', m['name'], 'read')(context),
+                            m['unit']) for m in per_layer}
+    finally:
+      shutil.rmtree(context['trace_dir'], ignore_errors=True)
     values = {n: v for n, v in values.items() if v[0] is not None}
     result['device'].update(busy_s=reduced['busy_mean_s'],
                             window_s=reduced['window_s'])

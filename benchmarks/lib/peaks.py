@@ -52,12 +52,15 @@ def mlp_flops(batch, dims):
   return int(sum(3 * 2 * batch * a * b for a, b in dims))
 
 
-def step_floor_seconds(peaks, flops, row_bytes, state_slots):
+def step_floor_seconds(peaks, flops, row_bytes, state_slots, head_bytes=0):
   """The least time one chip needs for its share of a step, and which
   peak binds: the larger of dense FLOPs over peak FLOP/s and bytes over
   peak bytes/s, where bytes are the distinct rows read once forward and,
   in the apply, read and written once per state slot (``state_slots`` is
-  1 for SGD's table alone, 2 with Adagrad's accumulator)."""
+  1 for SGD's table alone, 2 with Adagrad's accumulator, 3 with Adam's
+  two moments), plus ``head_bytes``, what the class's ``work`` says its
+  head must move beyond them."""
   t_flops = flops / peaks['bf16_flops_per_s']
-  t_bytes = row_bytes * (1 + 2 * state_slots) / peaks['hbm_bytes_per_s']
+  t_bytes = ((row_bytes * (1 + 2 * state_slots) + head_bytes)
+             / peaks['hbm_bytes_per_s'])
   return max(t_flops, t_bytes), ('flops' if t_flops > t_bytes else 'bytes')

@@ -18,8 +18,9 @@ no second copy of a table is ever held, and a reading costs one pass over
 the table at HBM speed.  For table ``t`` after a step: ``sum((W -
 W0)**2)``, the sum of squares of the first gradient worked out from the
 state (``(W0 - W) / lr`` for SGD, ``(W0 - W) * sqrt(acc + eps) / lr`` for
-Adagrad, as the reference reads it back from its own state), and how many
-elements moved at all.
+Adagrad, ``m / (1 - b1)`` for Adam, as the reference reads it back from its
+own state), and how many elements moved at all.  ``dense_states`` hands
+the same reading the dense leaves' part of the optax state.
 """
 
 import functools
@@ -112,10 +113,32 @@ def make_tables(dist, layout, specs, words):
   return fn(meta)
 
 
+# per optimizer kind: the leaf of the program's sparse optimizer state
+# that the first gradient is worked out from (``None``: from the table
+# alone), and where the optax state keeps the dense leaves' counterpart
+_STATE_LEAF = {'sgd': None, 'adagrad': 'acc', 'adam': 'm'}
+_DENSE_STATE = {'adagrad': lambda state: state[0].sum_of_squares,
+                'adam': lambda state: state[0].mu}
+
+
+def dense_states(kind, dense_opt_state):
+  """The optax state of the dense leaves as the reference's optimizer
+  names it: a pytree of the dense parameters' shape whose leaves are
+  ``{name: array}`` (host numpy), or ``None`` where the kind keeps none."""
+  import jax
+  name = _STATE_LEAF[kind]
+  if name is None:
+    return None
+  return jax.tree.map(lambda a: {name: np.asarray(a)},
+                      _DENSE_STATE[kind](dense_opt_state))
+
+
 @functools.lru_cache(maxsize=None)
-def _reader(kind, lr, eps):
+def _reader(kind, lr, eps, b1):
   import jax
   import jax.numpy as jnp
+  if kind not in _STATE_LEAF:
+    raise ValueError(f'unknown optimizer kind {kind!r}')
 
   def read(leaf, acc, first_row, num_chunks, flat_start, count, words, scale):
     leaf = leaf.reshape(leaf.shape[-2:])
@@ -139,6 +162,9 @@ def _reader(kind, lr, eps):
       if kind == 'adagrad':
         a = jax.lax.dynamic_slice(acc, (start, 0), (chunk, lanes))
         grad = delta * jnp.sqrt(a + jnp.float32(eps)) / jnp.float32(lr)
+      elif kind == 'adam':
+        a = jax.lax.dynamic_slice(acc, (start, 0), (chunk, lanes))
+        grad = jnp.where(mine, a, 0.0) / (jnp.float32(1) - jnp.float32(b1))
       else:
         grad = delta / jnp.float32(lr)
       # compared, not subtracted: a backend that contracts the hash's
@@ -167,12 +193,14 @@ def table_readings(optimizer, specs, layout, emb_params, emb_opt_state,
   stands (the caller says after which step that is).  ``optimizer`` is the
   configuration's own statement; one compiled reader per leaf shape."""
   read = _reader(optimizer['kind'], float(optimizer['learning_rate']),
-                 float(optimizer.get('epsilon', 0.0)))
+                 float(optimizer.get('epsilon', 0.0)),
+                 float(optimizer.get('b1', 0.0)))
+  state_leaf = _STATE_LEAF[optimizer['kind']]
   pending = []
   for tid, (key, dev, flat_start, count) in enumerate(layout):
     leaf = _device_shard(emb_params[key], dev)
-    acc = (_device_shard(emb_opt_state[key]['acc'], dev)
-           if optimizer['kind'] == 'adagrad' else None)
+    acc = (_device_shard(emb_opt_state[key][state_leaf], dev)
+           if state_leaf else None)
     rows_total, lanes = leaf.shape[-2:]
     first = flat_start // lanes
     cover = (flat_start + count - 1) // lanes - first + 1

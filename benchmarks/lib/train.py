@@ -19,13 +19,12 @@ import time
 import numpy as np
 
 from benchmarks.lib import cell as cell_lib
-from benchmarks.lib import (names, program_state, reference, traffic, weights,
-                            xtrace)
+from benchmarks.lib import names, program_state, reference, weights, xtrace
 
 TRACE_STEPS = 8  # a traced window holds at most this many steps
 
 
-def _capacity_rows(model, mix, config, cache_dir, pool_inputs, mesh, emb_params):
+def _capacity_rows(model, mix, config, cache_dir, generate, mesh, emb_params):
   """Calibrated per-group capacities of the sparse apply, from a batch of
   the mix's FIXED calibration seed: the capacities are static shapes of
   the step, so they may not move with ``--seed`` (the compiled step would
@@ -41,8 +40,7 @@ def _capacity_rows(model, mix, config, cache_dir, pool_inputs, mesh, emb_params)
   if os.path.exists(path):
     with open(path) as f:
       return tuple(json.load(f))
-  cats = traffic.train_pool(mix, pool_inputs, config['num_numerical_features'],
-                            mix['calibration_seed'], batches=1)[0][0]
+  cats = generate(mix['calibration_seed'], batches=1)[0][0]
   caps = calibrate_capacity_rows(model.dist, [jnp.asarray(c) for c in cats],
                                  params=emb_params)
   os.makedirs(cache_dir, exist_ok=True)
@@ -55,16 +53,17 @@ def _capacity_rows(model, mix, config, cache_dir, pool_inputs, mesh, emb_params)
 
 def _dense_readings(model, dense0, dense1, dense_opt_state):
   """Per dense leaf ``(name, change_norm, grad_norm, moved)``: the same
-  read-back as for tables, on host copies (the dense part is a few MB)."""
-  opt = reference._Optimizer(model.optimizer, lambda a: a)
-  acc = None
-  if opt.kind == 'adagrad':
-    acc = dense_opt_state[0].sum_of_squares
+  read-back as for tables, on host copies (the dense part is a few MB);
+  a leaf is named by its tree path, as the reference names it."""
   import jax
-  host = lambda tree: jax.tree.map(np.asarray, tree)
-  dense1, acc = host(dense1), (host(acc) if acc is not None else None)
-  return [(name, *opt.leaf_readings(p0, p1, a1)) for name, p0, p1, a1 in
-          reference._dense_leaves(dense0, dense1, acc, opt)]
+  opt = reference._Optimizer(model.optimizer, lambda a: a)
+  flat0, tree = jax.tree.flatten(dense0)
+  flat1 = tree.flatten_up_to(jax.tree.map(np.asarray, dense1))
+  states = program_state.dense_states(opt.kind, dense_opt_state)
+  states = ([None] * len(flat0) if states is None
+            else tree.flatten_up_to(states))
+  return [(name, *opt.leaf_readings(p0, p1, s1)) for name, p0, p1, s1 in
+          zip(reference.leaf_names(dense0), flat0, flat1, states)]
 
 
 def run(cell, config, mix, limits, args, devices, started, cache_dir):
@@ -86,13 +85,14 @@ def run(cell, config, mix, limits, args, devices, started, cache_dir):
   batch = int(mix['global_batch'])
   pool_inputs = [(model.tables[t][0], h) for t, h in
                  zip(model.input_table_map, model.hotness)]
-  host_pool = traffic.train_pool(mix, pool_inputs,
-                                 config['num_numerical_features'], seed)
+  generator = names.resolve(mix['generator'])
+  generate = lambda seed, **kw: generator(mix, pool_inputs, config, seed, **kw)
+  host_pool = generate(seed)
   words = weights.table_words(seed, len(model.tables))
   layout = program_state.table_layout(dist)
   stamp('model planned, pool drawn on the host')
   emb_params = program_state.make_tables(dist, layout, model.tables, words)
-  caps = _capacity_rows(model, mix, config, cache_dir, pool_inputs, mesh,
+  caps = _capacity_rows(model, mix, config, cache_dir, generate, mesh,
                         emb_params)
   emb_opt = model.emb_optimizer_cls(capacity_rows=caps,
                                     **model.emb_optimizer_kwargs)
@@ -103,11 +103,16 @@ def run(cell, config, mix, limits, args, devices, started, cache_dir):
       model.dense_optimizer, emb_opt)
   del emb_params
   step = make_hybrid_train_step(dist, model.head_loss_fn,
-                                model.dense_optimizer, emb_opt)
+                                model.dense_optimizer, emb_opt,
+                                **model.step_kwargs)
   pool = []
-  for cats, numerical, labels in host_pool:
-    placed = make_global_batch(mesh, *cats, numerical, labels)
-    pool.append((list(placed[:len(cats)]), (placed[-2], placed[-1])))
+  for cats, rest in host_pool:
+    leaves, tree = jax.tree.flatten(rest)
+    placed = make_global_batch(mesh, *cats, *leaves)
+    if len(cats) + len(leaves) == 1:
+      placed = (placed,)                   # one array comes back bare
+    pool.append((list(placed[:len(cats)]),
+                 tree.unflatten(placed[len(cats):])))
   stamp('tables written, state made, pool placed')
   compiled = step.jitted.lower(state, *pool[0]).compile()
   memory = compiled.memory_analysis()

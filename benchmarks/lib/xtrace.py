@@ -20,8 +20,22 @@ collective opcode -> ``a2a`` / ``collective``; ``tf_op`` primitive
 ``matmul``; opcode ``copy`` (no primitive of the above) -> ``copy``;
 else ``other``.  Checked on a recorded v5e trace in
 ``tests/test_xtrace.py``.
+
+An op is also booked to its SCOPE PATH: the ``jax.named_scope``s it was
+traced under (the program's phases, ``apply/dedup/g1``), read off
+``tf_op`` as ``tools/trace_report.py`` reads them (this is the
+benchmark's own copy of that rule): the path split at its top-level
+``/``, the primitive at its end dropped, ``transpose(jvp(x))`` unwrapped
+to ``x`` (a phase's backward belongs to the phase), and every part left
+out that is JAX's and no scope: a function's name (``jit(step)``) and the
+control-flow wrappers (``while/body``, ``cond/branch_1_fun``).  What has
+a ``tf_op`` and no scope in it is ``unscoped``; what XLA made itself, with
+no ``tf_op``, is ``no_source``.  Phase times are SELF times (a ``while``
+does not count its body twice), so all paths with the two remainders sum
+to the device's busy time.
 """
 
+import functools
 import glob
 import gzip
 import json
@@ -33,6 +47,12 @@ CLASSES = ('a2a', 'collective', 'gather', 'scatter', 'sort', 'cumsum',
 _OPCODE = re.compile(r'^%\S+ = .*? ([\w\-]+)\(')
 _COLLECTIVES = ('all-reduce', 'all-gather', 'reduce-scatter',
                 'collective-permute', 'collective-broadcast')
+_TRANSFORM = re.compile(r'^(?:transpose|jvp|vmap)\((.*)\)$')
+# parts of a ``tf_op`` path that JAX's control flow puts there
+_CONTROL = re.compile(r'^(?:while|body|cond|branch_\d+_fun|scan|checkpoint'
+                      r'|remat\d*|closed_call|core_call|custom_jvp_call'
+                      r'|custom_vjp_call(?:_jaxpr)?|pjit|shard_map)$')
+UNSCOPED, NO_SOURCE = 'unscoped', 'no_source'
 
 
 def start_trace(directory):
@@ -81,6 +101,52 @@ def classify(args):
   return 'other'
 
 
+@functools.lru_cache(maxsize=None)   # a trace repeats each op every step
+def scope_path(tf_op):
+  """The scope path of one device op from its ``tf_op`` (module docstring):
+  ``apply/dedup/g1``, or ``unscoped``, or ``no_source`` for an empty one."""
+  if not tf_op:
+    return NO_SOURCE
+  parts, depth, cur = [], 0, ''
+  for ch in tf_op.rstrip(':'):
+    depth += (ch == '(') - (ch == ')')
+    if ch == '/' and depth == 0:
+      parts.append(cur)
+      cur = ''
+    else:
+      cur += ch
+  path = []
+  for part in parts:          # the last part, the primitive, never joined
+    m = _TRANSFORM.match(part)
+    while m:
+      part = m.group(1)
+      m = _TRANSFORM.match(part)
+    if '(' not in part:
+      path += [p for p in part.split('/') if not _CONTROL.match(p)]
+  return '/'.join(path) or UNSCOPED
+
+
+def _self_times(ops):
+  """``[(event, self microseconds)]`` for one thread's events: an op that
+  encloses others (``while``, ``conditional``) keeps only the time its
+  children do not cover, so the self times sum to the union."""
+  out, stack = [], []   # stack of [event, end, child microseconds]
+
+  def pop():
+    ev, _, child = stack.pop()
+    out.append((ev, max(0.0, ev['dur'] - child)))
+
+  for ev in sorted(ops, key=lambda e: (e['ts'], -e['dur'])):
+    while stack and ev['ts'] >= stack[-1][1]:
+      pop()
+    if stack:
+      stack[-1][2] += ev['dur']
+    stack.append([ev, ev['ts'] + ev['dur'], 0.0])
+  while stack:
+    pop()
+  return out
+
+
 def _union(intervals):
   """Total length and merged list of ``[(start, end)]``."""
   merged = []
@@ -103,6 +169,9 @@ def reduce_trace(path, window_span='bench/window', program=None):
   Returns a dict: ``window_s``; ``devices`` (plane names); ``steps``
   (program runs inside the window, fullest device); ``busy_s`` per
   device and ``busy_mean_s``; ``class_s`` per device (class -> seconds);
+  ``phase_s`` per device (scope path, ``unscoped`` or ``no_source`` ->
+  self seconds) and ``phase_class_s`` per device (the same paths -> class
+  -> self seconds), which sum to ``busy_s``;
   ``fullest`` (device with most busy time); ``ops`` (``name (class)`` -> seconds
   on the fullest device); ``idle_gaps`` (``[(host span, seconds)]``,
   longest first, fullest device); ``module_s`` (device seconds of the
@@ -143,7 +212,8 @@ def reduce_trace(path, window_span='bench/window', program=None):
 
   out = {'window_s': (hi - lo) * 1e-6,
          'devices': [proc[p] for p in device_pids],
-         'busy_s': {}, 'class_s': {}, 'steps': 0, 'ops': {},
+         'busy_s': {}, 'class_s': {}, 'phase_s': {}, 'phase_class_s': {},
+         'steps': 0, 'ops': {},
          'idle_gaps': [], 'module_s': 0.0, 'step_period_s': 0.0, 'modules': {},
          'fullest': None}
   per_dev = {}
@@ -157,8 +227,16 @@ def reduce_trace(path, window_span='bench/window', program=None):
     for e in ops:
       e['class'] = classify(e.get('args', {}))
       classes[e['class']] += e['dur'] * 1e-6
+    phases, phase_classes = {}, {}
+    for e, self_us in _self_times(ops):
+      path = scope_path(e.get('args', {}).get('tf_op', ''))
+      phases[path] = phases.get(path, 0.0) + self_us * 1e-6
+      by = phase_classes.setdefault(path, {})
+      by[e['class']] = by.get(e['class'], 0.0) + self_us * 1e-6
     out['busy_s'][proc[pid]] = busy * 1e-6
     out['class_s'][proc[pid]] = classes
+    out['phase_s'][proc[pid]] = phases
+    out['phase_class_s'][proc[pid]] = phase_classes
     per_dev[pid] = (ops, mods, merged)
   if not per_dev:
     out['busy_mean_s'] = 0.0

@@ -37,9 +37,12 @@ def rehearse(cell_name, seed, trace, seconds=1.0, cache_dir=None):
                        cache_dir or os.path.join(ROOT, '.bench_cache', 'toy'))
 
 
+CELLS = ('toy-synthetic-1', 'toy-dlrm-4', 'toy-token-1')
+
+
 def main():
   ok = True
-  for cell_name in ('toy-synthetic-1', 'toy-dlrm-4'):
+  for cell_name in CELLS:
     for trace in (0, 1):
       result = rehearse(cell_name, seed=2**31 + 12345 + trace, trace=trace)
       ok &= result['correct']

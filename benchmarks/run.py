@@ -6,7 +6,9 @@ One process; runs on the machine it is started on; refuses anything but a
 TPU with at least the chips the cell asks for.  Prints progress and the
 per-step record on earlier lines, the numbers compared beside their
 limits as the last lines of standard error, and one JSON object as the
-last line of standard output.
+last line of standard output.  A traffic mix may name, under
+``runtime_env``, variables the TPU runtime reads as it starts: they are
+set here, before JAX is imported, where the caller has not set them.
 """
 import time
 _STARTED = time.perf_counter()
@@ -33,9 +35,16 @@ def main(argv=None):
   if args.workload not in cells:
     raise SystemExit(f'unknown workload {args.workload!r}: {sorted(cells)}')
   chips = int(cells[args.workload]['chips'])
+  # what the mix asks of the runtime (``runtime_env``), before JAX loads it
+  from benchmarks.lib import names
+  mix = names.load_json(os.path.join(ROOT, 'benchmarks'), 'traffic',
+                        cells[args.workload]['traffic'])
+  names.set_runtime_env(mix, os.environ)
 
   import jax
+  imported = time.perf_counter() - _STARTED
   devices = jax.devices()
+  held = time.perf_counter() - _STARTED
   if devices[0].platform != 'tpu' or len(devices) < chips:
     raise SystemExit(
         f'benchmarks/run.py: {args.workload} needs {chips} TPU chip(s); JAX '
@@ -47,7 +56,8 @@ def main(argv=None):
   cache = compile_cache.configure()
   jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
   print(f'device: {len(devices)} x {devices[0].device_kind}, using {chips}; '
-        f'compile cache {cache}', file=sys.stderr, flush=True)
+        f'compile cache {cache}; jax imported at {imported:.1f} s, devices '
+        f'held at {held:.1f} s', file=sys.stderr, flush=True)
 
   from benchmarks.lib import cell
   result = cell.run_cell(manifest, os.path.join(ROOT, 'benchmarks'),
