@@ -50,6 +50,10 @@ METRIC_TYPES: Dict[str, str] = {
     'train.rollbacks': 'counter',
     'train.loss': 'gauge',
     'train.sync_ms': 'histogram',
+    # a packed token batch (models/hybrid_ssm.count_batch)
+    'train.tokens': 'counter',
+    'train.documents': 'counter',
+    'train.loss_positions': 'counter',
     # host CSR feed (parallel/csr_feed.py)
     'feed.batches': 'counter',
     'feed.skipped': 'counter',

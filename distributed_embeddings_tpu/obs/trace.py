@@ -137,6 +137,16 @@ REGISTERED_PHASES: Dict[str, str] = {
     # ``transpose(jvp(head))``) and the optax update of the dense params
     'head': 'dense head',
     'dense_update': 'dense head',
+    # inside ``head``, the parts of a hybrid state-space / attention
+    # stack (models/hybrid_ssm.py), forward and backward alike: the
+    # mixer's projections and gated norm, its causal convolution, the
+    # selective scan; attention; the SwiGLU; logits and loss
+    'mixer/proj': 'dense head',
+    'mixer/conv': 'dense head',
+    'mixer/selective_scan': 'dense head',
+    'attention': 'dense head',
+    'mlp': 'dense head',
+    'vocab': 'dense head',
     # the sparse optimizer step, each under a child scope per group:
     # the update stream's assembly and cross-slice merge ...
     'apply/stream': 'sparse apply',
@@ -148,6 +158,9 @@ REGISTERED_PHASES: Dict[str, str] = {
     'apply/update': 'sparse apply',
     # ... and the write back into table and state
     'apply/write_rows': 'sparse apply',
+    # a table the head also multiplies by (design §25): the head's dense
+    # gradient joined to the row sums, and the one whole-table update
+    'apply/tied': 'sparse apply',
 }
 
 PRIMITIVE_LEAVES = ('gather', 'scatter', 'sort', 'cumsum',

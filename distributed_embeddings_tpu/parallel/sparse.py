@@ -36,7 +36,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1177,10 +1177,90 @@ def _segwalk_apply(optimizer, table, state, flat_ids, flat_g, lr,
   return t2, {'acc': a2}
 
 
+def _tied_layout(dist: DistributedEmbedding, table_ids, optimizer):
+  """Where each table the head reads lies: ``{table id: (group index,
+  device, first row in the group's shard, rows)}``.  Refuses by name
+  what the tied apply does not serve."""
+  refused = [
+      ('a hot-row cache', bool(getattr(dist, 'hot_enabled', False))),
+      ('quantized storage', getattr(dist, 'quant', None) is not None),
+      ('a cold tier', bool(getattr(dist.plan, 'cold_tier_groups', []))),
+      ('more than one slice', dist.num_slices > 1),
+  ]
+  for what, there in refused:
+    if there:
+      raise NotImplementedError(
+          f'head_reads_tables: not with {what} (docs/design.md §25)')
+  if not hasattr(optimizer, 'apply_hot'):
+    raise NotImplementedError(
+        f'head_reads_tables: {type(optimizer).__name__} has no dense '
+        'apply (apply_hot)')
+  group_of = {g.key: gi for gi, g in enumerate(dist.plan.groups)}
+  layout = dist.plan.shard_layout()
+  out = {}
+  for tid in table_ids:
+    cfg = dist.table_configs[tid]
+    shards = layout[tid]
+    whole = (0, cfg.output_dim, 0, cfg.input_dim, 1)
+    if len(shards) != 1 or tuple(shards[0][3:]) != whole:
+      raise NotImplementedError(
+          f'head_reads_tables: table {tid} is sliced over the mesh; the '
+          'head reads whole tables')
+    dev, key, row_offset = shards[0][:3]
+    gi = group_of[key]
+    if getattr(dist.plan.groups[gi], 'storage_pack', 1) > 1:
+      raise NotImplementedError(
+          f'head_reads_tables: table {tid} (width {cfg.output_dim}) is '
+          'stored lane-packed; construct the layer with '
+          'packed_storage=False')
+    out[tid] = (gi, dev, row_offset, cfg.input_dim)
+  return out
+
+
+def _tied_apply(optimizer, table, state, flat_ids, g_rows, g_index, lr,
+                rows_cap: int, head_grads):
+  """One update of a group that holds a table the head also multiplies
+  by (a tied vocabulary, docs/design.md §25).  The lookups' occurrence
+  rows are segment-summed as ever (``compact_segments`` at the
+  guaranteed capacity: nothing can overflow), scattered into the head's
+  dense ``[rows, width]`` gradient, and the shard takes ONE dense
+  optimizer step (``apply_hot``): every row of a head-read table counts
+  as asked for (the softmax touches them all), any other row of the
+  group only if the batch did, so moments and step count advance once.
+
+  ``head_grads``: ``[(first row, rows, gradient [rows, width], mine)]``,
+  ``mine`` a traced flag: whether this device owns the table."""
+  needs_sq = bool(getattr(optimizer, 'needs_sq', True))
+  uids, sum_g, sum_sq, _ = compact_segments(
+      flat_ids, g_rows, _guaranteed_cap(flat_ids.shape[0], rows_cap),
+      rows_cap, with_sq=needs_sq, g_index=g_index)
+  with obs_trace.phase('apply/tied'):
+    hints = dict(mode='drop', unique_indices=True, indices_are_sorted=True)
+    ids = _distinct_oob(uids, rows_cap)
+    dense_g = jnp.zeros(table.shape, jnp.float32).at[ids].add(sum_g, **hints)
+    asked = jnp.zeros((table.shape[0], 1), jnp.float32).at[ids].add(
+        1.0, **hints)
+    dense_sq = (jnp.zeros_like(dense_g).at[ids].add(sum_sq, **hints)
+                if needs_sq else None)
+    for first, rows, grad, mine in head_grads:
+      grad = jnp.where(mine, grad.astype(jnp.float32), 0.0)
+      dense_g = dense_g.at[first:first + rows].add(grad)
+      asked = asked.at[first:first + rows].add(mine.astype(jnp.float32))
+      if needs_sq:
+        dense_sq = dense_sq.at[first:first + rows].add(grad * grad)
+    return optimizer.apply_hot(table, state, dense_g, dense_sq, lr,
+                               count=asked)
+
+
 def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
                         global_batch: int, hotness: tuple,
-                        fetch_caps: tuple = ()):
+                        fetch_caps: tuple = (), tied: tuple = ()):
   """shard_map'd per-device sparse update over all fusion groups.
+
+  ``tied``: ``((table id, (group, device, first row, rows)), ...)`` of
+  the tables the head also reads (``_tied_layout``): the trailing args
+  then carry one replicated ``[rows, width]`` head gradient per entry,
+  and their groups update through ``_tied_apply``.
 
   Hot-cache layers (``dist.hot_enabled``): the per-subgroup streams
   arrive ALREADY deduplicated per (source device, slot) — the same
@@ -1202,7 +1282,8 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
   and return the updated fetch rows as a per-group WRITEBACK output
   the host stores into the tier.
   """
-  key = ('sparse_apply', optimizer, global_batch, hotness, fetch_caps)
+  key = ('sparse_apply', optimizer, global_batch, hotness, fetch_caps,
+         tied)
   if key in dist._fn_cache:
     return dist._fn_cache[key]
   subs = dist._subgroups(hotness)
@@ -1229,7 +1310,8 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
   def local_fn(params, opt_state, lr, fetch, *res_and_g):
     residuals = res_and_g[:len(subs)]
     gs = res_and_g[len(subs):2 * len(subs)]
-    hot_gs = res_and_g[2 * len(subs):]
+    hot_gs = res_and_g[2 * len(subs):2 * len(subs) + len(hot_gis)]
+    tied_gs = res_and_g[2 * len(subs) + len(hot_gis):]
     new_params = dict(params)
     new_state = dict(opt_state)
     writeback = {}
@@ -1401,6 +1483,18 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
             flat_g = g_rows[:, :w]
             flat_sq = g_rows[:, w:]
         spack = getattr(group, 'storage_pack', 1)
+        head_grads = [
+            (first, rows, tied_gs[k], jax.lax.axis_index(ax) == dev)
+            for k, (_, (tgi, dev, first, rows)) in enumerate(tied)
+            if tgi == gi]
+        if head_grads:
+          table, state2 = _tied_apply(optimizer, params[key][0], state_g,
+                                      flat_ids, g_rows, g_idx, lr, rows_cap,
+                                      head_grads)
+          new_params[key] = table[None]
+          new_state[key] = {k: v[None] for k, v in state2.items()}
+          fence = table[0, 0]
+          continue
         if quant is not None or gi in tiered:
           # quantized and/or tiered group (design §12): the table operand
           # is the (payload, scale) pair; cold-tier groups concatenate
@@ -1643,7 +1737,7 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
         tuple(
             P(ax, None, dist.dcn_axis, None)
             for _ in range(2 * len(subs))) + tuple(
-                P(None, None) for _ in hot_gis),
+                P(None, None) for _ in range(len(hot_gis) + len(tied))),
         out_specs=(param_specs, state_spec, wb_spec),
         check_vma=False)
     return fn(params, opt_state, lr, fetch, *res_and_g)
@@ -1655,8 +1749,13 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
 def sparse_apply_updates(dist: DistributedEmbedding, optimizer, params,
                          opt_state, residuals, gsubs, lr,
                          global_batch: int, hotness: tuple,
-                         hot_grads=None, cold_fetch=None):
+                         hot_grads=None, cold_fetch=None, head_grads=None):
   """Apply one sparse optimizer step to the embedding params.
+
+  ``head_grads``: ``{table id: [rows, width]}``, the head's own dense
+  gradient of each table it reads (``make_hybrid_train_step(...,
+  head_reads_tables=)``); it joins the lookups' row sums before the
+  table's one update (``_tied_apply``).
 
   ``hot_grads``: for hot-cache layers, the ``{group_index: [K, w]}``
   replicated hot-row gradient buffers from ``backward_to_mp``.
@@ -1676,8 +1775,12 @@ def sparse_apply_updates(dist: DistributedEmbedding, optimizer, params,
         'cold_fetch= (the batch fetch the forward consumed): the tier '
         'rows it updates live in those buffers (docs/design.md §12)')
   fetch = getattr(cold_fetch, 'device', cold_fetch) if cold_fetch else {}
+  tied = ()
+  if head_grads:
+    tied = tuple(sorted(
+        _tied_layout(dist, sorted(head_grads), optimizer).items()))
   fn = _build_sparse_apply(dist, optimizer, global_batch, hotness,
-                           fetch_caps=_fetch_caps_sig(fetch))
+                           fetch_caps=_fetch_caps_sig(fetch), tied=tied)
   hot_list = []
   if hot_grads:
     hot_list = [hot_grads[gi] for gi in dist.plan.hot_groups]
@@ -1688,7 +1791,7 @@ def sparse_apply_updates(dist: DistributedEmbedding, optimizer, params,
         'that backward_to_mp returns alongside gsubs)')
   new_params, new_state, writeback = fn(
       params, opt_state, jnp.asarray(lr, jnp.float32), fetch,
-      *residuals, *gsubs, *hot_list)
+      *residuals, *gsubs, *hot_list, *(head_grads[tid] for tid, _ in tied))
   if tier_on:
     return new_params, new_state, writeback
   return new_params, new_state
@@ -1700,7 +1803,8 @@ def make_hybrid_train_step(dist: DistributedEmbedding,
                            emb_optimizer,
                            lr_schedule: Optional[Callable] = None,
                            donate: bool = True,
-                           jit: bool = True) -> Callable:
+                           jit: bool = True,
+                           head_reads_tables: Sequence[int] = ()) -> Callable:
   """Build the full hybrid-parallel sparse train step.
 
   The TPU-native analog of the reference training loop
@@ -1721,6 +1825,14 @@ def make_hybrid_train_step(dist: DistributedEmbedding,
       (dense schedules live inside the optax chain); defaults to the
       optimizer's fixed ``learning_rate``.
     donate: donate state buffers (in-place update of the tables).
+    head_reads_tables: ids of tables the head also multiplies by (a tied
+      vocabulary, docs/design.md §25).  ``head_loss_fn`` is then called
+      as ``(dense_params, emb_outs, batch, tables)`` with ``tables =
+      {table id: [rows, width]}``; the head's dense gradient of each
+      joins the lookups' row sums and the table takes one update over
+      every row.  On a mesh the owner's shard reaches the data-parallel
+      head, and the head's gradient returns, by the mesh's collectives.
+      With the default the step is what it was.
 
   Returns:
     ``step(state, cats, batch) -> (state, loss)`` (jitted).  ``cats`` is
@@ -1741,6 +1853,8 @@ def make_hybrid_train_step(dist: DistributedEmbedding,
           'SparseSGD or SparseAdagrad (docs/design.md §12)')
     for leaf, (ldtype, fill) in specs_fn().items():
       dist.cold_tier.ensure_opt(leaf, fill, ldtype)
+  tied = (_tied_layout(dist, tuple(head_reads_tables), emb_optimizer)
+          if head_reads_tables else {})
 
   def step(state: TrainState, cats, batch, cold_fetch=None):
     emb_params = state.params['embedding']
@@ -1765,11 +1879,18 @@ def make_hybrid_train_step(dist: DistributedEmbedding,
 
     # the scope opens inside the function vjp differentiates, so the
     # head's backward carries it too (``transpose(jvp(head))``)
+    # the owner's rows of each table the head reads, as one array: under
+    # the step's jit a static index into the sharded leaf, which the
+    # partitioner turns into the mesh's collective.  None read: the head
+    # is called, and differentiated, without a fourth argument.
+    tables = [{tid: emb_params[f'group_{gi}'][dev, first:first + rows]
+               for tid, (gi, dev, first, rows) in tied.items()}] if tied else []
     loss, pull = jax.vjp(
         obs_trace.phase('head')(
-            lambda dp, eo: head_loss_fn(dp, eo, batch)), dense_params,
-        tuple(emb_outs))
-    d_dense, d_emb = pull(jnp.ones((), loss.dtype))
+            lambda dp, eo, *tb: head_loss_fn(dp, eo, batch, *tb)),
+        dense_params, tuple(emb_outs), *tables)
+    d_dense, d_emb, *head_grads = pull(jnp.ones((), loss.dtype))
+    head_grads = head_grads[0] if tied else None
 
     with obs_trace.phase('dense_update'):
       updates, dense_opt_state = dense_optimizer.update(
@@ -1837,7 +1958,7 @@ def make_hybrid_train_step(dist: DistributedEmbedding,
           else emb_optimizer.learning_rate)
     new_emb, emb_opt_state = sparse_apply_updates(
         dist, emb_optimizer, emb_params, emb_opt_state, residuals, gsubs,
-        lr, global_batch, hotness)
+        lr, global_batch, hotness, head_grads=head_grads)
 
     params = {**new_dense, 'embedding': new_emb}
     return TrainState(params, (dense_opt_state, emb_opt_state),
