@@ -106,6 +106,17 @@ def route_ids(ids: jax.Array, offsets: jax.Array, vocab: jax.Array,
   return jnp.where(mask, clipped + offsets[:, None, None], rows_cap)
 
 
+def sort_with_order(ids: jax.Array, *riders: jax.Array):
+  """Stable ascending sort of 1-D ``ids`` that keeps what it ordered:
+  ``(ids[order], order, *(r[order] for r in riders))`` with ``order =
+  jnp.argsort(ids)``, from the one multi-operand sort ``argsort`` lowers
+  to before it drops the sorted keys.  On v5e a two-operand int32 sort
+  is 1.4 ns a key and a third operand 0.5 ns more, where fetching
+  ``x[order]`` afterwards is 7 ns a row (PERF.md, PR 28)."""
+  iota = jnp.arange(ids.shape[0], dtype=jnp.int32)
+  return jax.lax.sort((ids, iota) + riders, num_keys=1, is_stable=True)
+
+
 def unique_with_inverse(ids: jax.Array, cap: int):
   """Per-row sort-unique with inverse positions (the cold-id dedup of
   the hot-cache exchange, docs/design.md §10).
@@ -131,8 +142,7 @@ def unique_with_inverse(ids: jax.Array, cap: int):
 
   def one(row):
     keyv = jnp.where(row >= 0, row, big)
-    order = jnp.argsort(keyv)
-    sid = keyv[order]
+    sid = jnp.sort(keyv)
     first = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
     real = sid < big
     rank = jnp.cumsum((first & real).astype(jnp.int32)) - 1
@@ -170,8 +180,7 @@ def dense_segment_sum(seg: jax.Array, rows: jax.Array, num: int,
   tens of ms for the n-bound scatter.
   """
   n = seg.shape[0]
-  order = jnp.argsort(seg)
-  s = seg[order]
+  s, order = sort_with_order(seg)
   payload = (rows[order] if row_index is None
              else rows[jnp.take(row_index, order)]).astype(jnp.float32)
   payload = jnp.where((s < num)[:, None], payload, 0.0)

@@ -49,6 +49,7 @@ from distributed_embeddings_tpu.parallel.dist_embedding import (
 from distributed_embeddings_tpu.parallel.grad import TrainState
 from distributed_embeddings_tpu.parallel.overlap import (chunk_bounds,
                                                          effective_chunks)
+from distributed_embeddings_tpu.parallel.routing import sort_with_order
 
 _LOG = logging.getLogger(__name__)
 
@@ -59,7 +60,6 @@ def compact_segments(ids: jax.Array,
                      cap: int,
                      sentinel: int,
                      with_sq: bool = False,
-                     order: Optional[jax.Array] = None,
                      g_index: Optional[jax.Array] = None,
                      max_seg: Optional[int] = None):
   """Sort-dedup and COMPACT segment sums into static capacity ``cap``.
@@ -67,12 +67,14 @@ def compact_segments(ids: jax.Array,
   The key fact motivating this (measured on v5e, docs/perf_notes.md):
   XLA scatter costs ~110-140 ns per update row REGARDLESS of how many
   rows are sentinel-dropped — only the *static* row count matters — while
-  sorts are ~5 ns/row and gathers ~10-20 ns/row.  ``dedup_rows`` keeps the
-  nnz-length shape, so its scatters still pay full price; this variant
-  compacts the unique rows to the front of a ``cap``-sized buffer so the
-  optimizer's scatters shrink by the duplicate factor (~6x on the
-  power-law synthetic inputs) or down to the fused table's row count,
-  whichever is smaller.
+  a two-operand sort is 1.5 ns a key and a gather 7 to 25 ns a row
+  (PERF.md, PR 28).  ``dedup_rows`` keeps the nnz-length shape, so its
+  scatters still pay full price; this variant compacts the unique rows
+  to the front of a ``cap``-sized buffer so the optimizer's scatters
+  shrink by the duplicate factor (~6x on the power-law synthetic inputs)
+  or down to the fused table's row count, whichever is smaller.  It
+  sorts, gathers the payload in sorted order and hands the sorted stream
+  to ``_compact_sorted``.
 
   Segment sums use the sorted-cumsum-difference trick (vectorised,
   contiguous); over millions of rows f32 cumsum cancellation adds a
@@ -89,8 +91,6 @@ def compact_segments(ids: jax.Array,
     sentinel: value marking dropped rows in the compacted output.
     with_sq: also return per-segment sums of squared gradients (for
       per-occurrence Adagrad accumulator semantics).
-    order: optional precomputed ``argsort(ids)`` (lets callers share the
-      sort with an overflow pre-check).
     g_index: optional ``[n]`` int32 position->row map into COMPACT
       ``grads`` (``[m, w]``, one row per (sample, bag)): multi-hot
       broadcasts never materialise — the sorted payload gathers
@@ -113,35 +113,48 @@ def compact_segments(ids: jax.Array,
     zeros, ``num_unique`` is a traced scalar (segments counted including
     the sentinel segment).
   """
-  n = ids.shape[0]
-  if g_index is not None and g_index.shape[0] != n:
+  sid, order = sort_with_order(ids)
+  return _compact_sorted(sid, _sorted_payload(grads, order, g_index), cap,
+                         sentinel, with_sq=with_sq, max_seg=max_seg)
+
+
+def _sorted_payload(grads: jax.Array, order: jax.Array,
+                    g_index: Optional[jax.Array]) -> jax.Array:
+  """The f32 payload rows in their stream's sorted order: the one gather
+  no sort carries (``compact_segments`` has the ``g_index`` contract)."""
+  if g_index is not None and g_index.shape[0] != order.shape[0]:
     raise ValueError(f'g_index length {g_index.shape[0]} != stream '
-                     f'length {n}')  # jnp.take would silently clip
-  if order is None:
-    order = jnp.argsort(ids)
-  sid = ids[order]
-  sg = (grads[order] if g_index is None else
-        grads[jnp.take(g_index, order)]).astype(jnp.float32)
+                     f'length {order.shape[0]}')  # jnp.take would silently clip
+  return (grads[order] if g_index is None else
+          grads[jnp.take(g_index, order)]).astype(jnp.float32)
+
+
+@obs_trace.phase('apply/dedup')
+def _compact_sorted(sid: jax.Array, sg: jax.Array, cap: int, sentinel: int,
+                    with_sq: bool = False, max_seg: Optional[int] = None):
+  """``compact_segments`` from the point where the stream is sorted
+  (``sid`` ``[n]`` ascending, ``sg`` ``[n, w]`` its f32 payload rows):
+  ranks, compaction, totals; a stream that arrives sorted (``_lane_pack``,
+  ``_dedup_and_apply``) enters here.  A gather costs five to fifteen
+  keys of a sort, so the sorts CARRY what they order and only payloads
+  are fetched through a permutation: the sort that brings each segment's
+  last position to slot ``rank`` carries the slot's id, and ``lo``, the
+  running sum just before a segment's first position, is the previous
+  slot's ``hi`` (segments are contiguous, slots in rank order): the
+  same gather read one slot earlier."""
+  n = sid.shape[0]
   is_first, is_last, first_pos, _ = _sorted_segments(sid)
   rank = jnp.cumsum(is_first.astype(jnp.int32)) - 1
   num_unique = rank[-1] + 1
   # bring each segment's last position to slot `rank`
   key = jnp.where(is_last, rank, n)
-  order2 = jnp.argsort(key)[:cap]
-  valid = key[order2] < n
-  uids = jnp.where(valid, sid[order2], sentinel)
-
-  # Segment totals ONLY at the compacted positions: total = inclusive
-  # cumsum at the segment's last position minus the cumsum just before
-  # its first position.  This keeps a single [n, w] running-sum buffer
-  # per payload (instead of materialising per-position totals plus an
-  # n-row gather of the exclusive sums) — the compaction's big
-  # temporaries halve and one n-row random gather disappears.
-  fp = first_pos[order2]                             # [cap]
+  skey, order2, uids = (x[:cap] for x in sort_with_order(key, sid))
+  valid = skey < n
+  uids = jnp.where(valid, uids, sentinel)
 
   if max_seg is not None:
-    # exact bounded-multiplicity totals (see Args): complete at each
-    # segment's last position, which is exactly what order2 selects
+    # exact bounded-multiplicity totals (compact_segments' Args): complete
+    # at each segment's last position, which is exactly what order2 selects
     sum_g = jnp.where(valid[:, None],
                       _seg_fold_bounded(sg, first_pos, max_seg)[order2],
                       0.0)
@@ -151,9 +164,20 @@ def compact_segments(ids: jax.Array,
               if with_sq else None)
     return uids, sum_g, sum_sq, num_unique
 
+  # Segment totals ONLY at the compacted positions: inclusive cumsum at
+  # the segment's last position (hi) minus that at the previous segment's
+  # last (lo): one [n, w] running-sum buffer and ONE gather a payload,
+  # read through two windows.  The gather starts two slots early so that
+  # both windows start past row 0 and neither is a bitcast of its
+  # buffer: fused beside `hi` itself, a bitcast `hi[:-1]` let the v5e
+  # compiler write `hi - lo` over `hi` in place while it still read it
+  # one slot behind (tiny-train-uniform, PERF.md PR 28: wrong rows).
+  order2 = jnp.pad(order2, (2, 0))
+  slot0 = (jnp.arange(valid.shape[0]) == 0)[:, None]
+
   def seg_tot(csum):
-    hi = csum[order2]
-    lo = jnp.where((fp > 0)[:, None], csum[jnp.maximum(fp - 1, 0)], 0.0)
+    ext = csum[order2]
+    hi, lo = ext[2:], jnp.where(slot0, 0.0, ext[1:-1])
     return jnp.where(valid[:, None], hi - lo, 0.0)
 
   sum_g = seg_tot(jnp.cumsum(sg, axis=0))
@@ -212,8 +236,7 @@ def dedup_rows(ids: jax.Array, grads: jax.Array,
   discards those).  Returns ``(unique_ids, summed_grads)`` of the same
   length as the inputs.
   """
-  order = jnp.argsort(ids)
-  sid = ids[order]
+  sid, order = sort_with_order(ids)
   sg = grads[order].astype(jnp.float32)
   _, is_last, _, seg_total = _sorted_segments(sid)
   uids = jnp.where(is_last, sid, sentinel)
@@ -759,11 +782,9 @@ def _lane_pack(uids, sum_g, sum_sq, pack: int, rows_cap: int,
       [g_lanes, lane_expand(sum_sq, slot, pack)], axis=1))
   cap2 = min(c, psent + 2)
   # uids come rank-ordered (ascending, sentinels last) from the outer
-  # compact_segments, so pids is already sorted: skip the argsort
-  pids_c, pay_c, _, _ = compact_segments(
-      pids, payload, cap2, psent,
-      order=jnp.arange(c, dtype=jnp.int32),
-      max_seg=pack if exact else None)
+  # compaction, so pids is already sorted: no sort, no sorted gather
+  pids_c, pay_c, _, _ = _compact_sorted(
+      pids, payload, cap2, psent, max_seg=pack if exact else None)
   g_packed = pay_c[:, :lanes]
   sq_packed = pay_c[:, lanes:] if sum_sq is not None else None
   return pids_c, g_packed, sq_packed
@@ -944,22 +965,21 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
     pack = 128 // w if packable else 1
     packable = packable and rows_cap // pack + 2 < cap
 
+  # squares that arrive pre-accumulated are segment-summed as an extra
+  # payload column block instead of squaring the (pre-summed) grads
+  sq_cols = with_sq and flat_sq is not None
   with obs_trace.phase('apply/dedup'):
-    order = jnp.argsort(flat_ids) if cap < cap_safe else None
-  if with_sq and flat_sq is not None:
-    # squares arrive pre-accumulated: segment-sum them as an extra
-    # payload column block instead of squaring the (pre-summed) grads
-    with obs_trace.phase('apply/dedup'):
-      payload = jnp.concatenate(
-          [flat_g.astype(jnp.float32),
-           flat_sq.astype(jnp.float32)], axis=1)
-    uids, tot, _, num_unique = compact_segments(
-        flat_ids, payload, cap, sentinel, order=order, max_seg=max_seg)
-    sum_g, sum_sq = tot[:, :w], tot[:, w:]
-  else:
-    uids, sum_g, sum_sq, num_unique = compact_segments(
-        flat_ids, flat_g, cap, sentinel, with_sq=with_sq, order=order,
-        g_index=g_index, max_seg=max_seg)
+    # one sort serves the main wave and the correction's body
+    sid, order = sort_with_order(flat_ids)
+    sg = _sorted_payload(
+        jnp.concatenate([flat_g.astype(jnp.float32),
+                         flat_sq.astype(jnp.float32)], axis=1)
+        if sq_cols else flat_g, order, g_index)
+  uids, sum_g, sum_sq, num_unique = _compact_sorted(
+      sid, sg, cap, sentinel, with_sq=with_sq and not sq_cols,
+      max_seg=max_seg)
+  if sq_cols:
+    sum_g, sum_sq = sum_g[:, :w], sum_g[:, w:]
   if storage_packed:
     # updates lane-pack against the physically packed operand directly
     pids, g_p, sq_p = _lane_pack(uids, sum_g, sum_sq, pack, rows_cap,
@@ -992,9 +1012,9 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
     # rather than O(n) when the fused table is smaller than the stream
     t3, s3 = args
     with obs_trace.phase('apply/dedup'):
-      sid = flat_ids[order]
-      sg = (flat_g[order] if g_index is None else
-            flat_g[jnp.take(g_index, order)]).astype(jnp.float32)
+      # the payload is gathered again, not kept from the main wave: an
+      # [n, w] buffer alive across the apply would count toward peak HBM
+      sg = _sorted_payload(flat_g, order, g_index)
       is_first, is_last, first_pos_c, seg_total = _sorted_segments(sid)
       if max_seg is not None:
         # the bounded exact fold of the main wave (layout-independent

@@ -8,6 +8,8 @@ sparse scatter updates (parallel/sparse.py) must land on exactly the same
 weights.
 """
 
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -292,6 +294,226 @@ def test_compact_segments_unit():
   np.testing.assert_allclose(out_sq, dense_sq, rtol=1e-4, atol=1e-5)
   # the sentinel occupies one segment; all real uniques must fit
   assert int(nuniq) == len(set(ids[ids < vocab].tolist())) + 1
+
+
+# --- the dedup as it stood before ISSUE 28, frozen: its sorts dropped what
+# they ordered and every per-slot value came back through an index gather
+# (``ids[order]``, ``key[order2]``, ``sid[order2]``, ``first_pos[order2]``,
+# ``csum[fp - 1]``; ``_lane_pack`` gathered through ``arange``).  The
+# program's functions must return the same bits by their shorter road.
+
+
+def _frozen_compact_segments(ids, grads, cap, sentinel, with_sq=False,
+                             order=None, g_index=None, max_seg=None):
+  from distributed_embeddings_tpu.parallel.sparse import (_seg_fold_bounded,
+                                                          _sorted_segments)
+  n = ids.shape[0]
+  if order is None:
+    order = jnp.argsort(ids)
+  sid = ids[order]
+  sg = (grads[order] if g_index is None else
+        grads[jnp.take(g_index, order)]).astype(jnp.float32)
+  is_first, is_last, first_pos, _ = _sorted_segments(sid)
+  rank = jnp.cumsum(is_first.astype(jnp.int32)) - 1
+  num_unique = rank[-1] + 1
+  key = jnp.where(is_last, rank, n)
+  order2 = jnp.argsort(key)[:cap]
+  valid = key[order2] < n
+  uids = jnp.where(valid, sid[order2], sentinel)
+  fp = first_pos[order2]
+  if max_seg is not None:
+    sum_g = jnp.where(valid[:, None],
+                      _seg_fold_bounded(sg, first_pos, max_seg)[order2], 0.0)
+    sum_sq = (jnp.where(
+        valid[:, None],
+        _seg_fold_bounded(sg * sg, first_pos, max_seg)[order2], 0.0)
+              if with_sq else None)
+    return uids, sum_g, sum_sq, num_unique
+
+  def seg_tot(csum):
+    hi = csum[order2]
+    lo = jnp.where((fp > 0)[:, None], csum[jnp.maximum(fp - 1, 0)], 0.0)
+    return jnp.where(valid[:, None], hi - lo, 0.0)
+
+  sum_g = seg_tot(jnp.cumsum(sg, axis=0))
+  sum_sq = seg_tot(jnp.cumsum(sg * sg, axis=0)) if with_sq else None
+  return uids, sum_g, sum_sq, num_unique
+
+
+def _frozen_lane_pack(uids, sum_g, sum_sq, pack, rows_cap, exact=False):
+  from distributed_embeddings_tpu.ops.pallas_segwalk import (lane_expand,
+                                                             packed_ids)
+  c, w = sum_g.shape
+  lanes = pack * w
+  psent = rows_cap // pack
+  pids, slot = packed_ids(uids, pack, rows_cap)
+  g_lanes = lane_expand(sum_g, slot, pack)
+  payload = (g_lanes if sum_sq is None else jnp.concatenate(
+      [g_lanes, lane_expand(sum_sq, slot, pack)], axis=1))
+  pids_c, pay_c, _, _ = _frozen_compact_segments(
+      pids, payload, min(c, psent + 2), psent,
+      order=jnp.arange(c, dtype=jnp.int32),
+      max_seg=pack if exact else None)
+  return (pids_c, pay_c[:, :lanes],
+          pay_c[:, lanes:] if sum_sq is not None else None)
+
+
+def _dedup_stream(seed, n, w, vocab, sentinels=0, presorted=False,
+                  max_seg=None):
+  """An update stream: ``n`` ids under ``vocab`` (each at most ``max_seg``
+  times where that is given), ``sentinels`` of them padding."""
+  rng = np.random.default_rng(seed)
+  if max_seg is None:
+    ids = rng.integers(0, vocab, size=(n,))
+  else:
+    ids = rng.permutation(np.repeat(np.arange(vocab), max_seg))[:n]
+  ids = ids.astype(np.int32)
+  ids[rng.choice(n, size=sentinels, replace=False)] = vocab
+  if presorted:
+    ids = np.sort(ids)
+  return ids, rng.normal(size=(n, w)).astype(np.float32)
+
+
+def _assert_same_outputs(got, want):
+  for a, b in zip(got, want):
+    assert (a is None) == (b is None)
+    if a is not None:
+      a, b = np.asarray(a), np.asarray(b)
+      assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+# (n, w, vocab, cap or its distance from the unique count, keywords)
+_COMPACT_CASES = {
+    'sentinels-w8': (512, 8, 40, 42, dict(sentinels=9)),
+    'cap-below-w16': (512, 16, 90, 'uniq-7', dict(sentinels=3)),
+    'cap-at-w16': (512, 16, 90, 'uniq+0', dict(sentinels=3)),
+    'cap-above-w16': (512, 16, 90, 'uniq+5', dict()),
+    'cap-over-n-w8': (64, 8, 500, 600, dict(sentinels=2)),
+    'sq-w8': (512, 8, 40, 42, dict(sentinels=9, with_sq=True)),
+    'sq-cap-below-w128': (384, 128, 70, 'uniq-20',
+                          dict(sentinels=5, with_sq=True)),
+    'g-index-w16': (512, 16, 40, 42, dict(sentinels=4, g_index=True,
+                                          with_sq=True)),
+    'max-seg-w8': (256, 8, 100, 102, dict(sentinels=6, max_seg=3)),
+    'max-seg-sq-w128': (256, 128, 100, 'uniq-4',
+                        dict(sentinels=6, max_seg=3, with_sq=True)),
+    'presorted-w16': (512, 16, 40, 42, dict(sentinels=9, presorted=True,
+                                            with_sq=True)),
+    'w128': (384, 128, 70, 72, dict(sentinels=5)),
+    'one-segment-w8': (128, 8, 1, 3, dict(with_sq=True)),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_COMPACT_CASES))
+def test_compact_segments_keeps_the_frozen_formulations_bits(case):
+  """ISSUE 28 changes how the dedup moves its values and none of them:
+  every output equal to the bit, ``np.array_equal`` and no tolerance."""
+  from distributed_embeddings_tpu.parallel.sparse import compact_segments
+  n, w, vocab, cap, kw = _COMPACT_CASES[case]
+  kw = dict(kw)
+  ids, g = _dedup_stream(
+      sum(map(ord, case)), n, w, vocab, sentinels=kw.pop('sentinels', 0),
+      presorted=kw.pop('presorted', False), max_seg=kw.get('max_seg'))
+  if isinstance(cap, str):
+    cap = len(set(ids.tolist())) + int(cap[4:])
+  if kw.pop('g_index', False):
+    # compact payload rows, each stream position naming one of them
+    g_index = np.random.default_rng(1).integers(0, n // 4, size=(n,))
+    kw['g_index'] = jnp.asarray(g_index.astype(np.int32))
+    g = g[:n // 4]
+  got = jax.jit(lambda i, x: compact_segments(i, x, cap, vocab, **kw))(ids, g)
+  want = jax.jit(
+      lambda i, x: _frozen_compact_segments(i, x, cap, vocab, **kw))(ids, g)
+  assert got[0].shape == (min(cap, n),)
+  _assert_same_outputs(got, want)
+
+
+@pytest.mark.parametrize('exact', [False, True])
+@pytest.mark.parametrize('with_sq', [False, True])
+@pytest.mark.parametrize('w,rows_cap,n', [(8, 1600, 2048), (16, 4096, 300),
+                                          (64, 512, 1024)])
+def test_lane_pack_keeps_the_frozen_formulations_bits(w, rows_cap, n,
+                                                      with_sq, exact):
+  """``_lane_pack`` on what the outer compaction hands it (ascending
+  ids, sentinels last), whether the packed capacity shrinks the buffer
+  (``rows_cap // pack + 2 < c``) or not."""
+  from distributed_embeddings_tpu.parallel.sparse import (_lane_pack,
+                                                          compact_segments)
+  pack = 128 // w
+  ids, g = _dedup_stream(w + n, n, w, rows_cap, sentinels=7)
+  uids, sum_g, sum_sq, _ = jax.jit(lambda i, x: compact_segments(
+      i, x, min(n, rows_cap + 2), rows_cap, with_sq=with_sq))(ids, g)
+  got = jax.jit(lambda *a: _lane_pack(*a, pack, rows_cap, exact=exact))(
+      uids, sum_g, sum_sq)
+  want = jax.jit(
+      lambda *a: _frozen_lane_pack(*a, pack, rows_cap, exact=exact))(
+          uids, sum_g, sum_sq)
+  assert got[0].shape == (min(uids.shape[0], rows_cap // pack + 2),)
+  _assert_same_outputs(got, want)
+
+
+def _gathers(jaxpr, from_iota=()):
+  """``(gathers, gathers indexed by an iota alone, which outputs are an
+  iota alone)`` of a jaxpr, nested calls included.  A value is "an iota
+  alone" when nothing but ``iota``s and literals feeds it: a gather
+  through one is a copy, or a shift, written as a permutation."""
+  from jax.extend import core as jex_core
+  iota = {v for v, f in zip(jaxpr.invars, from_iota) if f}
+  known = lambda v: isinstance(v, jex_core.Literal) or v in iota
+  total = by_iota = 0
+  for eqn in jaxpr.eqns:
+    flags = [not isinstance(v, jex_core.Literal) and v in iota
+             for v in eqn.invars]
+    inner = [p for p in eqn.params.values() if hasattr(p, 'jaxpr')]
+    if eqn.primitive.name == 'gather':
+      total += 1
+      by_iota += flags[1]
+    if len(inner) == 1 and len(inner[0].jaxpr.invars) == len(eqn.invars):
+      t, b, outs = _gathers(inner[0].jaxpr, flags)
+      total, by_iota = total + t, by_iota + b
+    else:
+      outs = [eqn.primitive.name == 'iota' or
+              (any(flags) and all(map(known, eqn.invars)))] * len(eqn.outvars)
+    iota |= {v for v, f in zip(eqn.outvars, outs) if f}
+  return total, by_iota, [known(v) and not isinstance(v, jex_core.Literal)
+                          for v in jaxpr.outvars]
+
+
+@pytest.mark.parametrize('with_sq,frozen_count', [(False, 7), (True, 9)])
+def test_compact_segments_gathers_only_payloads(with_sq, frozen_count):
+  """The structural counter of ISSUE 28: the traced compaction holds one
+  gather for the payload in sorted order and one per running sum for
+  ``hi``.  Whoever writes ``x[order]`` after an ``argsort`` here again
+  (20 ms a step for an int32 stream of 2.9 M on v5e) fails this."""
+  from distributed_embeddings_tpu.parallel.sparse import compact_segments
+  ids, g = _dedup_stream(0, 512, 16, 40, sentinels=9)
+  trace = lambda f: jax.make_jaxpr(
+      lambda i, x: f(i, x, 42, 40, with_sq=with_sq))(ids, g)
+  assert _gathers(trace(_frozen_compact_segments).jaxpr)[0] == frozen_count
+  assert _gathers(trace(compact_segments).jaxpr)[0] == 2 + with_sq
+  # ``hi`` and ``lo`` are windows of one gather that both start past its
+  # row 0: a window from row 0 is a bitcast of the buffer, and beside it
+  # the v5e compiler wrote ``hi - lo`` over ``hi`` while still reading
+  # it one slot behind (PERF.md, PR 28)
+  starts = re.findall(r'f32\[\d+,16\] = slice\[[^\]]*start_indices=\((\d+), 0\)',
+                      str(trace(compact_segments)))
+  assert sorted(starts) == sorted(['1', '2'] * (1 + with_sq))
+  text = jax.jit(lambda i, x: compact_segments(
+      i, x, 42, 40, with_sq=with_sq)).lower(ids, g).as_text()
+  assert text.count('"stablehlo.gather"(') == 2 + with_sq
+
+
+def test_lane_pack_gathers_through_no_iota():
+  """``_lane_pack``'s stream arrives sorted: it enters the compaction
+  past the sort, and nothing is gathered through ``arange`` (the frozen
+  formulation did, twice, and once more for ``lo``)."""
+  from distributed_embeddings_tpu.parallel.sparse import _lane_pack
+  uids = jnp.arange(300, dtype=jnp.int32)
+  g = jnp.ones((300, 16), jnp.float32)
+  trace = lambda f: _gathers(jax.make_jaxpr(
+      lambda u, x: f(u, x, x, 8, 512))(uids, g).jaxpr)[:2]
+  assert trace(_frozen_lane_pack) == (7, 2)
+  assert trace(_lane_pack) == (1, 0)
 
 
 def _while_as_cond(cond_fun, body_fun, init):

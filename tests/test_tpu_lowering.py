@@ -63,6 +63,31 @@ def _compile_single(v5e_topo, fn, *shapes_dtypes):
   assert compiled is not None
 
 
+def _written_over_two_views(hlo):
+  """Instructions of a compiled v5e program whose result shares its
+  buffer with two or more of their operands (``aliasing_operands``: one
+  list holding the result's index, which is the operand count, beside
+  two operand indices).  Two operands in one buffer are two views of one
+  array, a slice taken as a bitcast beside the array itself; a result
+  written over them in place reads what it already wrote wherever the
+  views are offset.  ISSUE 28 met it in tiny-train-uniform's step:
+  ``fusion(hi, bitcast(hi)[:-1], valid)`` with ``[0, 1, 3]``, wrong rows
+  at every window's edge; ``sparse._compact_sorted`` now reads both
+  views as windows past row 0 of one gather, which no bitcast serves."""
+  import re
+  found = []
+  for line in hlo.splitlines():
+    m = re.search(r'= \S+ [\w-]+\((.*?)\), .*"aliasing_operands":(.*)', line)
+    if not m:
+      continue
+    result = str(m.group(1).count('%'))
+    for group in re.findall(r'"indices":\[([^\]]*)\]', m.group(2)):
+      indices = re.findall(r'\d+', group)
+      if result in indices and len(indices) > 2:
+        found.append(line.strip()[:200])
+  return found
+
+
 @pytest.mark.parametrize('op', ['sgd', 'adagrad_dedup', 'adagrad_sq'])
 @pytest.mark.parametrize('w', [8, 16, 32, 64, 128])
 @pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16])
@@ -237,6 +262,7 @@ def test_overflow_correction_copies_no_shard_for_v5e(v5e, rows, width,
                                                  labels).compile().as_text()
   # the correction is in the program, as a loop
   assert ' while(' in hlo and ' conditional(' not in hlo
+  assert not _written_over_two_views(hlo)
   shards = {f'f32[{g.param_rows},{g.param_width}]'
             for g in dist.plan.groups}
   copies = [m.group(0) for m in re.finditer(
