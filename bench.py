@@ -298,12 +298,6 @@ def main():
                       help='opt into the fused segment-walk apply '
                       '(ops/pallas_segwalk.py): sorted raw stream in, '
                       'no compaction pipeline')
-  parser.add_argument('--sparsecore_apply', action='store_true',
-                      help='opt into the SparseCore grad+optimizer '
-                      'apply (parallel/sparsecore.py): the update '
-                      'stream executes through the static-CSR buffers '
-                      '— real custom call on SC hardware, executable '
-                      'emulation elsewhere (docs/design.md §8)')
   parser.add_argument('--stream_dtype', default='float32',
                       choices=['float32', 'bfloat16'],
                       help='segwalk update-stream payload dtype '
@@ -674,7 +668,7 @@ def main():
                          compute_dtype=compute_dtype,
                          packed_storage=args.packed_storage,
                          lookup_impl=args.lookup_impl)
-  if args.lookup_impl == 'sparsecore' or args.sparsecore_apply:
+  if args.lookup_impl == 'sparsecore':
     # Resolve the SC backend BEFORE any compile or measurement work: on
     # a TPU without jax-tpu-embedding this raises the §8 contract error
     # immediately (a labelled failure artifact), instead of burning the
@@ -747,7 +741,6 @@ def main():
                           capacity_fraction=args.capacity_fraction,
                           capacity_rows=capacity_rows,
                           use_segwalk_apply=args.segwalk_apply,
-                          use_sparsecore_apply=args.sparsecore_apply,
                           stream_dtype=args.stream_dtype,
                           accum_dtype=args.accum_dtype)
   if args.trainer == 'sparse':
@@ -1700,16 +1693,14 @@ def main():
     # a shape proxy, not the Criteo-1TB vocabularies.
     metric += (f' [throughput {args.batch_size / (step_ms / 1000) / 1e6:.3f}'
                f'M samples/s; reference DLRM 8xA100 TF32: 9.158M]')
-  if (args.segwalk_apply or args.sparsecore_apply) \
-      and args.trainer == 'sparse':
+  if args.segwalk_apply and args.trainer == 'sparse':
     # without this note an A/B run can silently measure the XLA
     # fallback and read as "kernel is no faster"
     from distributed_embeddings_tpu.utils.apply_eligibility import (
         eligibility_line)
     metric += ' [' + eligibility_line(
         model.dist_embedding, args.param_dtype,
-        args.segwalk_apply, accum_dtype=args.accum_dtype,
-        sparsecore_apply=args.sparsecore_apply) + ']'
+        args.segwalk_apply, accum_dtype=args.accum_dtype) + ']'
   if args.lookup_impl == 'sparsecore':
     # the resolved backend AND the engaged-group count must be on the
     # line: an emulation number must never read as SC hardware, and a

@@ -438,9 +438,9 @@ def acc_dtype_ok(table_dtype, accum_dtype) -> bool:
   """THE accumulator-dtype predicate: f32 always; bf16 only on bf16
   tables (a bf16 accumulator needs the pair-fetch granularity the bf16
   table establishes — Mosaic rejects single-sublane bf16 slices).
-  Single source shared by this module's validation, the dispatch gate
-  (``sparse._use_segwalk``) and both eligibility probes
-  (``utils/apply_eligibility.py``) so they can never drift."""
+  Single source shared by this module's validation and the one function
+  that picks the apply (``sparse.choose_apply``, which the eligibility
+  report asks too)."""
   adt = jnp.dtype(accum_dtype)
   return adt == jnp.dtype(jnp.float32) or (
       adt == jnp.dtype(jnp.bfloat16)

@@ -36,7 +36,8 @@ from __future__ import annotations
 import dataclasses
 import logging
 
-from typing import Callable, Dict, Optional, Sequence, Tuple
+from typing import (Callable, Dict, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -300,21 +301,10 @@ class SparseSGD:
   # pair are the binding temps at pod scale — docs/perf_notes.md);
   # gradients round to bf16 once before the f32 segment summation
   stream_dtype: str = 'float32'
-  # opt-in SparseCore grad+optimizer apply (parallel/sparsecore.py,
-  # docs/design.md §8): the update stream executes through the
-  # partition-sorted static-CSR buffers — the real
-  # tpu_sparse_dense_matmul_grad_with_sgd custom call on SC hardware,
-  # the executable XLA emulation elsewhere.  Dispatched per group
-  # exactly like use_segwalk_apply (natural-storage f32 groups up to
-  # SC_WIDTH_LIMIT; others keep the XLA/segwalk paths); takes
-  # precedence over use_segwalk_apply where both engage.
-  use_sparsecore_apply: bool = False
 
   needs_sq = False
   needs_touch = False
   supports_lane_packing = True
-  # capability tag for the SC grad custom calls (sparsecore.apply_supported)
-  sc_apply_kind = 'sgd'
 
   def init(self, dist: DistributedEmbedding, params) -> Dict:
     out = {f'group_{gi}': {} for gi in range(len(dist.plan.groups))}
@@ -399,16 +389,9 @@ class SparseAdagrad:
   stream_dtype: str = 'float32'
   # accumulator STORAGE dtype ('float32' | 'bfloat16'); see class docstring
   accum_dtype: str = 'float32'
-  # opt-in SparseCore grad+optimizer apply (see SparseSGD): emulates /
-  # binds tpu_sparse_dense_matmul_grad_with_adagrad per group; both
-  # dedup (reference) and per-occurrence-squares semantics ride the
-  # same CSR buffers (the squares are a second segment-sum payload)
-  use_sparsecore_apply: bool = False
 
   needs_touch = False
   supports_lane_packing = True
-  # capability tag for the SC grad custom calls (sparsecore.apply_supported)
-  sc_apply_kind = 'adagrad'
 
   @property
   def needs_sq(self):
@@ -689,7 +672,7 @@ class _QuantizedTableOptimizer:
   """Dequant -> f32 update -> requant adapter (docs/design.md §12).
 
   Wraps a row-wise optimizer so the audited compact/apply pipeline
-  (``_dedup_and_apply`` / ``_apply_unique_chunked`` / the correction
+  (``_dedup_and_apply`` / ``_apply_wave`` / the correction
   wave) runs unchanged against QUANTIZED tables: the "table" operand
   becomes the ``(payload, scale)`` pair, the update arithmetic runs
   through the inner optimizer's ``row_updates`` (ONE definition of the
@@ -811,10 +794,62 @@ def _capacity(optimizer, n: int, rows_cap: int,
   return min(cap_safe, max(8, -(-int(n * frac) // 8) * 8))
 
 
-def _apply_unique_chunked(optimizer, table, state, uids, sum_g, sum_sq,
-                          lr, n_chunks: int):
-  """Feed one compacted unique-row stream to ``apply_unique`` in
-  ``n_chunks`` static row chunks (docs/design.md §11).
+class _Stream(NamedTuple):
+  """One group's update stream, as the sparse apply's stages hand it on
+  (docs/design.md §26).  What tells a slice's own stream from one merged
+  across slices travels here, so that each kernel has one call site.
+
+  ids: ``[n]`` int32 rows; ``rows_cap`` and anything above it is padding
+    and dropped.
+  rows: ``[n, w]`` gradient rows, one a position — or, with ``index``,
+    the COMPACT ``[m, w]`` rows (one per (sample, bag)) the positions
+    point into.
+  rows_cap: static row count of the space ``ids`` index: the group's
+    fused shard, the owner's hier-local shard after a hierarchical
+    merge, resident plus fetched rows of a cold-tier group.
+  index: ``[n]`` int32 position -> row of ``rows`` (the
+    ``compact_segments`` contract): a multi-hot bag's one cotangent row
+    is never broadcast, in the main wave or in the overflow
+    correction's loop body (whose temps count toward peak HBM even
+    untaken).  None: ``rows`` holds one row a position.  Not together
+    with ``squares`` (that stream is already compact).
+  squares: ``[n, w]`` squared-gradient rows that arrive SUMMED (each
+    slice pre-compacts before the cross-slice merge, a hot-cache
+    backward per source device; squares of those sums would be wrong,
+    so the squares travel as their own additive channel).  None: the
+    apply squares ``rows`` itself where the optimizer needs squares.
+  max_seg: static bound on how often one row occurs (the slice count,
+    after the merge: each row at most once a slice).  Totals then fold
+    exactly, which keeps them layout-independent (flat-vs-hier
+    bit-parity, design §20; ``compact_segments``).  None: no bound.
+  """
+  ids: jax.Array
+  rows: jax.Array
+  rows_cap: int
+  index: Optional[jax.Array] = None
+  squares: Optional[jax.Array] = None
+  max_seg: Optional[int] = None
+
+
+def _viewed(table, state, shape):
+  """``table`` and every state leaf of its shape as ``shape``, a
+  row-major regrouping of the same bytes (Adam's per-row step counter
+  ``t`` has another shape and stays)."""
+  stored = table.shape
+  return table.reshape(shape), {
+      k: (v.reshape(shape) if v.shape == stored else v)
+      for k, v in state.items()}
+
+
+def _apply_wave(optimizer, table, state, uids, sum_g, sum_sq, lr,
+                pack: int, rows_cap: int, exact: bool, n_chunks: int = 1):
+  """One wave of ``_dedup_and_apply``: the compacted unique rows,
+  lane-packed where ``pack`` rows of the table share a row of the
+  operand (``choose_apply``), through ``apply_unique`` in ``n_chunks``
+  static row chunks (docs/design.md §11).  Both waves come through
+  here, so the correction lane-packs whenever the main wave does: its
+  ``uids`` are ascending with the sentinels last like the main wave's,
+  which is all ``_lane_pack``'s sorted-pids shortcut needs.
 
   The compacted rows are UNIQUE, so the chunk applies touch disjoint
   table/state rows and threading the table through them is bit-exact vs
@@ -824,6 +859,9 @@ def _apply_unique_chunked(optimizer, table, state, uids, sum_g, sum_sq,
   buffer is rank-ordered (ascending ids, sentinels last), so the tail
   chunks carry only dropped sentinel rows and every chunk keeps the
   sorted-indices scatter hint."""
+  if pack > 1:
+    uids, sum_g, sum_sq = _lane_pack(uids, sum_g, sum_sq, pack, rows_cap,
+                                     exact=exact)
   k = effective_chunks(n_chunks, uids.shape[0])
   if k == 1:
     return optimizer.apply_unique(table, state, uids, sum_g, sum_sq, lr)
@@ -834,32 +872,24 @@ def _apply_unique_chunked(optimizer, table, state, uids, sum_g, sum_sq,
   return table, state
 
 
-def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
-                     rows_cap: int, cap_rows: Optional[int] = None,
-                     flat_sq=None, storage_pack: int = 1, g_index=None,
-                     n_chunks: int = 1, max_seg: Optional[int] = None):
+def _dedup_and_apply(optimizer, table, state, stream: _Stream, lr,
+                     cap_rows: Optional[int] = None, storage_pack: int = 1,
+                     n_chunks: int = 1):
   """Compact duplicate update rows, then run the optimizer on the unique
-  rows only.
+  rows only.  ``stream``: see ``_Stream``.
 
   ``storage_pack > 1``: ``table`` (and elementwise state leaves) arrive
   in the group's PHYSICAL packed layout ``[rows_cap/pack, 128]``
   (``GroupSpec.storage_pack``); updates are lane-packed against the
   operand itself and the results return in the same layout — no reshape
   of the parameter ever exists in the step, so the lane-padded relayout
-  (``packed_dispatch_ok``) cannot occur at any group size.
-
-  ``flat_sq``: optional pre-accumulated per-occurrence squared-gradient
-  rows aligned with ``flat_g`` (the cross-slice gather pre-compacts per
-  slice; squares of per-slice SUMS would be wrong, so the squares travel
-  as their own additive channel).  When absent, squares are computed
-  from the raw stream as usual.
-
-  ``g_index``: optional ``[n]`` position->row map into COMPACT
-  ``flat_g`` (``[m, w]``; the ``compact_segments`` contract) — the
-  multi-hot broadcast never materialises, in the main wave or the
-  overflow correction's loop body (whose temps count toward peak
-  HBM even untaken).  Mutually exclusive with ``flat_sq`` (that path's
-  stream is already per-occurrence-compacted by the DCN exchange).
+  (``packed_dispatch_ok``) cannot occur at any group size.  The one
+  exception is an optimizer without lane-wise apply semantics
+  (SparseAdam's per-row step counter): its operand is viewed natural
+  around both waves.  That reshape CAN provoke the relayout on huge
+  narrow groups — the documented cost of pairing Adam with
+  packed_storage; disable packed_storage on the layer to avoid it.
+  Which view serves is ``choose_apply``'s answer.
 
   Scatter cost is linear in the STATIC update-row count (~110-140 ns/row
   on v5e whether or not rows are dropped — docs/perf_notes.md), so the
@@ -882,7 +912,7 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
 
   ``n_chunks > 1`` (``DistributedEmbedding(overlap_chunks=)``,
   docs/design.md §11): the compacted unique-row stream feeds
-  ``apply_unique`` in static row chunks (``_apply_unique_chunked``) —
+  ``apply_unique`` in static row chunks (``_apply_wave``) —
   bit-exact, because compacted rows are disjoint — so the apply's
   scatters pipeline against the chunked gradient exchange instead of
   forming one monolithic tail.  The correction wave stays monolithic
@@ -897,33 +927,20 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
   state)`` as its carried value — NOT a ``lax.cond``: no table or
   state leaf may pass an XLA conditional.  XLA gives each branch of a
   conditional its own operand, so the shard is copied whole once per
-  branch, on every step, whether or not the cap overflowed.  Compiled
-  for v5e:2x2 with the correction as ``lax.cond(num_unique > cap,
-  correction, identity, (t2, s2))`` (ISSUE 25)::
-
-      %copy.346 = f32[20025088,128] copy(%fusion.27)   # the scatter-add
-      %copy.347 = f32[20025088,128] copy(%copy.346)
-      %tuple.61 = (..., f32[20025088,128]) tuple(..., %copy.347)
-      %conditional.1 = (f32[1,20025088,128]) conditional(%bitcast.96,
-          %copy.346, %tuple.61), branch_computations={...},
-          op_name="jit(step)/shard_map/cond"
-
-  31.2 ms each, 47% of dlrm-train-4chip's step; four such copies (table
-  and accumulator, two each) in synthetic-tiny, which also pushed the
-  compiler into rematerialising both scatters (+1.32 GiB of temps).
-  A while loop is the construct XLA aliases by design — one buffer
-  through init, body and result — and compiles to ``%fusion.27 ->
-  %tuple -> %while -> get-tuple-element -> output`` with the
-  correction's own scatter writing the carried element in place.  (An
-  earlier formulation put the WHOLE apply inside a two-branch cond:
-  the same lesson, +4.5 GB of temps at synthetic-tiny scale.)
+  branch, on every step, whether or not the cap overflowed: 31.2 ms
+  each, 47% of dlrm-train-4chip's step (the compiled text and the
+  numbers of ISSUE 25: docs/design.md §26).  A while loop is the
+  construct XLA aliases by design — one buffer through init, body and
+  result — with the correction's own scatter writing the carried
+  element in place.
   ``analysis/graphlint``'s donation pass holds every train program to
   this (``donation/state-leaf-in-cond``), and
   ``tests/test_tpu_lowering.py`` holds the compiled step to no
   shard-shaped copy.
   """
+  flat_ids, flat_g, rows_cap, g_index, flat_sq, max_seg = stream
   if g_index is not None and flat_sq is not None:
-    raise ValueError('g_index and flat_sq are mutually exclusive (the '
+    raise ValueError('index and squares are mutually exclusive (the '
                      'pre-summed-squares stream is already compact)')
   n = flat_ids.shape[0]
   sentinel = rows_cap
@@ -931,39 +948,15 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
   cap = _capacity(optimizer, n, rows_cap, cap_rows)
   with_sq = bool(getattr(optimizer, 'needs_sq', True))
   w = flat_g.shape[1]
-  storage_packed = storage_pack > 1
-  if (storage_packed
-      and not getattr(optimizer, 'supports_lane_packing', False)):
-    # optimizer without lane-wise apply semantics (SparseAdam's per-row
-    # step counter): unpack to natural views, apply, repack.  The
-    # natural reshape CAN provoke the lane-padded relayout on huge
-    # narrow groups — the documented cost of pairing Adam with
-    # packed_storage; disable packed_storage on the layer to avoid it.
-    packed_shape = table.shape
+  _, view, pack, _ = choose_apply(optimizer, table, rows_cap, w,
+                                  storage_pack=storage_pack, cap=cap)
+  exact = max_seg is not None
+  stored = None
+  if view in ('packed_view', 'unpacked'):
+    # the operand is stored in another shape than the waves apply to
+    stored = table.shape
     with obs_trace.phase('apply/read_rows'):
-      tn = table.reshape(rows_cap, w)
-      sn = {k: (v.reshape(rows_cap, w) if v.shape == packed_shape else v)
-            for k, v in state.items()}
-    t2, s2 = _dedup_and_apply(optimizer, tn, sn, flat_ids, flat_g, lr,
-                              rows_cap, cap_rows=cap_rows, flat_sq=flat_sq,
-                              g_index=g_index, n_chunks=n_chunks,
-                              max_seg=max_seg)
-    with obs_trace.phase('apply/write_rows'):
-      return t2.reshape(packed_shape), {
-          k: (v.reshape(packed_shape) if v.shape == (rows_cap, w) else v)
-          for k, v in s2.items()
-      }
-  if storage_packed:
-    pack, packable = storage_pack, False
-  else:
-    # packed_view_ok folds in the lane-padded-layout HBM bound shared
-    # with the eligibility probe; the extra clauses here are
-    # runtime-only facts (optimizer support, compaction capacity
-    # headroom).
-    packable = (packed_view_ok(rows_cap, w)
-                and getattr(optimizer, 'supports_lane_packing', False))
-    pack = 128 // w if packable else 1
-    packable = packable and rows_cap // pack + 2 < cap
+      table, state = _viewed(table, state, (rows_cap // pack, pack * w))
 
   # squares that arrive pre-accumulated are segment-summed as an extra
   # payload column block instead of squaring the (pre-summed) grads
@@ -980,31 +973,8 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
       max_seg=max_seg)
   if sq_cols:
     sum_g, sum_sq = sum_g[:, :w], sum_g[:, w:]
-  if storage_packed:
-    # updates lane-pack against the physically packed operand directly
-    pids, g_p, sq_p = _lane_pack(uids, sum_g, sum_sq, pack, rows_cap,
-                                 exact=max_seg is not None)
-    t2, s2 = _apply_unique_chunked(optimizer, table, state, pids, g_p,
-                                   sq_p, lr, n_chunks)
-  elif packable:
-    pids, g_p, sq_p = _lane_pack(uids, sum_g, sum_sq, pack, rows_cap,
-                                 exact=max_seg is not None)
-    with obs_trace.phase('apply/read_rows'):
-      ptable = table.reshape(rows_cap // pack, pack * w)
-      pstate = {
-          k: v.reshape(rows_cap // pack, pack * w) for k, v in state.items()
-      }
-    t2, s2 = _apply_unique_chunked(optimizer, ptable, pstate, pids, g_p,
-                                   sq_p, lr, n_chunks)
-    with obs_trace.phase('apply/write_rows'):
-      t2 = t2.reshape(rows_cap, w)
-      s2 = {k: v.reshape(rows_cap, w) for k, v in s2.items()}
-  else:
-    t2, s2 = _apply_unique_chunked(optimizer, table, state, uids, sum_g,
-                                   sum_sq, lr, n_chunks)
-
-  if cap >= cap_safe:
-    return t2, s2
+  t2, s2 = _apply_wave(optimizer, table, state, uids, sum_g, sum_sq, lr,
+                       pack, rows_cap, exact, n_chunks)
 
   def correction(args):
     # apply the segments the cap dropped (ranks >= cap), compacted to
@@ -1016,7 +986,7 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
       # [n, w] buffer alive across the apply would count toward peak HBM
       sg = _sorted_payload(flat_g, order, g_index)
       is_first, is_last, first_pos_c, seg_total = _sorted_segments(sid)
-      if max_seg is not None:
+      if exact:
         # the bounded exact fold of the main wave (layout-independent
         # totals, design §20) — the correction must sum identically
         seg_total = lambda x: _seg_fold_bounded(x, first_pos_c, max_seg)
@@ -1033,19 +1003,17 @@ def _dedup_and_apply(optimizer, table, state, flat_ids, flat_g, lr,
         tot_sq = jnp.where(valid3[:, None], seg_total(sq_src)[order3], 0.0)
       else:
         tot_sq = None
-    if storage_packed:
-      # correction rows lane-pack too (uids2 is ascending-with-sentinels
-      # like the main wave's compacted buffer, so _lane_pack's
-      # sorted-pids shortcut holds)
-      pids2, g_p2, sq_p2 = _lane_pack(uids2, tot_g, tot_sq, pack, rows_cap,
-                                      exact=max_seg is not None)
-      return optimizer.apply_unique(t3, s3, pids2, g_p2, sq_p2, lr)
-    return optimizer.apply_unique(t3, s3, uids2, tot_g, tot_sq, lr)
+    return _apply_wave(optimizer, t3, s3, uids2, tot_g, tot_sq, lr, pack,
+                       rows_cap, exact)
 
-  (t2, s2), _ = jax.lax.while_loop(
-      lambda carry: carry[1],
-      lambda carry: (correction(carry[0]), False),
-      ((t2, s2), num_unique > cap))
+  if cap < cap_safe:
+    (t2, s2), _ = jax.lax.while_loop(
+        lambda carry: carry[1],
+        lambda carry: (correction(carry[0]), False),
+        ((t2, s2), num_unique > cap))
+  if stored is not None:
+    with obs_trace.phase('apply/write_rows'):
+      t2, s2 = _viewed(t2, s2, stored)
   return t2, s2
 
 
@@ -1075,125 +1043,138 @@ def packed_view_ok(rows_cap: int, width: int) -> bool:
   """Whether a NARROW group can engage the fused kernels through the
   lane-packed ``[rows_cap/pack, 128]`` view: width must divide 128,
   rows must divide by the pack factor, and the padded layout must fit
-  the HBM bound.  The single predicate shared by the runtime dispatch
-  (``_dedup_and_apply``) and the eligibility probe
-  (``utils/apply_eligibility.py``) so the two can never drift."""
+  the HBM bound (``choose_apply`` asks)."""
   return (width < 128 and 128 % width == 0
           and rows_cap % (128 // width) == 0
           and packed_dispatch_ok(rows_cap, width))
 
 
-def _use_sparsecore(optimizer, dist, table, storage_pack: int) -> bool:
-  """Whether the SparseCore grad+optimizer path serves this group's
-  apply — dispatched exactly like ``use_segwalk_apply``: the opt-in
-  flag plus the per-group support gate (natural-storage f32 groups up
-  to ``SC_WIDTH_LIMIT``; SGD/Adagrad RMW).  Resolving the layer's
-  backend may raise the docs/design.md §8 contract error: an explicit
-  ``use_sparsecore_apply=True`` on a TPU without jax-tpu-embedding is
-  an error, never a silent XLA substitute."""
-  if not getattr(optimizer, 'use_sparsecore_apply', False):
-    return False
-  from distributed_embeddings_tpu.parallel import sparsecore
-  if not sparsecore.apply_supported(optimizer, table, storage_pack):
-    return False
-  dist._resolve_sc_backend()
-  return True
+class ApplyChoice(NamedTuple):
+  """``choose_apply``'s answer for one group."""
+  kernel: str    # 'tied' | 'segwalk' | 'xla'
+  view: str      # the XLA apply's view of the operand (``choose_apply``)
+  pack: int      # table rows in one row of the operand the waves update
+  declined: str  # why a segment-walk kernel that was asked for does not
+  #                serve the group; '' where it serves or was not asked for
 
 
-def _sc_apply(optimizer, dist, table, state, flat_ids, flat_g, lr,
-              g_index=None):
-  """Route one group's apply through the SparseCore path: the real
-  fused grad custom call when the layer resolved to it, else the
-  executable emulation (``sparsecore.sc_grad_apply``)."""
-  from distributed_embeddings_tpu.parallel import sparsecore
-  num_sc = getattr(dist.plan, 'num_sc', 4)
-  if dist._resolve_sc_backend() == 'custom_call':
-    n = flat_ids.shape[0]
-    with obs_trace.phase('apply/dedup'):
-      csr = sparsecore.csr_from_routed(flat_ids.reshape(1, n, 1),
-                                       table.shape[0], num_sc, 'sum')
-    # one custom call sums, reads, updates and writes
-    with obs_trace.phase('apply/update'):
-      return sparsecore.custom_call_grad_apply(optimizer, table, state,
-                                               csr, flat_g, lr, num_sc,
-                                               g_index=g_index)
-  # the emulation: CSR round trip as part of the dedup, then the
-  # compact_segments + apply_unique pair under their own phases
-  with obs_trace.phase('apply/dedup'):
-    return sparsecore.sc_grad_apply(optimizer, table, state, flat_ids,
-                                    flat_g, lr, num_sc, g_index=g_index)
+def choose_apply(optimizer, table, rows_cap: int, width: int, *,
+                 storage_pack: int = 1, cap: Optional[int] = None,
+                 tied: bool = False, adapted: bool = False,
+                 summed_squares: bool = False, group: Optional[str] = None,
+                 active: Optional[bool] = None) -> ApplyChoice:
+  """THE one place that says which apply serves a group: the group
+  loop's ``_apply_group``, ``_dedup_and_apply``'s choice of view and
+  ``utils/apply_eligibility``'s report all ask here.
 
+  ``table``: the operand's aval (shape and dtype; read only where the
+  kernel is asked for).  ``rows_cap`` x ``width``: the group's natural
+  rows.  ``cap``: the compaction capacity, where it is known
+  (``_dedup_and_apply``).  ``tied``: the head also reads a table of the
+  group.  ``adapted``: the operand is a quantized ``(payload, scale)``
+  pair or carries a fetched cold-tier tail.  ``summed_squares``: the
+  stream's squares arrive summed (``_Stream.squares``).
 
-def _use_segwalk(optimizer, table, group: str = '') -> bool:
-  """Whether the fused segment-walk kernel serves this group's apply.
+  kernel: ``'tied'`` (``_tied_apply``) where the head reads the group;
+  else ``'segwalk'`` (``_segwalk_apply``) where ``use_segwalk_apply``
+  asks for the kernel, it runs here (``active``; None: on a TPU, or
+  where a test or an AOT compile stands in for one) and nothing below
+  declines; else ``'xla'`` (``_dedup_and_apply``).  The kernel is
+  opt-in, so a request it cannot serve must not pass in silence: for the
+  dispatch (``group`` given) on a TPU, where the request is meant, each
+  group's outcome is logged once per trace — INFO when the kernel
+  serves it, WARNING with the reason when the group takes the XLA apply
+  instead.
 
-  The kernel is opt-in, so a request it cannot serve must not pass in
-  silence: on a TPU (where the request is meant), each group's outcome
-  is logged once per trace — INFO when the kernel serves it, WARNING
-  with the reason when the group takes the XLA apply instead.
-  ``utils/apply_eligibility.eligibility_line`` gives the same answer for
-  a whole layer before anything is traced."""
+  view, pack — how the XLA apply sees the operand: ``'stored_packed'``,
+  lane-packed updates against the physically packed operand itself;
+  ``'unpacked'``, that operand viewed natural for an optimizer with no
+  lane-wise apply (SparseAdam); ``'packed_view'``, a natural narrow
+  operand viewed ``[rows_cap/pack, 128]`` where that shrinks the
+  scatters below the compaction capacity (``packed_view_ok`` folds in
+  the lane-padded-layout HBM bound); ``'natural'`` otherwise.
+  """
+  lane_ok = getattr(optimizer, 'supports_lane_packing', False)
+  if storage_pack > 1:
+    view, pack = (('stored_packed', storage_pack) if lane_ok
+                  else ('unpacked', 1))
+  elif (lane_ok and packed_view_ok(rows_cap, width)
+        and (cap is None or rows_cap // (128 // width) + 2 < cap)):
+    view, pack = 'packed_view', 128 // width
+  else:
+    view, pack = 'natural', 1
+  if tied:
+    return ApplyChoice('tied', view, pack, '')
   if not getattr(optimizer, 'use_segwalk_apply', False):
-    return False
+    return ApplyChoice('xla', view, pack, '')
   from distributed_embeddings_tpu.ops import pallas_segwalk
   accum = getattr(optimizer, 'accum_dtype', 'float32')
-  declined = None
-  if not pallas_segwalk.acc_dtype_ok(table.dtype, accum):
-    # bf16 accumulators ride the bf16 table's pair-fetch path ONLY;
-    # other combinations take XLA (single-source predicate)
+  on_tpu = jax.default_backend() == 'tpu'
+  if active is None:
+    active = (on_tpu or pallas_segwalk.FORCE_INTERPRET
+              or pallas_segwalk.ASSUME_TPU)
+  declined = ''
+  if adapted:
+    # the kernel's table contract is f32 rows in one array
+    declined = 'a quantized or cold-tier operand takes the XLA adapter'
+  elif summed_squares:
+    # multi-slice per-occurrence Adagrad, hot-cache streams
+    declined = 'the stream carries squares the kernel cannot consume'
+  elif not pallas_segwalk.acc_dtype_ok(table.dtype, accum):
+    # bf16 accumulators ride the bf16 table's pair-fetch path ONLY
     declined = f'{accum} accumulators on a {table.dtype} table'
   elif not pallas_segwalk.supported(table):
     declined = f'table {table.shape} {table.dtype} is not a kernel shape'
   elif not packed_dispatch_ok(table.shape[0], table.shape[1]):
     declined = ('the lane-padded relayout of this narrow group would '
                 f'exceed {PACKED_PARAM_BYTES_LIMIT >> 30} GiB')
-  if jax.default_backend() == 'tpu':
+  elif not active:
+    declined = f'the kernel does not run on {jax.default_backend()}'
+  if group is not None and on_tpu:
     if declined:
       _LOG.warning('use_segwalk_apply: %s takes the XLA apply (%s)',
                    group, declined)
     else:
       _LOG.info('use_segwalk_apply: %s takes the segment-walk kernel',
                 group)
-    return not declined
-  return not declined and (pallas_segwalk.FORCE_INTERPRET
-                           or pallas_segwalk.ASSUME_TPU)
+  return ApplyChoice('xla' if declined else 'segwalk', view, pack, declined)
 
 
 @obs_trace.phase('apply/dedup')
-def _segwalk_apply(optimizer, table, state, flat_ids, flat_g, lr,
-                   storage_pack: int = 1, g_index=None):
-  """Sort the raw stream and hand it to the fused segment-walk kernel
+def _segwalk_apply(optimizer, table, state, stream: _Stream, lr,
+                   storage_pack: int = 1):
+  """Hand the raw stream to the fused segment-walk kernel
   (ops/pallas_segwalk.py) — no compaction, no capacity, no correction
   wave: every segment is applied exactly once.  (Phases: the wrapper's
   sort and operand assembly are this path's ``apply/dedup``; the kernel,
   which sums, reads, updates and writes in one pass, opens
   ``apply/update`` inside.)  ``storage_pack > 1``:
   the table arrives (and returns) in the physical packed layout; the
-  kernel runs its packed path on the operand itself.  ``g_index``:
-  ``flat_g`` holds COMPACT per-(sample, bag) rows and ``g_index`` maps
-  each stream position to its row — the multi-hot broadcast never
-  materialises (pallas_segwalk.segwalk_apply docstring)."""
+  kernel runs its packed path on the operand itself.  A stream with an
+  ``index`` goes in as it is: the kernel's one ``[n, 128]`` operand
+  gathers straight from the compact rows and the multi-hot broadcast
+  never materialises (pallas_segwalk.segwalk_apply docstring)."""
   from distributed_embeddings_tpu.ops import pallas_segwalk
   interp = pallas_segwalk.FORCE_INTERPRET
-  lw = flat_g.shape[1] if storage_pack > 1 else None
+  lw = stream.rows.shape[1] if storage_pack > 1 else None
   # RAW stream in: the kernel wrapper sorts internally so the payload
   # gathers once, directly into its dense [n, 128] operand (sorting
   # here first would materialise an extra lane-padded narrow gather —
   # the multi-GiB [n, w<128] temps of the round-4 memory audit)
-  ids = flat_ids.astype(jnp.int32)
-  g = flat_g.astype(jnp.float32)
+  ids = stream.ids.astype(jnp.int32)
+  g = stream.rows.astype(jnp.float32)
   sdt = getattr(optimizer, 'stream_dtype', 'float32')
   if isinstance(optimizer, SparseSGD):
     t2 = pallas_segwalk.segwalk_apply(
         table, None, ids, g, lr, op='sgd', interpret=interp,
         logical_width=lw, presorted=False, stream_dtype=sdt,
-        g_index=g_index)
+        g_index=stream.index)
     return t2, state
   op = 'adagrad_dedup' if optimizer.dedup else 'adagrad_sq'
   t2, a2 = pallas_segwalk.segwalk_apply(
       table, state['acc'], ids, g, lr, op=op, eps=optimizer.epsilon,
       interpret=interp, logical_width=lw, presorted=False,
-      stream_dtype=sdt, g_index=g_index)
+      stream_dtype=sdt, g_index=stream.index)
   return t2, {'acc': a2}
 
 
@@ -1237,8 +1218,7 @@ def _tied_layout(dist: DistributedEmbedding, table_ids, optimizer):
   return out
 
 
-def _tied_apply(optimizer, table, state, flat_ids, g_rows, g_index, lr,
-                rows_cap: int, head_grads):
+def _tied_apply(optimizer, table, state, stream: _Stream, lr, head_grads):
   """One update of a group that holds a table the head also multiplies
   by (a tied vocabulary, docs/design.md §25).  The lookups' occurrence
   rows are segment-summed as ever (``compact_segments`` at the
@@ -1251,9 +1231,11 @@ def _tied_apply(optimizer, table, state, flat_ids, g_rows, g_index, lr,
   ``head_grads``: ``[(first row, rows, gradient [rows, width], mine)]``,
   ``mine`` a traced flag: whether this device owns the table."""
   needs_sq = bool(getattr(optimizer, 'needs_sq', True))
+  rows_cap = stream.rows_cap
   uids, sum_g, sum_sq, _ = compact_segments(
-      flat_ids, g_rows, _guaranteed_cap(flat_ids.shape[0], rows_cap),
-      rows_cap, with_sq=needs_sq, g_index=g_index)
+      stream.ids, stream.rows,
+      _guaranteed_cap(stream.ids.shape[0], rows_cap), rows_cap,
+      with_sq=needs_sq, g_index=stream.index)
   with obs_trace.phase('apply/tied'):
     hints = dict(mode='drop', unique_indices=True, indices_are_sorted=True)
     ids = _distinct_oob(uids, rows_cap)
@@ -1272,10 +1254,322 @@ def _tied_apply(optimizer, table, state, flat_ids, g_rows, g_index, lr,
                                count=asked)
 
 
+# --------------------------------------------------------------------------
+# The group loop's stages (docs/design.md §26), in the order they run:
+# stream -> merge across slices -> operand -> apply -> (after the loop)
+# hot groups.  A ``_Stream`` passes between them.
+# --------------------------------------------------------------------------
+
+
+def _group_stream(slots, residuals, gs, rows_cap: int, fence):
+  """Stage 1: one group's slots -> its update stream, under the caller's
+  ``apply/stream``.
+
+  ``slots``: ``[(si, divide)]``, the indices of the group's slots into
+  ``residuals`` (``[1, n_cap, GB, h]`` routed ids, padding at
+  ``rows_cap``) and ``gs`` (``[1, n_cap, GB, wc]`` cotangent rows, one a
+  bag), and whether a 'mean' bag is still to be divided by its id count
+  in this shard's window.  Not where the cotangent arrives divided: a
+  ``mean_row_sliced`` slot's was divided by the TRUE per-sample count
+  (make_hybrid_train_step; the shard-local count here would be the
+  window count), a hot-cache stream's by the backward.  Hot-cache
+  streams are already per-(source, slot) deduplicated h=1 rows and, for
+  per-occurrence-squares optimizers, carry the squared channel as
+  trailing columns (``wc = 2w``) — segment-summed additively, never
+  re-squared.
+
+  Returns ``(stream, fence)``: ``fence`` is the serialisation token the
+  group loop threads through the applies.
+  """
+  # Multi-hot bags broadcast ONE cotangent row to every occurrence.
+  # When duplication is real (n >= 2m), keep the compact
+  # [n_cap*GB, w] rows plus an [n] position->row index instead of
+  # materialising the h-fold broadcast (the 12.6 GiB-class stream
+  # temps of the jumbo memory audit); the segwalk path consumes the
+  # indirection natively, the XLA paths gather it back in their sort.
+  # Below 2x duplication the indirection LOSES: the compact rows
+  # are a materialised array (the lazy broadcast fuses into its
+  # consumer) and w<128 rows store T(8,128) lane-padded — at m ~ n
+  # that re-buys the round-4 padding blowup (+3.3 GiB measured on
+  # medium@32) — so those groups keep the fused broadcast.
+  shapes = [residuals[si].shape[1:] for si, _ in slots]
+  n_total = sum(n_cap * gb * h for n_cap, gb, h in shapes)
+  m_total = sum(n_cap * gb for n_cap, gb, _ in shapes)
+  use_idx = n_total >= 2 * m_total
+  ids_list, grad_list, gidx_list = [], [], []
+  row_off = 0
+  for si, divide in slots:
+    ids = residuals[si][0]            # [n_cap, GB, h]
+    gg = gs[si][0].astype(jnp.float32)  # [n_cap, GB, wc]
+    if divide:
+      cnt = jnp.sum(ids < rows_cap, axis=2).astype(jnp.float32)
+      gg = gg / jnp.maximum(cnt, 1.0)[..., None]
+    n_cap, gb, h = ids.shape
+    wc = gg.shape[-1]
+    ids_list.append(ids.reshape(-1))
+    if use_idx:
+      grad_list.append(gg.reshape(-1, wc))
+      gidx_list.append(
+          row_off + jnp.repeat(
+              jnp.arange(n_cap * gb, dtype=jnp.int32), h))
+      row_off += n_cap * gb
+    else:
+      pos_g = jnp.broadcast_to(gg[:, :, None, :], ids.shape + (wc,))
+      grad_list.append(pos_g.reshape(-1, wc))
+  cat = lambda xs: jnp.concatenate(xs) if len(xs) > 1 else xs[0]
+  flat_ids, g_rows = cat(ids_list), cat(grad_list)
+  g_idx = cat(gidx_list) if use_idx else None
+  # serialise the per-group applies: without a data dependency XLA may
+  # schedule every group's sort/gather/scatter pipeline concurrently,
+  # keeping all their multi-hundred-MB compaction temporaries live at
+  # once — on a chip already holding params + accumulator that tips
+  # peak HBM over the edge (docs/perf_notes.md, train-step section).
+  # Only the IDS pass the barrier: everything downstream (sort,
+  # gathers, applies) depends on them, which orders the pipelines,
+  # while the gradient stream stays fusible into its consumer (a
+  # barriered flat_g materialises as a full lane-padded narrow temp
+  # — 2 GiB at synthetic-small scale, round-4 memory audit)
+  (flat_ids, fence) = jax.lax.optimization_barrier((flat_ids, fence))
+  return _Stream(flat_ids, g_rows, rows_cap, g_idx), fence
+
+
+def _merge_slices(stream: _Stream, width: int, needs_sq: bool,
+                  sq_in_rows: bool, dcn_axis: str, num_slices: int,
+                  hier_group=None, axis_name: Optional[str] = None):
+  """Stage 2, where the mesh has more than one slice: the cross-slice
+  update exchange — the DP-gradient step for the slice-REPLICATED table
+  shards (each slice computed updates from its own sub-batch; every
+  replica must apply them all, identically).  Streams pre-compact to
+  unique rows per slice, bounding the DCN gather to the fused table's
+  row count instead of the raw batch*hotness stream;
+  per-occurrence-squares optimizers (``needs_sq``) ship the squares as
+  their own additive channel (squares of pre-summed rows would be
+  wrong).  After the gather every slice holds the identical combined
+  stream, so the applies (and replicas) stay in sync.
+
+  ``sq_in_rows``: a hot-cache stream, whose rows carry the squares as
+  trailing payload columns — they segment-sum additively with the grads
+  and split at the same column offsets after the gather.
+  ``hier_group``: the group's hierarchical (dcn x ici) layout (design
+  §20) where tables shard over the axis PRODUCT: the cross-slice leg is
+  then an all_to_all of per-owner hier-row streams instead of the
+  replicated all_gather — each deduplicated row's update crosses DCN
+  once, to its one owner (slice, device) cell, and only that cell
+  applies it, in the OWNER's hier-local row space (``[rows_cap_h, w]``
+  shards, sentinel ``rows_cap_h``); the pre-compaction stays in flat
+  fused space, exactly like the flat path.
+
+  Returns the merged stream: each row at most once a slice
+  (``max_seg``), squares (if any) summed.
+  """
+  rows_cap = stream.rows_cap
+  # Pre-compaction capacity must be the GUARANTEED bound
+  # (uniques + sentinel <= rows_cap + 2): a fraction/calibrated
+  # cap could silently drop segments here, where no correction
+  # wave runs (the wave guards only the post-gather apply).
+  pcap = _guaranteed_cap(stream.ids.shape[0], rows_cap)
+  ship_sq = needs_sq and not sq_in_rows
+  uids_s, sum_g_s, sum_sq_s, _ = compact_segments(
+      stream.ids, stream.rows, pcap, rows_cap, with_sq=ship_sq,
+      g_index=stream.index)
+  if hier_group is not None:
+    # Hierarchical update exchange (design §20): each compacted
+    # row maps through the static interval tables to its owner
+    # (slice, hier row); ONE DCN all_to_all per group ships every
+    # per-slice sum to its owner cell (same inner device index —
+    # pure cross-slice traffic), with non-owned positions at the
+    # hier sentinel so the apply drops them.  The receiver
+    # flattens slice-major, reproducing the flat all_gather's
+    # position order — so per-row segment sums add in the same
+    # sequence and the applied updates stay bit-exact vs flat.
+    hl = hier_group
+    S = num_slices
+    cap_h = hl.rows_cap_h
+    me_d = jax.lax.axis_index(axis_name)
+    cut_lo = jnp.asarray(hl.cut_lo)[me_d]
+    cut_sl = jnp.asarray(hl.cut_slice)[me_d]
+    cut_h = jnp.asarray(hl.cut_hier)[me_d]
+    valid = (uids_s >= 0) & (uids_s < rows_cap)
+    safe = jnp.clip(uids_s, 0, rows_cap - 1)
+    k2 = jnp.clip(
+        jnp.searchsorted(cut_lo, safe, side='right') - 1,
+        0, cut_lo.shape[0] - 1)
+    owner = cut_sl[k2]
+    hrow = safe - cut_lo[k2] + cut_h[k2]
+    dest = jax.lax.broadcasted_iota(jnp.int32,
+                                    (S,) + uids_s.shape, 0)
+    hids = jnp.where(valid[None] & (owner[None] == dest),
+                     hrow[None], cap_h).astype(jnp.int32)
+    packed = [
+        jax.lax.bitcast_convert_type(hids, jnp.float32)[..., None],
+        jnp.broadcast_to(sum_g_s[None], (S,) + sum_g_s.shape)
+    ]
+    if ship_sq:
+      packed.append(
+          jnp.broadcast_to(sum_sq_s[None], (S,) + sum_sq_s.shape))
+    gathered = jax.lax.all_to_all(
+        jnp.concatenate(packed, axis=2), dcn_axis, 0, 0)
+    gathered = gathered.reshape(-1, gathered.shape[2])
+    rows_cap = cap_h
+  else:
+    # ONE DCN collective per group: ids ride as a bitcast f32
+    # column alongside the grad (and square) payload
+    packed = [
+        jax.lax.bitcast_convert_type(uids_s, jnp.float32)[:, None],
+        sum_g_s
+    ]
+    if ship_sq:
+      packed.append(sum_sq_s)
+    gathered = jax.lax.all_gather(jnp.concatenate(packed, axis=1),
+                                  dcn_axis, axis=0, tiled=True)
+  return _Stream(
+      jax.lax.bitcast_convert_type(gathered[:, 0], jnp.int32),
+      gathered[:, 1:1 + width], rows_cap,
+      squares=gathered[:, 1 + width:] if needs_sq else None,
+      max_seg=num_slices)
+
+
+def _split_square_columns(stream: _Stream, width: int) -> _Stream:
+  """Stage 2 on one slice, for a hot-cache stream that carries squares:
+  the additive squared-grad channel leaves the payload columns.  (No
+  ``max_seg``: a row may come from every source device.)"""
+  return stream._replace(rows=stream.rows[:, :width],
+                         squares=stream.rows[:, width:])
+
+
+def _group_operand(table, scale, state, stream: _Stream, fetch_g,
+                   resident: int):
+  """Stage 3, before the apply, for a quantized and/or cold-tier group
+  (design §12): the table operand becomes the ``(payload, scale)`` pair
+  (``scale`` None: not quantized) that ``_QuantizedTableOptimizer``
+  updates, requantizing exactly the touched rows with a refreshed
+  scale; a cold-tier group (``fetch_g``: its part of the batch's
+  fetch, else None) concatenates the fetched tail rows of payload,
+  scale and optimizer state behind the ``resident`` rows and remaps the
+  stream's ids into that space, so that the SAME compact/apply runs.
+  Returns ``(operand, state, stream)``."""
+  if fetch_g is not None:
+    with obs_trace.phase('apply/stream'):
+      frows = fetch_g['rows'][0]
+      cap_f = frows.shape[0]
+      # remap tail ids into the concatenated [resident + cap_f] space:
+      # resident ids pass through, fetched tail ids land at
+      # resident + fetch position, everything else (sentinel; a tail id
+      # the pre-pass missed, impossible by contract) drops at the
+      # new sentinel resident + cap_f
+      flat_ids = stream.ids
+      pos = jnp.searchsorted(frows, flat_ids).astype(jnp.int32)
+      safe_pos = jnp.minimum(pos, cap_f - 1)
+      hit = ((flat_ids >= resident) & (flat_ids < stream.rows_cap)
+             & (frows[safe_pos] == flat_ids))
+      stream = stream._replace(
+          ids=jnp.where(
+              flat_ids < resident, flat_ids,
+              jnp.where(hit, resident + safe_pos, resident + cap_f)),
+          rows_cap=resident + cap_f)
+    with obs_trace.phase('apply/read_rows'):
+      table = jnp.concatenate([table, fetch_g['payload'][0]])
+      if scale is not None:
+        scale = jnp.concatenate([scale, fetch_g['scale'][0]])
+      state = {
+          k: jnp.concatenate([v, fetch_g['opt'][k][0]])
+          for k, v in state.items()
+      }
+  return (table if scale is None else (table, scale)), state, stream
+
+
+def _tier_writeback(table, scale, state, resident: int):
+  """Stage 3, after the apply of a cold-tier group: the updated fetched
+  rows leave as the group's WRITEBACK (the host stores them into the
+  tier) and the operand is the ``resident`` rows again.  Returns
+  ``(table, scale, state, writeback)``."""
+  with obs_trace.phase('apply/write_rows'):
+    wb = {'payload': table[resident:][None]}
+    if scale is not None:
+      wb['scale'] = scale[resident:][None]
+    wb['opt'] = {k: v[resident:][None] for k, v in state.items()}
+    table = table[:resident]
+    if scale is not None:
+      scale = scale[:resident]
+    state = {k: v[:resident] for k, v in state.items()}
+  return table, scale, state, wb
+
+
+def _apply_group(optimizer, table, state, stream: _Stream, lr, group: str,
+                 storage_pack: int = 1, cap_rows: Optional[int] = None,
+                 n_chunks: int = 1, head_grads=(), adapted: bool = False):
+  """Stage 4: one group's update, by the apply ``choose_apply`` names.
+  Each kernel is called here and nowhere else in the group loop.  The
+  chunked gradient-apply (``n_chunks``, design §11) is the XLA apply's:
+  the segwalk kernel is a single-pass streaming apply and consumes the
+  full stream, the tied apply is one dense step."""
+  choice = choose_apply(
+      optimizer, table, stream.rows_cap, stream.rows.shape[1],
+      storage_pack=storage_pack, tied=bool(head_grads), adapted=adapted,
+      summed_squares=stream.squares is not None, group=group)
+  if choice.kernel == 'tied':
+    return _tied_apply(optimizer, table, state, stream, lr, head_grads)
+  if choice.kernel == 'segwalk':
+    return _segwalk_apply(optimizer, table, state, stream, lr,
+                          storage_pack=storage_pack)
+  return _dedup_and_apply(optimizer, table, state, stream, lr,
+                          cap_rows=cap_rows, storage_pack=storage_pack,
+                          n_chunks=n_chunks)
+
+
+def _apply_hot_group(optimizer, hot_op, state, hg, width: int, lr,
+                     needs_sq: bool, needs_touch: bool, n_chunks: int):
+  """Stage 5, after the group loop: ONE dense elementwise step on a hot
+  group's replicated buffer, on the mesh-psummed gradient sums — the
+  dense add that replaces K random-access scatter rows per hot id
+  (design §10).  The grads arrived replicated (the backward psums
+  them), so every replica applies identically and the buffers stay in
+  sync.  ``hot_op``: the ``[K, w]`` buffer, or its quantized
+  ``(payload, scale)`` pair.  ``hg``: ``[K, w (+ w) (+ 1)]``, the sums,
+  the squares where ``needs_sq``, the occurrence count where
+  ``needs_touch``."""
+  hg = hg.astype(jnp.float32)
+  sum_g = hg[:, :width]
+  sum_sq = hg[:, width:2 * width] if needs_sq else None
+  # trailing occurrence-count column (needs_touch optimizers:
+  # lazy Adam's dense touched-row mask, design §11)
+  cnt_off = 2 * width if needs_sq else width
+  count = hg[:, cnt_off:cnt_off + 1] if needs_touch else None
+  K = hg.shape[0]
+  kch = effective_chunks(n_chunks, K)
+  with obs_trace.phase('apply/update'):
+    if kch == 1:
+      return optimizer.apply_hot(hot_op, state, sum_g, sum_sq, lr,
+                                 count=count)
+    # chunked dense hot apply (design §11): apply_hot is
+    # elementwise per row, so row-range chunks are bit-exact — and
+    # chunk k's step can execute while chunk k+1's psummed
+    # gradient slice is still in flight (the backward psums the
+    # hot grads in the same row chunks).  Quantized buffers chunk
+    # identically: the per-row requant is row-local.
+    pieces, spieces = [], []
+    for lo, hi in chunk_bounds(K, kch):
+      hp, hs = optimizer.apply_hot(
+          jax.tree.map(lambda x: x[lo:hi], hot_op),
+          {kk: vv[lo:hi] for kk, vv in state.items()},
+          sum_g[lo:hi],
+          None if sum_sq is None else sum_sq[lo:hi], lr,
+          count=None if count is None else count[lo:hi])
+      pieces.append(hp)
+      spieces.append(hs)
+    hot_new = jax.tree.map(lambda *p: jnp.concatenate(p, axis=0), *pieces)
+    hstate = ({} if not spieces[0] else {
+        kk: jnp.concatenate([s[kk] for s in spieces], axis=0)
+        for kk in spieces[0]
+    })
+  return hot_new, hstate
+
+
 def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
                         global_batch: int, hotness: tuple,
                         fetch_caps: tuple = (), tied: tuple = ()):
-  """shard_map'd per-device sparse update over all fusion groups.
+  """shard_map'd per-device sparse update over all fusion groups: a loop
+  over the groups through the stages above, then the hot groups.
 
   ``tied``: ``((table id, (group, device, first row, rows)), ...)`` of
   the tables the head also reads (``_tied_layout``): the trailing args
@@ -1288,19 +1582,7 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
   args carry one replicated ``[hot_rows_cap, w]`` (``2w`` with
   per-occurrence squares) gradient buffer per hot group, applied as a
   DENSE elementwise optimizer step (``apply_hot``) with no scatter.
-
-  QUANTIZED plans (design §12) route every group through the
-  ``_QuantizedTableOptimizer`` adapter: the table operand is the
-  ``(payload, scale)`` pair and exactly the touched rows requantize
-  with a refreshed scale.  The segwalk/SparseCore streaming kernels do
-  not serve quantized groups (their table contract is f32; per-group
-  fallback like every other kernel seam).
-
-  COLD-TIER groups additionally concatenate the batch's fetched tail
-  rows (payload/scale/optimizer rows) onto the resident operand, remap
-  tail ids into the concatenated space, run the SAME compact/apply,
-  and return the updated fetch rows as a per-group WRITEBACK output
-  the host stores into the tier.
+  Quantized and cold-tier groups (design §12): ``_group_operand``.
   """
   key = ('sparse_apply', optimizer, global_batch, hotness, fetch_caps,
          tied)
@@ -1312,20 +1594,13 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
   cached = bool(getattr(dist, 'hot_enabled', False))
   needs_sq = bool(getattr(optimizer, 'needs_sq', True))
   needs_touch = cached and bool(getattr(optimizer, 'needs_touch', False))
-  # chunked gradient-apply (design §11): the XLA apply paths feed
-  # apply_unique/apply_hot per chunk; the segwalk/SparseCore kernels
-  # are single-pass streaming applies and consume the full stream
   n_chunks = getattr(dist.plan, 'overlap_chunks', 1)
   quant = getattr(dist, 'quant', None)
   tiered = set(getattr(dist.plan, 'cold_tier_groups', []))
   opt_q = (_QuantizedTableOptimizer(optimizer, quant)
            if quant is not None else optimizer)
-  # hierarchical (dcn x ici) placement (design §20): tables shard over
-  # the axis PRODUCT, so the cross-slice leg becomes an all_to_all of
-  # per-owner hier-row streams instead of the replicated all_gather —
-  # each deduplicated row's update crosses DCN once, to its one owner
-  # (slice, device) cell, and only that cell applies it.
   hier = dist.hier if getattr(dist, 'dcn_sharding', False) else None
+  caps = getattr(optimizer, 'capacity_rows', None) or ()
 
   def local_fn(params, opt_state, lr, fetch, *res_and_g):
     residuals = res_and_g[:len(subs)]
@@ -1337,373 +1612,67 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
     writeback = {}
     fence = lr  # serialisation token threaded through the group applies
     for gi, group in enumerate(dist.plan.groups):
+      slots = [(si, group.combiner == 'mean' and not sub.mean_row_sliced
+                and not cached)
+               for si, sub in enumerate(subs) if sub.gi == gi]
+      if not slots:
+        continue
+      key = f'group_{gi}'
+      skey = f'scale_group_{gi}'
       with obs_trace.phase_group(f'g{gi}'):
-        ids_list, grad_list, gidx_list = [], [], []
-        rows_cap = group.rows_cap
-        # hier: downstream applies run in the OWNER's hier-local row
-        # space ([rows_cap_h, w] shards, sentinel rows_cap_h); the
-        # pre-compaction above stays in flat fused space (sentinel
-        # rows_cap), exactly like the flat path
-        rows_cap_apply = (hier.groups[gi].rows_cap_h if hier is not None
-                          else rows_cap)
-        w = group.width
-        slots = [(si, sub) for si, sub in enumerate(subs) if sub.gi == gi]
-        if not slots:
-          continue
-        # Multi-hot bags broadcast ONE cotangent row to every occurrence.
-        # When duplication is real (n >= 2m), keep the compact
-        # [n_cap*GB, w] rows plus an [n] position->row index instead of
-        # materialising the h-fold broadcast (the 12.6 GiB-class stream
-        # temps of the jumbo memory audit); the segwalk path consumes the
-        # indirection natively, the XLA paths gather it back below.
-        # Below 2x duplication the indirection LOSES: the compact rows
-        # are a materialised array (the lazy broadcast fuses into its
-        # consumer) and w<128 rows store T(8,128) lane-padded — at m ~ n
-        # that re-buys the round-4 padding blowup (+3.3 GiB measured on
-        # medium@32) — so those groups keep the fused broadcast.
-        # hot-cache streams are already per-(source, slot) deduplicated
-        # h=1 rows whose cotangents were pre-divided (mean) and, for
-        # per-occurrence-squares optimizers, carry the squared channel as
-        # trailing columns — segment-summed additively, never re-squared
         with obs_trace.phase('apply/stream'):
-          wc = 2 * w if (cached and needs_sq) else w
-          n_total = sum(residuals[si][0].size for si, _ in slots)
-          m_total = sum(residuals[si][0].shape[0] * residuals[si][0].shape[1]
-                        for si, _ in slots)
-          use_idx = n_total >= 2 * m_total
-          row_off = 0
-          for si, sub in slots:
-            ids = residuals[si][0]            # [n_cap, GB, h]
-            gg = gs[si][0].astype(jnp.float32)  # [n_cap, GB, w]
-            if group.combiner == 'mean' and not sub.mean_row_sliced \
-                and not cached:
-              cnt = jnp.sum(ids < rows_cap, axis=2).astype(jnp.float32)
-              gg = gg / jnp.maximum(cnt, 1.0)[..., None]
-            # mean_row_sliced: the cotangent arrives pre-divided by the TRUE
-            # per-sample count (make_hybrid_train_step), and the shard-local
-            # count here would be the window count - no division
-            n_cap, gb, h = ids.shape
-            ids_list.append(ids.reshape(-1))
-            if use_idx:
-              grad_list.append(gg.reshape(-1, wc))
-              gidx_list.append(
-                  row_off + jnp.repeat(
-                      jnp.arange(n_cap * gb, dtype=jnp.int32), h))
-              row_off += n_cap * gb
-            else:
-              pos_g = jnp.broadcast_to(gg[:, :, None, :], ids.shape + (wc,))
-              grad_list.append(pos_g.reshape(-1, wc))
-          flat_ids = jnp.concatenate(ids_list) if len(ids_list) > 1 \
-              else ids_list[0]
-          g_rows = jnp.concatenate(grad_list) if len(grad_list) > 1 \
-              else grad_list[0]
-          g_idx = None
-          if use_idx:
-            g_idx = jnp.concatenate(gidx_list) if len(gidx_list) > 1 \
-                else gidx_list[0]
-          key = f'group_{gi}'
-          # serialise the per-group applies: without a data dependency XLA may
-          # schedule every group's sort/gather/scatter pipeline concurrently,
-          # keeping all their multi-hundred-MB compaction temporaries live at
-          # once — on a chip already holding params + accumulator that tips
-          # peak HBM over the edge (docs/perf_notes.md, train-step section).
-          # Only the IDS pass the barrier: everything downstream (sort,
-          # gathers, applies) depends on them, which orders the pipelines,
-          # while the gradient stream stays fusible into its consumer (a
-          # barriered flat_g materialises as a full lane-padded narrow temp
-          # — 2 GiB at synthetic-small scale, round-4 memory audit)
-          (flat_ids, fence) = jax.lax.optimization_barrier((flat_ids, fence))
+          stream, fence = _group_stream(slots, residuals, gs,
+                                        group.rows_cap, fence)
           state_g = {k: v[0] for k, v in opt_state[key].items()}
-          cap_rows = None
-          caps = getattr(optimizer, 'capacity_rows', None)
-          if caps is not None and gi < len(caps):
-            cap_rows = caps[gi]
-          flat_sq = None
-          flat_g = None  # materialised lazily: only the XLA paths need the
-          #                per-occurrence stream; segwalk consumes (g_rows,
-          #                g_idx) without ever broadcasting the bags
           if dist.num_slices > 1:
-            # Cross-slice update exchange — the DP-gradient step for the
-            # slice-REPLICATED table shards (each slice computed updates
-            # from its own sub-batch; every replica must apply them all,
-            # identically).  Streams pre-compact to unique rows per slice,
-            # bounding the DCN gather to the fused table's row count
-            # instead of the raw batch*hotness stream; per-occurrence-
-            # squares optimizers (needs_sq) ship the squares as their own
-            # additive channel (squares of pre-summed rows would be wrong).
-            # After the gather every slice holds the identical combined
-            # stream, so the applies (and replicas) stay in sync.
-            # Pre-compaction capacity must be the GUARANTEED bound
-            # (uniques + sentinel <= rows_cap + 2): a fraction/calibrated
-            # cap could silently drop segments here, where no correction
-            # wave runs (the wave guards only the post-gather apply).
-            pcap = _guaranteed_cap(flat_ids.shape[0], rows_cap)
-            # cached streams carry squares as trailing payload columns —
-            # they segment-sum additively with the grads and split at the
-            # same column offsets after the gather
-            uids_s, sum_g_s, sum_sq_s, _ = compact_segments(
-                flat_ids, g_rows, pcap, rows_cap,
-                with_sq=needs_sq and not cached, g_index=g_idx)
-            if hier is not None:
-              # Hierarchical update exchange (design §20): each compacted
-              # row maps through the static interval tables to its owner
-              # (slice, hier row); ONE DCN all_to_all per group ships every
-              # per-slice sum to its owner cell (same inner device index —
-              # pure cross-slice traffic), with non-owned positions at the
-              # hier sentinel so the apply drops them.  The receiver
-              # flattens slice-major, reproducing the flat all_gather's
-              # position order — so per-row segment sums add in the same
-              # sequence and the applied updates stay bit-exact vs flat.
-              hl = hier.groups[gi]
-              S = dist.num_slices
-              cap_h = hl.rows_cap_h
-              me_d = jax.lax.axis_index(ax)
-              cut_lo = jnp.asarray(hl.cut_lo)[me_d]
-              cut_sl = jnp.asarray(hl.cut_slice)[me_d]
-              cut_h = jnp.asarray(hl.cut_hier)[me_d]
-              valid = (uids_s >= 0) & (uids_s < rows_cap)
-              safe = jnp.clip(uids_s, 0, rows_cap - 1)
-              k2 = jnp.clip(
-                  jnp.searchsorted(cut_lo, safe, side='right') - 1,
-                  0, cut_lo.shape[0] - 1)
-              owner = cut_sl[k2]
-              hrow = safe - cut_lo[k2] + cut_h[k2]
-              dest = jax.lax.broadcasted_iota(jnp.int32,
-                                              (S,) + uids_s.shape, 0)
-              hids = jnp.where(valid[None] & (owner[None] == dest),
-                               hrow[None], cap_h).astype(jnp.int32)
-              packed = [
-                  jax.lax.bitcast_convert_type(hids, jnp.float32)[..., None],
-                  jnp.broadcast_to(sum_g_s[None], (S,) + sum_g_s.shape)
-              ]
-              if needs_sq and not cached:
-                packed.append(
-                    jnp.broadcast_to(sum_sq_s[None], (S,) + sum_sq_s.shape))
-              gathered = jax.lax.all_to_all(
-                  jnp.concatenate(packed, axis=2), dist.dcn_axis, 0, 0)
-              gathered = gathered.reshape(-1, gathered.shape[2])
-            else:
-              # ONE DCN collective per group: ids ride as a bitcast f32
-              # column alongside the grad (and square) payload
-              packed = [
-                  jax.lax.bitcast_convert_type(uids_s, jnp.float32)[:, None],
-                  sum_g_s
-              ]
-              if needs_sq and not cached:
-                packed.append(sum_sq_s)
-              gathered = jax.lax.all_gather(jnp.concatenate(packed, axis=1),
-                                            dist.dcn_axis, axis=0, tiled=True)
-            flat_ids = jax.lax.bitcast_convert_type(gathered[:, 0], jnp.int32)
-            flat_g = gathered[:, 1:1 + w]
-            if needs_sq:
-              flat_sq = gathered[:, 1 + w:]
-          if cached and needs_sq and flat_g is None:
-            # single-slice cached stream: split the additive squared-grad
-            # channel off the payload columns for the flat_sq apply path
-            flat_g = g_rows[:, :w]
-            flat_sq = g_rows[:, w:]
-        spack = getattr(group, 'storage_pack', 1)
+            stream = _merge_slices(
+                stream, group.width, needs_sq, cached, dist.dcn_axis,
+                dist.num_slices,
+                hier_group=None if hier is None else hier.groups[gi],
+                axis_name=ax)
+          elif cached and needs_sq:
+            stream = _split_square_columns(stream, group.width)
         head_grads = [
             (first, rows, tied_gs[k], jax.lax.axis_index(ax) == dev)
             for k, (_, (tgi, dev, first, rows)) in enumerate(tied)
             if tgi == gi]
-        if head_grads:
-          table, state2 = _tied_apply(optimizer, params[key][0], state_g,
-                                      flat_ids, g_rows, g_idx, lr, rows_cap,
-                                      head_grads)
-          new_params[key] = table[None]
-          new_state[key] = {k: v[None] for k, v in state2.items()}
-          fence = table[0, 0]
-          continue
-        if quant is not None or gi in tiered:
-          # quantized and/or tiered group (design §12): the table operand
-          # is the (payload, scale) pair; cold-tier groups concatenate
-          # the batch's fetched tail rows and return the updated rows as
-          # writeback.  Streaming kernels (segwalk/SparseCore apply) do
-          # not serve these groups — XLA adapter path only.
-          table_op = params[key][0]
-          scale_op = (params[f'scale_group_{gi}'][0]
-                      if quant is not None else None)
-          rows_eff = rows_cap_apply
-          res = group.device_rows
-          if gi in tiered:
-            with obs_trace.phase('apply/stream'):
-              f = fetch[gi]
-              frows = f['rows'][0]
-              cap_f = frows.shape[0]
-              # remap tail ids into the concatenated [res + cap_f] space:
-              # resident ids pass through, fetched tail ids land at
-              # res + fetch position, everything else (sentinel; a tail id
-              # the pre-pass missed, impossible by contract) drops at the
-              # new sentinel res + cap_f
-              pos = jnp.searchsorted(frows, flat_ids).astype(jnp.int32)
-              safe_pos = jnp.minimum(pos, cap_f - 1)
-              hit = ((flat_ids >= res) & (flat_ids < rows_cap)
-                     & (frows[safe_pos] == flat_ids))
-              flat_ids = jnp.where(
-                  flat_ids < res, flat_ids,
-                  jnp.where(hit, res + safe_pos, res + cap_f))
-              rows_eff = res + cap_f
-            with obs_trace.phase('apply/read_rows'):
-              table_op = jnp.concatenate([table_op, f['payload'][0]])
-              if scale_op is not None:
-                scale_op = jnp.concatenate([scale_op, f['scale'][0]])
-              state_g = {
-                  k: jnp.concatenate([v, f['opt'][k][0]])
-                  for k, v in state_g.items()
-              }
-          operand = ((table_op, scale_op) if quant is not None
-                     else table_op)
-          if flat_g is None:
-            t2, state2 = _dedup_and_apply(opt_q, operand, state_g,
-                                          flat_ids, g_rows, lr, rows_eff,
-                                          cap_rows=cap_rows,
-                                          g_index=g_idx,
-                                          n_chunks=n_chunks)
-          else:
-            # post-gather merge: each row appears at most once per slice,
-            # so the bounded exact fold keeps the merged totals
-            # layout-independent (flat-vs-hier bit-parity, design §20)
-            t2, state2 = _dedup_and_apply(opt_q, operand, state_g,
-                                          flat_ids, flat_g, lr, rows_eff,
-                                          cap_rows=cap_rows,
-                                          flat_sq=flat_sq,
-                                          n_chunks=n_chunks,
-                                          max_seg=dist.num_slices)
-          pay2, sc2 = t2 if quant is not None else (t2, None)
-          if gi in tiered:
-            with obs_trace.phase('apply/write_rows'):
-              wb = {'payload': pay2[res:][None]}
-              if sc2 is not None:
-                wb['scale'] = sc2[res:][None]
-              wb['opt'] = {k: v[res:][None] for k, v in state2.items()}
-              writeback[gi] = wb
-              pay2 = pay2[:res]
-              if sc2 is not None:
-                sc2 = sc2[:res]
-              state2 = {k: v[:res] for k, v in state2.items()}
-          new_params[key] = pay2[None]
-          if sc2 is not None:
-            new_params[f'scale_group_{gi}'] = sc2[None]
-          new_state[key] = {k: v[None] for k, v in state2.items()}
-          fence = pay2[0, 0]
-          continue
-        if flat_sq is None and _use_sparsecore(optimizer, dist,
-                                               params[key][0], spack):
-          # SparseCore grad+optimizer path (docs/design.md §8): the
-          # stream executes through the partition-sorted CSR buffers.
-          # flat_sq present (multi-slice per-occurrence Adagrad) means
-          # pre-accumulated squares the CSR grad op cannot consume —
-          # that case keeps the XLA path, like segwalk.
-          if flat_g is None:  # single-slice: compact rows + index
-            table, state2 = _sc_apply(optimizer, dist, params[key][0],
-                                      state_g, flat_ids, g_rows, lr,
-                                      g_index=g_idx)
-          else:  # multi-slice: the DCN exchange already compacted
-            table, state2 = _sc_apply(optimizer, dist, params[key][0],
-                                      state_g, flat_ids, flat_g, lr)
-        elif flat_sq is None and _use_segwalk(optimizer, params[key][0],
-                                              group=key):
-          # fused segment-walk path (flat_sq present means the stream
-          # carries pre-accumulated squares the kernel cannot consume —
-          # multi-slice per-occurrence Adagrad falls back to XLA).
-          # Single-slice: hand over the compact rows + index — the
-          # kernel's one [n, 128] operand gathers straight from them
-          if flat_g is None:
-            table, state2 = _segwalk_apply(optimizer, params[key][0],
-                                           state_g, flat_ids, g_rows, lr,
-                                           storage_pack=spack,
-                                           g_index=g_idx)
-          else:  # multi-slice: the DCN exchange already compacted
-            table, state2 = _segwalk_apply(optimizer, params[key][0],
-                                           state_g, flat_ids, flat_g, lr,
-                                           storage_pack=spack)
-        else:
-          if flat_g is None:  # single-slice: the compact rows + index go
-            #                   straight through (g_idx None = h1 stream)
-            table, state2 = _dedup_and_apply(optimizer, params[key][0],
-                                             state_g, flat_ids, g_rows, lr,
-                                             rows_cap, cap_rows=cap_rows,
-                                             storage_pack=spack,
-                                             g_index=g_idx,
-                                             n_chunks=n_chunks)
-          else:  # multi-slice: the DCN exchange already compacted; each
-            #       row appears at most once per slice, so the bounded
-            #       exact fold keeps the merged totals layout-independent
-            #       (flat-vs-hier bit-parity, design §20)
-            table, state2 = _dedup_and_apply(optimizer, params[key][0],
-                                             state_g, flat_ids, flat_g, lr,
-                                             rows_cap_apply,
-                                             cap_rows=cap_rows,
-                                             flat_sq=flat_sq,
-                                             storage_pack=spack,
-                                             n_chunks=n_chunks,
-                                             max_seg=dist.num_slices)
+        table, scale = params[key][0], None
+        adapted = quant is not None or gi in tiered
+        if adapted:
+          if quant is not None:
+            scale = params[skey][0]
+          table, state_g, stream = _group_operand(
+              table, scale, state_g, stream,
+              fetch[gi] if gi in tiered else None, group.device_rows)
+        table, state_g = _apply_group(
+            opt_q, table, state_g, stream, lr, key,
+            storage_pack=getattr(group, 'storage_pack', 1),
+            cap_rows=caps[gi] if gi < len(caps) else None,
+            n_chunks=n_chunks, head_grads=head_grads, adapted=adapted)
+        if quant is not None:
+          table, scale = table
+        if gi in tiered:
+          table, scale, state_g, writeback[gi] = _tier_writeback(
+              table, scale, state_g, group.device_rows)
         new_params[key] = table[None]
-        new_state[key] = {k: v[None] for k, v in state2.items()}
+        if scale is not None:
+          new_params[skey] = scale[None]
+        new_state[key] = {k: v[None] for k, v in state_g.items()}
         fence = table[0, 0]
 
-    # hot-cache buffers: ONE dense elementwise step per hot group on
-    # the mesh-psummed gradient sums — the dense add that replaces K
-    # random-access scatter rows per hot id (design §10).  The grads
-    # arrived replicated (the backward psums them), so every replica
-    # applies identically and the buffers stay in sync.
     for k_idx, gi in enumerate(hot_gis):
-      hk = f'hot_group_{gi}'
-      hg = hot_gs[k_idx].astype(jnp.float32)
-      hw = dist.plan.groups[gi].width
-      sum_g = hg[:, :hw]
-      sum_sq = hg[:, hw:2 * hw] if needs_sq else None
-      # trailing occurrence-count column (needs_touch optimizers:
-      # lazy Adam's dense touched-row mask, design §11)
-      cnt_off = 2 * hw if needs_sq else hw
-      count = hg[:, cnt_off:cnt_off + 1] if needs_touch else None
-      K = hg.shape[0]
-      kch = effective_chunks(n_chunks, K)
-      hsk = f'hot_scale_group_{gi}'
+      hk, hsk = f'hot_group_{gi}', f'hot_scale_group_{gi}'
       hot_op = ((params[hk], params[hsk]) if quant is not None
                 else params[hk])
-
-      def slice_op(op, lo, hi):
-        return ((op[0][lo:hi], op[1][lo:hi]) if quant is not None
-                else op[lo:hi])
-
-      with obs_trace.phase_group(f'g{gi}'), obs_trace.phase('apply/update'):
-        if kch == 1:
-          hot_new, hstate = opt_q.apply_hot(hot_op, opt_state[hk],
-                                            sum_g, sum_sq, lr,
-                                            count=count)
-        else:
-          # chunked dense hot apply (design §11): apply_hot is
-          # elementwise per row, so row-range chunks are bit-exact — and
-          # chunk k's step can execute while chunk k+1's psummed
-          # gradient slice is still in flight (the backward psums the
-          # hot grads in the same row chunks).  Quantized buffers chunk
-          # identically: the per-row requant is row-local.
-          pieces, spieces = [], []
-          for lo, hi in chunk_bounds(K, kch):
-            hp, hs = opt_q.apply_hot(
-                slice_op(hot_op, lo, hi),
-                {kk: vv[lo:hi] for kk, vv in opt_state[hk].items()},
-                sum_g[lo:hi],
-                None if sum_sq is None else sum_sq[lo:hi], lr,
-                count=None if count is None else count[lo:hi])
-            pieces.append(hp)
-            spieces.append(hs)
-          if quant is not None:
-            hot_new = (jnp.concatenate([p[0] for p in pieces], axis=0),
-                       jnp.concatenate([p[1] for p in pieces], axis=0))
-          else:
-            hot_new = jnp.concatenate(pieces, axis=0)
-          hstate = ({} if not spieces[0] else {
-              kk: jnp.concatenate([s[kk] for s in spieces], axis=0)
-              for kk in spieces[0]
-          })
+      with obs_trace.phase_group(f'g{gi}'):
+        hot_new, new_state[hk] = _apply_hot_group(
+            opt_q, hot_op, opt_state[hk], hot_gs[k_idx],
+            dist.plan.groups[gi].width, lr, needs_sq, needs_touch,
+            n_chunks)
       if quant is not None:
         new_params[hk], new_params[hsk] = hot_new
       else:
         new_params[hk] = hot_new
-      new_state[hk] = hstate
     return new_params, new_state, writeback
 
   n_groups = len(dist.plan.groups)

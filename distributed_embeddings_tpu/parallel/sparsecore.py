@@ -3,8 +3,10 @@
 This is the host/SPMD side of the SparseCore offload designed in
 docs/design.md §8, implemented end to end so every stage runs and is
 testable on the faked 8-device CPU mesh today; only the final custom-call
-binding (``custom_call_lookup`` / ``custom_call_grad_apply``) stays
-hardware-gated behind the ONE adapter seam at the bottom of this file.
+binding (``custom_call_lookup``) stays hardware-gated behind the ONE
+adapter seam at the bottom of this file.  The lookup only: a layer built
+with it trains through the sparse apply every layer takes
+(``parallel/sparse.py``).
 
 The SparseCore contract (TPU v4 paper, arXiv:2304.01433 §3; the
 jax-tpu-embedding surface): tables are MOD-sharded over
@@ -29,21 +31,12 @@ lane granularity).  Two builders produce the SAME logical content:
   inside the jitted train step (flat exact-capacity variant: padding is
   a hardware buffer-sizing concern, not a semantics one).
 
-The emulation backend then executes the buffers with TensorCore XLA ops:
-
-- ``emulated_lookup``: gather at the CSR's reconstituted fused rows,
-  scatter back to the dense (sample, hot) grid, and run the SHARED
-  combine tail (``dist_embedding._combine_rows``) — identical masking
-  and summation order to the TensorCore path, hence bit-identical f32
-  outputs (the equivalence fuzz asserts exact equality);
-- ``sc_grad_apply``: the grad+optimizer custom calls
-  (``tpu_sparse_dense_matmul_grad_with_{sgd,adagrad}``) emulated as an
-  XLA segment-sum + row-wise RMW over the same buffers, expressed
-  through the audited ``compact_segments`` + ``apply_unique`` pair.
-  The hardware walks partitions in parallel; the emulation fixes the
-  walk order to the update-stream order (the ``inverse_order`` bridge)
-  so results are reproducible and bit-comparable with the TensorCore
-  sparse path.
+The emulation backend then executes the buffers with TensorCore XLA ops
+(``emulated_lookup``): gather at the CSR's reconstituted fused rows,
+scatter back to the dense (sample, hot) grid, and run the SHARED
+combine tail (``dist_embedding._combine_rows``) — identical masking
+and summation order to the TensorCore path, hence bit-identical f32
+outputs (the equivalence fuzz asserts exact equality).
 
 Requesting the real binding without the library always raises the
 contract error below — never a silent fallback to TensorCore or to the
@@ -65,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 # Groups the SparseCore path declines, staying on the TensorCore paths
-# (docs/design.md §8 #4): combiner=None pass-through (SC is a reducing
+# (docs/design.md §8 #3): combiner=None pass-through (SC is a reducing
 # engine) and very wide rows (SC tile SRAM holds rows up to a few
 # hundred lanes; 256 is the conservative published bound).
 SC_WIDTH_LIMIT = 256
@@ -87,13 +80,10 @@ class StaticCsr(NamedTuple):
   pytree, so it flows through jit/shard_map); ``num_sc`` travels as a
   Python-level argument to the consumers.
 
-  ``hot_ids`` and ``positions`` are EMULATION-ONLY auxiliaries (the
-  hardware ABI carries only the first four buffers): ``hot_ids`` lets
-  the emulated forward scatter entries back onto the dense
-  (sample, hot) grid for the bit-exact shared combine tail;
-  ``positions`` is each entry's origin in the flattened routed stream,
-  the determinism bridge the emulated grad apply uses to fix its walk
-  order.
+  ``hot_ids`` is an EMULATION-ONLY auxiliary (the hardware ABI carries
+  only the first four buffers): it lets the emulated forward scatter
+  entries back onto the dense (sample, hot) grid for the bit-exact
+  shared combine tail.
   """
   row_pointers: jax.Array   # [num_sc] end offsets per partition
   embedding_ids: jax.Array  # [N] partition-local row ids (row // num_sc)
@@ -101,7 +91,6 @@ class StaticCsr(NamedTuple):
   gains: jax.Array          # [N] f32 multiplier (0 at padding)
   partition_ids: jax.Array  # [N] partition of each entry (num_sc = pad)
   hot_ids: jax.Array        # [N] hot-axis position (emulation aux)
-  positions: jax.Array      # [N] origin position in the flat stream
 
 
 def group_supported(table_aval, combiner: Optional[str],
@@ -147,22 +136,6 @@ def engaged_groups(plan, param_dtype) -> List[int]:
       if g.storage_pack == 1 and group_supported(
           jax.ShapeDtypeStruct((g.rows_cap, g.width), dt), g.combiner, 1)
   ]
-
-
-def apply_supported(optimizer, table_aval, storage_pack: int = 1) -> bool:
-  """Whether ``sc_grad_apply`` serves this (optimizer, group): natural
-  (unpacked) storage, f32, SC-servable width, and an optimizer whose
-  RMW the SC grad custom calls implement — declared by the capability
-  attribute ``sc_apply_kind`` ('sgd' / 'adagrad') on the optimizer, so
-  subclasses and renames keep working and the eligibility probe shares
-  the same contract."""
-  if storage_pack > 1:
-    return False  # SC plans store natural; packed groups are TensorCore
-  if table_aval.shape[1] > SC_WIDTH_LIMIT:
-    return False
-  if jnp.dtype(table_aval.dtype) != jnp.float32:
-    return False
-  return getattr(optimizer, 'sc_apply_kind', None) in ('sgd', 'adagrad')
 
 
 # --------------------------------------------------------------------------
@@ -251,7 +224,6 @@ def csr_from_routed(routed: jax.Array, rows_cap: int, num_sc: int,
       gains=gains,
       partition_ids=part_sorted,
       hot_ids=hot.astype(jnp.int32),
-      positions=order,
   )
 
 
@@ -450,56 +422,6 @@ def emulated_lookup(table: jax.Array, routed: jax.Array,
   return _combine_rows(dense.reshape(n_cap, gb, h, w),
                        mask.reshape(n_cap, gb, h), combiner, table_dtype,
                        compute_dtype)
-
-
-def sc_grad_apply(optimizer, table: jax.Array, state: Dict[str, jax.Array],
-                  flat_ids: jax.Array, grads: jax.Array, lr,
-                  num_sc: int, g_index: Optional[jax.Array] = None):
-  """Executable emulation of the SC grad+optimizer custom calls
-  (``tpu_sparse_dense_matmul_grad_with_{sgd,adagrad}``): rebuild the
-  update stream's partition-sorted CSR buffers (the same transform that
-  feeds the forward), then execute their semantics in XLA — segment-sum
-  of the per-occurrence gradient rows followed by the row-wise RMW,
-  expressed through the audited ``compact_segments`` +
-  ``optimizer.apply_unique`` pair from parallel/sparse.py.
-
-  The hardware walks its partitions in parallel with unspecified
-  interleave; the emulation reads the buffers back through the CSR's
-  ``positions`` bridge so the segment summation consumes entries in
-  update-stream order — making the result bit-identical (f32) to the
-  TensorCore sparse path at guaranteed capacity, which the equivalence
-  fuzz exploits.
-
-  Args mirror ``sparse._dedup_and_apply``'s stream contract: ``grads``
-  is either per-occurrence ``[n, w]`` rows or compact per-(sample, bag)
-  rows with ``g_index`` mapping positions to rows.
-  """
-  from distributed_embeddings_tpu.parallel.sparse import (_guaranteed_cap,
-                                                          compact_segments)
-  rows_cap = table.shape[0]
-  n = flat_ids.shape[0]
-  sentinel = rows_cap
-  # the CSR buffers for this stream (sample grid = stream positions)
-  csr = csr_from_routed(flat_ids.reshape(1, n, 1), rows_cap, num_sc,
-                        combiner='sum')
-  # read the stream BACK OUT of the buffers in original order: inverse
-  # of the partition sort (the determinism bridge; proves the buffers
-  # carry the full stream)
-  inv = jnp.zeros((n,), jnp.int32).at[csr.positions].set(
-      jnp.arange(n, dtype=jnp.int32), unique_indices=True)
-  stream_ids = jnp.where(
-      csr.sample_ids < n,
-      csr.embedding_ids * num_sc + csr.partition_ids, sentinel)[inv]
-  with_sq = bool(getattr(optimizer, 'needs_sq', False))
-  cap = _guaranteed_cap(n, rows_cap)
-  # g_index passes straight through: compact_segments gathers the
-  # payload from the COMPACT per-(sample, bag) rows in sorted order, so
-  # the h-fold multi-hot broadcast never materialises here either (the
-  # same indirection contract as the segwalk/XLA dispatch)
-  uids, sum_g, sum_sq, _ = compact_segments(stream_ids, grads, cap,
-                                            sentinel, with_sq=with_sq,
-                                            g_index=g_index)
-  return optimizer.apply_unique(table, state, uids, sum_g, sum_sq, lr)
 
 
 # --------------------------------------------------------------------------
@@ -777,16 +699,6 @@ def measure_preprocess_ms(dist, cats, repeats: int = 3,
 # --------------------------------------------------------------------------
 
 
-def _require_custom_call():
-  """Import gate shared by both adapter functions: one place, one
-  contract message."""
-  try:
-    import jax_tpu_embedding
-  except ImportError:
-    raise NotImplementedError(_CONTRACT_MSG) from None
-  return jax_tpu_embedding
-
-
 def custom_call_lookup(table: jax.Array, csr: StaticCsr,
                        combiner: Optional[str], compute_dtype,
                        num_sc: int) -> jax.Array:
@@ -798,7 +710,10 @@ def custom_call_lookup(table: jax.Array, csr: StaticCsr,
   backend; this function only swaps the executable emulation for the
   real custom call on SC hardware, where it is validated.  Without the
   library it raises the contract error (never a silent fallback)."""
-  lib = _require_custom_call()
+  try:
+    import jax_tpu_embedding as lib
+  except ImportError:
+    raise NotImplementedError(_CONTRACT_MSG) from None
   raise NotImplementedError(
       'jax-tpu-embedding is importable but this binding has not been '
       'validated on SparseCore hardware in this environment; wire '
@@ -806,23 +721,3 @@ def custom_call_lookup(table: jax.Array, csr: StaticCsr,
       'here (row_pointers/embedding_ids/sample_ids/gains map 1:1) and '
       'validate against the emulation backend, which is the executable '
       'specification of the expected numerics.')
-
-
-def custom_call_grad_apply(optimizer, table, state, csr: StaticCsr, grads,
-                           lr, num_sc: int,
-                           g_index: Optional[jax.Array] = None):
-  """Hardware-gated twin of ``sc_grad_apply`` for the fused
-  ``tpu_sparse_dense_matmul_grad_with_{sgd,adagrad}`` custom calls; same
-  single-seam discipline as ``custom_call_lookup``.
-
-  ``grads``/``g_index`` follow the stream contract of ``sc_grad_apply``:
-  with ``g_index`` the rows are COMPACT per-(sample, bag) — the binding
-  must expand through the index (or hand the pair to hardware that
-  consumes it) before/while walking the CSR's n entries, exactly as the
-  emulation's ``compact_segments(..., g_index=...)`` does."""
-  lib = _require_custom_call()
-  raise NotImplementedError(
-      'jax-tpu-embedding is importable but this binding has not been '
-      'validated on SparseCore hardware in this environment; wire '
-      f'{lib.__name__}.tpu_sparse_dense_matmul_grad_with_* here and '
-      'validate against sc_grad_apply, the executable specification.')

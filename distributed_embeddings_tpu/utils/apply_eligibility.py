@@ -1,10 +1,10 @@
-"""Eligibility report for the fused sparse-apply kernels.
+"""Eligibility report for the fused sparse-apply kernel.
 
 An A/B run that silently measures the XLA fallback (wrong backend, bf16
 tables, unsupported widths) reads as "the kernel is no faster" —
 `bench.py` embeds this check in its artifact line and the diagnostic
-harnesses print it, all through this single helper so the semantics
-cannot drift between them.
+harnesses print it.  What it reports is the dispatch's own answer
+(``parallel/sparse.choose_apply``); only the wording lives here.
 """
 
 from __future__ import annotations
@@ -24,87 +24,35 @@ def _active_suffix(force_interpret: bool, assume_tpu: bool = False) -> str:
   return f', inactive on {backend}'
 
 
-def _segwalk_group_ok(g, dt) -> bool:
-  """The ONE predicate deciding whether the segment-walk kernel serves a
-  fusion group — shared by the report and the all-groups check so they
-  can never drift from each other (the dispatch in parallel/sparse.py
-  applies the same gates)."""
-  from distributed_embeddings_tpu.ops import pallas_segwalk
-  from distributed_embeddings_tpu.parallel.sparse import packed_dispatch_ok
-  if getattr(g, 'storage_pack', 1) > 1:
-    # packed storage: the kernel consumes the physical [rows/pack, 128]
-    # operand with no reshape, so the lane-padded-layout HBM bound
-    # (packed_dispatch_ok) does not apply at any group size
-    return pallas_segwalk.supported(
-        jax.ShapeDtypeStruct((g.param_rows, g.param_width), dt))
-  return (pallas_segwalk.supported(
-      jax.ShapeDtypeStruct((g.rows_cap, g.width), dt))
-          and packed_dispatch_ok(g.rows_cap, g.width))
-
-
-def _group_table_aval(g, dt):
-  """The shape the KERNEL actually sees for this group: the kernel is
-  width-128-only at the kernel boundary, so narrow groups engage
-  through the lane-packed ``[rows_cap/pack, 128]`` view (the in-kernel
-  packed path for the segment-walk) — the probe must mirror that or it
-  misreports exactly the fallback confusion it exists to prevent.  The
-  runtime's
-  packed dispatch additionally declines huge narrow groups whose
-  lane-padded layout would blow HBM (``packed_dispatch_ok``); those
-  groups are probed at their natural narrow width — which the kernels
-  reject — so the reported count matches the actual dispatch."""
-  from distributed_embeddings_tpu.parallel.sparse import packed_view_ok
-  if getattr(g, 'storage_pack', 1) > 1:
-    # packed storage: the kernel sees the physical layout itself — no
-    # reshape, so no packed_dispatch_ok gate at any group size
-    return jax.ShapeDtypeStruct((g.param_rows, g.param_width), dt)
-  w = g.width
-  if packed_view_ok(g.rows_cap, w):
-    pack = 128 // w
-    return jax.ShapeDtypeStruct((g.rows_cap // pack, 128), dt)
-  return jax.ShapeDtypeStruct((g.rows_cap, w), dt)
+def _segwalk_groups(dist, param_dtype, accum_dtype: str, active):
+  """Per fusion group, whether ``choose_apply`` hands its update to the
+  segment-walk kernel: asked as the group loop asks, of the shape the
+  group's shard is stored in."""
+  from distributed_embeddings_tpu.parallel.sparse import (SparseAdagrad,
+                                                          choose_apply)
+  asks = SparseAdagrad(use_segwalk_apply=True, accum_dtype=accum_dtype)
+  adapted = getattr(dist, 'quant', None) is not None
+  tiered = set(getattr(dist.plan, 'cold_tier_groups', []))
+  return [
+      choose_apply(
+          asks, jax.ShapeDtypeStruct((g.param_rows, g.param_width),
+                                     jnp.dtype(param_dtype)),
+          g.rows_cap, g.width, storage_pack=g.storage_pack,
+          adapted=adapted or gi in tiered, active=active).kernel == 'segwalk'
+      for gi, g in enumerate(dist.plan.groups)]
 
 
 def eligibility_line(dist, param_dtype, segwalk_apply: bool,
-                     accum_dtype: str = 'float32',
-                     sparsecore_apply: bool = False) -> str:
-  """One line saying which fusion groups each requested fused kernel
+                     accum_dtype: str = 'float32') -> str:
+  """One line saying which fusion groups the requested fused kernel
   would actually serve, and whether it engages on this backend at all
-  (empty string when no kernel is requested).  ``accum_dtype`` mirrors
-  the dispatch's low-precision-accumulator gate
-  (``sparse._use_segwalk``): segwalk serves bf16 accumulators only on
-  bf16 tables (the pair-fetch path)."""
-  parts = []
-  dt = jnp.dtype(param_dtype)
-  groups = dist.plan.groups
-  if segwalk_apply:
-    from distributed_embeddings_tpu.ops import pallas_segwalk
-    ok = (sum(1 for g in groups if _segwalk_group_ok(g, dt))
-          if pallas_segwalk.acc_dtype_ok(dt, accum_dtype) else 0)
-    parts.append(f'segwalk_apply: {ok}/{len(groups)} groups eligible'
-                 f'{_active_suffix(pallas_segwalk.FORCE_INTERPRET, pallas_segwalk.ASSUME_TPU)}')
-  if sparsecore_apply:
-    # dispatch mirror of sparse._use_sparsecore: a minimal probe
-    # carrying the capability tag; the shape/dtype/storage gates are real
-    from types import SimpleNamespace
-    from distributed_embeddings_tpu.parallel import sparsecore
-
-    probe = SimpleNamespace(sc_apply_kind='sgd')
-    ok = sum(1 for g in groups if sparsecore.apply_supported(
-        probe, jax.ShapeDtypeStruct((g.rows_cap, g.width), dt),
-        getattr(g, 'storage_pack', 1)))
-    try:
-      # resolve the LAYER's configured backend — the one the dispatch
-      # actually runs — not a hardcoded 'auto'
-      requested = getattr(dist, 'sparsecore_backend', 'auto')
-      backend = sparsecore.resolve_backend(requested) if ok else 'n/a'
-    except NotImplementedError:
-      # a TPU without jax-tpu-embedding: the report must still print
-      # (the dispatch itself raises at apply time)
-      backend = 'unavailable (jax-tpu-embedding absent)'
-    parts.append(f'sparsecore_apply: {ok}/{len(groups)} groups eligible '
-                 f'(backend: {backend})')
-  return '; '.join(parts)
+  (empty string when no kernel is requested)."""
+  if not segwalk_apply:
+    return ''
+  from distributed_embeddings_tpu.ops import pallas_segwalk
+  served = _segwalk_groups(dist, param_dtype, accum_dtype, active=True)
+  return (f'segwalk_apply: {sum(served)}/{len(served)} groups eligible'
+          f'{_active_suffix(pallas_segwalk.FORCE_INTERPRET, pallas_segwalk.ASSUME_TPU)}')
 
 
 def segwalk_serves_all_groups(dist, param_dtype,
@@ -112,12 +60,4 @@ def segwalk_serves_all_groups(dist, param_dtype,
   """True when the segment-walk kernel will handle EVERY fusion group on
   the active backend — in which case compaction capacities are dead
   weight (the kernel has none)."""
-  from distributed_embeddings_tpu.ops import pallas_segwalk
-  dt = jnp.dtype(param_dtype)
-  if not pallas_segwalk.acc_dtype_ok(dt, accum_dtype):
-    return False  # mirrors sparse._use_segwalk's accumulator gate
-  if not (jax.default_backend() == 'tpu'
-          or pallas_segwalk.FORCE_INTERPRET
-          or pallas_segwalk.ASSUME_TPU):
-    return False
-  return all(_segwalk_group_ok(g, dt) for g in dist.plan.groups)
+  return all(_segwalk_groups(dist, param_dtype, accum_dtype, active=None))

@@ -269,7 +269,7 @@ def test_eligibility_reports_packed_groups_served():
   reported (and dispatched) kernel-eligible because no reshape exists."""
   from distributed_embeddings_tpu.parallel import sparse
   from distributed_embeddings_tpu.utils.apply_eligibility import (
-      _group_table_aval, _segwalk_group_ok)
+      eligibility_line)
   mesh = _mesh()
   big_rows = (sparse.PACKED_PARAM_BYTES_LIMIT // (128 * 4)) * WORLD * 8
   # enough tables that the auto threshold never column-slices the big
@@ -280,9 +280,11 @@ def test_eligibility_reports_packed_groups_served():
   packed = DistributedEmbedding(cfgs, mesh=mesh, packed_storage=True)
   natural = DistributedEmbedding(cfgs, mesh=mesh, packed_storage=False)
   (gp,), (gn,) = packed.plan.groups, natural.plan.groups
-  assert _segwalk_group_ok(gp, jnp.float32), 'packed big group must serve'
-  assert not _segwalk_group_ok(gn, jnp.float32), 'natural big group barred'
-  assert _group_table_aval(gp, jnp.float32).shape == (gp.param_rows, 128)
+  assert gp.param_width == 128 and gn.param_width == 16
+  assert '1/1 groups' in eligibility_line(packed, 'float32', True), \
+      'packed big group must serve'
+  assert '0/1 groups' in eligibility_line(natural, 'float32', True), \
+      'natural big group barred'
 
 
 def test_eligibility_line_renders_every_branch():
@@ -299,8 +301,6 @@ def test_eligibility_line_renders_every_branch():
   for accum in ('float32', 'bfloat16'):
     line = eligibility_line(dist, 'float32', True, accum_dtype=accum)
     assert 'segwalk_apply:' in line, (accum, line)
-  line = eligibility_line(dist, 'float32', True, sparsecore_apply=True)
-  assert 'segwalk_apply:' in line and 'sparsecore_apply:' in line, line
 
 
 def test_calibration_mirror_matches_packed_layout():

@@ -156,6 +156,7 @@ def test_segwalk_apply_microbench(w, n):
   into the stream read (docs/perf_notes.md, multi-chip model)."""
   from distributed_embeddings_tpu.ops import pallas_segwalk
   from distributed_embeddings_tpu.parallel.sparse import (SparseAdagrad,
+                                                          _Stream,
                                                           _dedup_and_apply)
   rng = np.random.default_rng(5)
   rows = 8_000_000 if w == 16 else 1_000_000
@@ -180,7 +181,8 @@ def test_segwalk_apply_microbench(w, n):
         op='adagrad_dedup', eps=1e-7)
 
   def xla_fn(tab, ac, ids):
-    t2, s2 = _dedup_and_apply(opt, tab, {'acc': ac}, ids, g, 0.01, rows)
+    t2, s2 = _dedup_and_apply(opt, tab, {'acc': ac},
+                              _Stream(ids, g, rows), 0.01)
     return t2, s2['acc']
 
   def bench(fn):

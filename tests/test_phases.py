@@ -1,6 +1,6 @@
 """Device phases (obs.trace.phase, design §15): every section of the
-compiled step carries a registered phase, in all five builders and all
-three apply paths; the scopes add no operation; the program's host
+compiled step carries a registered phase, in all five builders and both
+apply paths; the scopes add no operation; the program's host
 spans reach the profiler's own trace; and ``tools/trace_report.py
 --profile`` reads a recorded v5e trace of a scoped program.
 """
@@ -54,9 +54,6 @@ CASES = {
     'segwalk': ({}, 4, SparseAdagrad(0.05, use_segwalk_apply=True),
                 FWD | BWD | DENSE
                 | {'apply/stream', 'apply/dedup', 'apply/update'}),
-    'sparsecore': ({'sparsecore_backend': 'emulate'}, 4,
-                   SparseSGD(0.05, use_sparsecore_apply=True),
-                   FWD | BWD | APPLY | DENSE),
 }
 
 

@@ -676,8 +676,9 @@ def test_overflow_loop_gives_the_cond_forms_bits(monkeypatch, opt_name,
   def run(form):
     def fn(table, state, ids, g, sq):
       return sparse_mod._dedup_and_apply(
-          opt, table, state, ids, g, LR, rows_cap, cap_rows=cap_rows,
-          flat_sq=sq, storage_pack=pack, max_seg=max_seg)
+          opt, table, state,
+          sparse_mod._Stream(ids, g, rows_cap, None, sq, max_seg), LR,
+          cap_rows=cap_rows, storage_pack=pack)
 
     with monkeypatch.context() as m:
       if form == 'cond':
@@ -786,19 +787,20 @@ def test_bf16_accumulator_segwalk_gate():
   on f32 tables the dispatch and the eligibility probe must BOTH
   report the XLA fallback (single-source gate, advisor r3)."""
   from distributed_embeddings_tpu.ops import pallas_segwalk
-  from distributed_embeddings_tpu.parallel.sparse import _use_segwalk
+  from distributed_embeddings_tpu.parallel.sparse import choose_apply
   from distributed_embeddings_tpu.utils.apply_eligibility import (
       segwalk_serves_all_groups)
   dist, params_emb, *_ = build()
   opt = SparseAdagrad(use_segwalk_apply=True, accum_dtype='bfloat16')
-  assert not _use_segwalk(opt, jnp.zeros((1024, 128), jnp.float32))
+  kernel = lambda table: choose_apply(opt, table, 1024, 128).kernel
+  assert kernel(jnp.zeros((1024, 128), jnp.float32)) == 'xla'
   assert not segwalk_serves_all_groups(dist, 'float32',
                                        accum_dtype='bfloat16')
   # positive case: bf16 table + bf16 accumulator engages the kernel
   # (backend-gated; FORCE_INTERPRET stands in for the chip here)
   pallas_segwalk.FORCE_INTERPRET = True
   try:
-    assert _use_segwalk(opt, jnp.zeros((1024, 128), jnp.bfloat16))
+    assert kernel(jnp.zeros((1024, 128), jnp.bfloat16)) == 'segwalk'
     # serves-all needs a plan whose row granularity satisfies the bf16
     # pair divisibility — the planner grants that when params ARE bf16.
     # Large-ish unsliced tables: auto column slicing would split widths
@@ -859,13 +861,15 @@ def test_dispatch_says_which_path_each_group_takes_on_tpu(monkeypatch,
   opt = SparseAdagrad(use_segwalk_apply=True)
   good = jax.ShapeDtypeStruct((1024, 128), jnp.float32)
   odd = jax.ShapeDtypeStruct((1024, 24), jnp.float32)
+  kernel = lambda o, t, group: sparse.choose_apply(
+      o, t, *t.shape, group=group).kernel
   with caplog.at_level(logging.INFO, logger=sparse.__name__):
-    assert not sparse._use_segwalk(opt, good, group='group_0')
+    assert kernel(opt, good, 'group_0') == 'xla'
     assert not caplog.records  # CPU backend: not asked here, not said
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    assert sparse._use_segwalk(opt, good, group='group_0')
-    assert not sparse._use_segwalk(opt, odd, group='group_1')
-    assert not sparse._use_segwalk(SparseAdagrad(), odd, group='group_1')
+    assert kernel(opt, good, 'group_0') == 'segwalk'
+    assert kernel(opt, odd, 'group_1') == 'xla'
+    assert kernel(SparseAdagrad(), odd, 'group_1') == 'xla'
   said = [(r.levelname, r.getMessage()) for r in caplog.records]
   assert said[0][0] == 'INFO' and 'group_0 takes the segment-walk' in said[0][1]
   assert said[1][0] == 'WARNING' and 'group_1 takes the XLA apply' in said[1][1]
