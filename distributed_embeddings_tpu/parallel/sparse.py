@@ -66,10 +66,12 @@ def compact_segments(ids: jax.Array,
   """Sort-dedup and COMPACT segment sums into static capacity ``cap``.
 
   The key fact motivating this (measured on v5e, docs/perf_notes.md):
-  XLA scatter costs ~110-140 ns per update row REGARDLESS of how many
-  rows are sentinel-dropped — only the *static* row count matters — while
-  a two-operand sort is 1.5 ns a key and a gather 7 to 25 ns a row
-  (PERF.md, PR 28).  ``dedup_rows`` keeps the nnz-length shape, so its
+  a scatter's cost follows its STATIC shapes, however many rows are
+  sentinel-dropped — XLA's row emitter pays per static update row, its
+  streaming emitter per update row plus one pass over the operand
+  (``write_algorithm`` has both laws and picks) — while a two-operand
+  sort is 1.5 ns a key and a gather 7 to 25 ns a row (PERF.md, PR 28).
+  ``dedup_rows`` keeps the nnz-length shape, so its
   scatters still pay full price; this variant compacts the unique rows
   to the front of a ``cap``-sized buffer so the optimizer's scatters
   shrink by the duplicate factor (~6x on the power-law synthetic inputs)
@@ -277,6 +279,62 @@ def _distinct_oob(uids: jax.Array, limit: int) -> jax.Array:
                    uids, limit + jnp.arange(n, dtype=uids.dtype))
 
 
+# XLA:TPU has two scatter emitters and ``indices_are_sorted`` alone picks
+# one (compile-only, ISSUE 30: 16,355,328 B of scoped VMEM with the hint,
+# 139,264 B without, whatever ``unique_indices`` says).  With the hint the
+# scatter STREAMS the whole operand through VMEM, ``a * R + b * U`` for R
+# operand rows and U update rows; without it it walks the update ROWS,
+# ``c * U`` whatever R is where they lie 18 or more rows apart (less a
+# row where neighbours share a tile, 14 to 26 ns, but the stream is the
+# faster there anyway).  ns per row on a v5e at 128 float32 lanes, the
+# operand every benchmark cell's waves write, fitted by
+# ``examples/benchmarks/scatter_probe.py`` over R in {2.5 M, 8.775 M,
+# 20.0 M} x U in {92 K, 1.12 M, 2.88 M} (the grid: PERF.md section 6,
+# PR 30; add and set agree on ``a`` to 0.3%).  The times cross where a
+# wave writes 2.3% of the operand's rows:
+_STREAM_NS_PER_OPERAND_ROW = 1.59
+_STREAM_NS_PER_UPDATE_ROW = {'add': 5.74, 'set': 3.16}
+_ROWS_NS_PER_UPDATE_ROW = {'add': 73.7, 'set': 72.9}
+
+
+def write_algorithm(update_rows: int, operand_rows: int,
+                    op: str = 'add') -> str:
+  """Which scatter emitter ``_write_rows`` takes for ``update_rows``
+  unique rows into an operand of ``operand_rows``: ``'stream'`` where
+  the wave is dense enough that one pass over the whole operand is the
+  cheaper, ``'rows'`` where it touches so small a share that the pass
+  would cost more than the rows (dlrm-train-4chip: 92,272 of 20,025,088,
+  where the stream was 51% of the step)."""
+  stream = (_STREAM_NS_PER_OPERAND_ROW * operand_rows
+            + _STREAM_NS_PER_UPDATE_ROW[op] * update_rows)
+  return ('stream' if _ROWS_NS_PER_UPDATE_ROW[op] * update_rows > stream
+          else 'rows')
+
+
+def _write_rows(operand: jax.Array, uids: jax.Array, rows: jax.Array,
+                op: str) -> jax.Array:
+  """THE scatter of compacted unique rows: ``op`` (``'add'`` or
+  ``'set'``) ``rows`` into ``operand`` at ``uids``.  Compacted ids are
+  ascending and ``_distinct_oob`` makes them strictly unique, so
+  ``unique_indices`` holds at every call and either emitter writes each
+  row once: the same values, whichever ``write_algorithm`` picks from
+  the wave's static shapes."""
+  with obs_trace.phase('apply/write_rows'):
+    at = operand.at[_distinct_oob(uids, operand.shape[0])]
+    return getattr(at, op)(
+        rows.astype(operand.dtype), mode='drop', unique_indices=True,
+        indices_are_sorted=write_algorithm(
+            uids.shape[0], operand.shape[0], op) == 'stream')
+
+
+def _apply_rows(optimizer, table, state, uids, sum_g, sum_sq, lr):
+  """One step at COMPACTED unique rows (``compact_segments``): the
+  optimizer's ``row_updates``, added to the table's rows."""
+  delta, state = optimizer.row_updates(state, uids, sum_g, sum_sq, lr,
+                                       table.shape[0])
+  return _write_rows(table, uids, delta, 'add'), state
+
+
 @dataclasses.dataclass(frozen=True)
 class SparseSGD:
   """Row-wise SGD; exact (SGD is linear, so summed duplicate rows match
@@ -326,18 +384,7 @@ class SparseSGD:
     row (design §12): SGD is stateless."""
     return {}
 
-  def apply_unique(self, table, state, uids, sum_g, sum_sq, lr):
-    """Apply one step at COMPACTED unique rows (``compact_segments``)."""
-    delta, state = self.row_updates(state, uids, sum_g, sum_sq, lr,
-                                    table.shape[0])
-    # compacted ids are ascending; _distinct_oob makes them strictly
-    # unique so the hints let XLA vectorise the scatter instead of
-    # serialising for duplicates
-    with obs_trace.phase('apply/write_rows'):
-      uids = _distinct_oob(uids, table.shape[0])
-      return table.at[uids].add(delta.astype(table.dtype), mode='drop',
-                                unique_indices=True,
-                                indices_are_sorted=True), state
+  apply_unique = _apply_rows
 
   def apply_hot(self, hot, state, sum_g, sum_sq, lr, count=None):
     """DENSE step on a replicated hot-cache buffer (design §10):
@@ -455,14 +502,12 @@ class SparseAdagrad:
     # had: the program is the same, only its metadata is new)
     with obs_trace.phase('apply/update'):
       add = _rounded_square(sum_g) if self.dedup else sum_sq
-    # compacted ids are ascending; _distinct_oob makes them strictly
-    # unique (clipped sentinel gathers may duplicate the last row, hence
-    # unique_indices=False there): the hints let XLA vectorise the
-    # gather/scatters instead of serialising for duplicates
+    # compacted ids are ascending (clipped sentinel gathers may
+    # duplicate the last row, hence unique_indices=False there): the
+    # hint lets XLA vectorise the gather instead of serialising for
+    # duplicates; the write is ``_write_rows``'s
     with obs_trace.phase('apply/read_rows'):
       safe = jnp.clip(uids, 0, limit - 1)
-    with obs_trace.phase('apply/write_rows'):
-      dids = _distinct_oob(uids, limit)
     # low-precision accumulators: gather up-casts, arithmetic (add +
     # rsqrt) stays f32, only the store rounds to accum_dtype — the
     # update this step uses the EXACT f32 running value
@@ -472,24 +517,12 @@ class SparseAdagrad:
           indices_are_sorted=True).astype(jnp.float32)
     with obs_trace.phase('apply/update'):
       acc_rows = old_rows + add
-    with obs_trace.phase('apply/write_rows'):
-      acc = state['acc'].at[dids].set(acc_rows.astype(state['acc'].dtype),
-                                      mode='drop',
-                                      unique_indices=True,
-                                      indices_are_sorted=True)
+    acc = _write_rows(state['acc'], uids, acc_rows, 'set')
     with obs_trace.phase('apply/update'):
       delta = -lr * sum_g * jax.lax.rsqrt(acc_rows + self.epsilon)
     return delta, {'acc': acc}
 
-  def apply_unique(self, table, state, uids, sum_g, sum_sq, lr):
-    """One step at COMPACTED unique rows (see ``row_updates``)."""
-    delta, state = self.row_updates(state, uids, sum_g, sum_sq, lr,
-                                    table.shape[0])
-    with obs_trace.phase('apply/write_rows'):
-      uids = _distinct_oob(uids, table.shape[0])
-      return table.at[uids].add(delta.astype(table.dtype), mode='drop',
-                                unique_indices=True,
-                                indices_are_sorted=True), state
+  apply_unique = _apply_rows
 
   def apply_hot(self, hot, state, sum_g, sum_sq, lr, count=None):
     """DENSE Adagrad step on a replicated hot-cache buffer: the same
@@ -591,17 +624,14 @@ class SparseAdam:
     path did internally."""
     del sum_sq
     g = sum_g
-    # strictly unique ascending ids; see SparseAdagrad.row_updates
-    hints = dict(unique_indices=True, indices_are_sorted=True)
+    # ascending ids; see SparseAdagrad.row_updates
     ghints = dict(unique_indices=False, indices_are_sorted=True)
     # (operations in the order they always had; see SparseAdagrad)
     with obs_trace.phase('apply/read_rows'):
       safe = jnp.clip(uids, 0, limit - 1)
     with obs_trace.phase('apply/update'):
       valid = (uids < limit)[:, None]
-    with obs_trace.phase('apply/write_rows'):
-      ids = _distinct_oob(uids, limit)
-      t = state['t'].at[ids].add(1, mode='drop', **hints)
+    t = _write_rows(state['t'], uids, jnp.ones_like(uids), 'add')
     with obs_trace.phase('apply/read_rows'):
       m_old = state['m'].at[safe].get(**ghints)
     with obs_trace.phase('apply/update'):
@@ -610,11 +640,8 @@ class SparseAdam:
       v_old = state['v'].at[safe].get(**ghints)
     with obs_trace.phase('apply/update'):
       v_rows = self.b2 * v_old + (1 - self.b2) * g * g
-    with obs_trace.phase('apply/write_rows'):
-      m = state['m'].at[ids].set(jnp.where(valid, m_rows, 0), mode='drop',
-                                 **hints)
-      v = state['v'].at[ids].set(jnp.where(valid, v_rows, 0), mode='drop',
-                                 **hints)
+    m = _write_rows(state['m'], uids, jnp.where(valid, m_rows, 0), 'set')
+    v = _write_rows(state['v'], uids, jnp.where(valid, v_rows, 0), 'set')
     with obs_trace.phase('apply/read_rows'):
       t_rows = t.at[safe].get(**ghints).astype(jnp.float32)[:, None]
     with obs_trace.phase('apply/update'):
@@ -623,15 +650,7 @@ class SparseAdam:
       delta = -lr * mhat / (jnp.sqrt(vhat) + self.epsilon)
     return delta, {'m': m, 'v': v, 't': t}
 
-  def apply_unique(self, table, state, uids, sum_g, sum_sq, lr):
-    """One lazy-Adam step at COMPACTED unique rows (``row_updates``)."""
-    delta, state = self.row_updates(state, uids, sum_g, sum_sq, lr,
-                                    table.shape[0])
-    with obs_trace.phase('apply/write_rows'):
-      ids = _distinct_oob(uids, table.shape[0])
-      return table.at[ids].add(delta.astype(table.dtype), mode='drop',
-                               unique_indices=True,
-                               indices_are_sorted=True), state
+  apply_unique = _apply_rows
 
   def apply_hot(self, hot, state, sum_g, sum_sq, lr, count=None):
     """DENSE lazy-Adam step on a replicated hot-cache buffer.
@@ -706,12 +725,8 @@ class _QuantizedTableOptimizer:
              * scale.at[safe].get(**ghints))
     with obs_trace.phase('apply/update'):
       npay, nscale = quantization.quantize_jnp(old + delta, self.spec)
-    hints = dict(mode='drop', unique_indices=True,
-                 indices_are_sorted=True)
-    with obs_trace.phase('apply/write_rows'):
-      dids = _distinct_oob(uids, limit)
-      return (payload.at[dids].set(npay, **hints),
-              scale.at[dids].set(nscale, **hints)), state2
+    return (_write_rows(payload, uids, npay, 'set'),
+            _write_rows(scale, uids, nscale, 'set')), state2
 
   def apply_hot(self, pt, state, sum_g, sum_sq, lr, count=None):
     """Dense step on a quantized replicated hot buffer: dequantize the
@@ -758,12 +773,11 @@ def _lane_pack(uids, sum_g, sum_sq, pack: int, rows_cap: int,
                                                              packed_ids)
   c, w = sum_g.shape
   lanes = pack * w
-  psent = rows_cap // pack
+  cap2, psent = wave_shape(c, rows_cap, pack)
   pids, slot = packed_ids(uids, pack, rows_cap)
   g_lanes = lane_expand(sum_g, slot, pack)
   payload = (g_lanes if sum_sq is None else jnp.concatenate(
       [g_lanes, lane_expand(sum_sq, slot, pack)], axis=1))
-  cap2 = min(c, psent + 2)
   # uids come rank-ordered (ascending, sentinels last) from the outer
   # compaction, so pids is already sorted: no sort, no sorted gather
   pids_c, pay_c, _, _ = _compact_sorted(
@@ -771,6 +785,22 @@ def _lane_pack(uids, sum_g, sum_sq, pack: int, rows_cap: int,
   g_packed = pay_c[:, :lanes]
   sq_packed = pay_c[:, lanes:] if sum_sq is not None else None
   return pids_c, g_packed, sq_packed
+
+
+def wave_shape(cap: int, rows_cap: int, pack: int) -> Tuple[int, int]:
+  """``(update rows, operand rows)`` of a wave of ``cap`` compacted rows
+  as ``_apply_wave`` hands it to ``apply_unique`` (unchunked):
+  lane-packed, ``pack`` table rows share one of the operand's, so at most
+  every packed row plus the sentinel's two slots."""
+  operand = rows_cap // pack
+  return (cap if pack == 1 else min(cap, operand + 2)), operand
+
+
+def write_rows_line(group: str, wave: int, operand: int, write: str) -> str:
+  """The dispatch's log line and ``utils/apply_eligibility``'s report:
+  which emitter a group's main wave takes, and the share that decided."""
+  return (f'apply/write_rows: {group} writes {wave:,} rows into '
+          f'{operand:,} ({100.0 * wave / operand:.2f}%): {write}')
 
 
 def _guaranteed_cap(n: int, rows_cap: int) -> int:
@@ -857,8 +887,9 @@ def _apply_wave(optimizer, table, state, uids, sum_g, sum_sq, lr,
   becomes ``n_chunks`` independent pieces the scheduler can interleave
   with the still-arriving chunked gradient exchange.  The compacted
   buffer is rank-ordered (ascending ids, sentinels last), so the tail
-  chunks carry only dropped sentinel rows and every chunk keeps the
-  sorted-indices scatter hint."""
+  chunks carry only dropped sentinel rows and every chunk's ids are
+  ascending (``_write_rows`` picks each chunk's emitter from the
+  chunk's own rows)."""
   if pack > 1:
     uids, sum_g, sum_sq = _lane_pack(uids, sum_g, sum_sq, pack, rows_cap,
                                      exact=exact)
@@ -891,8 +922,9 @@ def _dedup_and_apply(optimizer, table, state, stream: _Stream, lr,
   packed_storage; disable packed_storage on the layer to avoid it.
   Which view serves is ``choose_apply``'s answer.
 
-  Scatter cost is linear in the STATIC update-row count (~110-140 ns/row
-  on v5e whether or not rows are dropped — docs/perf_notes.md), so the
+  Scatter cost follows the STATIC update-row count, whether or not rows
+  are dropped (both of XLA's emitters: ``write_algorithm``,
+  docs/perf_notes.md), so the
   raw per-occurrence stream (batch x hotness x slots rows) is compacted
   first.  Capacity = min(n, rows_cap + 2, capacity_fraction * n): the
   fused table's own row count bounds uniques for small fused groups
@@ -948,8 +980,9 @@ def _dedup_and_apply(optimizer, table, state, stream: _Stream, lr,
   cap = _capacity(optimizer, n, rows_cap, cap_rows)
   with_sq = bool(getattr(optimizer, 'needs_sq', True))
   w = flat_g.shape[1]
-  _, view, pack, _ = choose_apply(optimizer, table, rows_cap, w,
-                                  storage_pack=storage_pack, cap=cap)
+  choice = choose_apply(optimizer, table, rows_cap, w,
+                        storage_pack=storage_pack, cap=cap)
+  view, pack = choice.view, choice.pack
   exact = max_seg is not None
   stored = None
   if view in ('packed_view', 'unpacked'):
@@ -1056,6 +1089,9 @@ class ApplyChoice(NamedTuple):
   pack: int      # table rows in one row of the operand the waves update
   declined: str  # why a segment-walk kernel that was asked for does not
   #                serve the group; '' where it serves or was not asked for
+  write: str = ''  # the emitter the main wave's writes take
+  #                  (``write_algorithm``: 'stream' | 'rows'); '' where the
+  #                  capacity is not known or another kernel writes
 
 
 def choose_apply(optimizer, table, rows_cap: int, width: int, *,
@@ -1070,7 +1106,10 @@ def choose_apply(optimizer, table, rows_cap: int, width: int, *,
   ``table``: the operand's aval (shape and dtype; read only where the
   kernel is asked for).  ``rows_cap`` x ``width``: the group's natural
   rows.  ``cap``: the compaction capacity, where it is known
-  (``_dedup_and_apply``).  ``tied``: the head also reads a table of the
+  (``_dedup_and_apply``, and the dispatch); with it the answer names the
+  emitter the main wave's writes take (``write``: ``write_algorithm``'s
+  own answer, logged at INFO once per trace for the dispatch).
+  ``tied``: the head also reads a table of the
   group.  ``adapted``: the operand is a quantized ``(payload, scale)``
   pair or carries a fetched cold-tier tail.  ``summed_squares``: the
   stream's squares arrive summed (``_Stream.squares``).
@@ -1105,8 +1144,16 @@ def choose_apply(optimizer, table, rows_cap: int, width: int, *,
     view, pack = 'natural', 1
   if tied:
     return ApplyChoice('tied', view, pack, '')
+  write = ''
+  if cap is not None:
+    wave, operand = wave_shape(cap, rows_cap, pack)
+    write = write_algorithm(
+        wave, operand,
+        'set' if isinstance(optimizer, _QuantizedTableOptimizer) else 'add')
+    if group is not None:
+      _LOG.info(write_rows_line(group, wave, operand, write))
   if not getattr(optimizer, 'use_segwalk_apply', False):
-    return ApplyChoice('xla', view, pack, '')
+    return ApplyChoice('xla', view, pack, '', write)
   from distributed_embeddings_tpu.ops import pallas_segwalk
   accum = getattr(optimizer, 'accum_dtype', 'float32')
   on_tpu = jax.default_backend() == 'tpu'
@@ -1137,7 +1184,8 @@ def choose_apply(optimizer, table, rows_cap: int, width: int, *,
     else:
       _LOG.info('use_segwalk_apply: %s takes the segment-walk kernel',
                 group)
-  return ApplyChoice('xla' if declined else 'segwalk', view, pack, declined)
+  return ApplyChoice('xla' if declined else 'segwalk', view, pack, declined,
+                     write if declined else '')
 
 
 @obs_trace.phase('apply/dedup')
@@ -1506,7 +1554,9 @@ def _apply_group(optimizer, table, state, stream: _Stream, lr, group: str,
   choice = choose_apply(
       optimizer, table, stream.rows_cap, stream.rows.shape[1],
       storage_pack=storage_pack, tied=bool(head_grads), adapted=adapted,
-      summed_squares=stream.squares is not None, group=group)
+      summed_squares=stream.squares is not None, group=group,
+      cap=_capacity(optimizer, stream.ids.shape[0], stream.rows_cap,
+                    cap_rows))
   if choice.kernel == 'tied':
     return _tied_apply(optimizer, table, state, stream, lr, head_grads)
   if choice.kernel == 'segwalk':

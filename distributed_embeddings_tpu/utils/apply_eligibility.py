@@ -61,3 +61,37 @@ def segwalk_serves_all_groups(dist, param_dtype,
   the active backend — in which case compaction capacities are dead
   weight (the kernel has none)."""
   return all(_segwalk_groups(dist, param_dtype, accum_dtype, active=None))
+
+
+def write_rows_lines(dist, optimizer, stream_rows=None):
+  """One line per fusion group saying which scatter emitter its main
+  wave's ``apply/write_rows`` takes (``choose_apply``'s ``write``:
+  ``'stream'`` over the whole operand or ``'rows'`` at the update rows),
+  with the rows written (U), the operand's rows (R) and the share.
+
+  ``stream_rows``: per group, the length of its update stream (batch x
+  hotness over its slots on one device); without it the capacity is the
+  optimizer's calibrated ``capacity_rows`` bounded by the shard alone,
+  and a group with neither says so."""
+  from distributed_embeddings_tpu.parallel.sparse import (_capacity,
+                                                          choose_apply,
+                                                          wave_shape,
+                                                          write_rows_line)
+  caps = getattr(optimizer, 'capacity_rows', None) or ()
+  lines = []
+  for gi, g in enumerate(dist.plan.groups):
+    cap_rows = caps[gi] if gi < len(caps) else None
+    n = stream_rows[gi] if stream_rows is not None else None
+    if n is None and cap_rows is None:
+      lines.append(f'apply/write_rows: group_{gi} unknown (no stream '
+                   'length and no calibrated capacity_rows)')
+      continue
+    cap = _capacity(optimizer, g.rows_cap + 2 if n is None else n,
+                    g.rows_cap, cap_rows)
+    choice = choose_apply(
+        optimizer, jax.ShapeDtypeStruct((g.param_rows, g.param_width),
+                                        jnp.float32),
+        g.rows_cap, g.width, storage_pack=g.storage_pack, cap=cap)
+    wave, operand = wave_shape(cap, g.rows_cap, choice.pack)
+    lines.append(write_rows_line(f'group_{gi}', wave, operand, choice.write))
+  return lines

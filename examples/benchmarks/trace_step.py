@@ -78,10 +78,12 @@ def main():
                           capacity_fraction=args.capacity_fraction,
                           capacity_rows=capacity_rows,
                           use_segwalk_apply=args.segwalk_apply)
+  from distributed_embeddings_tpu.utils.apply_eligibility import (
+      eligibility_line, write_rows_lines)
   if args.segwalk_apply:
-    from distributed_embeddings_tpu.utils.apply_eligibility import (
-        eligibility_line)
     print(eligibility_line(dist, args.param_dtype, args.segwalk_apply))
+  else:
+    print('\n'.join(write_rows_lines(dist, emb_opt)))
   step = make_hybrid_train_step(dist, head_loss_fn, opt, emb_opt)
   state = init_hybrid_train_state(dist, params, opt, emb_opt)
   batch = (num0, labels0)

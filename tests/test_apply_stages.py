@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from distributed_embeddings_tpu.analysis import graphlint
 from distributed_embeddings_tpu.ops import pallas_segwalk
 from distributed_embeddings_tpu.parallel import (DistributedEmbedding,
                                                  SparseAdagrad, SparseAdam,
@@ -245,3 +246,230 @@ def test_flat_and_hierarchical_merges_give_bit_equal_row_totals(needs_sq):
     np.testing.assert_array_equal(hier[owner * D + d, hrow], flat[d, real])
     checked += int(np.any(flat[d, real] != 0, axis=1).sum())
   assert checked > 20, 'the streams touched next to no row'
+
+
+# ---- stage 4: the write (ISSUE 30) ------------------------------------------
+# One function scatters compacted unique rows (``sparse._write_rows``) and
+# picks XLA:TPU's scatter emitter from the wave's static shapes
+# (``sparse.write_algorithm``): 'stream' sets ``indices_are_sorted``, 'rows'
+# leaves it off.
+
+SDS = jax.ShapeDtypeStruct
+SCATTERS = {'scatter-add': 'add', 'scatter': 'set'}
+
+
+def _scatters(fn, *args):
+  """``[(op, update rows, operand rows, scope, params)]`` of every scatter
+  ``fn`` traces to, sub-jaxprs (jit, while, shard_map) included."""
+  return [(SCATTERS[e.primitive.name], e.invars[2].aval.shape[0],
+           e.invars[0].aval.shape[0], str(e.source_info.name_stack),
+           e.params)
+          for e, _ in graphlint._walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+          if e.primitive.name in SCATTERS]
+
+
+def _boundary(operand_rows, op):
+  """The largest wave that takes the row emitter into ``operand_rows``,
+  from the module's constants: ``U * (c - b) <= a * R``."""
+  a = sparse._STREAM_NS_PER_OPERAND_ROW
+  b = sparse._STREAM_NS_PER_UPDATE_ROW[op]
+  c = sparse._ROWS_NS_PER_UPDATE_ROW[op]
+  return int(a * operand_rows / (c - b))
+
+
+@pytest.mark.parametrize('op', ['add', 'set'])
+@pytest.mark.parametrize('cell, wave, operand, takes', [
+    ('dlrm-train-4chip', 92_272, 20_025_088, 'rows'),
+    ('tiny-train-zipf', 1_116_536, 8_775_000, 'stream'),
+    ('tiny-train-uniform', 2_883_584, 8_775_000, 'stream'),
+    ('just_under_the_boundary', None, 20_025_088, 'rows'),
+    ('just_over_the_boundary', None, 20_025_088, 'stream'),
+])
+def test_write_rows_takes_the_emitter_the_static_share_says(
+    cell, wave, operand, takes, op):
+  """At the cells' own shapes (abstract: no memory) the one scatter
+  ``_write_rows`` traces carries ``indices_are_sorted`` exactly where
+  ``write_algorithm`` says 'stream', and the answer flips between two
+  neighbouring wave sizes at the module constants' boundary."""
+  if wave is None:
+    wave = _boundary(operand, op) + (takes == 'stream')
+  assert sparse.write_algorithm(wave, operand, op) == takes
+  (got,) = _scatters(
+      lambda t, u, r: sparse._write_rows(t, u, r, op),
+      SDS((operand, 128), jnp.float32), SDS((wave,), jnp.int32),
+      SDS((wave, 128), jnp.float32))
+  assert got[:3] == (op, wave, operand) and 'apply/write_rows' in got[3]
+  assert got[4]['indices_are_sorted'] == (takes == 'stream')
+  assert got[4]['unique_indices']
+  assert got[4]['mode'] == jax.lax.GatherScatterMode.FILL_OR_DROP
+
+
+def _quantized(inner):
+  from distributed_embeddings_tpu.parallel import quantization
+  return sparse._QuantizedTableOptimizer(
+      inner, quantization.resolve_table_dtype('int8'))
+
+
+WRITERS = {
+    'sgd': lambda: SparseSGD(learning_rate=0.5),
+    'adagrad_dedup': lambda: SparseAdagrad(learning_rate=0.5),
+    'adagrad_per_occurrence': lambda: SparseAdagrad(learning_rate=0.5,
+                                                    dedup=False),
+    'adagrad_bf16_acc': lambda: SparseAdagrad(learning_rate=0.5,
+                                              accum_dtype='bfloat16'),
+    'adam': lambda: SparseAdam(learning_rate=0.5),
+    'quantized_adagrad': lambda: _quantized(SparseAdagrad(learning_rate=0.5)),
+}
+
+
+def _toy_wave(name, rng, rows=96, wave=40, real=29, w=16):
+  """One optimizer's operand, state and a compacted wave: ``real``
+  ascending unique rows, then the sentinel repeated."""
+  opt = WRITERS[name]()
+  table = jnp.asarray(rng.normal(size=(rows, w)).astype(np.float32))
+  uids = np.full(wave, rows, np.int32)
+  uids[:real] = np.sort(rng.choice(rows, real, replace=False))
+  sum_g = rng.normal(size=(wave, w)).astype(np.float32)
+  sum_g[real:] = 0.0
+  inner = getattr(opt, 'inner', opt)
+  if isinstance(inner, SparseAdagrad):
+    state = {'acc': jnp.full((rows, w), 0.1, jnp.dtype(inner.accum_dtype))}
+  elif isinstance(inner, SparseAdam):
+    state = {'m': jnp.zeros((rows, w)), 'v': jnp.zeros((rows, w)),
+             't': jnp.zeros((rows,), jnp.int32)}
+  else:
+    state = {}
+  if inner is not opt:
+    from distributed_embeddings_tpu.parallel import quantization
+    table = quantization.quantize_jnp(table, opt.spec)
+  return opt, table, state, jnp.asarray(uids), jnp.asarray(sum_g)
+
+
+@pytest.mark.parametrize('name', list(WRITERS))
+def test_both_emitters_write_the_same_bits(name, monkeypatch):
+  """Rows are unique and each is written once, so the hint changes the
+  emitter and nothing else: table, scale and every state leaf are the
+  same bits either way, and the sentinel tail wrote nothing."""
+  opt, table, state, uids, sum_g = _toy_wave(name, np.random.default_rng(7))
+  sum_sq = sum_g * sum_g if getattr(opt, 'needs_sq', False) else None
+
+  def run(takes):
+    monkeypatch.setattr(sparse, 'write_algorithm', lambda *a: takes)
+    seen = _scatters(opt.apply_unique, table, state, uids, sum_g, sum_sq,
+                     0.5)
+    assert seen and all(
+        s[4]['indices_are_sorted'] == (takes == 'stream') for s in seen)
+    return jax.tree.leaves(jax.jit(opt.apply_unique)(
+        table, state, uids, sum_g, sum_sq, 0.5))
+
+  stream, rows = run('stream'), run('rows')
+  assert len(stream) == len(jax.tree.leaves((table, state)))
+  for a, b in zip(stream, rows):
+    assert a.dtype == b.dtype
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+  before = np.asarray(jax.tree.leaves(table)[0])
+  touched = np.any(np.asarray(stream[0]) != before, axis=1)
+  np.testing.assert_array_equal(np.flatnonzero(touched),
+                                np.asarray(uids)[:29])
+
+
+@pytest.mark.parametrize('rows_win', [False, True])
+@pytest.mark.parametrize('name', ['sgd', 'adagrad_dedup', 'adam',
+                                  'quantized_adagrad'])
+def test_every_apply_scatter_of_a_train_step_comes_through_write_rows(
+    name, rows_win, monkeypatch):
+  """A jaxpr walk over one whole train step: every scatter under an
+  ``apply/`` phase is ``_write_rows``'s (scope ``apply/write_rows``,
+  ``unique_indices``, mode drop, the hint ``write_algorithm`` gives for
+  its own shapes).  ``rows_win`` turns the constants so that the row
+  emitter wins at every toy shape: a site that hard-codes the hint
+  would then stand out."""
+  import optax
+  from distributed_embeddings_tpu.parallel import (init_hybrid_train_state,
+                                                   make_hybrid_train_step)
+  if rows_win:
+    monkeypatch.setattr(sparse, '_ROWS_NS_PER_UPDATE_ROW',
+                        {'add': 0.0, 'set': 0.0})
+  opt = getattr(WRITERS[name](), 'inner', WRITERS[name]())
+  kw = {'table_dtype': 'int8'} if name.startswith('quantized') else {}
+  mesh = create_mesh(jax.devices()[:WORLD])
+  cfgs = [TableConfig(200, 16, 'sum'), TableConfig(90, 16, 'sum'),
+          TableConfig(300, 128, 'sum'), TableConfig(64, 128, 'mean')]
+  dist = DistributedEmbedding(cfgs, mesh=mesh, **kw)
+  dense = {'kernel': jnp.zeros((sum(c.output_dim for c in cfgs), 1))}
+
+  def head(dp, eo, b):
+    return jnp.mean((jnp.concatenate(list(eo), axis=-1) @ dp['kernel'] - b)
+                    **2)
+
+  step = make_hybrid_train_step(dist, head, optax.sgd(0.1), opt, jit=False)
+  state = init_hybrid_train_state(dist, {'embedding': dist.init(0), **dense},
+                                  optax.sgd(0.1), opt)
+  rng = np.random.default_rng(3)
+  cats = [jnp.asarray(rng.integers(0, c.input_dim, (32, 2)).astype(np.int32))
+          for c in cfgs]
+  seen = [s for s in _scatters(step, state, cats, jnp.zeros((32, 1)))
+          if 'apply/' in s[3]]
+  writes = {'sgd': 1, 'adagrad_dedup': 2, 'adam': 4, 'quantized_adagrad': 3}
+  assert len(seen) >= writes[name] * len(dist.plan.groups), seen
+  for op, wave, operand, scope, params in seen:
+    assert 'apply/write_rows' in scope, scope
+    assert params['unique_indices'], scope
+    assert params['mode'] == jax.lax.GatherScatterMode.FILL_OR_DROP
+    takes = sparse.write_algorithm(wave, operand, op)
+    assert takes == ('rows' if rows_win else takes)
+    assert params['indices_are_sorted'] == (takes == 'stream'), (
+        scope, op, wave, operand)
+
+
+@pytest.mark.parametrize('name', list(LAYERS))
+def test_choose_apply_names_the_emitter_the_main_wave_takes(name):
+  """``ApplyChoice.write`` against the program: the table scatter that
+  ``_dedup_and_apply`` traces for the group (abstract operands: the two
+  huge layers cost no memory) carries the hint ``choose_apply`` named,
+  and the per-group report prints the same word with the wave, the
+  operand and the share."""
+  from distributed_embeddings_tpu.utils.apply_eligibility import (
+      write_rows_lines)
+  rows, width, kw, accum, *_ = LAYERS[name]
+  mesh = create_mesh(jax.devices()[:WORLD])
+  cfgs = [TableConfig(rows, width, 'sum')] + [
+      TableConfig(64, width, 'sum') for _ in range(WORLD - 1)]
+  dist = DistributedEmbedding(cfgs, mesh=mesh,
+                              column_slice_threshold=1 << 40, **kw)
+  (g,) = dist.plan.groups
+  n = 4096
+  opt = SparseAdagrad(accum_dtype=accum, capacity_fraction=1.0)
+  dtype = jnp.dtype(kw.get('param_dtype', jnp.float32))
+  shape = (g.param_rows, g.param_width)
+  table, op, apply_opt = SDS(shape, dtype), 'add', opt
+  if dist.quant is not None:
+    table = (SDS(shape, jnp.int8), SDS((shape[0], 1), jnp.float32))
+    op, apply_opt = 'set', sparse._QuantizedTableOptimizer(opt, dist.quant)
+  cap = sparse._capacity(opt, n, g.rows_cap, None)
+  choice = sparse.choose_apply(apply_opt, table, g.rows_cap, g.width,
+                               storage_pack=g.storage_pack, cap=cap,
+                               adapted=dist.quant is not None)
+  wave, operand = sparse.wave_shape(cap, g.rows_cap, choice.pack)
+  assert choice.write == sparse.write_algorithm(wave, operand, op)
+  assert choice.write == ('rows' if rows == BIG else 'stream')
+
+  def run(table, acc, ids, grads):
+    return sparse._dedup_and_apply(
+        apply_opt, table, {'acc': acc},
+        sparse._Stream(ids, grads, g.rows_cap), 0.1,
+        storage_pack=g.storage_pack)
+
+  seen = _scatters(run, table, SDS(shape, jnp.dtype(accum)),
+                   SDS((n,), jnp.int32), SDS((n, g.width), jnp.float32))
+  main = [s for s in seen if s[0] == op and s[2] == operand
+          and s[1] == wave]
+  assert main, seen
+  assert all(s[4]['indices_are_sorted'] == (choice.write == 'stream')
+             for s in main)
+  if dist.quant is None:
+    (line,) = write_rows_lines(dist, opt, stream_rows=[n])
+    assert line == (f'apply/write_rows: group_0 writes {wave:,} rows into '
+                    f'{operand:,} ({100.0 * wave / operand:.2f}%): '
+                    f'{choice.write}'), line
+    assert 'unknown' in write_rows_lines(dist, opt)[0]

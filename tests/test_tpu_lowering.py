@@ -11,11 +11,14 @@ hardware-gated in tests/test_pallas_tpu.py.
 
 Covers every kernel configuration AND the full 4-chip hybrid train
 step (flat and two-axis meshes) compiled for v5e 2x2, and holds the
-default XLA apply's compiled step to no whole-shard copy (ISSUE 25).
+default XLA apply's compiled step to no whole-shard copy (ISSUE 25) and
+its row writes to the scatter emitter their share of the shard calls
+for (ISSUE 30, two steps at the benchmark cells' own shapes).
 
 Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
-spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 51 cases pass
-in about 40 s on 8 host cores.  This is the free gate to run
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 53 cases pass
+in about 135 s on 8 host cores (90 s of it the two full-size steps).
+This is the free gate to run
 (``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
 """
 
@@ -269,6 +272,86 @@ def test_overflow_correction_copies_no_shard_for_v5e(v5e, rows, width,
       r'%?\S+ = (\S+) copy\([^)]*\)', hlo)
             if m.group(1).split('{')[0] in shards]
   assert not copies, copies
+
+
+def _write_rows_scoped_vmem(hlo):
+  """Scoped VMEM bytes each scatter fusion under ``apply/write_rows``
+  asks for in a compiled v5e program (``used_scoped_memory_configs``).
+  XLA:TPU's streaming scatter emitter stages 15 to 16 MiB of the operand
+  through VMEM, its row emitter 136 KiB (ISSUE 30)."""
+  import re
+  sizes = []
+  for line in hlo.splitlines():
+    if (' fusion(' in line and 'apply/write_rows' in line
+        and re.search(r'op_name="[^"]*scatter', line)):
+      m = re.search(r'used_scoped_memory_configs":\[[^\]]*"size":"(\d+)"',
+                    line)
+      if m:  # (a fusion that only wraps the emitter's carries none)
+        sizes.append(int(m.group(1)))
+  return sizes
+
+
+@pytest.mark.parametrize(
+    'cell, chips, tables, width, batch, hot, cap, opt, shard, takes', [
+        # dlrm-train-4chip's busiest chip: two 10 M-row tables of 512 B
+        # rows, SGD, the calibrated 92,272 rows a wave (0.46%)
+        ('dlrm-train-4chip', 4, [10_012_544] * 8, 128, 65536, 1, 92_272,
+         'sgd', (20_025_088, 128), 'rows'),
+        # tiny-train-zipf's width-16 group, stored lane-packed: 1,116,536
+        # rows into 8,775,000 (12.7%), table and accumulator
+        ('tiny-train-zipf', 1, [70_200_000], 16, 65536, 44, 1_116_536,
+         'adagrad', (8_775_000, 128), 'stream'),
+    ])
+def test_write_rows_emitter_follows_the_share_for_v5e(
+    v5e, cell, chips, tables, width, batch, hot, cap, opt, shard, takes):
+  """ISSUE 30: in the whole compiled step at the cells' own shapes the
+  ``apply/write_rows`` scatters take the emitter ``write_algorithm``
+  names: under 1 MiB of scoped VMEM where the wave is a sliver of the
+  shard (the streaming emitter read and rewrote all 9.55 GiB to change
+  0.46% of it: 51% of dlrm-train-4chip's step), over 8 MiB where the
+  wave is dense.  Neither step writes a result over two views of one
+  buffer (PR 28's hazard)."""
+  import optax
+  from jax.sharding import Mesh
+  from distributed_embeddings_tpu.parallel import (DistributedEmbedding,
+                                                   SparseAdagrad, SparseSGD,
+                                                   TableConfig, TrainState,
+                                                   make_hybrid_train_step,
+                                                   sparse)
+  mesh = Mesh(np.asarray(v5e.devices).ravel()[:chips], ('data',))
+  configs = [TableConfig(rows, width, 'sum') for rows in tables]
+  dist = DistributedEmbedding(configs, mesh=mesh)
+  (g,) = dist.plan.groups
+  assert (g.param_rows, g.param_width) == shard
+  emb_opt = (SparseSGD if opt == 'sgd' else SparseAdagrad)(
+      learning_rate=0.01, capacity_rows=(cap,))
+  dense_opt = optax.sgd(0.01)
+
+  def head(dp, eo, b):
+    h = jnp.concatenate(list(eo), axis=-1)
+    return jnp.mean((h @ dp['kernel'] - b)**2)
+
+  step = make_hybrid_train_step(dist, head, dense_opt, emb_opt,
+                                donate=False, jit=False)
+  state, _, labels = _step_avals(dist, mesh, configs, batch, dense_opt)
+  if opt == 'sgd':
+    state = TrainState(params=state.params,
+                       opt_state=(state.opt_state[0], {'group_0': {}}),
+                       step=state.step)
+  cats = [_sds((batch, hot), jnp.int32, labels.sharding) for _ in configs]
+  hlo = jax.jit(step, donate_argnums=(0,)).lower(state, cats,
+                                                 labels).compile().as_text()
+  wave, operand = sparse.wave_shape(cap, g.rows_cap, g.storage_pack)
+  assert (wave, operand) == (cap, shard[0])
+  assert sparse.write_algorithm(wave, operand) == takes
+  sizes = _write_rows_scoped_vmem(hlo)
+  # the main wave's and the overflow correction's (its wave, the
+  # guaranteed capacity, lies on the same side of the rule in both
+  # cells), one a leaf the optimizer writes
+  assert len(sizes) == 2 * (1 if opt == 'sgd' else 2), sizes
+  assert all((s < 1 << 20) if takes == 'rows' else (s > 8 << 20)
+             for s in sizes), sizes
+  assert not _written_over_two_views(hlo)
 
 
 @pytest.mark.parametrize('op', ['sgd', 'adagrad_sq'])
