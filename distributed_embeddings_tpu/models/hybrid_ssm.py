@@ -292,16 +292,70 @@ def mamba_mixer(cfg: HybridSSMConfig, p, u, segment_ids):
     return jnp.matmul(y, p['out_proj'])
 
 
-def _attend(scale, q, k, v, seg_q, seg_k, first):
+def _attend(scale, q, k, v, seg_q, seg_k, first, key_first=None,
+            window=None):
   """Queries ``q [S, Bq, Hkv, G, D]`` at positions ``first..`` against
-  keys ``k``, ``v`` ``[S, Bk, Hkv, D]`` at positions ``0..``: causal,
-  within the document."""
+  keys ``k``, ``v`` ``[S, Bk, Hkv, D]`` at positions ``key_first..`` (0
+  where none is given):
+  causal, within the document, and with a ``window`` only the keys
+  fewer than ``window`` positions back."""
   s = jnp.einsum('sqhgd,skhd->shgqk', q, k) * scale
   pos_q = first + jnp.arange(q.shape[1])
+  pos_k = jnp.arange(k.shape[1])
+  if key_first is not None:
+    pos_k = key_first + pos_k
   mask = ((seg_q[:, :, None] == seg_k[:, None, :])
-          & (pos_q[:, None] >= jnp.arange(k.shape[1])[None, :]))
+          & (pos_q[:, None] >= pos_k[None, :]))
+  if window is not None:
+    mask = mask & (pos_q[:, None] - pos_k[None, :] < window)
   s = jnp.where(mask[:, None, None], s, -jnp.inf)
   return jnp.einsum('shgqk,skhd->sqhgd', jax.nn.softmax(s, axis=-1), v)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 8))
+def _attend_window(scale, q, k, v, seg_q, seg_k, first, key_first, window):
+  """``_attend`` under ``jax.checkpoint`` with the positions as run-time
+  scalars, so that blocks of one shape are ONE traced function: JAX
+  traces, differentiates and lowers it once and calls it a block (XLA
+  inlines the calls: the compiled step is what unrolled tracing gives).
+  A windowed layer's blocks past its first window all have one shape."""
+  return jax.checkpoint(_attend, static_argnums=(0, 8))(
+      scale, q, k, v, seg_q, seg_k, first, key_first, window)
+
+
+def blocked_attention(scale, q, k, v, segment_ids, block_limit, window=None):
+  """Causal document-masked grouped-query attention, one block of
+  queries at a time: ``q [S, L, Hkv, G, D]``, ``k``, ``v`` ``[S, L, Hkv,
+  D]`` -> ``[S, L, Hkv, G, D]``.  Each block runs under
+  ``jax.checkpoint`` against the keys up to its own end, and with a
+  ``window`` from the block boundary at or before its first query's
+  oldest key: only the key blocks that meet the window are computed, and
+  no ``[heads, L, L]`` array exists.  (The windowed blocks past the
+  first window all have one shape.  Three ways of making them one body
+  of the compiled step were tried at 8,192 positions under a window of
+  2,048, compile-only for a v5e: under ``lax.map``, as a ``lax.scan``
+  that writes into the result it carries, and with the later windows
+  side by side as a batch.  Each held 1.2 to 1.7 GiB more than the
+  unrolled blocks and put the mixture-of-experts cell past the chip's
+  memory, for a tenth to a fifth of the compile time.  So they stay
+  unrolled in the compiled step, and are one function to JAX.)"""
+  length = q.shape[1]
+  block = _block(length, block_limit)
+  if window is None:
+    attend = jax.checkpoint(functools.partial(_attend, scale),
+                            static_argnums=(5,))
+    return jnp.concatenate(
+        [attend(q[:, i:i + block], k[:, :i + block], v[:, :i + block],
+                segment_ids[:, i:i + block], segment_ids[:, :i + block], i)
+         for i in range(0, length, block)], axis=1)
+  out = []
+  for i in range(0, length, block):
+    lo = max(0, (i - window + 1) // block * block)
+    out.append(_attend_window(
+        scale, q[:, i:i + block], k[:, lo:i + block], v[:, lo:i + block],
+        segment_ids[:, i:i + block], segment_ids[:, lo:i + block],
+        jnp.int32(i), jnp.int32(lo), window))
+  return jnp.concatenate(out, axis=1)
 
 
 def attention_mixer(cfg: HybridSSMConfig, p, u, segment_ids):
@@ -314,14 +368,8 @@ def attention_mixer(cfg: HybridSSMConfig, p, u, segment_ids):
     q = jnp.matmul(u, p['q_proj']).reshape(seqs, length, kv_heads, group, d)
     k = jnp.matmul(u, p['k_proj']).reshape(seqs, length, kv_heads, d)
     v = jnp.matmul(u, p['v_proj']).reshape(seqs, length, kv_heads, d)
-    attend = jax.checkpoint(
-        functools.partial(_attend, cfg.attention_multiplier),
-        static_argnums=(5,))
-    block = _block(length, cfg.attention_block)
-    out = [attend(q[:, i:i + block], k[:, :i + block], v[:, :i + block],
-                  segment_ids[:, i:i + block], segment_ids[:, :i + block], i)
-           for i in range(0, length, block)]
-    out = jnp.concatenate(out, axis=1).reshape(seqs, length, -1)
+    out = blocked_attention(cfg.attention_multiplier, q, k, v, segment_ids,
+                            cfg.attention_block).reshape(seqs, length, -1)
     return jnp.matmul(out, p['o_proj'])
 
 

@@ -1,0 +1,335 @@
+"""Sparse mixture-of-experts language model over an UNTIED vocabulary:
+windowed and full gated attention, a routed feed-forward layer of which
+this chip holds a share, four norms a layer.
+
+A stack of residual blocks given by ``layer_types``
+(``'sliding_attention'`` or ``'full_attention'``): gated grouped-query
+attention, then a feed-forward that is a dense SwiGLU in the first
+``num_dense_layers`` blocks and, in the others, a shared-expert SwiGLU
+beside the routed experts held here (``layers/routed_experts.py``).  The
+token table is ONE table behind ``DistributedEmbedding``
+(``combiner=None``, one id per position) that only the lookup reads: it
+takes the sparse apply's default path under ``SparseAdam``
+(``make_hybrid_train_step(..., head_reads_tables=())``), and the output
+head ``lm_head [hidden, vocabulary]`` is a dense leaf of its own.
+
+Equations (a published sigmoid-routed family with gated attention; keys
+as in its ``config.json``)::
+
+  x0      = row * sqrt(hidden_size)                       (mup_enabled)
+  h       = x + rmsnorm(attn(rmsnorm(x)))                 a norm before AND
+  x'      = h + rmsnorm(ffn(rmsnorm(h)))                  after each sub-layer
+  logits  = rmsnorm(x_last) @ lm_head
+
+  attn(u): q = u Wq [Hq x D]; k = u Wk, v = u Wv [Hkv x D]; g = u Wg [Hq x D]
+           q = rmsnorm_D(q), k = rmsnorm_D(k)             per head, learned gain
+           sliding_attention: rotary(q, k; rope_theta, the whole head,
+                              the position in the sequence); full_attention:
+                              no positional embedding
+           p_ij = softmax_j(q_i . k_j / sqrt(D)) over j <= i of i's document,
+                  and i - j < sliding_window on a sliding_attention layer
+           out  = ((p v) * sigmoid(g)) Wo
+
+  ffn, dense layers:  swiglu(u) = (silu(u Wgate) * (u Wup)) Wdown
+  ffn, routed layers: swiglu_shared(u) + the held experts' part of
+                      sum_{e in top_k} w_e swiglu_e(u)    (routed_experts.py)
+
+PACKED DOCUMENTS ARE INDEPENDENT (``segment_ids``), as in
+``models/hybrid_ssm.py``, whose ``rms_norm``, ``swiglu``,
+``blocked_attention`` and ``vocab_loss`` this stack shares (and its
+recomputation, here a half-layer at a time: ``layer``): a ``sliding_attention`` layer computes only the key
+blocks that meet a query block's window.  The router's selection bias
+(``expert_bias``) is a leaf that stays where it was initialised: the
+published trainer moves it by a rule outside forward and backward, which
+``make_hybrid_train_step`` has no place for, and its gradient is exactly
+nought.
+
+Device phases (inside ``head``): ``attention/window``, ``attention/full``
+(both under ``attention``), ``mlp`` (the dense SwiGLU), ``moe/route``,
+``moe/dispatch``, ``moe/experts``, ``moe/combine``, ``moe/shared``,
+``vocab``.  Gauges, set outside the step by ``record_routing_stats``:
+``moe.assignments_held``, ``moe.load_max_over_mean``,
+``moe.overflow_rows``.
+"""
+
+from __future__ import annotations
+
+import concurrent.futures
+import dataclasses
+import functools
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from distributed_embeddings_tpu.layers import routed_experts as routed
+from distributed_embeddings_tpu.models.hybrid_ssm import (
+    blocked_attention, count_batch, rms_norm, swiglu, vocab_loss)
+from distributed_embeddings_tpu.obs import metrics as obs_metrics
+from distributed_embeddings_tpu.obs import trace as obs_trace
+
+__all__ = ['MoELMConfig', 'init_params', 'count_batch', 'forward',
+           'make_head_loss_fn', 'selections', 'routing_stats',
+           'record_routing_stats']
+
+_KINDS = ('sliding_attention', 'full_attention')
+
+
+@dataclasses.dataclass(frozen=True)
+class MoELMConfig:
+  """Sizes of the stack, named as the published configuration names
+  them.  ``num_experts`` counts the experts HELD here, ``first_expert ..``
+  of the ``router_width`` the router scores; ``vocab_size`` the rows of
+  the token table and the columns of ``lm_head``."""
+  hidden_size: int
+  vocab_size: int
+  layer_types: Tuple[str, ...]
+  num_dense_layers: int
+  intermediate_size: int
+  moe_intermediate_size: int
+  num_experts: int
+  router_width: int
+  num_experts_per_tok: int
+  num_attention_heads: int
+  num_key_value_heads: int
+  head_dim: int
+  sliding_window: int
+  rope_theta: float = 10000.0
+  route_scale: float = 1.0
+  first_expert: int = 0
+  capacity_factor: float = 1.25
+  rms_norm_eps: float = 1e-5
+  mup_enabled: bool = True
+  attention_block: int = 256
+  vocab_block: int = 2048
+  logits_scaling: float = 1.0       # ``vocab_loss`` divides by it
+
+  @classmethod
+  def from_dict(cls, config: Dict[str, Any], **overrides):
+    """From a ``config.json`` of the family; what this class does not
+    compute is refused by name.  Where the file is a chip's share of a
+    deployment, ``num_experts`` is the count held and the router keeps
+    the width the file states under ``published``."""
+    refused = {'score_func': 'sigmoid', 'route_norm': True, 'n_group': 1,
+               'topk_group': 1, 'num_shared_experts': 1,
+               'hidden_act': 'silu', 'rope_scaling': None,
+               'tie_word_embeddings': False}
+    for key, only in refused.items():
+      if config.get(key, only) != only:
+        raise NotImplementedError(
+            f'moe_lm: {key}={config[key]!r} (only {only!r})')
+    unknown = set(config['layer_types']) - set(_KINDS)
+    if unknown:
+      raise NotImplementedError(f'moe_lm: layer types {sorted(unknown)}')
+    fields = {f.name for f in dataclasses.fields(cls)}
+    picked = {k: v for k, v in config.items() if k in fields}
+    picked['layer_types'] = tuple(config['layer_types'])
+    picked['router_width'] = int(config.get('published', {}).get(
+        'num_experts', config['num_experts']))
+    return cls(**{**picked, **overrides})
+
+  @property
+  def routed(self) -> routed.RoutedExpertsConfig:
+    return routed.RoutedExpertsConfig(
+        router_width=self.router_width,
+        experts_per_token=self.num_experts_per_tok,
+        num_held=self.num_experts, first_expert=self.first_expert,
+        route_scale=self.route_scale, capacity_factor=self.capacity_factor)
+
+
+def init_params(cfg: MoELMConfig, seed: int):
+  """The dense parameters as host numpy: kernels ``N(0, 1/fan_in)`` (an
+  expert's from its own fan-in), norm gains 1, ``expert_bias`` 0.  Every
+  kernel is drawn from a stream of its own, ``[seed, 5, its number]``,
+  on a few threads.  (The user's entry, as ``hybrid_ssm.init_params``.
+  The benchmark draws the same numbers by a routine of its own, because
+  its reference may import nothing of the program;
+  ``tests/test_moe_lm.py`` holds the two equal.)"""
+  d, heads = cfg.hidden_size, cfg.num_attention_heads * cfg.head_dim
+  kv = cfg.num_key_value_heads * cfg.head_dim
+  ffn, held = cfg.moe_intermediate_size, cfg.num_experts
+  kernels = []
+
+  def kernel(*shape):
+    kernels.append(np.empty(shape, np.float32))
+    return kernels[-1]
+
+  def draw(i):
+    out = kernels[i]
+    np.random.default_rng([int(seed), 5, i]).standard_normal(
+        out.shape, np.float32, out=out)
+    out /= np.float32(np.sqrt(out.shape[-2]))
+
+  ones = lambda n: np.ones(n, np.float32)
+  layers = []
+  for i, _ in enumerate(cfg.layer_types):
+    p = {'input_norm': ones(d), 'post_attn_norm': ones(d),
+         'pre_mlp_norm': ones(d), 'post_mlp_norm': ones(d),
+         'attention': {
+             'q_proj': kernel(d, heads), 'k_proj': kernel(d, kv),
+             'v_proj': kernel(d, kv), 'gate_proj': kernel(d, heads),
+             'o_proj': kernel(heads, d), 'q_norm': ones(cfg.head_dim),
+             'k_norm': ones(cfg.head_dim)}}
+    if i < cfg.num_dense_layers:
+      p['mlp_in'] = kernel(d, 2 * cfg.intermediate_size)
+      p['mlp_out'] = kernel(cfg.intermediate_size, d)
+    else:
+      p['moe'] = {
+          'router': kernel(d, cfg.router_width),
+          'expert_bias': np.zeros(cfg.router_width, np.float32),
+          'shared': {'mlp_in': kernel(d, 2 * ffn),
+                     'mlp_out': kernel(ffn, d)},
+          'experts_in': kernel(held, d, 2 * ffn),
+          'experts_out': kernel(held, ffn, d)}
+    layers.append(p)
+  lm_head = kernel(d, cfg.vocab_size)
+  with concurrent.futures.ThreadPoolExecutor(max_workers=8) as pool:
+    list(pool.map(draw, range(len(kernels))))
+  return {'layers': layers, 'final_norm': ones(d), 'lm_head': lm_head}
+
+
+def rotary(x, theta: float):
+  """Rotary position embedding over the whole head: ``x [S, L, ..., D]``
+  at positions ``0 .. L - 1`` of the sequence, the head's two halves
+  rotated against each other (``x1 cos - x2 sin | x2 cos + x1 sin``)."""
+  length, d = x.shape[1], x.shape[-1]
+  inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+  angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq
+  shape = (1, length) + (1,) * (x.ndim - 3) + (d // 2,)
+  cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+  x1, x2 = jnp.split(x, 2, axis=-1)
+  return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def attention(cfg: MoELMConfig, kind: str, p, u, segment_ids):
+  """Gated grouped-query attention on ``u [S, L, hidden]``: per-head
+  norms of queries and keys, rotary and a window on a
+  ``sliding_attention`` layer, neither on a ``full_attention`` one."""
+  sliding = kind == 'sliding_attention'
+  scope = (obs_trace.phase('attention/window') if sliding
+           else obs_trace.phase('attention/full'))
+  with scope:
+    seqs, length, _ = u.shape
+    kv_heads, d = cfg.num_key_value_heads, cfg.head_dim
+    group = cfg.num_attention_heads // kv_heads
+    q = jnp.matmul(u, p['q_proj']).reshape(seqs, length, kv_heads, group, d)
+    k = jnp.matmul(u, p['k_proj']).reshape(seqs, length, kv_heads, d)
+    v = jnp.matmul(u, p['v_proj']).reshape(seqs, length, kv_heads, d)
+    gate = jnp.matmul(u, p['gate_proj'])
+    q = rms_norm(q, p['q_norm'], cfg.rms_norm_eps)
+    k = rms_norm(k, p['k_norm'], cfg.rms_norm_eps)
+    if sliding:
+      q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+    out = blocked_attention(
+        d ** -0.5, q, k, v, segment_ids, cfg.attention_block,
+        window=cfg.sliding_window if sliding else None)
+    out = out.reshape(seqs, length, -1) * jax.nn.sigmoid(gate)
+    return jnp.matmul(out, p['o_proj'])
+
+
+def routed_ffn(cfg: MoELMConfig, p, u):
+  """``(y, sel)``: the shared expert plus the held experts' part, on ``u
+  [S, L, hidden]``, and the experts each token took ``[S * L, k]``."""
+  with obs_trace.phase('moe/shared'):
+    shared = swiglu(p['shared'], u)
+  y, sel = routed.routed_experts(cfg.routed, p, u.reshape(-1, u.shape[-1]))
+  return shared + y.reshape(u.shape), sel
+
+
+def layer(cfg: MoELMConfig, kind: str, p, x, segment_ids):
+  """One residual block: ``(x', sel)``, ``sel`` the experts each token
+  took in a routed block and ``None`` in a dense one.  Attention and
+  feed-forward are rematerialised APART: the backward pass keeps the
+  block's input and the state between the two, and recomputes one half
+  at a time.  (Under one
+  ``jax.checkpoint`` around the whole block, as ``hybrid_ssm.forward``
+  has it, the attention blocks and the waves, which rematerialise
+  themselves, kept their buffers alive across the block's recomputation:
+  0.7 GiB more at the published sizes, compile-only for a v5e.)"""
+  eps = cfg.rms_norm_eps
+
+  @jax.checkpoint
+  def mixer(p, x):
+    return rms_norm(attention(cfg, kind, p['attention'],
+                              rms_norm(x, p['input_norm'], eps),
+                              segment_ids), p['post_attn_norm'], eps)
+
+  @jax.checkpoint
+  def feed_forward(p, x):
+    u = rms_norm(x, p['pre_mlp_norm'], eps)
+    ffn, sel = (routed_ffn(cfg, p['moe'], u) if 'moe' in p
+                else (swiglu(p, u), None))
+    return rms_norm(ffn, p['post_mlp_norm'], eps), sel
+
+  x = x + mixer(p, x)
+  ffn, sel = feed_forward(p, x)
+  return x + ffn, sel
+
+
+def _embed(cfg: MoELMConfig, rows):
+  return rows * (cfg.hidden_size ** 0.5) if cfg.mup_enabled else rows
+
+
+def _stack(cfg: MoELMConfig, dense, rows, segment_ids):
+  """The blocks in turn: the last hidden states and every routed
+  block's selection."""
+  x, chosen = _embed(cfg, rows), []
+  for kind, p in zip(cfg.layer_types, dense['layers']):
+    x, sel = layer(cfg, kind, p, x, segment_ids)
+    if sel is not None:
+      chosen.append(sel)
+  return x, chosen
+
+
+def forward(cfg: MoELMConfig, dense, rows, segment_ids):
+  """The stack's last hidden states from the looked-up rows ``[S, L,
+  hidden]``, each half of a layer under ``jax.checkpoint`` (``layer``)."""
+  return _stack(cfg, dense, rows, segment_ids)[0]
+
+
+def make_head_loss_fn(cfg: MoELMConfig):
+  """``head_loss_fn(dense, emb_outs, batch)`` for
+  ``make_hybrid_train_step`` with the default ``head_reads_tables=()``:
+  ``emb_outs[0]`` the looked-up rows ``[S * L, hidden]``, ``batch =
+  (targets, segment_ids)`` both ``[S, L]``, ``dense['lm_head']`` the
+  output head ``[hidden, vocabulary]``."""
+
+  def head_loss_fn(dense, emb_outs, batch):
+    targets, segment_ids = batch
+    x = forward(cfg, dense, emb_outs[0].reshape(
+        targets.shape + (cfg.hidden_size,)), segment_ids)
+    return vocab_loss(cfg, x, dense['final_norm'], dense['lm_head'].T,
+                      targets)
+
+  return head_loss_fn
+
+
+def selections(cfg: MoELMConfig, dense, rows, segment_ids):
+  """Forward only: the experts every token takes in each routed layer,
+  ``[routed layers, S * L, experts a token]``, as the step's own blocks
+  choose them."""
+  return jnp.stack(_stack(cfg, dense, rows, segment_ids)[1])
+
+
+def routing_stats(cfg: MoELMConfig, dense, rows, segment_ids):
+  """Forward only: per routed layer (arrays ``[routed layers]``) the
+  assignments this chip holds, the largest held expert's load over the
+  mean and the assignments past the capacity
+  (``routed_experts.routing_stats``).  Jit it and call it every N steps,
+  outside the timed step; ``record_routing_stats`` sets the gauges."""
+  return jax.vmap(functools.partial(routed.routing_stats, cfg.routed))(
+      selections(cfg, dense, rows, segment_ids))
+
+
+def record_routing_stats(stats):
+  """The gauges ``moe.assignments_held`` (summed over the routed
+  layers), ``moe.load_max_over_mean`` (the worst layer) and
+  ``moe.overflow_rows`` (summed) from ``routing_stats``' arrays."""
+  stats = {k: np.asarray(v) for k, v in stats.items()}
+  obs_metrics.set_gauge('moe.assignments_held',
+                        float(stats['assignments_held'].sum()))
+  obs_metrics.set_gauge('moe.load_max_over_mean',
+                        float(stats['load_max_over_mean'].max()))
+  obs_metrics.set_gauge('moe.overflow_rows',
+                        float(stats['overflow_rows'].sum()))

@@ -54,6 +54,10 @@ METRIC_TYPES: Dict[str, str] = {
     'train.tokens': 'counter',
     'train.documents': 'counter',
     'train.loss_positions': 'counter',
+    # a routed layer's load (models/moe_lm.record_routing_stats)
+    'moe.assignments_held': 'gauge',
+    'moe.load_max_over_mean': 'gauge',
+    'moe.overflow_rows': 'gauge',
     # host CSR feed (parallel/csr_feed.py)
     'feed.batches': 'counter',
     'feed.skipped': 'counter',

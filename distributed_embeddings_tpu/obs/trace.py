@@ -147,6 +147,18 @@ REGISTERED_PHASES: Dict[str, str] = {
     'attention': 'dense head',
     'mlp': 'dense head',
     'vocab': 'dense head',
+    # inside ``head``, a mixture-of-experts stack (models/moe_lm.py): its
+    # two kinds of attention (both under ``attention``), and the routed
+    # layer (layers/routed_experts.py): router product, top-k and
+    # weights; keys, sort and the gather into the buffer; the grouped
+    # products; weighting and the sum back to tokens; the shared expert
+    'attention/window': 'dense head',
+    'attention/full': 'dense head',
+    'moe/route': 'routed experts',
+    'moe/dispatch': 'routed experts',
+    'moe/experts': 'routed experts',
+    'moe/combine': 'routed experts',
+    'moe/shared': 'routed experts',
     # the sparse optimizer step, each under a child scope per group:
     # the update stream's assembly and cross-slice merge ...
     'apply/stream': 'sparse apply',

@@ -16,7 +16,7 @@ its row writes to the scatter emitter their share of the shard calls
 for (ISSUE 30, two steps at the benchmark cells' own shapes).
 
 Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
-spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 53 cases pass
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 54 cases pass
 in about 135 s on 8 host cores (90 s of it the two full-size steps).
 This is the free gate to run
 (``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
@@ -35,6 +35,7 @@ from distributed_embeddings_tpu.ops import pallas_lookup, pallas_segwalk
 
 
 import os
+import re
 
 
 @pytest.fixture(scope='module')
@@ -392,3 +393,55 @@ def test_segwalk_bf16_accumulator_compiles_for_v5e(v5e, op, w):
   _compile_single(v5e, fn, ((rows, w), jnp.bfloat16),
                   ((rows, w), jnp.bfloat16), ((n,), jnp.int32),
                   ((n, w), jnp.float32))
+
+
+def test_routed_experts_compile_for_v5e_with_the_kernel_and_no_stacked_wave(
+    v5e):
+  """The routed layer (layers/routed_experts.py) at the
+  mixture-of-experts cell's own shapes, value and gradient under
+  ``jax.checkpoint``: XLA lowers ``ragged_dot`` to a Mosaic kernel of
+  its own on a TPU (``ragged-dot`` custom calls; no dense product a
+  group), and the overflow waves keep no copy of the held experts'
+  kernels a wave (ISSUE 31: seven nested ``cond``s held 24 sets of zero
+  cotangents at once, and residuals leaving a ``cond`` inside the scan
+  were stacked six deep; either put the cell's step past the chip's
+  memory)."""
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.layers import routed_experts as routed
+  cfg = routed.RoutedExpertsConfig(router_width=128, experts_per_token=8,
+                                   num_held=16, route_scale=2.826,
+                                   capacity_factor=3.75)
+  tokens, d, ffn = 16384, 2048, 1024
+  # a capacity between one wave and all of them, so that the waves that
+  # always run AND the scan under the cond are compiled: three waves of
+  # 20,480 slots every step, four more under the cond
+  assert (cfg.wave_slots(tokens), cfg.capacity(tokens),
+          cfg.waves(tokens)) == (20480, 61440, 7)
+  sh = SingleDeviceSharding(v5e.devices[0])
+  f32 = lambda *shape: _sds(shape, jnp.float32, sh)
+  p = {'router': f32(d, 128), 'expert_bias': f32(128),
+       'experts_in': f32(16, d, 2 * ffn), 'experts_out': f32(16, ffn, d)}
+
+  def loss(p, u):
+    return jnp.sum(jax.checkpoint(
+        lambda p, u: routed.routed_experts(cfg, p, u)[0])(p, u) ** 2)
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+      p, f32(tokens, d)).compile()
+  text = compiled.as_text()
+  # the benchmark's ``moe_expert_ms`` finds these kernels BY NAME among
+  # the ops no phase books, because XLA gives them an ``op_name`` of
+  # their own and no scope: a compiler that renames them, or hands them
+  # the scope they were traced under, fails here and not in a metric
+  kernels = [line for line in text.splitlines()
+             if re.match(r'\s*%ragged-dot-[\w\-.]+ = .*custom-call\(', line)]
+  assert kernels and all('tpu_custom_call' in line for line in kernels)
+  names = {re.search(r'op_name="([^"]*)"', line).group(1)
+           for line in kernels}
+  assert names and all(n.startswith('ragged-dot') and '/' not in n
+                       for n in names), names
+  assert 'f32[6,16,2048,2048]' not in text
+  # 3.61 GiB as it stands (a wave's buffers, both kernels' gradients and
+  # the skipped branch's one set of zero cotangents); six stacked copies
+  # would add 3 GiB, a cond a wave some 9
+  assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2**30
