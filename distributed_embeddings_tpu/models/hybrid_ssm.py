@@ -37,11 +37,12 @@ its chunk.  Decay, ``softplus`` and the recurrence are float32; matrix
 products take float32 operands at the backend's default precision (on
 a TPU: bfloat16 products, float32 accumulation), as the other heads.
 
-Attention is a Python loop over query blocks, each under
-``jax.checkpoint`` against the keys up to its own end, so no ``[heads,
-L, L]`` array exists.  Plain ``jax.numpy`` rather than the Pallas flash
-kernel: one code path on the CPU tests' shapes (48 positions, heads of
-8) and the chip's, and attention is one layer in ten.
+Attention's core (``blocked_attention``) is the fused kernels of
+``ops/pallas_attention.py`` where the program is compiled for a TPU and
+the shapes fit their tiles, and otherwise (a CPU, the tests' 48
+positions and heads of 8) a Python loop over query blocks in plain
+``jax.numpy``, each under ``jax.checkpoint`` against the keys up to its
+own end.  Either way no ``[heads, L, L]`` array exists.
 
 Every layer runs under ``jax.checkpoint``: the backward pass keeps the
 layers' inputs and recomputes each layer's interior.
@@ -65,6 +66,7 @@ import numpy as np
 
 from distributed_embeddings_tpu.obs import metrics as obs_metrics
 from distributed_embeddings_tpu.obs import trace as obs_trace
+from distributed_embeddings_tpu.ops import pallas_attention
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -324,9 +326,27 @@ def _attend_window(scale, q, k, v, seg_q, seg_k, first, key_first, window):
 
 
 def blocked_attention(scale, q, k, v, segment_ids, block_limit, window=None):
-  """Causal document-masked grouped-query attention, one block of
-  queries at a time: ``q [S, L, Hkv, G, D]``, ``k``, ``v`` ``[S, L, Hkv,
-  D]`` -> ``[S, L, Hkv, G, D]``.  Each block runs under
+  """Causal document-masked grouped-query attention: ``q [S, L, Hkv, G,
+  D]``, ``k``, ``v`` ``[S, L, Hkv, D]`` -> ``[S, L, Hkv, G, D]``; with a
+  ``window`` only the keys fewer than ``window`` positions back.  The one
+  place that chooses: the fused kernels (``ops/pallas_attention.py``)
+  where the program is compiled for a TPU and the static shapes fit
+  their tiles, and one block of ``block_limit`` queries at a time in
+  plain ``jax.numpy`` otherwise (a CPU, lengths of tens of positions).
+  The counters ``attention.kernel_layers`` and
+  ``attention.blocked_layers`` say at trace time which a compiled step
+  holds."""
+  if pallas_attention.takes(q.shape):
+    obs_metrics.inc('attention.kernel_layers')
+    return pallas_attention.attention(scale, q, k, v, segment_ids, window)
+  obs_metrics.inc('attention.blocked_layers')
+  return _unrolled_attention(scale, q, k, v, segment_ids, block_limit,
+                             window)
+
+
+def _unrolled_attention(scale, q, k, v, segment_ids, block_limit, window):
+  """``blocked_attention`` one block of queries at a time.  Each block
+  runs under
   ``jax.checkpoint`` against the keys up to its own end, and with a
   ``window`` from the block boundary at or before its first query's
   oldest key: only the key blocks that meet the window are computed, and

@@ -54,6 +54,10 @@ METRIC_TYPES: Dict[str, str] = {
     'train.tokens': 'counter',
     'train.documents': 'counter',
     'train.loss_positions': 'counter',
+    # which path each call of models/hybrid_ssm.blocked_attention took,
+    # counted as a step is traced
+    'attention.kernel_layers': 'counter',
+    'attention.blocked_layers': 'counter',
     # a routed layer's load (models/moe_lm.record_routing_stats)
     'moe.assignments_held': 'gauge',
     'moe.load_max_over_mean': 'gauge',

@@ -445,3 +445,103 @@ def test_routed_experts_compile_for_v5e_with_the_kernel_and_no_stacked_wave(
   # the skipped branch's one set of zero cotangents); six stacked copies
   # would add 3 GiB, a cond a wave some 9
   assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2**30
+
+
+@pytest.fixture
+def attention_for_tpu():
+  # the AOT trace runs on the CPU backend: ASSUME_TPU makes
+  # ``blocked_attention`` choose as it does on the chip
+  from distributed_embeddings_tpu.ops import pallas_attention
+  pallas_attention.ASSUME_TPU = True
+  try:
+    yield pallas_attention
+  finally:
+    pallas_attention.ASSUME_TPU = False
+
+
+def _kernel_calls(hlo):
+  """``op_name`` of every Pallas custom call of a compiled program."""
+  return [re.search(r'op_name="([^"]*)"', line).group(1)
+          for line in hlo.splitlines()
+          if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize('phase,shape,scale,window', [
+    ('attention/window', (2, 8192, 4, 8, 128), 128 ** -0.5, 2048),
+    ('attention/full', (2, 8192, 4, 8, 128), 128 ** -0.5, None),
+    ('attention', (2, 4096, 8, 4, 64), 1 / 64, None)])
+def test_attention_kernels_compile_for_v5e_under_their_phase(
+    v5e, attention_for_tpu, phase, shape, scale, window):
+  """The fused attention (ops/pallas_attention.py) at the two
+  language-model cells' own shapes, value and gradients under
+  ``jax.checkpoint`` inside the phase the model opens around it: the
+  forward twice, dq, dk|dv, and every one of them carries the phase in
+  its ``op_name`` (after it come ``checkpoint``, ``rematted_computation``
+  and the kernel's own name), which is how the benchmark's
+  ``attention_ms`` and ``window_attention_ms`` book their time."""
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.models import hybrid_ssm
+  from distributed_embeddings_tpu.obs import trace as obs_trace
+  sh = SingleDeviceSharding(v5e.devices[0])
+  seqs, length, kv_heads, _, d = shape
+  assert attention_for_tpu.takes(shape)
+
+  @jax.checkpoint
+  def core(q, k, v, seg):
+    with obs_trace.phase(phase):
+      return hybrid_ssm.blocked_attention(scale, q, k, v, seg, 512, window)
+
+  loss = lambda q, k, v, seg: jnp.sum(core(q, k, v, seg) ** 2)
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      _sds(shape, jnp.float32, sh),
+      _sds((seqs, length, kv_heads, d), jnp.float32, sh),
+      _sds((seqs, length, kv_heads, d), jnp.float32, sh),
+      _sds((seqs, length), jnp.int32, sh)).compile()
+  names = _kernel_calls(compiled.as_text())
+  assert all(obs_trace.phase_of(name) == (phase, None)
+             for name in names), names
+  assert sorted(name.split('/')[-2] for name in names) == [
+      'attention_dkv', 'attention_dq', 'attention_fwd', 'attention_fwd']
+
+
+def test_moe_lm_layer_compiles_for_v5e_with_no_score_buffer(
+    v5e, attention_for_tpu):
+  """A windowed ``trinity-mini`` layer (the dense one: attention as in
+  every layer, a SwiGLU of 6,144 where the others route) at the cell's
+  shapes, value and gradients: with the kernels no float32 ``[.., 512,
+  n >= 512]`` array of scores is left in the compiled program (the
+  unrolled blocks wrote and read ``f32[2,4,8,512,2560]`` a block)."""
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.models import moe_lm
+  from distributed_embeddings_tpu.obs import trace as obs_trace
+  cfg = moe_lm.MoELMConfig(
+      hidden_size=2048, vocab_size=25024,
+      layer_types=('sliding_attention',), num_dense_layers=1,
+      intermediate_size=6144, moe_intermediate_size=1024, num_experts=16,
+      router_width=128, num_experts_per_tok=8, num_attention_heads=32,
+      num_key_value_heads=4, head_dim=128, sliding_window=2048,
+      attention_block=512)
+  sh = SingleDeviceSharding(v5e.devices[0])
+  f32 = lambda *shape: _sds(shape, jnp.float32, sh)
+  d, heads, kv, ffn = 2048, 32 * 128, 4 * 128, 6144
+  p = {'input_norm': f32(d), 'post_attn_norm': f32(d),
+       'pre_mlp_norm': f32(d), 'post_mlp_norm': f32(d),
+       'attention': {'q_proj': f32(d, heads), 'k_proj': f32(d, kv),
+                     'v_proj': f32(d, kv), 'gate_proj': f32(d, heads),
+                     'o_proj': f32(heads, d), 'q_norm': f32(128),
+                     'k_norm': f32(128)},
+       'mlp_in': f32(d, 2 * ffn), 'mlp_out': f32(ffn, d)}
+
+  def loss(p, x, seg):
+    return jnp.sum(moe_lm.layer(cfg, 'sliding_attention', p, x, seg)[0] ** 2)
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+      p, f32(2, 8192, d), _sds((2, 8192), jnp.int32, sh)).compile()
+  text = compiled.as_text()
+  names = _kernel_calls(text)
+  assert names and all(
+      obs_trace.phase_of(name) == ('attention/window', None)
+      for name in names), names
+  scores = {m.group(0) for m in re.finditer(r'f32\[(?:\d+,)+512,(\d+)\]', text)
+            if int(m.group(1)) >= 512}
+  assert not scores, scores
