@@ -14,7 +14,8 @@ Equations, ``u [T, d]`` the tokens' rows::
 
   s    = sigmoid(float32(u) @ router)           [T, router_width], HIGHEST
   sel  = top_k(s + expert_bias)                 expert_bias: no gradient
-  w_e  = route_scale * s_e / (sum_{e' in sel} s_e' + 1e-20)   over ALL k
+  w_e  = route_scale * s_e / (sum_{e' in sel} s_e' + route_norm_eps)
+                                                over ALL k, held or not
   y    = sum_{e in sel, e held} w_e * swiglu_e(u)
 
 How it groups is the sparse apply's idiom (``parallel/sparse.py``) made
@@ -92,6 +93,7 @@ class RoutedExpertsConfig:
   first_expert: int = 0
   route_scale: float = 1.0
   capacity_factor: float = 1.25
+  route_norm_eps: float = 1e-20     # the normaliser's constant, a family's own
 
   def __post_init__(self):
     if not 0 <= self.first_expert <= self.router_width - self.num_held:
@@ -144,7 +146,7 @@ def route(cfg: RoutedExpertsConfig, u, router, expert_bias):
         scores + jax.lax.stop_gradient(expert_bias), cfg.experts_per_token)
     picked = jnp.take_along_axis(scores, sel, axis=-1)
     weights = cfg.route_scale * picked / (
-        jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+        jnp.sum(picked, axis=-1, keepdims=True) + cfg.route_norm_eps)
     return sel, weights
 
 

@@ -58,6 +58,9 @@ METRIC_TYPES: Dict[str, str] = {
     # counted as a step is traced
     'attention.kernel_layers': 'counter',
     'attention.blocked_layers': 'counter',
+    # gated short-convolution layers a compiled step holds, one a call
+    # at trace time (models/moe_lm.short_conv)
+    'mixer.short_conv_layers': 'counter',
     # a routed layer's load (models/moe_lm.record_routing_stats)
     'moe.assignments_held': 'gauge',
     'moe.load_max_over_mean': 'gauge',

@@ -154,6 +154,9 @@ REGISTERED_PHASES: Dict[str, str] = {
     # products; weighting and the sum back to tokens; the shared expert
     'attention/window': 'dense head',
     'attention/full': 'dense head',
+    # a gated short convolution, whole: in-projection, both gates, the
+    # depthwise convolution, out-projection (models/moe_lm.short_conv)
+    'mixer/short_conv': 'dense head',
     'moe/route': 'routed experts',
     'moe/dispatch': 'routed experts',
     'moe/experts': 'routed experts',

@@ -59,6 +59,14 @@ _MASKED = -0.7 * float(np.finfo(np.float32).max)
 # double-buffered) passes the 16 MiB a kernel gets by default
 _VMEM_LIMIT = 64 * 2**20
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
+# Every product in the kernels accumulates in float32 and STATES its
+# precision: the operands are bfloat16 already, and a caller's
+# ``jax.default_matmul_precision('highest')`` (a probe that reads what
+# the products' precision does to a router's choice) would else ask
+# Mosaic for a float32 contraction of bfloat16 tiles, which it refuses
+# to compile ("Bad lhs type").
+_PRODUCT = dict(preferred_element_type=jnp.float32,
+                precision=lax.Precision.DEFAULT)
 
 
 def takes(q_shape) -> bool:
@@ -134,8 +142,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
     k, v = k_ref[...], v_ref[...]
 
     def head(g, _):
-      s = lax.dot_general(q_ref[g], k, _NT,
-                          preferred_element_type=jnp.float32)
+      s = lax.dot_general(q_ref[g], k, _NT, **_PRODUCT)
       s = jnp.where(mask, s, _MASKED)
       m_prev, l_prev = m_ref[g], l_ref[g]
       m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -144,7 +151,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
       l_ref[g] = alpha * l_prev + p.sum(axis=-1, keepdims=True)
       m_ref[g] = m_next
       o_ref[g] = alpha[:, :d] * o_ref[g] + jnp.dot(
-          p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+          p.astype(v.dtype), v, **_PRODUCT)
 
     lax.fori_loop(0, group, head, None)
 
@@ -171,15 +178,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, do_ref, lse_ref,
     k, v = k_ref[...], v_ref[...]
 
     def head(g, _):
-      s = lax.dot_general(q_ref[g], k, _NT,
-                          preferred_element_type=jnp.float32)
+      s = lax.dot_general(q_ref[g], k, _NT, **_PRODUCT)
       s = jnp.where(mask, s, _MASKED)
       p = jnp.exp(s - _across(lse_ref[g], block))
-      dp = lax.dot_general(do_ref[g], v, _NT,
-                           preferred_element_type=jnp.float32)
+      dp = lax.dot_general(do_ref[g], v, _NT, **_PRODUCT)
       ds = p * (dp - _across(di_ref[g], block))
-      dq_ref[g] += jnp.dot(ds.astype(k.dtype), k,
-                           preferred_element_type=jnp.float32)
+      dq_ref[g] += jnp.dot(ds.astype(k.dtype), k, **_PRODUCT)
 
     lax.fori_loop(0, group, head, None)
 
@@ -204,15 +208,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, do_ref, lse_ref,
 
     def head(g, _):
       q, do = q_ref[g], do_ref[g]
-      s = lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+      s = lax.dot_general(k, q, _NT, **_PRODUCT)
       s = jnp.where(mask, s, _MASKED)
       p = jnp.exp(s - lse_ref[g][:1, :])
-      dv_ref[...] += jnp.dot(p.astype(do.dtype), do,
-                             preferred_element_type=jnp.float32)
-      dp = lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+      dv_ref[...] += jnp.dot(p.astype(do.dtype), do, **_PRODUCT)
+      dp = lax.dot_general(v, do, _NT, **_PRODUCT)
       ds = p * (dp - di_ref[g][:1, :])
-      dk_ref[...] += jnp.dot(ds.astype(q.dtype), q,
-                             preferred_element_type=jnp.float32)
+      dk_ref[...] += jnp.dot(ds.astype(q.dtype), q, **_PRODUCT)
 
     lax.fori_loop(0, group, head, None)
 

@@ -16,7 +16,7 @@ its row writes to the scatter emitter their share of the shard calls
 for (ISSUE 30, two steps at the benchmark cells' own shapes).
 
 Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
-spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 54 cases pass
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 60 cases pass
 in about 135 s on 8 host cores (90 s of it the two full-size steps).
 This is the free gate to run
 (``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
@@ -34,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from distributed_embeddings_tpu.ops import pallas_lookup, pallas_segwalk
 
 
+import functools
 import os
 import re
 
@@ -545,3 +546,108 @@ def test_moe_lm_layer_compiles_for_v5e_with_no_score_buffer(
   scores = {m.group(0) for m in re.finditer(r'f32\[(?:\d+,)+512,(\d+)\]', text)
             if int(m.group(1)) >= 512}
   assert not scores, scores
+
+
+def test_short_conv_stack_compiles_for_v5e_under_its_phase(
+    v5e, attention_for_tpu):
+  """The short-convolution mixture-of-experts cell's parts at its own
+  shapes (``benchmarks/configs/lfm2-24b-a2b.json``, 2 x 8,192
+  positions).  The operator alone, value and every gradient: every op
+  of the compiled program that has a source carries the phase
+  ``mixer/short_conv`` (projections and gates included: the benchmark's
+  ``short_conv_ms`` books them there).  A ``conv`` layer over a routed
+  feed-forward, 8 of 64 experts at ``capacity_factor`` 8.0, value and
+  gradients: compiles with XLA's ``ragged-dot`` kernels inside a
+  chip's memory.  The whole stack, traced: its two attention layers take
+  the fused kernels and five operators are counted."""
+  import json
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.models import moe_lm
+  from distributed_embeddings_tpu.obs import metrics as obs_metrics
+  from distributed_embeddings_tpu.obs import trace as obs_trace
+  root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  with open(os.path.join(root, 'benchmarks', 'configs',
+                         'lfm2-24b-a2b.json')) as f:
+    cfg = moe_lm.MoELMConfig.from_dict(json.load(f))
+  sh = SingleDeviceSharding(v5e.devices[0])
+  f32 = lambda *shape: _sds(shape, jnp.float32, sh)
+  d, ffn, held = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
+  x, seg = f32(2, 8192, d), _sds((2, 8192), jnp.int32, sh)
+  conv = {'in_proj': f32(d, 3 * d), 'conv_kernel': f32(3, d),
+          'out_proj': f32(d, d)}
+
+  def operator(p, u, seg, cot):
+    out, vjp = jax.vjp(lambda p, u: moe_lm.short_conv(p, u, seg), p, u)
+    return out, vjp(cot)
+
+  text = jax.jit(operator).lower(conv, x, seg, x).compile().as_text()
+  # (an argument's ``op_name`` is its own name: no op of the program)
+  names = {n for n in re.findall(r'op_name="([^"]*)"', text) if '/' in n}
+  assert names and all(
+      obs_trace.phase_of(n) == ('mixer/short_conv', None) for n in names), [
+          n for n in names
+          if obs_trace.phase_of(n) != ('mixer/short_conv', None)]
+
+  layer = {'input_norm': f32(d), 'pre_mlp_norm': f32(d), 'conv': conv,
+           'moe': {'router': f32(d, 64), 'expert_bias': f32(64),
+                   'experts_in': f32(held, d, 2 * ffn),
+                   'experts_out': f32(held, ffn, d)}}
+  assert (cfg.routed.wave_slots(16384), cfg.routed.waves(16384),
+          cfg.routed.capacity(16384)) == (10240, 7, 71680)
+
+  def loss(p, x, seg):
+    return jnp.sum(moe_lm.layer(cfg, 'conv', p, x, seg)[0] ** 2)
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+      layer, x, seg).compile()
+  assert re.search(r'%ragged-dot-[\w\-.]+ = .*custom-call\(',
+                   compiled.as_text())
+  # 2.9 GiB as it stands: a wave's buffers and both kernels' gradients
+  assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+  heads, kv = cfg.num_attention_heads * cfg.head_dim, (
+      cfg.num_key_value_heads * cfg.head_dim)
+  attention = {'q_proj': f32(d, heads), 'k_proj': f32(d, kv),
+               'v_proj': f32(d, kv), 'o_proj': f32(heads, d),
+               'q_norm': f32(cfg.head_dim), 'k_norm': f32(cfg.head_dim)}
+  dense_ffn = {'mlp_in': f32(d, 2 * cfg.intermediate_size),
+               'mlp_out': f32(cfg.intermediate_size, d)}
+  layers = [{'input_norm': f32(d), 'pre_mlp_norm': f32(d),
+             **({'conv': conv} if kind == 'conv'
+                else {'attention': attention}),
+             **(dense_ffn if i < cfg.num_dense_layers
+                else {'moe': layer['moe']})}
+            for i, kind in enumerate(cfg.layer_types)]
+  obs_metrics.reset()
+  obs_metrics.enable()
+  try:
+    jax.eval_shape(functools.partial(moe_lm.forward, cfg),
+                   {'layers': layers, 'final_norm': f32(d)}, x, seg)
+    counted = obs_metrics.snapshot()
+  finally:
+    obs_metrics.disable()
+    obs_metrics.reset()
+  assert counted == {'attention.kernel_layers': 2.0,
+                     'mixer.short_conv_layers': 5.0}
+
+
+def test_attention_kernels_compile_under_a_callers_highest_precision(
+    v5e, attention_for_tpu):
+  """A caller's ``jax.default_matmul_precision('highest')`` (the routing
+  probes read a router's choice under it) reaches into a kernel's
+  products unless they state their own: Mosaic then refused the float32
+  contraction of bfloat16 tiles ("Bad lhs type").  Forward and backward
+  at the short-convolution cell's attention shape."""
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.models import hybrid_ssm
+  sh = SingleDeviceSharding(v5e.devices[0])
+  shape = (2, 8192, 8, 4, 64)
+  assert attention_for_tpu.takes(shape)
+  loss = lambda q, k, v, seg: jnp.sum(hybrid_ssm.blocked_attention(
+      0.125, q, k, v, seg, 512) ** 2)
+  with jax.default_matmul_precision('highest'):
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _sds(shape, jnp.float32, sh), _sds((2, 8192, 8, 64), jnp.float32, sh),
+        _sds((2, 8192, 8, 64), jnp.float32, sh),
+        _sds((2, 8192), jnp.int32, sh)).compile()
+  assert len(_kernel_calls(compiled.as_text())) == 3
