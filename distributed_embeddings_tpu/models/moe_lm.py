@@ -65,10 +65,11 @@ Device phases (inside ``head``): ``attention/window``, ``attention/full``
 projections and gates included, as an attention phase holds its own: a
 fusion carries one name, and XLA fuses the gates into the products beside
 them), ``mlp`` (the dense SwiGLU), ``moe/route``, ``moe/dispatch``,
-``moe/experts``, ``moe/combine``, ``moe/shared``, ``vocab``.  Counter, at
-trace time: ``mixer.short_conv_layers``.  Gauges, set outside the step by
-``record_routing_stats``: ``moe.assignments_held``,
-``moe.load_max_over_mean``, ``moe.overflow_rows``.
+``moe/experts``, ``moe/combine``, ``moe/shared``, ``vocab``.  Counters, at
+trace time: ``mixer.short_conv_layers``, ``moe.kept_outputs``.  Gauges,
+set outside the step by ``record_routing_stats``:
+``moe.assignments_held``, ``moe.load_max_over_mean``,
+``moe.overflow_rows``.
 """
 
 from __future__ import annotations
@@ -81,6 +82,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_embeddings_tpu.layers import routed_experts as routed
 from distributed_embeddings_tpu.models.hybrid_ssm import (
@@ -93,6 +95,9 @@ __all__ = ['MoELMConfig', 'init_params', 'count_batch', 'forward',
            'record_routing_stats']
 
 _KINDS = ('sliding_attention', 'full_attention', 'conv')
+# the one array the feed-forward half's checkpoint keeps, where the
+# backward pass reads it: a routed sub-layer's output (``layer``)
+_ROUTED_OUTPUT = 'routed_ffn'
 # What a family's modelling code fixes and no key of its ``config.json``
 # states (``fixed``), and the keys it names otherwise (``keys``), by
 # ``model_type``; a file without one is ``afmoe``'s.
@@ -335,8 +340,19 @@ def layer(cfg: MoELMConfig, kind: str, p, x, segment_ids):
   ``jax.checkpoint`` around the whole block, as ``hybrid_ssm.forward``
   has it, the attention blocks and the waves, which rematerialise
   themselves, kept their buffers alive across the block's recomputation:
-  0.7 GiB more at the published sizes, compile-only for a v5e.)"""
+  0.7 GiB more at the published sizes, compile-only for a v5e.)
+
+  Of a routed block the backward pass keeps the sub-layer's OUTPUT too
+  (``[S, L, hidden]`` float32), where a family's norm after it reads it
+  (counter ``moe.kept_outputs``).  The waves rematerialise themselves
+  and keep only their arguments, so all that recomputing them with the
+  half could hand the backward pass is their sum, which that norm's
+  gradient reads and nothing else does: for it every wave ran forward a
+  third time (docs/design.md §27).  A dense block's recomputation is
+  what its own backward reads, and nothing of it is kept."""
   eps = cfg.rms_norm_eps
+  if 'moe' in p and cfg.sandwich_norms:
+    obs_metrics.inc('moe.kept_outputs')
 
   @jax.checkpoint
   def mixer(p, x):
@@ -346,11 +362,16 @@ def layer(cfg: MoELMConfig, kind: str, p, x, segment_ids):
     return (rms_norm(out, p['post_attn_norm'], eps) if cfg.sandwich_norms
             else out)
 
-  @jax.checkpoint
+  @functools.partial(
+      jax.checkpoint,
+      policy=jax.checkpoint_policies.save_only_these_names(_ROUTED_OUTPUT))
   def feed_forward(p, x):
     u = rms_norm(x, p['pre_mlp_norm'], eps)
-    ffn, sel = (routed_ffn(cfg, p['moe'], u) if 'moe' in p
-                else (swiglu(p, u), None))
+    if 'moe' in p:
+      ffn, sel = routed_ffn(cfg, p['moe'], u)
+      ffn = checkpoint_name(ffn, _ROUTED_OUTPUT)
+    else:
+      ffn, sel = swiglu(p, u), None
     return (rms_norm(ffn, p['post_mlp_norm'], eps) if cfg.sandwich_norms
             else ffn), sel
 

@@ -61,6 +61,9 @@ METRIC_TYPES: Dict[str, str] = {
     # gated short-convolution layers a compiled step holds, one a call
     # at trace time (models/moe_lm.short_conv)
     'mixer.short_conv_layers': 'counter',
+    # routed blocks whose output the backward pass keeps for the norm
+    # after it, one a block at trace time (models/moe_lm.layer)
+    'moe.kept_outputs': 'counter',
     # a routed layer's load (models/moe_lm.record_routing_stats)
     'moe.assignments_held': 'gauge',
     'moe.load_max_over_mean': 'gauge',

@@ -13,9 +13,12 @@ harness.
 """
 
 import argparse
+import contextlib
+import dataclasses
 import functools
 import json
 import os
+import re
 import time
 
 import numpy as np
@@ -303,6 +306,131 @@ def test_four_devices_train_as_one_does():
     x, y = np.asarray(x), np.asarray(y)
     assert _rel(x, y) < 1e-5
     np.testing.assert_allclose(x, y, atol=1e-4)
+
+
+def _routed_block(seed):
+  """A routed block's leaves with norm gains that are not 1 (so that the
+  norm after the sub-layer shows), its input and a cotangent."""
+  p = _dense(seed)['layers'][1]
+  rng = np.random.default_rng(seed)
+  for leaf in ('input_norm', 'pre_mlp_norm', 'post_attn_norm',
+               'post_mlp_norm'):
+    p[leaf] = jnp.asarray(rng.uniform(0.5, 1.5, CFG.hidden_size),
+                          jnp.float32)
+  return p, _hidden(seed + 1), _hidden(seed + 2)
+
+
+def _block_grad(cfg, p, x, cot):
+  """The gradient of one ``sliding_attention`` block in its parameters
+  and its input, as a jitted function of them.  (The loss is not linear
+  in the block's output: the backward pass reads the forward's, as under
+  a stack it always does.)"""
+  def loss(p, x):
+    return jnp.sum(
+        cot * prog.layer(cfg, 'sliding_attention', p, x, SEGMENTS)[0] ** 2)
+  return jax.jit(jax.grad(loss, argnums=(0, 1)))
+
+
+@contextlib.contextmanager
+def _nothing_kept(monkeypatch):
+  """The block as it stood until PR 34: with no array named, the half's
+  checkpoint keeps nothing, and the backward of the norm after a routed
+  sub-layer reads the recomputed waves' sum."""
+  with monkeypatch.context() as patch:
+    patch.setattr(prog, 'checkpoint_name', lambda x, name: x)
+    yield
+
+
+# at the toy sizes a wave is 128 slots of the 384 there can be: at 1.25
+# one wave always runs and two lie under the ``cond`` and its ``scan``,
+# at 8.0 all three always run
+CAPACITIES = (1.25, 8.0)
+
+
+@pytest.mark.parametrize('capacity', CAPACITIES)
+def test_a_routed_blocks_gradient_is_the_unrematerialised_blocks(
+    capacity, monkeypatch):
+  """With a routed sub-layer's output KEPT for the norm after it, the
+  block's gradient (every parameter, and the input) is bit for bit what
+  it was when the norm read the recomputed waves' sum, and to rounding
+  what the same block gives with no ``jax.checkpoint`` anywhere (the
+  halves', the waves', the attention blocks')."""
+  cfg = dataclasses.replace(CFG, capacity_factor=capacity)
+  assert cfg.sandwich_norms and cfg.routed.waves(96) == 3
+  p, x, cot = _routed_block(20)
+  mine = _block_grad(cfg, p, x, cot)(p, x)
+  with _nothing_kept(monkeypatch):
+    before = _block_grad(cfg, p, x, cot)(p, x)
+  for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(before)):
+    np.testing.assert_array_equal(a, b)
+  monkeypatch.setattr(jax, 'checkpoint', lambda f, **_: f)
+  plain = _block_grad(cfg, p, x, cot)
+  assert 'checkpoint' not in str(plain.trace(p, x).jaxpr)
+  plain = plain(p, x)
+  assert (jax.tree.structure(mine) == jax.tree.structure(plain))
+  for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(plain)):
+    assert _rel(a, b) < 1e-5
+  # (the selection bias moves the selection only)
+  assert not np.any(mine[0]['moe']['expert_bias'])
+  assert float(jnp.linalg.norm(mine[0]['post_mlp_norm'])) > 0
+
+
+@pytest.mark.parametrize('capacity', CAPACITIES)
+def test_the_norm_after_a_routed_sub_layer_costs_no_wave_a_pass(
+    capacity, monkeypatch):
+  """The mechanism, by count: the optimized program of a routed block's
+  gradient holds no more products and no more scatters under a family
+  with a norm after the sub-layer than under one without (each wave
+  forward twice, the forward pass's and its own checkpoint's, and
+  backward once).  With nothing kept the half's recomputation ran every
+  wave a third time to hand the norm their sum: more of both.  (The
+  mixer is taken out of the count: the norm after IT stays inside its
+  checkpoint and changes what its own recomputation keeps.)"""
+  monkeypatch.setattr(prog, 'attention', lambda cfg, kind, p, u, seg: u)
+  p, x, cot = _routed_block(21)
+
+  def count(sandwich):
+    cfg = dataclasses.replace(CFG, capacity_factor=capacity,
+                              sandwich_norms=sandwich)
+    text = _block_grad(cfg, p, x, cot).lower(p, x).compile().as_text()
+    return {op: len(re.findall(rf' {op}\(', text))
+            for op in ('dot', 'scatter')}
+
+  with_norm, without = count(True), count(False)
+  assert without['dot'] > 0 and without['scatter'] > 0
+  assert with_norm['dot'] <= without['dot'], (with_norm, without)
+  assert with_norm['scatter'] <= without['scatter'], (with_norm, without)
+  with _nothing_kept(monkeypatch):
+    before = count(True)
+  assert before['dot'] > with_norm['dot'], (before, with_norm)
+  assert before['scatter'] > with_norm['scatter'], (before, with_norm)
+
+
+def _kept_outputs(cfg, dense):
+  obs.reset()
+  obs.metrics.enable()
+  try:
+    jax.eval_shape(functools.partial(prog.forward, cfg), dense,
+                   0.1 * _hidden(15), SEGMENTS)
+    return obs.metrics.snapshot().get('moe.kept_outputs', 0)
+  finally:
+    obs.metrics.disable()
+    obs.reset()
+
+
+def test_kept_outputs_counts_the_routed_blocks_under_a_norm():
+  """``moe.kept_outputs`` counts one a traced routed block whose output
+  the backward pass keeps: both routed blocks of the toy ``afmoe``
+  stack (not its dense block), none of an ``lfm2_moe`` stack, which has
+  no norm after a sub-layer."""
+  assert _kept_outputs(CFG, _dense(14)) == 2
+  lfm2 = os.path.join(cell_lib.BENCH_DIR, 'tests', 'toy_lfm2')
+  config = names.load_json(lfm2, 'configs', 'toy-lfm2')
+  cfg = prog.MoELMConfig.from_dict(config)
+  assert len(cfg.layer_types) - cfg.num_dense_layers == 2
+  dense = jax.eval_shape(lambda: jax.tree.map(
+      jnp.asarray, prog.init_params(cfg, 14)))
+  assert _kept_outputs(cfg, dense) == 0
 
 
 def test_routing_stats_set_the_gauges_outside_the_step():

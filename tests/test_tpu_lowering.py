@@ -16,7 +16,7 @@ its row writes to the scatter emitter their share of the shard calls
 for (ISSUE 30, two steps at the benchmark cells' own shapes).
 
 Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
-spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 60 cases pass
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 61 cases pass
 in about 135 s on 8 host cores (90 s of it the two full-size steps).
 This is the free gate to run
 (``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
@@ -505,6 +505,27 @@ def test_attention_kernels_compile_for_v5e_under_their_phase(
       'attention_dkv', 'attention_dq', 'attention_fwd', 'attention_fwd']
 
 
+def _attention_leaves(cfg, f32):
+  """Shapes of one attention layer's leaves (``moe_lm.init_params``)."""
+  d, heads = cfg.hidden_size, cfg.num_attention_heads * cfg.head_dim
+  kv = cfg.num_key_value_heads * cfg.head_dim
+  gate = {'gate_proj': f32(d, heads)} if cfg.attention_gate else {}
+  return {'q_proj': f32(d, heads), 'k_proj': f32(d, kv),
+          'v_proj': f32(d, kv), **gate, 'o_proj': f32(heads, d),
+          'q_norm': f32(cfg.head_dim), 'k_norm': f32(cfg.head_dim)}
+
+
+def _routed_leaves(cfg, f32):
+  """Shapes of one routed feed-forward's leaves."""
+  d, ffn = cfg.hidden_size, cfg.moe_intermediate_size
+  shared = ({'shared': {'mlp_in': f32(d, 2 * ffn), 'mlp_out': f32(ffn, d)}}
+            if cfg.num_shared_experts else {})
+  return {'router': f32(d, cfg.router_width),
+          'expert_bias': f32(cfg.router_width), **shared,
+          'experts_in': f32(cfg.num_experts, d, 2 * ffn),
+          'experts_out': f32(cfg.num_experts, ffn, d)}
+
+
 def test_moe_lm_layer_compiles_for_v5e_with_no_score_buffer(
     v5e, attention_for_tpu):
   """A windowed ``trinity-mini`` layer (the dense one: attention as in
@@ -524,13 +545,10 @@ def test_moe_lm_layer_compiles_for_v5e_with_no_score_buffer(
       attention_block=512)
   sh = SingleDeviceSharding(v5e.devices[0])
   f32 = lambda *shape: _sds(shape, jnp.float32, sh)
-  d, heads, kv, ffn = 2048, 32 * 128, 4 * 128, 6144
+  d, ffn = 2048, 6144
   p = {'input_norm': f32(d), 'post_attn_norm': f32(d),
        'pre_mlp_norm': f32(d), 'post_mlp_norm': f32(d),
-       'attention': {'q_proj': f32(d, heads), 'k_proj': f32(d, kv),
-                     'v_proj': f32(d, kv), 'gate_proj': f32(d, heads),
-                     'o_proj': f32(heads, d), 'q_norm': f32(128),
-                     'k_norm': f32(128)},
+       'attention': _attention_leaves(cfg, f32),
        'mlp_in': f32(d, 2 * ffn), 'mlp_out': f32(ffn, d)}
 
   def loss(p, x, seg):
@@ -546,6 +564,45 @@ def test_moe_lm_layer_compiles_for_v5e_with_no_score_buffer(
   scores = {m.group(0) for m in re.finditer(r'f32\[(?:\d+,)+512,(\d+)\]', text)
             if int(m.group(1)) >= 512}
   assert not scores, scores
+
+
+def test_a_routed_afmoe_block_runs_its_waves_forward_twice_on_v5e(
+    v5e, attention_for_tpu):
+  """A routed ``trinity-mini`` block (a windowed layer over 16 of 128
+  experts and the shared one, ``capacity_factor`` 8.0: seven waves of
+  20,480 slots, all of them every step) at the cell's shapes under
+  ``jax.grad``: eight ``ragged-dot`` product kernels a wave body, two
+  forward passes of two products (the forward's, and the wave's own
+  checkpoint's) and four backward products.  With the norm after the
+  sub-layer inside the feed-forward half's checkpoint there were ten:
+  the half's recomputation ran every wave a third time to hand the norm
+  their sum (ISSUE 34)."""
+  import json
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.models import moe_lm
+  root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  with open(os.path.join(root, 'benchmarks', 'configs',
+                         'trinity-mini.json')) as f:
+    cfg = moe_lm.MoELMConfig.from_dict(json.load(f))
+  assert cfg.sandwich_norms and cfg.num_shared_experts == 1
+  assert (cfg.routed.wave_slots(16384), cfg.routed.waves(16384),
+          cfg.routed.capacity(16384)) == (20480, 7, 143360)
+  sh = SingleDeviceSharding(v5e.devices[0])
+  f32 = lambda *shape: _sds(shape, jnp.float32, sh)
+  d = cfg.hidden_size
+  p = {'input_norm': f32(d), 'post_attn_norm': f32(d),
+       'pre_mlp_norm': f32(d), 'post_mlp_norm': f32(d),
+       'attention': _attention_leaves(cfg, f32),
+       'moe': _routed_leaves(cfg, f32)}
+
+  def loss(p, x, seg):
+    return jnp.sum(moe_lm.layer(cfg, 'sliding_attention', p, x, seg)[0] ** 2)
+
+  text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+      p, f32(2, 8192, d), _sds((2, 8192), jnp.int32, sh)).compile().as_text()
+  products = [line for line in text.splitlines() if re.match(
+      r'\s*%ragged-dot-(?!metadata)[\w\-.]+ = .*custom-call\(', line)]
+  assert len(products) == 8 * cfg.routed.waves(16384), len(products)
 
 
 def test_short_conv_stack_compiles_for_v5e_under_its_phase(
@@ -571,7 +628,7 @@ def test_short_conv_stack_compiles_for_v5e_under_its_phase(
     cfg = moe_lm.MoELMConfig.from_dict(json.load(f))
   sh = SingleDeviceSharding(v5e.devices[0])
   f32 = lambda *shape: _sds(shape, jnp.float32, sh)
-  d, ffn, held = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
+  d = cfg.hidden_size
   x, seg = f32(2, 8192, d), _sds((2, 8192), jnp.int32, sh)
   conv = {'in_proj': f32(d, 3 * d), 'conv_kernel': f32(3, d),
           'out_proj': f32(d, d)}
@@ -589,9 +646,7 @@ def test_short_conv_stack_compiles_for_v5e_under_its_phase(
           if obs_trace.phase_of(n) != ('mixer/short_conv', None)]
 
   layer = {'input_norm': f32(d), 'pre_mlp_norm': f32(d), 'conv': conv,
-           'moe': {'router': f32(d, 64), 'expert_bias': f32(64),
-                   'experts_in': f32(held, d, 2 * ffn),
-                   'experts_out': f32(held, ffn, d)}}
+           'moe': _routed_leaves(cfg, f32)}
   assert (cfg.routed.wave_slots(16384), cfg.routed.waves(16384),
           cfg.routed.capacity(16384)) == (10240, 7, 71680)
 
@@ -605,11 +660,7 @@ def test_short_conv_stack_compiles_for_v5e_under_its_phase(
   # 2.9 GiB as it stands: a wave's buffers and both kernels' gradients
   assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
-  heads, kv = cfg.num_attention_heads * cfg.head_dim, (
-      cfg.num_key_value_heads * cfg.head_dim)
-  attention = {'q_proj': f32(d, heads), 'k_proj': f32(d, kv),
-               'v_proj': f32(d, kv), 'o_proj': f32(heads, d),
-               'q_norm': f32(cfg.head_dim), 'k_norm': f32(cfg.head_dim)}
+  attention = _attention_leaves(cfg, f32)
   dense_ffn = {'mlp_in': f32(d, 2 * cfg.intermediate_size),
                'mlp_out': f32(cfg.intermediate_size, d)}
   layers = [{'input_norm': f32(d), 'pre_mlp_norm': f32(d),

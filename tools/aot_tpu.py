@@ -9,7 +9,8 @@ sets the kernels' AOT hooks (``ops/pallas_attention.ASSUME_TPU``,
 line with the arguments after it, and prints the ``obs.metrics``
 registry afterwards, in which ``attention.kernel_layers`` and
 ``attention.blocked_layers`` say which path each traced attention layer
-took:
+took and ``moe.kept_outputs`` how many routed blocks keep their output
+for the backward pass:
 
   JAX_PLATFORMS=cpu python3 tools/aot_tpu.py benchmarks/dev/aot_hybrid.py \\
       --config trinity-mini --traffic train-packed-8k [--hlo step.txt]
