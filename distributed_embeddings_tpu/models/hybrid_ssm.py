@@ -335,13 +335,17 @@ def blocked_attention(scale, q, k, v, segment_ids, block_limit, window=None):
   plain ``jax.numpy`` otherwise (a CPU, lengths of tens of positions).
   The counters ``attention.kernel_layers`` and
   ``attention.blocked_layers`` say at trace time which a compiled step
-  holds."""
-  if pallas_attention.takes(q.shape):
-    obs_metrics.inc('attention.kernel_layers')
-    return pallas_attention.attention(scale, q, k, v, segment_ids, window)
-  obs_metrics.inc('attention.blocked_layers')
-  return _unrolled_attention(scale, q, k, v, segment_ids, block_limit,
-                             window)
+  holds.  Both run under the phase ``attention/core`` (the kernels with
+  the transposes they need), so that a trace tells the core from the
+  projections, norms, rotary and gate that stand around it in the
+  caller's ``attention`` phase."""
+  with obs_trace.phase('attention/core'):
+    if pallas_attention.takes(q.shape):
+      obs_metrics.inc('attention.kernel_layers')
+      return pallas_attention.attention(scale, q, k, v, segment_ids, window)
+    obs_metrics.inc('attention.blocked_layers')
+    return _unrolled_attention(scale, q, k, v, segment_ids, block_limit,
+                               window)
 
 
 def _unrolled_attention(scale, q, k, v, segment_ids, block_limit, window):
@@ -400,12 +404,20 @@ def swiglu(p, u):
 
 
 def layer(cfg: HybridSSMConfig, kind: str, p, x, segment_ids):
+  """One residual block.  The norm before each sub-layer, the multiplier
+  and the add are the phase ``residual``: what a block costs beside its
+  mixer and its SwiGLU (as far as XLA leaves it unfused: a norm fused
+  into the product after it keeps the product's name)."""
   mixer = mamba_mixer if kind == 'mamba' else attention_mixer
-  x = x + cfg.residual_multiplier * mixer(
-      cfg, p['mixer'], rms_norm(x, p['mixer_norm'], cfg.rms_norm_eps),
-      segment_ids)
-  return x + cfg.residual_multiplier * swiglu(
-      p, rms_norm(x, p['mlp_norm'], cfg.rms_norm_eps))
+  with obs_trace.phase('residual'):
+    u = rms_norm(x, p['mixer_norm'], cfg.rms_norm_eps)
+  out = mixer(cfg, p['mixer'], u, segment_ids)
+  with obs_trace.phase('residual'):
+    x = x + cfg.residual_multiplier * out
+    u = rms_norm(x, p['mlp_norm'], cfg.rms_norm_eps)
+  out = swiglu(p, u)
+  with obs_trace.phase('residual'):
+    return x + cfg.residual_multiplier * out
 
 
 def vocab_loss(cfg: HybridSSMConfig, x, final_norm, table, targets):

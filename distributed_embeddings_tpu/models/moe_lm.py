@@ -349,35 +349,45 @@ def layer(cfg: MoELMConfig, kind: str, p, x, segment_ids):
   half could hand the backward pass is their sum, which that norm's
   gradient reads and nothing else does: for it every wave ran forward a
   third time (docs/design.md §27).  A dense block's recomputation is
-  what its own backward reads, and nothing of it is kept."""
+  what its own backward reads, and nothing of it is kept.
+
+  The norms before (and, in a family that has them, after) each
+  sub-layer and the two adds are the phase ``residual``; the per-head
+  norms of queries and keys stay ``attention``'s."""
   eps = cfg.rms_norm_eps
   if 'moe' in p and cfg.sandwich_norms:
     obs_metrics.inc('moe.kept_outputs')
 
+  def norm(u, gain):
+    with obs_trace.phase('residual'):
+      return rms_norm(u, gain, eps)
+
   @jax.checkpoint
   def mixer(p, x):
-    u = rms_norm(x, p['input_norm'], eps)
+    u = norm(x, p['input_norm'])
     out = (short_conv(p['conv'], u, segment_ids) if kind == 'conv'
            else attention(cfg, kind, p['attention'], u, segment_ids))
-    return (rms_norm(out, p['post_attn_norm'], eps) if cfg.sandwich_norms
-            else out)
+    return norm(out, p['post_attn_norm']) if cfg.sandwich_norms else out
 
   @functools.partial(
       jax.checkpoint,
       policy=jax.checkpoint_policies.save_only_these_names(_ROUTED_OUTPUT))
   def feed_forward(p, x):
-    u = rms_norm(x, p['pre_mlp_norm'], eps)
+    u = norm(x, p['pre_mlp_norm'])
     if 'moe' in p:
       ffn, sel = routed_ffn(cfg, p['moe'], u)
       ffn = checkpoint_name(ffn, _ROUTED_OUTPUT)
     else:
       ffn, sel = swiglu(p, u), None
-    return (rms_norm(ffn, p['post_mlp_norm'], eps) if cfg.sandwich_norms
+    return (norm(ffn, p['post_mlp_norm']) if cfg.sandwich_norms
             else ffn), sel
 
-  x = x + mixer(p, x)
+  out = mixer(p, x)
+  with obs_trace.phase('residual'):
+    x = x + out
   ffn, sel = feed_forward(p, x)
-  return x + ffn, sel
+  with obs_trace.phase('residual'):
+    return x + ffn, sel
 
 
 def _embed(cfg: MoELMConfig, rows):

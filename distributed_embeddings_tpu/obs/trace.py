@@ -147,6 +147,15 @@ REGISTERED_PHASES: Dict[str, str] = {
     'attention': 'dense head',
     'mlp': 'dense head',
     'vocab': 'dense head',
+    # both stacks: the norm before (in ``trinity-mini`` also after) each
+    # sub-layer, the residual multiplier and the add, which ``layer``
+    # calls outside every sub-layer's own phase
+    'residual': 'dense head',
+    # inside ``attention`` (``hybrid_ssm.blocked_attention``, which both
+    # stacks reach): the masked softmax products themselves, as fused
+    # kernels with their transposes or as unrolled blocks, apart from
+    # the projections, per-head norms, rotary and gate around them
+    'attention/core': 'dense head',
     # inside ``head``, a mixture-of-experts stack (models/moe_lm.py): its
     # two kinds of attention (both under ``attention``), and the routed
     # layer (layers/routed_experts.py): router product, top-k and
@@ -527,10 +536,10 @@ def profile(directory: str, path: Optional[str] = None):
   stops both on exit.  Read it with ``tools/trace_report.py --profile
   <directory>``.
 
-  The phases of a compiled program are metadata of its executable, and
-  the persistent compile cache's key leaves metadata out: a program
-  served from a cache filled before its phases existed shows none.
-  Capture from an empty cache directory (docs/userguide.md)."""
+  The phases of a compiled program are metadata of its executable; the
+  persistent compile cache's key takes them in
+  (``utils/compile_cache.configure``), so a program whose phases changed
+  is compiled again and the trace shows today's."""
   import jax
   options = jax.profiler.ProfileOptions()
   options.python_tracer_level = 0

@@ -117,6 +117,44 @@ def sort_with_order(ids: jax.Array, *riders: jax.Array):
   return jax.lax.sort((ids, iota) + riders, num_keys=1, is_stable=True)
 
 
+def _cumulative0(x: jax.Array, reducer, identity) -> jax.Array:
+  """The running ``reducer`` of ``x`` along axis 0 as JAX's own lowering
+  of a cumulative primitive spells it (``cumred_reduce_window_impl``)."""
+  n = x.shape[0]
+  if n == 0:
+    return x
+  rest = x.ndim - 1
+  return jax.lax.reduce_window(
+      x, identity, reducer, (n,) + (1,) * rest, (1,) * x.ndim,
+      ((n - 1, 0),) + ((0, 0),) * rest)
+
+
+def cumsum0(x: jax.Array) -> jax.Array:
+  """``jnp.cumsum(x, axis=0)`` as ONE ``lax.reduce_window`` bound where
+  it is called, so that the op carries the caller's phase
+  (``obs.trace.phase``) into the device trace.  ``jnp.cumsum`` is not
+  used in ``parallel/``: JAX lowers the ``cumsum`` primitive through one
+  private function that every call site of a module shares (``@cumsum``),
+  and the ops inside it keep no scope, so a trace books them to
+  ``unscoped`` whatever phase asked for them (PERF.md section 3).  This
+  is that function's body: the same window ``(n, 1, ..)``, strides and
+  padding ``(n - 1, 0)``, the same compiled op.  What must stay true:
+  the initial value is a NumPy scalar, so JAX binds the monoid primitive
+  and the op's name ends in ``reduce_window_sum``, the leaf trace
+  reductions class as ``cumsum`` (a ``jnp`` array binds the generic
+  ``reduce_window``, which they would class ``other``).  ``x`` is
+  int32 or float32; nothing differentiates through it."""
+  return _cumulative0(x, jax.lax.add, np.array(0, x.dtype))
+
+
+def cummax0(x: jax.Array) -> jax.Array:
+  """``lax.cummax(x, axis=0)`` bound where it is called: ``cumsum0``'s
+  rule for the running maximum (leaf ``reduce_window_max``)."""
+  identity = (-np.inf if np.issubdtype(x.dtype, np.floating)
+              else np.iinfo(x.dtype).min)
+  return _cumulative0(x, jax.lax.max, np.array(identity, x.dtype))
+
+
 def unique_with_inverse(ids: jax.Array, cap: int):
   """Per-row sort-unique with inverse positions (the cold-id dedup of
   the hot-cache exchange, docs/design.md §10).
@@ -145,7 +183,7 @@ def unique_with_inverse(ids: jax.Array, cap: int):
     sid = jnp.sort(keyv)
     first = jnp.concatenate([jnp.ones((1,), bool), sid[1:] != sid[:-1]])
     real = sid < big
-    rank = jnp.cumsum((first & real).astype(jnp.int32)) - 1
+    rank = cumsum0((first & real).astype(jnp.int32)) - 1
     key2 = jnp.where(first & real, rank, n)
     order2 = jnp.argsort(key2)[:cap]
     valid2 = key2[order2] < n
@@ -185,12 +223,12 @@ def dense_segment_sum(seg: jax.Array, rows: jax.Array, num: int,
              else rows[jnp.take(row_index, order)]).astype(jnp.float32)
   payload = jnp.where((s < num)[:, None], payload, 0.0)
   is_last = jnp.concatenate([s[1:] != s[:-1], jnp.ones((1,), bool)])
-  csum = jnp.cumsum(payload, axis=0)
+  csum = cumsum0(payload)
   total = jnp.where(is_last[:, None], csum, 0.0)
   excl = jnp.concatenate(
       [jnp.zeros((1, rows.shape[-1]), jnp.float32), csum[:-1]])
   is_first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
-  first_pos = jax.lax.cummax(
+  first_pos = cummax0(
       jnp.where(is_first, jnp.arange(n, dtype=jnp.int32), 0))
   total = total - jnp.where(is_last[:, None], excl[first_pos], 0.0)
   # each in-bounds segment writes exactly once (its last position);

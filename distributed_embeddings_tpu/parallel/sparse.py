@@ -33,6 +33,7 @@ the row grad and always uses the deduplicated sum.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 
@@ -50,7 +51,8 @@ from distributed_embeddings_tpu.parallel.dist_embedding import (
 from distributed_embeddings_tpu.parallel.grad import TrainState
 from distributed_embeddings_tpu.parallel.overlap import (chunk_bounds,
                                                          effective_chunks)
-from distributed_embeddings_tpu.parallel.routing import sort_with_order
+from distributed_embeddings_tpu.parallel.routing import (cummax0, cumsum0,
+                                                          sort_with_order)
 
 _LOG = logging.getLogger(__name__)
 
@@ -147,7 +149,7 @@ def _compact_sorted(sid: jax.Array, sg: jax.Array, cap: int, sentinel: int,
   same gather read one slot earlier."""
   n = sid.shape[0]
   is_first, is_last, first_pos, _ = _sorted_segments(sid)
-  rank = jnp.cumsum(is_first.astype(jnp.int32)) - 1
+  rank = cumsum0(is_first.astype(jnp.int32)) - 1
   num_unique = rank[-1] + 1
   # bring each segment's last position to slot `rank`
   key = jnp.where(is_last, rank, n)
@@ -183,8 +185,8 @@ def _compact_sorted(sid: jax.Array, sg: jax.Array, cap: int, sentinel: int,
     hi, lo = ext[2:], jnp.where(slot0, 0.0, ext[1:-1])
     return jnp.where(valid[:, None], hi - lo, 0.0)
 
-  sum_g = seg_tot(jnp.cumsum(sg, axis=0))
-  sum_sq = seg_tot(jnp.cumsum(sg * sg, axis=0)) if with_sq else None
+  sum_g = seg_tot(cumsum0(sg))
+  sum_sq = seg_tot(cumsum0(sg * sg)) if with_sq else None
   return uids, sum_g, sum_sq, num_unique
 
 
@@ -218,10 +220,10 @@ def _sorted_segments(sid: jax.Array):
   change = sid[1:] != sid[:-1]
   is_first = jnp.concatenate([jnp.ones((1,), bool), change])
   is_last = jnp.concatenate([change, jnp.ones((1,), bool)])
-  first_pos = jax.lax.cummax(jnp.where(is_first, iota, 0))
+  first_pos = cummax0(jnp.where(is_first, iota, 0))
 
   def seg_total(x):
-    csum = jnp.cumsum(x, axis=0)
+    csum = cumsum0(x)
     excl = csum - x
     return csum - excl[first_pos]
 
@@ -1023,7 +1025,7 @@ def _dedup_and_apply(optimizer, table, state, stream: _Stream, lr,
         # the bounded exact fold of the main wave (layout-independent
         # totals, design §20) — the correction must sum identically
         seg_total = lambda x: _seg_fold_bounded(x, first_pos_c, max_seg)
-      rank = jnp.cumsum(is_first.astype(jnp.int32)) - 1
+      rank = cumsum0(is_first.astype(jnp.int32)) - 1
       keep = is_last & (rank >= cap)
       key2 = jnp.where(keep, rank, n)
       order3 = jnp.argsort(key2)[:cap_safe]
@@ -1704,10 +1706,15 @@ def _build_sparse_apply(dist: DistributedEmbedding, optimizer,
         if gi in tiered:
           table, scale, state_g, writeback[gi] = _tier_writeback(
               table, scale, state_g, group.device_rows)
-        new_params[key] = table[None]
-        if scale is not None:
-          new_params[skey] = scale[None]
-        new_state[key] = {k: v[None] for k, v in state_g.items()}
+        # the leading device axis is a reshape, but where a fusion ends in
+        # it XLA names the fusion after it: a tied group's whole-shard
+        # optimizer step would show under no phase
+        with (obs_trace.phase('apply/tied') if head_grads
+              else contextlib.nullcontext()):
+          new_params[key] = table[None]
+          if scale is not None:
+            new_params[skey] = scale[None]
+          new_state[key] = {k: v[None] for k, v in state_g.items()}
         fence = table[0, 0]
 
     for k_idx, gi in enumerate(hot_gis):

@@ -4,10 +4,9 @@ chip's time went, phase by phase: the one capture script.
 Wraps its measured steps in ``obs.trace.profile`` (the JAX profiler with
 Python's call tracer off, plus the program's host spans on the same
 clock) and prints ``tools/trace_report.py --profile`` on what it wrote,
-together with the compiled step's memory analysis.  Run it from an EMPTY
-compile cache (``JAX_COMPILATION_CACHE_DIR=$(mktemp -d)``): the cache's
-key leaves metadata out, so a step served from a cache filled before its
-phases existed shows none.
+together with the compiled step's memory analysis.  The compile cache's
+key takes the phases in (``utils/compile_cache.configure``), so a step
+whose phases changed is compiled again, never served with the old ones.
 
 Usage: python examples/benchmarks/trace_step.py [--trace /tmp/trace_step]
        [--segwalk_apply] [--param_dtype bfloat16] [--model tiny]

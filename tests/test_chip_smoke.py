@@ -144,6 +144,27 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     jax.config.update('jax_compilation_cache_dir', saved)
 
 
+@pytest.mark.parametrize('from_env', [True, False])
+def test_compile_cache_keys_on_the_scopes(monkeypatch, tmp_path, from_env):
+  """The device phases are metadata of the executable: the cache's key
+  takes metadata in, wherever the directory came from, so a step whose
+  phases changed is compiled again and not loaded with the old ones."""
+  from distributed_embeddings_tpu.utils import compile_cache
+  flag = 'jax_compilation_cache_include_metadata_in_key'
+  saved = (jax.config.jax_compilation_cache_dir, getattr(jax.config, flag))
+  try:
+    jax.config.update(flag, False)
+    if from_env:
+      monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    else:
+      monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    compile_cache.configure()
+    assert getattr(jax.config, flag) is True
+  finally:
+    jax.config.update('jax_compilation_cache_dir', saved[0])
+    jax.config.update(flag, saved[1])
+
+
 def test_no_other_compile_cache_setting_in_the_tree():
   """One helper owns the setting: no entry point overrides it in code."""
   hits = []

@@ -9,6 +9,7 @@ import importlib.util
 import json
 import os
 import pathlib
+import re
 import threading
 
 import numpy as np
@@ -612,6 +613,26 @@ def test_span_and_metric_enforcement_no_weaker(tmp_path):
   assert ('registry/span-unregistered', 'another/typo') in caught
   assert ('registry/metric-unregistered', 'typo.metric') in caught
   assert ('registry/phase-unregistered', 'fwd/gather') in caught
+
+
+def test_parallel_binds_cumulative_ops_through_the_scoped_helpers():
+  """An op reaches the trace under the scope of the place that BINDS
+  it, and JAX binds ``cumsum``/``cummax``/``cumprod``/``cumlogsumexp``
+  inside one function a module's call sites share (design §15): in
+  ``parallel/`` they go through ``routing.cumsum0``/``cummax0`` or a
+  device trace books them to no phase.  The two new phases come through
+  the registry like every other (the scan above)."""
+  assert {'residual', 'attention/core'} <= set(obs_trace.REGISTERED_PHASES)
+  bare = re.compile(r'\b(?:jnp|lax)\.(?:cumsum|cummax|cummin|cumprod'
+                    r'|cumlogsumexp)\(')
+  found = []
+  for path in sorted((ROOT / 'distributed_embeddings_tpu' / 'parallel')
+                     .glob('*.py')):
+    for number, line in enumerate(path.read_text().splitlines(), 1):
+      code = line.split('#', 1)[0]
+      if bare.search(code) and '``' not in code:   # docstrings quote them
+        found.append(f'{path.name}:{number}: {line.strip()}')
+  assert not found, '\n'.join(found)
 
 
 # --------------------------------------------------------------------------

@@ -6,8 +6,10 @@ spans reach the profiler's own trace; and ``tools/trace_report.py
 """
 
 import contextlib
+import functools
 import glob
 import gzip
+import importlib
 import importlib.util
 import json
 import pathlib
@@ -84,15 +86,16 @@ def _lower(case):
   return step.jitted.lower(state, cats, y)
 
 
-_HEAVY = re.compile(r' (gather|scatter|sort|dot|convolution|all-to-all)\('
-                    r'.*op_name="([^"]*)"')
+_HEAVY = re.compile(r' (gather|scatter|sort|dot|convolution|all-to-all'
+                    r'|reduce-window)\(.*op_name="([^"]*)"')
 
 
 @pytest.mark.parametrize('case', list(CASES))
 def test_every_section_of_the_step_carries_a_phase(case, monkeypatch):
   """Lower and compile the hybrid step once per builder and apply path:
   the path's phases all occur, and EVERY gather, scatter, sort, dot,
-  convolution and all-to-all that came from the program (``op_name``
+  convolution, all-to-all and running sum (a ``reduce-window``:
+  ``routing.cumsum0``) that came from the program (``op_name``
   starts with ``jit(``; XLA's own helpers carry a bare primitive) sits
   in a registered phase — through ``jvp``/``transpose(jvp)`` too — so a
   sixth builder cannot forget."""
@@ -120,6 +123,47 @@ def test_every_section_of_the_step_carries_a_phase(case, monkeypatch):
         missing.append(m.group(2))
   assert seen > 20, 'the scan found no ops: the HLO text changed shape'
   assert not missing, f'{case}: ops outside any phase: {sorted(set(missing))}'
+
+
+@pytest.mark.parametrize('dtype', [np.int32, np.float32])
+@pytest.mark.parametrize('trailing', [(), (3,)])
+@pytest.mark.parametrize('n', [1, 2, 4097])
+def test_cumulative_helpers_equal_jax_and_carry_the_callers_phase(
+    n, trailing, dtype):
+  """``routing.cumsum0`` / ``cummax0`` are ``jnp.cumsum(axis=0)`` /
+  ``lax.cummax`` bit for bit, and lowered for a TPU (no chip needed)
+  inside a phase the op's location ends in ``<phase>/reduce_window_sum``
+  (``_max``): the leaf trace reductions class as ``cumsum``, under the
+  scope of the place that called it.  ``jnp.cumsum`` itself lowers
+  through a function the module's call sites share, whose ops keep no
+  scope."""
+  from distributed_embeddings_tpu.parallel.routing import cummax0, cumsum0
+  rng = np.random.default_rng(n)
+  x = (rng.integers(-9, 9, (n,) + trailing) if dtype == np.int32
+       else rng.normal(size=(n,) + trailing)).astype(dtype)
+  for ours, theirs in ((cumsum0, functools.partial(jnp.cumsum, axis=0)),
+                       (cummax0, jax.lax.cummax)):
+    got, want = jax.jit(ours)(x), jax.jit(theirs)(x)
+    assert got.dtype == want.dtype == dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+  def scoped(x):
+    with obs_trace.phase('apply/dedup'):
+      a = cumsum0(x)
+    with obs_trace.phase('fwd/route'):
+      return a, cummax0(x)
+
+  text = jax.jit(scoped).trace(x).lower(
+      lowering_platforms=('tpu',)).as_text(debug_info=True)
+  assert 'call @cum' not in text          # bound here, no shared function
+  windows = re.findall(r'"stablehlo\.reduce_window".*?\}\) :.*?loc\((#loc\d+)\)',
+                       text, re.S)
+  named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+  assert [named[w] for w in windows] == [
+      'jit(scoped)/apply/dedup/reduce_window_sum',
+      'jit(scoped)/fwd/route/reduce_window_max']
+  assert (obs_trace.phase_of(named[windows[0]]), obs_trace.phase_of(
+      named[windows[1]])) == (('apply/dedup', None), ('fwd/route', None))
 
 
 def test_phases_add_no_operation(monkeypatch):
@@ -253,6 +297,126 @@ def test_host_spans_reach_the_profilers_trace(_obs_isolated, tmp_path):
   assert rep['unregistered'] == []
   assert tr.main(['--profile', directory, '--require', 'train/step']) == 0
   assert tr.main(['--profile', directory, '--require', 'fwd/route']) == 4
+
+
+# the ``tf_op`` of device ops as a language-model step's trace holds them
+# (``jit(step)`` outside, ``head`` under the step's vjp, the blocks'
+# halves under ``jax.checkpoint``): (tf_op, microseconds, the phase
+# ``trace_report`` books it to = the innermost registered one)
+_LM_OPS = [
+    ('jit(step)/jvp(head)/residual/mul:', 30.0, 'residual'),
+    ('jit(step)/transpose(jvp(head))/checkpoint/rematted_computation/'
+     'residual/reduce_sum:', 10.0, 'residual'),
+    ('jit(step)/jvp(head)/attention/window/dot_general:', 50.0,
+     'attention/window'),
+    ('jit(step)/jvp(head)/attention/window/attention/core/attention_fwd/'
+     'pallas_call:', 20.0, 'attention/core'),
+    ('jit(step)/transpose(jvp(head))/checkpoint/rematted_computation/'
+     'attention/attention/core/transpose:', 5.0, 'attention/core'),
+    ('jit(step)/apply/dedup/g0/reduce_window_sum:', 7.0, 'apply/dedup'),
+    ('reduce_window_sum:', 3.0, None),
+]
+
+
+def test_profile_report_lists_the_new_phases_under_their_layer():
+  """``tools/trace_report.py --profile`` takes ``residual`` and
+  ``attention/core`` from ``REGISTERED_PHASES`` alone: each op goes to
+  its innermost registered phase under the layer the table names, a
+  running sum bound under ``apply/dedup`` is the phase's and a bare one
+  ``unscoped``; and the benchmark's reduction (``lib/xtrace``, whose
+  scope rule is the copy of this one) reads the same ops by prefix:
+  the core counts for ``attention`` and ``attention/window`` too."""
+  tr = _load_trace_report()
+  events = [
+      {'ph': 'M', 'name': 'process_name', 'pid': 1,
+       'args': {'name': '/device:TPU:0'}},
+      {'ph': 'M', 'name': 'thread_name', 'pid': 1, 'tid': 1,
+       'args': {'name': 'XLA Ops'}},
+      {'ph': 'M', 'name': 'thread_name', 'pid': 1, 'tid': 2,
+       'args': {'name': 'XLA Modules'}},
+      {'ph': 'X', 'pid': 1, 'tid': 2, 'name': 'jit_step(1)', 'ts': 0.0,
+       'dur': 200.0}]
+  ts = 0.0
+  for i, (tf_op, us, _) in enumerate(_LM_OPS):
+    events.append({'ph': 'X', 'pid': 1, 'tid': 1, 'name': f'fusion.{i}',
+                   'ts': ts, 'dur': us,
+                   'args': {'tf_op': tf_op, 'long_name': f'%fusion.{i} = '}})
+    ts += us
+  rep = tr.profile_report(events, program='jit_step')
+  want = {}
+  for _, us, name in _LM_OPS:
+    if name:
+      want[name] = want.get(name, 0.0) + us / 1000.0
+  assert {n: p['ms'] for n, p in rep['phases'].items()} == pytest.approx(want)
+  assert {rep['phases'][n]['layer'] for n in (
+      'residual', 'attention/core', 'attention/window')} == {'dense head'}
+  assert rep['unscoped']['ms'] == pytest.approx(0.003)
+  assert [r['tf_op'] for r in rep['unscoped']['ops']] == ['reduce_window_sum:']
+  text = tr.format_profile(rep)
+  assert re.search(r'residual\s+dense head', text)
+  assert re.search(r'attention/core\s+dense head', text)
+  # the benchmark's own copy of the scope rule, on the same ops
+  xtrace = importlib.import_module('benchmarks.lib.xtrace')
+  layer = importlib.import_module('benchmarks.lib.layer')
+  paths = {}
+  for tf_op, us, name in _LM_OPS:
+    path = xtrace.scope_path(tf_op)
+    assert (path == xtrace.UNSCOPED) == (name is None), tf_op
+    assert name is None or layer.under(path, name), (path, name)
+    paths[path] = paths.get(path, 0.0) + us
+  under = lambda prefix: sum(us for path, us in paths.items()
+                             if layer.under(path, prefix))
+  assert (under('attention/core'), under('attention/window'),
+          under('attention'), under('residual')) == (25.0, 70.0, 75.0, 40.0)
+
+
+def test_profile_report_on_the_recorded_hybrid_step():
+  """Three steps of the small hybrid step recorded on one v5e from PR
+  35's tree (``benchmarks/dev/record_hybrid_trace.py``): the report
+  lists ``residual`` and ``attention/core`` under ``dense head`` with no
+  rule of its own for them, the core beside what is left of
+  ``attention``, and phases and remainders are the busy time."""
+  tr = _load_trace_report()
+  rep = tr.profile_report(tr.load_profile(str(
+      ROOT / 'benchmarks' / 'tests' / 'data'
+      / 'v5e_hybrid_step_scoped.trace.json.gz')), program='jit_step')
+  assert (rep['program'], rep['steps']) == ('jit_step', 3)
+  phases = rep['phases']
+  assert phases['residual']['layer'] == 'dense head'
+  assert phases['attention/core']['layer'] == 'dense head'
+  assert phases['residual']['ms'] == pytest.approx(0.0082, abs=1e-4)
+  assert phases['attention/core']['ms'] == pytest.approx(0.0532, abs=1e-4)
+  # the innermost phase takes an op: ``attention`` keeps what stands
+  # around the core, and the two are the benchmark's ``attention_ms``
+  assert phases['attention']['ms'] + phases['attention/core']['ms'] == (
+      pytest.approx(0.0681, abs=1e-4))
+  total = (sum(p['ms'] for p in phases.values()) + rep['unscoped']['ms']
+           + rep['no_source']['ms'])
+  assert total == pytest.approx(rep['busy_ms'], rel=1e-3)
+
+
+@pytest.mark.parametrize('second,same', [
+    ('%fused.9 (p.4: f32[2]) -> f32[2] {\n  %p.4 = f32[2] parameter(0)\n'
+     '  ROOT %add.7 = f32[2] add(%p.4, %p.4), metadata={op_name="a/b/add"}\n'
+     '}\n', True),
+    ('%fused.9 (p.4: f32[2]) -> f32[2] {\n  %p.4 = f32[2] parameter(0)\n'
+     '  ROOT %add.7 = f32[2] multiply(%p.4, %p.4)\n}\n', False)])
+def test_hlo_same_sees_through_names_and_metadata_only(tmp_path, second,
+                                                       same):
+  """``tools/hlo_same.py``: two compiled texts are the same program
+  where they differ in ``metadata={...}`` and in the numbers XLA gives
+  its instructions, and not where an operation differs."""
+  spec = importlib.util.spec_from_file_location(
+      'hlo_same_for_phases', ROOT / 'tools' / 'hlo_same.py')
+  hlo_same = importlib.util.module_from_spec(spec)
+  spec.loader.exec_module(hlo_same)
+  first = ('%fused.3 (p.1: f32[2]) -> f32[2] {\n  %p.1 = f32[2] parameter(0)\n'
+           '  ROOT %add.2 = f32[2] add(%p.1, %p.1), metadata={op_name="add"}\n'
+           '}\n')
+  (tmp_path / 'a.txt').write_text(first)
+  (tmp_path / 'b.txt').write_text(second)
+  assert hlo_same.main(['', str(tmp_path / 'a.txt'),
+                        str(tmp_path / 'b.txt')]) == (0 if same else 1)
 
 
 # --------------------------------------------------------------------------

@@ -13,10 +13,11 @@ Covers every kernel configuration AND the full 4-chip hybrid train
 step (flat and two-axis meshes) compiled for v5e 2x2, and holds the
 default XLA apply's compiled step to no whole-shard copy (ISSUE 25) and
 its row writes to the scatter emitter their share of the shard calls
-for (ISSUE 30, two steps at the benchmark cells' own shapes).
+for (ISSUE 30, two steps at the benchmark cells' own shapes), and its
+running sums to the phase that called them (ISSUE 35).
 
 Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
-spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 61 cases pass
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 63 cases pass
 in about 135 s on 8 host cores (90 s of it the two full-size steps).
 This is the free gate to run
 (``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
@@ -221,6 +222,76 @@ def test_full_hybrid_train_step_compiles_for_v5e(v5e, two_axis,
     temps = getattr(ma, 'temp_size_in_bytes', 0) or 0
     args_b = getattr(ma, 'argument_size_in_bytes', 0) or 0
     assert temps + args_b < 16 * 2**30, (temps, args_b)
+
+
+def _running_sum_names(hlo):
+  """``op_name`` of every instruction of a compiled program that came
+  from a running sum or maximum the PROGRAM bound (it starts with
+  ``jit(`` and ends in ``reduce_window_sum`` / ``_max``): what XLA's
+  reduce-window rewriter makes of the reducer's body.  What the rewriter
+  builds with no source of its own is XLA's and carries none of the
+  program's names (the tiled ``reduce-window``s themselves, bare on one
+  chip and ``jit(step)/shard_map/reduce-window.<n>`` under a
+  ``shard_map``; a reducer's parameters, bare ``reduce_window_sum``)."""
+  names = re.findall(r'op_name="(jit\([^"]*reduce_window_(?:sum|max))"', hlo)
+  assert len(names) >= 4, 'the scan found no running sum: the text changed'
+  return names
+
+
+def test_no_running_sum_of_the_apply_lacks_a_phase_on_v5e(v5e):
+  """The sparse apply of the 4-chip step (the default XLA apply),
+  compiled for v5e 2x2: every op that came from a running sum or maximum
+  (``routing.cumsum0`` / ``cummax0``) carries a registered phase in its
+  ``op_name``.  ``jnp.cumsum`` left them ``jit(step)/shard_map/
+  reduce_window_sum``, nothing between: the device trace booked such an
+  op to ``unscoped``, whatever phase asked for it."""
+  import optax
+  from jax.experimental import topologies
+  from distributed_embeddings_tpu.obs import trace as obs_trace
+  from distributed_embeddings_tpu.parallel import (DistributedEmbedding,
+                                                   SparseAdagrad,
+                                                   TableConfig,
+                                                   make_hybrid_train_step)
+  mesh = topologies.make_mesh(v5e, (4,), ('data',))
+  configs = [TableConfig(512, 16, 'sum'), TableConfig(300, 16, 'sum'),
+             TableConfig(200, 128, 'sum'), TableConfig(100, 8, 'mean')]
+  dist = DistributedEmbedding(configs, mesh=mesh)
+  dense_opt = optax.sgd(0.01)
+
+  def head(dp, eo, b):
+    h = jnp.concatenate(list(eo), axis=-1)
+    return jnp.mean((h @ dp['kernel'] - b)**2)
+
+  step = make_hybrid_train_step(dist, head, dense_opt, SparseAdagrad(0.01),
+                                donate=False, jit=False)
+  state, cats, labels = _step_avals(dist, mesh, configs, 512, dense_opt)
+  names = _running_sum_names(
+      jax.jit(step).lower(state, cats, labels).compile().as_text())
+  assert {obs_trace.phase_of(n) and obs_trace.phase_of(n)[0]
+          for n in names} == {'apply/dedup'}, sorted(set(names))
+
+
+def test_no_running_sum_of_the_route_lacks_a_phase_on_v5e(v5e):
+  """The route's two users of a running sum (the hot-row cache's dedup
+  of ids, ``unique_with_inverse``, and its per-row sums of cotangents,
+  ``dense_segment_sum``), each compiled for a v5e under the phase that
+  calls it: what XLA makes of their running sums carries that phase."""
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.obs import trace as obs_trace
+  from distributed_embeddings_tpu.parallel import routing
+  sh = SingleDeviceSharding(v5e.devices[0])
+
+  def route(ids, seg, rows):
+    with obs_trace.phase('fwd/route'):
+      uniq, inv = routing.unique_with_inverse(ids, ids.shape[1])
+    with obs_trace.phase('bwd/route'):
+      return uniq, inv, routing.dense_segment_sum(seg, rows, 1024)
+
+  names = _running_sum_names(jax.jit(route).lower(
+      _sds((4, 8192), jnp.int32, sh), _sds((65536,), jnp.int32, sh),
+      _sds((65536, 16), jnp.float32, sh)).compile().as_text())
+  assert {obs_trace.phase_of(n) and obs_trace.phase_of(n)[0]
+          for n in names} == {'fwd/route', 'bwd/route'}, sorted(set(names))
 
 
 @pytest.mark.parametrize('rows,width,batch,cap', [
@@ -477,7 +548,9 @@ def test_attention_kernels_compile_for_v5e_under_their_phase(
   language-model cells' own shapes, value and gradients under
   ``jax.checkpoint`` inside the phase the model opens around it: the
   forward twice, dq, dk|dv, and every one of them carries the phase in
-  its ``op_name`` (after it come ``checkpoint``, ``rematted_computation``
+  its ``op_name`` (after it come ``attention/core``, which
+  ``blocked_attention`` opens around the kernels and
+  ``attention_core_ms`` reads, ``checkpoint``, ``rematted_computation``
   and the kernel's own name), which is how the benchmark's
   ``attention_ms`` and ``window_attention_ms`` book their time."""
   from jax.sharding import SingleDeviceSharding
@@ -499,7 +572,11 @@ def test_attention_kernels_compile_for_v5e_under_their_phase(
       _sds((seqs, length, kv_heads, d), jnp.float32, sh),
       _sds((seqs, length), jnp.int32, sh)).compile()
   names = _kernel_calls(compiled.as_text())
-  assert all(obs_trace.phase_of(name) == (phase, None)
+  # the core's own phase is the innermost, the caller's stands before it
+  from benchmarks.lib import layer, xtrace
+  assert all(obs_trace.phase_of(name) == ('attention/core', None)
+             and layer.under(xtrace.scope_path(name),
+                             f'{phase}/attention/core')
              for name in names), names
   assert sorted(name.split('/')[-2] for name in names) == [
       'attention_dkv', 'attention_dq', 'attention_fwd', 'attention_fwd']
@@ -534,6 +611,7 @@ def test_moe_lm_layer_compiles_for_v5e_with_no_score_buffer(
   n >= 512]`` array of scores is left in the compiled program (the
   unrolled blocks wrote and read ``f32[2,4,8,512,2560]`` a block)."""
   from jax.sharding import SingleDeviceSharding
+  from benchmarks.lib import layer, xtrace
   from distributed_embeddings_tpu.models import moe_lm
   from distributed_embeddings_tpu.obs import trace as obs_trace
   cfg = moe_lm.MoELMConfig(
@@ -559,7 +637,9 @@ def test_moe_lm_layer_compiles_for_v5e_with_no_score_buffer(
   text = compiled.as_text()
   names = _kernel_calls(text)
   assert names and all(
-      obs_trace.phase_of(name) == ('attention/window', None)
+      obs_trace.phase_of(name) == ('attention/core', None)
+      and layer.under(xtrace.scope_path(name),
+                      'attention/window/attention/core')
       for name in names), names
   scores = {m.group(0) for m in re.finditer(r'f32\[(?:\d+,)+512,(\d+)\]', text)
             if int(m.group(1)) >= 512}

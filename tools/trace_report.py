@@ -22,9 +22,9 @@ code, ``REGISTERED_PHASES`` for sections of the compiled step).
 
 Phases and the two remainders are SELF times (a ``while`` or
 ``conditional`` does not count its body twice), so they sum to the
-chip's busy time.  A program served from a compile cache filled before
-its phases existed shows none: capture from an empty cache directory
-(docs/userguide.md).
+chip's busy time.  The phases are metadata of the executable; the
+compile cache's key takes them in (``utils/compile_cache.configure``),
+so a trace shows the scopes of the source it was captured from.
 
 Without ``--profile`` the argument is a Chrome-trace-event JSON written
 by ``distributed_embeddings_tpu.obs.trace.save()``, and the report is:
