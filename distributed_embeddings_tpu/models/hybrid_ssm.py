@@ -28,14 +28,19 @@ PACKED DOCUMENTS ARE INDEPENDENT (``segment_ids``): the state and the
 convolution's window restart at a document's first position and
 attention sees only its own document.
 
-The scan is the chunked state-space-dual form in plain ``jax.numpy``:
-per chunk of ``mamba_chunk_size`` positions the cumulative log-decay,
-the masked ``C B^T`` product against ``x``, and the chunk's end state;
-a ``lax.scan`` carries the state from chunk to chunk, its body under
-``jax.checkpoint`` so that no ``[heads, chunk, chunk]`` array outlives
-its chunk.  Decay, ``softplus`` and the recurrence are float32; matrix
-products take float32 operands at the backend's default precision (on
-a TPU: bfloat16 products, float32 accumulation), as the other heads.
+The scan is the chunked state-space-dual form: per chunk of
+``mamba_chunk_size`` positions the cumulative log-decay, the masked ``C
+B^T`` product against ``x``, and the chunk's end state.  ``ssd_scan``
+runs it on the kernels of ``ops/pallas_ssd.py`` where the program is
+compiled for a TPU and the shapes fit their tiles (every ``[heads,
+chunk, chunk]`` array in VMEM, the state carried there from chunk to
+chunk), and otherwise in plain ``jax.numpy`` (``_ssd_chunk``), a
+``lax.scan`` carrying the state, its body under ``jax.checkpoint`` so
+that no ``[heads, chunk, chunk]`` array outlives its chunk.  Decay,
+``softplus`` and the recurrence are float32; matrix products take
+float32 operands at the backend's default precision (on a TPU: bfloat16
+products, float32 accumulation), as the other heads, and the kernels
+round them as that default does.
 
 Attention's core (``blocked_attention``) is the fused kernels of
 ``ops/pallas_attention.py`` where the program is compiled for a TPU and
@@ -66,7 +71,7 @@ import numpy as np
 
 from distributed_embeddings_tpu.obs import metrics as obs_metrics
 from distributed_embeddings_tpu.obs import trace as obs_trace
-from distributed_embeddings_tpu.ops import pallas_attention
+from distributed_embeddings_tpu.ops import pallas_attention, pallas_ssd
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -256,11 +261,18 @@ def ssd_scan(x, dt, a_heads, b, c, segment_ids, chunk_size):
   ``x [S, L, H, P]``, ``dt [S, L, H]`` (positive), ``a_heads [H]``
   (negative), ``b``, ``c`` ``[S, L, N]``, ``segment_ids [S, L]`` counting
   a sequence's documents from 0.  ``L`` must be a multiple of
-  ``chunk_size``."""
+  ``chunk_size``.  The one place that chooses: the kernels of
+  ``ops/pallas_ssd.py`` where the program is compiled for a TPU and the
+  static shapes fit their tiles (the counter ``mixer.scan_kernel_layers``
+  says at trace time that a compiled step holds them), and a
+  ``lax.scan`` over ``_ssd_chunk`` otherwise."""
   seqs, length = x.shape[:2]
   if length % chunk_size:
     raise ValueError(f'ssd_scan: sequence length {length} is no multiple '
                      f'of the chunk size {chunk_size}')
+  if pallas_ssd.takes(x.shape, b.shape[-1], chunk_size):
+    obs_metrics.inc('mixer.scan_kernel_layers')
+    return pallas_ssd.scan(x, dt, a_heads, b, c, segment_ids, chunk_size)
   chunks = length // chunk_size
   split = lambda a: jnp.swapaxes(
       a.reshape((seqs, chunks, chunk_size) + a.shape[2:]), 0, 1)

@@ -13,11 +13,12 @@ Covers every kernel configuration AND the full 4-chip hybrid train
 step (flat and two-axis meshes) compiled for v5e 2x2, and holds the
 default XLA apply's compiled step to no whole-shard copy (ISSUE 25) and
 its row writes to the scatter emitter their share of the shard calls
-for (ISSUE 30, two steps at the benchmark cells' own shapes), and its
-running sums to the phase that called them (ISSUE 35).
+for (ISSUE 30, two steps at the benchmark cells' own shapes), its
+running sums to the phase that called them (ISSUE 35), and the
+state-space scan kernels to ``mixer/selective_scan`` (ISSUE 36).
 
 Marked ``slow`` to stay out of the tier-1 time budget, which is nearly
-spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 63 cases pass
+spent: with the installed jax 0.9.0 / libtpu 0.0.34 all 64 cases pass
 in about 135 s on 8 host cores (90 s of it the two full-size steps).
 This is the free gate to run
 (``pytest tests/test_tpu_lowering.py -m slow``) before any chip call.
@@ -782,3 +783,52 @@ def test_attention_kernels_compile_under_a_callers_highest_precision(
         _sds((2, 8192, 8, 64), jnp.float32, sh),
         _sds((2, 8192), jnp.int32, sh)).compile()
   assert len(_kernel_calls(compiled.as_text())) == 3
+
+
+@pytest.fixture
+def ssd_for_tpu():
+  # the AOT trace runs on the CPU backend: ASSUME_TPU makes ``ssd_scan``
+  # choose as it does on the chip
+  from distributed_embeddings_tpu.ops import pallas_ssd
+  pallas_ssd.ASSUME_TPU = True
+  try:
+    yield pallas_ssd
+  finally:
+    pallas_ssd.ASSUME_TPU = False
+
+
+def test_scan_kernels_compile_for_v5e_under_their_phase(v5e, ssd_for_tpu):
+  """One Mamba block of ``granite-train-packed`` (the cell's widths, 2 x
+  4,096 positions) under ``jax.checkpoint``, value and gradients as the
+  step has them: the forward kernel twice (the forward pass and the
+  block's recomputation) and the backward kernel once, and every one of
+  them carries ``mixer/selective_scan`` in its ``op_name``, which is how
+  ``ssm_scan_ms`` and ``ssm_scan_roofline`` book their time."""
+  import json
+  from jax.sharding import SingleDeviceSharding
+  from benchmarks.lib import layer, xtrace
+  from distributed_embeddings_tpu.models import hybrid_ssm
+  sh = SingleDeviceSharding(v5e.devices[0])
+  with open('benchmarks/configs/granite-4.0-h-micro.json') as f:
+    cfg = hybrid_ssm.HybridSSMConfig.from_dict(json.load(f))
+  d, inner, heads = cfg.hidden_size, cfg.d_inner, cfg.mamba_n_heads
+  f32 = lambda *shape: _sds(shape, jnp.float32, sh)
+  p = {'mixer_norm': f32(d), 'mlp_norm': f32(d),
+       'mlp_in': f32(d, 2 * cfg.intermediate_size),
+       'mlp_out': f32(cfg.intermediate_size, d),
+       'mixer': {'in_proj': f32(d, inner + cfg.conv_dim + heads),
+                 'conv_kernel': f32(cfg.mamba_d_conv, cfg.conv_dim),
+                 'conv_bias': f32(cfg.conv_dim), 'dt_bias': f32(heads),
+                 'A_log': f32(heads), 'D': f32(heads),
+                 'gated_norm': f32(inner), 'out_proj': f32(inner, d)}}
+  block = jax.checkpoint(functools.partial(hybrid_ssm.layer, cfg, 'mamba'))
+  loss = lambda p, x, seg: jnp.sum(block(p, x, seg) ** 2)
+  assert ssd_for_tpu.takes((2, 4096, heads, cfg.mamba_d_head),
+                           cfg.mamba_d_state, cfg.mamba_chunk_size)
+  compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+      p, f32(2, 4096, d), _sds((2, 4096), jnp.int32, sh)).compile()
+  names = _kernel_calls(compiled.as_text())
+  assert sorted(name.split('/')[-2] for name in names) == [
+      'ssd_scan_bwd', 'ssd_scan_fwd', 'ssd_scan_fwd'], names
+  assert all(layer.under(xtrace.scope_path(name), 'mixer/selective_scan')
+             for name in names), names
