@@ -67,6 +67,9 @@ METRIC_TYPES: Dict[str, str] = {
     # routed blocks whose output the backward pass keeps for the norm
     # after it, one a block at trace time (models/moe_lm.layer)
     'moe.kept_outputs': 'counter',
+    # the dedup's payload reads of narrow rows gathered as 128-lane rows,
+    # one a gather at trace time (parallel/sparse.take_rows)
+    'apply.lane_packed_gathers': 'counter',
     # a routed layer's load (models/moe_lm.record_routing_stats)
     'moe.assignments_held': 'gauge',
     'moe.load_max_over_mean': 'gauge',

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from distributed_embeddings_tpu.obs import metrics as obs_metrics
 from distributed_embeddings_tpu.obs import trace as obs_trace
 from distributed_embeddings_tpu.ops.ragged import RaggedBatch
 from distributed_embeddings_tpu.parallel.dist_embedding import (
@@ -130,8 +131,80 @@ def _sorted_payload(grads: jax.Array, order: jax.Array,
   if g_index is not None and g_index.shape[0] != order.shape[0]:
     raise ValueError(f'g_index length {g_index.shape[0]} != stream '
                      f'length {order.shape[0]}')  # jnp.take would silently clip
-  return (grads[order] if g_index is None else
-          grads[jnp.take(g_index, order)]).astype(jnp.float32)
+  idx = order if g_index is None else jnp.take(g_index, order)
+  return take_rows(grads, idx, monotone=False).astype(jnp.float32)
+
+
+def lane_packed_take(x: jax.Array, idx: jax.Array) -> jax.Array:
+  """``x[idx]`` for a narrow ``[N, w]`` array (``128 % w == 0``), read
+  as 128-lane rows: ``x`` viewed row-major as ``[ceil(N / p), 128]``
+  with ``p = 128 // w`` (zero rows padded at the end), the packed rows
+  ``idx // p`` gathered, lane block ``idx % p`` selected out of each.
+  The inverse of ``pallas_segwalk.lane_expand``.  Float32 only; data
+  movement only, so the result is ``x[idx]`` bit for bit: the lane block
+  is picked as raw bits, never by a masked float sum, which would turn
+  ``-0.0`` into ``0.0`` (and, on the chip, flush subnormals).  ``idx``
+  must lie in ``[0, N)``, as a permutation's entries do."""
+  n_rows, w = x.shape
+  p = 128 // w
+  if n_rows % p:
+    x = jnp.pad(x, ((0, p - n_rows % p), (0, 0)))
+  # the blocks are picked rows-minor, ``[p, w, n]``, by ONE reduction
+  # over them (the other blocks' bits masked to zero, so an OR is
+  # exact): narrow ``[n, w]`` slices of the gathered rows would be laid
+  # out lane-padded (8x their bytes at w = 16) and held apart; ``[w, n]``
+  # is dense and is the layout the result's readers keep it in
+  blocks = x.reshape(-1, 128)[idx // p].T.reshape(p, w, -1)
+  bits = jax.lax.bitcast_convert_type(blocks, jnp.uint32)
+  mine = jnp.arange(p)[:, None, None] == (idx % p)[None, None, :]
+  picked = jnp.bitwise_or.reduce(jnp.where(mine, bits, 0), axis=0)
+  return jax.lax.bitcast_convert_type(picked, jnp.float32).T
+
+
+def take_rows(x: jax.Array, idx: jax.Array, monotone: bool) -> jax.Array:
+  """``x[idx]`` for the dedup's payload reads, through
+  ``lane_packed_take`` where ``packed_gather_gains`` says the 128-lane
+  read is the cheaper at the static shapes and the access kind
+  (``monotone``: ascending ``idx``, as the running sum is read); each
+  traced gather that takes it counts one ``apply.lane_packed_gathers``."""
+  if not packed_gather_gains(idx.shape[0], *x.shape, x.dtype, monotone):
+    return x[idx]
+  obs_metrics.inc('apply.lane_packed_gathers')
+  return lane_packed_take(x, idx)
+
+
+# ns a row on a v5e of reading float32 rows of a narrow width out of an
+# operand laid out rows-minor, as the step lays out the narrow arrays it
+# computes, ``{0,1:T(8,128)}``: XLA's gather of the narrow rows
+# (``plain``) and ``lane_packed_take`` (``packed``, the selection
+# included), each (random, ascending), and the packed read's copy of the
+# operand into ``[N * w / 128, 128]``, per row of the operand
+# (``relayout``).  The means of ``examples/benchmarks/scatter_probe.py``'s
+# ``gather-plain``, ``-sorted``, ``-packed`` and ``-packed-sorted`` at
+# 1,116,536 and 2,883,584 rows read from 2,883,584 (PERF.md section 6),
+# but one: at 8 lanes the step reads its random rows at 4.93 ns
+# (``tiny-train-zipf``'s group 0 in its traced step, 13.25 ms for
+# 2,686,976 rows; the compiler places that operand in memory space 1,
+# which the probe's 10.03 did not see).  At 64 lanes and up the plain
+# gather reads at the wide cost.
+_GATHER_NS = {  # width: ({'plain'|'packed': (random, ascending)}, relayout)
+    8: ({'plain': (4.93, 20.43), 'packed': (6.00, 6.01)}, 1.82),
+    16: ({'plain': (17.02, 30.60), 'packed': (11.31, 11.44)}, 1.92),
+    32: ({'plain': (30.82, 51.07), 'packed': (11.63, 11.67)}, 2.12),
+    64: ({'plain': (7.82, 7.70), 'packed': (12.10, 12.06)}, 2.56),
+}
+
+
+def packed_gather_gains(n: int, rows: int, width: int, dtype,
+                        monotone: bool) -> bool:
+  """Whether ``lane_packed_take`` reads ``n`` float32 rows of ``width``
+  lanes out of ``rows`` faster than XLA's gather of the narrow rows:
+  ``packed n + relayout rows < plain n`` by ``_GATHER_NS``."""
+  if dtype != jnp.float32 or width not in _GATHER_NS:
+    return False
+  ns, relayout = _GATHER_NS[width]
+  plain, packed = ns['plain'][monotone], ns['packed'][monotone]
+  return packed * n + relayout * rows < plain * n
 
 
 @obs_trace.phase('apply/dedup')
@@ -181,7 +254,7 @@ def _compact_sorted(sid: jax.Array, sg: jax.Array, cap: int, sentinel: int,
   slot0 = (jnp.arange(valid.shape[0]) == 0)[:, None]
 
   def seg_tot(csum):
-    ext = csum[order2]
+    ext = take_rows(csum, order2, monotone=True)
     hi, lo = ext[2:], jnp.where(slot0, 0.0, ext[1:-1])
     return jnp.where(valid[:, None], hi - lo, 0.0)
 

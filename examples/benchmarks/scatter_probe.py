@@ -18,25 +18,51 @@ last line is the whole grid's:
   for v in add-both add-unique set-both set-unique; do
     python examples/benchmarks/scatter_probe.py --variant $v; done
 
+The gathers read rows the way the sparse apply's dedup does:
+``gather-plain`` (random rows) and ``gather-sorted`` (ascending rows, as
+the running sum is read) take XLA's gather of the narrow rows,
+``gather-packed`` and ``gather-packed-sorted`` the same rows through
+``sparse.lane_packed_take`` (128-lane rows, lanes picked).  A narrow
+array the step computes is laid out rows-minor (``{0,1:T(8,128)}`` in
+the compiled step, PERF.md section 6), so below 128 lanes the
+operand is built that way, as the transpose of a ``[w, rows]`` array;
+the copy into the packed view that the packed read adds once a step is
+timed on its own (``relayout_ms``).  What the readings decided (v5e,
+one operand of 2,883,584 rows at widths 8 to 128) is
+``sparse.packed_gather_gains``:
+
+  for v in gather-plain gather-sorted gather-packed gather-packed-sorted; do
+    python examples/benchmarks/scatter_probe.py --variant $v \
+        --rows 2883584 --n 1116536,2883584 --width 8,16,32,64,128; done
+
 Usage: python examples/benchmarks/scatter_probe.py --variant add-both
        [--rows 2500000,8775000,20025088] [--n 92272,1116536,2883584]
        [--width 128] [--iters 10] [--out chiprun_out/scatter_probe.jsonl]
+(``--width`` takes a list: 8,16,128)
 """
 
 import argparse
 import json
 import os
+import sys
 import time
+
+sys.path.insert(0, os.getcwd())
 
 # variant -> (ids are sorted, strictly unique, with a distinct
 # out-of-bounds tail; what runs).  'both' = unique_indices and
 # indices_are_sorted, what every apply site passed before PR 30.
 VARIANTS = ('add-both', 'add-unique', 'set-both', 'set-unique',
-            'add-plain', 'gather-plain', 'gather-sorted')
+            'add-plain', 'gather-plain', 'gather-sorted', 'gather-packed',
+            'gather-packed-sorted')
+# variants whose ids are drawn at random (the others: ascending, unique)
+RANDOM_IDS = ('add-plain', 'gather-plain', 'gather-packed')
 
 
 def _op(variant, rows):
   import jax.numpy as jnp
+  from distributed_embeddings_tpu.parallel.sparse import lane_packed_take
+  packed = lambda t, i, upd: lane_packed_take(t, jnp.clip(i, 0, rows - 1))
   both = dict(mode='drop', unique_indices=True, indices_are_sorted=True)
   uniq = dict(mode='drop', unique_indices=True)
   return {
@@ -48,6 +74,8 @@ def _op(variant, rows):
       'gather-plain': lambda t, i, upd: t.at[jnp.clip(i, 0, rows - 1)].get(),
       'gather-sorted': lambda t, i, upd: t.at[jnp.clip(i, 0, rows - 1)].get(
           indices_are_sorted=True),
+      'gather-packed': packed,
+      'gather-packed-sorted': packed,
   }[variant]
 
 
@@ -111,36 +139,74 @@ def main():
   p.add_argument('--rows', type=ints, default=[2_500_000, 8_775_000,
                                                20_025_088])
   p.add_argument('--n', type=ints, default=[92_272, 1_116_536, 2_883_584])
-  p.add_argument('--width', type=int, default=128)
+  p.add_argument('--width', type=ints, default=[128])
   p.add_argument('--iters', type=int, default=10)
   p.add_argument('--out', default='chiprun_out/scatter_probe.jsonl')
   args = p.parse_args()
 
   import jax
+  import numpy as np
+
+  rng = np.random.default_rng(0)
+  dev = jax.devices()[0]
+  os.makedirs(os.path.dirname(args.out) or '.', exist_ok=True)
+  for w in args.width:
+    print(f'variant={args.variant} w={w} backend={jax.default_backend()} '
+          f'device={dev.device_kind}')
+    _probe_width(args, w, rng, dev)
+    with open(args.out) as fh:
+      readings = [json.loads(l) for l in fh if l.strip()]
+    here = [r for r in readings
+            if r['width'] == w and r['device_kind'] == dev.device_kind]
+    print(json.dumps({'fit_ns': fit(here), 'width': w,
+                      'device_kind': dev.device_kind,
+                      'readings': len(here)}))
+
+
+def _relayout_ms(src, iters):
+  """Least ms of one copy of a rows-minor ``[rows, w]`` operand (``src``
+  is its ``[w, rows]`` transpose) into the row-major ``[rows * w / 128,
+  128]`` that ``lane_packed_take`` gathers from: what the packed read
+  adds a step beyond its gather, once per operand."""
+  import jax
+  f = jax.jit(lambda x: x.T.reshape(-1, 128))
+  f(src).block_until_ready()  # compiles
+  times = []
+  for _ in range(iters):
+    t0 = time.perf_counter()
+    f(src).block_until_ready()
+    times.append(time.perf_counter() - t0)
+  return min(times) * 1e3
+
+
+def _probe_width(args, w, rng, dev):
+  """Every ``--rows`` x ``--n`` reading of one variant at width ``w``,
+  each printed and appended to ``--out``."""
+  import jax
   import jax.numpy as jnp
   import numpy as np
 
-  w, iters, variant = args.width, args.iters, args.variant
+  iters, variant = args.iters, args.variant
   gather = variant.startswith('gather')
-  rng = np.random.default_rng(0)
-  dev = jax.devices()[0]
-  print(f'variant={variant} w={w} backend={jax.default_backend()} '
-        f'device={dev.device_kind}')
-  os.makedirs(os.path.dirname(args.out) or '.', exist_ok=True)
+  # below 128 lanes a gather's operand is laid out rows-minor, as the
+  # step's computed narrow arrays are: the transpose of [w, rows]
+  rows_minor = gather and w < 128
   for rows in args.rows:
-    table = jnp.zeros((rows, w), jnp.float32)
+    table = jnp.zeros((w, rows) if rows_minor else (rows, w), jnp.float32)
     for n in args.n:
       upd = jnp.asarray(rng.normal(size=(n, w)).astype(np.float32))
       # (an argument of the jitted loop: closed over, the 1.4 GiB of
       # update rows would be compiled into the executable)
       op = _op(variant, rows)
       stacks = [jnp.asarray(_ids(rng, rows, n, iters,
-                                 not variant.endswith('plain')))
+                                 variant not in RANDOM_IDS))
                 for _ in range(3)]
       if gather:
         # reduce over ALL gathered rows so no slice-of-gather
-        # simplification can shrink the measured gather
-        def run(tab, upd, s, op=op):
+        # simplification can shrink the measured gather (a relayout of
+        # the operand, which the loop hoists, is timed on its own below)
+        def run(src, upd, s, op=op):
+          tab = src.T if rows_minor else src
           def body(c, ids):
             return c + op(tab, ids, upd).sum(axis=0), None
           return jax.lax.scan(body, jnp.zeros((w,)), s)[0]
@@ -169,19 +235,13 @@ def main():
                      ns_per_update_row=ms * 1e6 / n,
                      ns_per_operand_row=ms * 1e6 / rows,
                      platform=dev.platform, device_kind=dev.device_kind)
+      if 'packed' in variant and rows_minor:
+        reading['relayout_ms'] = _relayout_ms(table, iters)
       print(json.dumps(reading), flush=True)
       with open(args.out, 'a') as fh:
         fh.write(json.dumps(reading) + '\n')
       del upd, stacks, f
     del table
-  with open(args.out) as fh:
-    readings = [json.loads(l) for l in fh if l.strip()]
-  here = [r for r in readings
-          if r['width'] == w and r['device_kind'] == dev.device_kind]
-  print(json.dumps({'fit_ns': fit(here), 'width': w,
-                    'device_kind': dev.device_kind,
-                    'readings': len(here)}))
-
 
 if __name__ == '__main__':
   main()

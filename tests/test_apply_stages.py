@@ -473,3 +473,48 @@ def test_choose_apply_names_the_emitter_the_main_wave_takes(name):
                     f'{operand:,} ({100.0 * wave / operand:.2f}%): '
                     f'{choice.write}'), line
     assert 'unknown' in write_rows_lines(dist, opt)[0]
+
+
+def _packed_gathers(fn, *args):
+  """``apply.lane_packed_gathers`` as ``fn`` is traced on ``args``."""
+  from distributed_embeddings_tpu import obs
+  obs.reset()
+  obs.metrics.enable()
+  try:
+    jax.make_jaxpr(fn)(*args)
+    return obs.metrics.snapshot().get('apply.lane_packed_gathers', 0)
+  finally:
+    obs.metrics.disable()
+    obs.reset()
+
+
+@pytest.mark.parametrize('width,with_sq,cap,count', [
+    (16, False, 64, 2), (16, True, 64, 3), (8, True, 16, 0),
+    (128, True, 64, 0)])
+def test_lane_packed_gathers_counts_each_narrow_payload_read(width, with_sq,
+                                                             cap, count):
+  """One count a payload read that takes the 128-lane path: the sorted
+  payload and each running sum's read at 16 lanes; none at 8 lanes,
+  where the narrow read of a payload is the cheaper and a running sum
+  read at a few slots does not pay for its relayout (``_GATHER_NS``),
+  and none at 128."""
+  n = 512
+  args = (SDS((n,), jnp.int32), SDS((n, width), jnp.float32))
+  assert _packed_gathers(lambda i, g: sparse.compact_segments(
+      i, g, cap, 40, with_sq=with_sq), *args) == count
+
+
+def test_lane_packed_gathers_reads_nothing_in_a_wide_tied_group():
+  """A tied group of 2,048 lanes (the language-model cells') keeps XLA's
+  gather of its rows: no count, through ``_tied_apply`` as the step
+  calls it (the lookups' rows through a position -> row map)."""
+  n, m, rows, width = 256, 64, 512, 2048
+
+  def tied(ids, index, grads, table):
+    return sparse._tied_apply(
+        SparseSGD(learning_rate=0.01), table, {},
+        sparse._Stream(ids, grads, rows, index=index), 0.01, [])
+
+  assert _packed_gathers(
+      tied, SDS((n,), jnp.int32), SDS((n,), jnp.int32),
+      SDS((m, width), jnp.float32), SDS((rows, width), jnp.float32)) == 0

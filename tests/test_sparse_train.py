@@ -394,6 +394,10 @@ _COMPACT_CASES = {
                           dict(sentinels=5, with_sq=True)),
     'g-index-w16': (512, 16, 40, 42, dict(sentinels=4, g_index=True,
                                           with_sq=True)),
+    # compact payload rows that 128 // 16 does not divide (the packed
+    # read pads the view), a capacity short of the unique count
+    'g-index-odd-rows-w16': (519, 16, 90, 'uniq-5',
+                             dict(sentinels=4, g_index=True)),
     'max-seg-w8': (256, 8, 100, 102, dict(sentinels=6, max_seg=3)),
     'max-seg-sq-w128': (256, 128, 100, 'uniq-4',
                         dict(sentinels=6, max_seg=3, with_sq=True)),
@@ -430,26 +434,74 @@ def test_compact_segments_keeps_the_frozen_formulations_bits(case):
 
 @pytest.mark.parametrize('exact', [False, True])
 @pytest.mark.parametrize('with_sq', [False, True])
-@pytest.mark.parametrize('w,rows_cap,n', [(8, 1600, 2048), (16, 4096, 300),
-                                          (64, 512, 1024)])
+@pytest.mark.parametrize('w,rows_cap,n,g_index', [
+    (8, 1600, 2048, False), (16, 4096, 300, False), (64, 512, 1024, False),
+    (16, 1024, 2045, True)])
 def test_lane_pack_keeps_the_frozen_formulations_bits(w, rows_cap, n,
-                                                      with_sq, exact):
+                                                      g_index, with_sq,
+                                                      exact):
   """``_lane_pack`` on what the outer compaction hands it (ascending
   ids, sentinels last), whether the packed capacity shrinks the buffer
-  (``rows_cap // pack + 2 < c``) or not."""
+  (``rows_cap // pack + 2 < c``) or not; ``g_index``: the outer
+  compaction reads its width-16 payload through a position -> row map,
+  each side through its own compaction."""
   from distributed_embeddings_tpu.parallel.sparse import (_lane_pack,
                                                           compact_segments)
   pack = 128 // w
   ids, g = _dedup_stream(w + n, n, w, rows_cap, sentinels=7)
-  uids, sum_g, sum_sq, _ = jax.jit(lambda i, x: compact_segments(
-      i, x, min(n, rows_cap + 2), rows_cap, with_sq=with_sq))(ids, g)
+  kw = dict(with_sq=with_sq)
+  if g_index:
+    kw['g_index'] = jnp.asarray(np.random.default_rng(n).integers(
+        0, n // 5, size=(n,)).astype(np.int32))
+    g = g[:n // 5]
+  outer = lambda f: jax.jit(lambda i, x: f(
+      i, x, min(n, rows_cap + 2), rows_cap, **kw)[:3])(ids, g)
   got = jax.jit(lambda *a: _lane_pack(*a, pack, rows_cap, exact=exact))(
-      uids, sum_g, sum_sq)
+      *outer(compact_segments))
   want = jax.jit(
       lambda *a: _frozen_lane_pack(*a, pack, rows_cap, exact=exact))(
-          uids, sum_g, sum_sq)
-  assert got[0].shape == (min(uids.shape[0], rows_cap // pack + 2),)
+          *outer(_frozen_compact_segments))
+  assert got[0].shape == (min(n, rows_cap + 2, rows_cap // pack + 2),)
   _assert_same_outputs(got, want)
+
+
+def _packed_take_cases():
+  """(width, operand rows, index kind) for ``lane_packed_take``: every
+  narrow width, row counts that ``128 // w`` divides and that it does
+  not, and each way the dedup indexes a payload."""
+  return [(w, rows, kind) for w in (8, 16, 32, 64)
+          for rows in (128 // w * 37, 128 // w * 37 + 3)
+          for kind in ('monotone', 'random', 'repeated', 'g_index')]
+
+
+@pytest.mark.parametrize('w,rows,kind', _packed_take_cases())
+def test_lane_packed_take_is_the_plain_gather_bit_for_bit(w, rows, kind):
+  """The 128-lane read moves the rows and computes nothing: ``x[idx]``
+  to the bit (``np.array_equal`` on the raw bits), with ``-0.0``, a NaN,
+  an infinity and a subnormal among the rows."""
+  from distributed_embeddings_tpu.parallel.sparse import lane_packed_take
+  rng = np.random.default_rng(w * rows)
+  x = rng.normal(size=(rows, w)).astype(np.float32)
+  x[1, :] = -0.0
+  x[2, 0], x[2, 1], x[2, 2] = np.nan, -np.inf, 1e-40
+  n = 3 * rows
+  idx = {'monotone': np.sort(rng.integers(0, rows, size=n)),
+         'random': rng.integers(0, rows, size=n),
+         'repeated': np.repeat(rng.integers(0, rows, size=n // 8), 8)}
+  if kind == 'g_index':
+    # the dedup's compact form: positions point at rows through a map,
+    # read in the order a sort gives
+    g_index = jnp.asarray(rng.integers(0, rows, size=n).astype(np.int32))
+    order = jnp.argsort(jnp.asarray(rng.integers(0, 50, size=n)))
+    take = lambda x: lane_packed_take(x, jnp.take(g_index, order))
+    want = np.asarray(jnp.asarray(x)[jnp.take(g_index, order)])
+  else:
+    ids = jnp.asarray(idx[kind].astype(np.int32))
+    take = lambda x: lane_packed_take(x, ids)
+    want = np.asarray(jnp.asarray(x)[ids])
+  got = np.asarray(jax.jit(take)(jnp.asarray(x)))
+  assert got.dtype == want.dtype and got.shape == want.shape
+  assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
 
 
 def _gathers(jaxpr, from_iota=()):
@@ -486,9 +538,11 @@ def test_compact_segments_gathers_only_payloads(with_sq, frozen_count):
   ``hi``.  Whoever writes ``x[order]`` after an ``argsort`` here again
   (20 ms a step for an int32 stream of 2.9 M on v5e) fails this."""
   from distributed_embeddings_tpu.parallel.sparse import compact_segments
+  # a capacity at which the running sum's read, too, pays for its
+  # relayout (``sparse.packed_gather_gains``)
   ids, g = _dedup_stream(0, 512, 16, 40, sentinels=9)
   trace = lambda f: jax.make_jaxpr(
-      lambda i, x: f(i, x, 42, 40, with_sq=with_sq))(ids, g)
+      lambda i, x: f(i, x, 64, 40, with_sq=with_sq))(ids, g)
   assert _gathers(trace(_frozen_compact_segments).jaxpr)[0] == frozen_count
   assert _gathers(trace(compact_segments).jaxpr)[0] == 2 + with_sq
   # ``hi`` and ``lo`` are windows of one gather that both start past its
@@ -499,8 +553,13 @@ def test_compact_segments_gathers_only_payloads(with_sq, frozen_count):
                       str(trace(compact_segments)))
   assert sorted(starts) == sorted(['1', '2'] * (1 + with_sq))
   text = jax.jit(lambda i, x: compact_segments(
-      i, x, 42, 40, with_sq=with_sq)).lower(ids, g).as_text()
+      i, x, 64, 40, with_sq=with_sq)).lower(ids, g).as_text()
   assert text.count('"stablehlo.gather"(') == 2 + with_sq
+  # below 128 lanes each payload is read as 128-lane rows
+  # (``lane_packed_take``): a narrow row read cuts across the rows-minor
+  # layout the v5e gives a computed [n, 16] array (PERF.md section 6)
+  lanes = re.findall(r'"stablehlo\.gather"\(.*?: \(tensor<\d+x(\d+)x', text)
+  assert lanes == ['128'] * (2 + with_sq), lanes
 
 
 def test_lane_pack_gathers_through_no_iota():

@@ -375,6 +375,13 @@ def _write_rows_scoped_vmem(hlo):
         # rows into 8,775,000 (12.7%), table and accumulator
         ('tiny-train-zipf', 1, [70_200_000], 16, 65536, 44, 1_116_536,
          'adagrad', (8_775_000, 128), 'stream'),
+        # tiny-train-uniform's: every position of the stream a row of its
+        # own, the capacity the whole stream, so no correction wave; the
+        # step in which the in-place hazard ``_written_over_two_views``
+        # reads first appeared, now with the running sum read as
+        # 128-lane rows (sparse.lane_packed_take)
+        ('tiny-train-uniform', 1, [70_200_000], 16, 65536, 44, 2_883_584,
+         'adagrad', (8_775_000, 128), 'stream'),
     ])
 def test_write_rows_emitter_follows_the_share_for_v5e(
     v5e, cell, chips, tables, width, batch, hot, cap, opt, shard, takes):
@@ -419,12 +426,19 @@ def test_write_rows_emitter_follows_the_share_for_v5e(
   assert (wave, operand) == (cap, shard[0])
   assert sparse.write_algorithm(wave, operand) == takes
   sizes = _write_rows_scoped_vmem(hlo)
-  # the main wave's and the overflow correction's (its wave, the
-  # guaranteed capacity, lies on the same side of the rule in both
-  # cells), one a leaf the optimizer writes
-  assert len(sizes) == 2 * (1 if opt == 'sgd' else 2), sizes
+  # the main wave's and, where the capacity is short of the guaranteed
+  # one, the overflow correction's (its wave, the guaranteed capacity,
+  # lies on the same side of the rule in every cell), one a leaf the
+  # optimizer writes
+  waves = 1 + (cap < sparse._guaranteed_cap(batch * hot * len(tables),
+                                            g.rows_cap))
+  assert len(sizes) == waves * (1 if opt == 'sgd' else 2), sizes
   assert all((s < 1 << 20) if takes == 'rows' else (s > 8 << 20)
              for s in sizes), sizes
+  if width < 128:
+    # the dedup's narrow payloads are read as 128-lane rows, the lane
+    # block picked by one OR over the blocks (sparse.lane_packed_take)
+    assert re.search(r'op_name="[^"]*apply/dedup[^"]*reduce_or"', hlo)
   assert not _written_over_two_views(hlo)
 
 
