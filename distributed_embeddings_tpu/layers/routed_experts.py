@@ -16,7 +16,10 @@ Equations, ``u [T, d]`` the tokens' rows::
   sel  = top_k(s + expert_bias)                 expert_bias: no gradient
   w_e  = route_scale * s_e / (sum_{e' in sel} s_e' + route_norm_eps)
                                                 over ALL k, held or not
-  y    = sum_{e in sel, e held} w_e * swiglu_e(u)
+  y    = sum_{e in sel, e held} w_e * expert_e(u)
+
+  expert_e(u) = (silu(u Wgate_e) * (u Wup_e)) Wdown_e   'swiglu': gated
+              = relu(u Wup_e)^2 Wdown_e                 'relu2': not gated
 
 How it groups is the sparse apply's idiom (``parallel/sparse.py``) made
 differentiable: an assignment to an absent expert takes the sentinel key
@@ -71,6 +74,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from distributed_embeddings_tpu.obs import metrics as obs_metrics
 from distributed_embeddings_tpu.obs import trace as obs_trace
 from distributed_embeddings_tpu.parallel.routing import sort_with_order
 
@@ -81,12 +85,14 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # the step at 17.08 GiB of a v5e's 15.75; in three waves of 1.25, each
 # rematerialised on its own, at 13.60: compile-only, PERF.md section 6.)
 _LARGEST_WAVE = 1.25
+_FORMS = ('swiglu', 'relu2')
 
 
 @dataclasses.dataclass(frozen=True)
 class RoutedExpertsConfig:
   """What the layer is told.  ``router_width`` counts every expert of
-  the model; ``first_expert .. first_expert + num_held - 1`` live here."""
+  the model; ``first_expert .. first_expert + num_held - 1`` live here.
+  ``expert_form`` is an expert's interior (``interior``)."""
   router_width: int
   experts_per_token: int
   num_held: int
@@ -94,8 +100,12 @@ class RoutedExpertsConfig:
   route_scale: float = 1.0
   capacity_factor: float = 1.25
   route_norm_eps: float = 1e-20     # the normaliser's constant, a family's own
+  expert_form: str = 'swiglu'
 
   def __post_init__(self):
+    if self.expert_form not in _FORMS:
+      raise NotImplementedError(
+          f'routed_experts: expert_form={self.expert_form!r} (only {_FORMS})')
     if not 0 <= self.first_expert <= self.router_width - self.num_held:
       raise ValueError(
           f'routed_experts: experts {self.first_expert}..'
@@ -150,6 +160,16 @@ def route(cfg: RoutedExpertsConfig, u, router, expert_bias):
     return sel, weights
 
 
+def interior(form: str, h):
+  """An expert's interior from its first product ``h``: ``silu(gate) *
+  up`` of ``h``'s two halves (``'swiglu'``, ``h`` is ``2 x ffn`` wide) or
+  ``relu(h)^2`` (``'relu2'``, ``h`` is ``ffn`` wide)."""
+  if form == 'relu2':
+    return jnp.square(jax.nn.relu(h))
+  gate, up = jnp.split(h, 2, axis=-1)
+  return jax.nn.silu(gate) * up
+
+
 def _grouped(lhs, rhs, group_sizes):
   """``lhs [m, k]`` times ``rhs [g, k, n]``, rows ``sum(group_sizes[:e])
   ..`` by ``rhs[e]``: ``jax.lax.ragged_dot`` on float32 operands, which
@@ -182,9 +202,9 @@ def _wave(cfg, p, u, flat_weights, ends, lo, at, key):
     sizes = sizes.at[-1].add(slots - jnp.sum(sizes))
     buffer = u[token]
   with obs_trace.phase('moe/experts'):
-    gate, up = jnp.split(_grouped(buffer, p['experts_in'], sizes), 2,
-                         axis=-1)
-    out = _grouped(jax.nn.silu(gate) * up, p['experts_out'], sizes)
+    out = _grouped(interior(cfg.expert_form,
+                            _grouped(buffer, p['experts_in'], sizes)),
+                   p['experts_out'], sizes)
   with obs_trace.phase('moe/combine'):
     weight = jnp.where(valid, flat_weights[at], 0.0)
     return jnp.zeros_like(u).at[token].add(out * weight[:, None])
@@ -210,7 +230,12 @@ def routed_experts(cfg: RoutedExpertsConfig, p: Dict, u):
   [T, d]``, and the experts each token took ``[T, k]`` (what
   ``routing_stats`` counts).  ``p``: ``router [d, router_width]``,
   ``expert_bias [router_width]``, ``experts_in [num_held, d, 2 x ffn]``
-  (gate | up), ``experts_out [num_held, ffn, d]``."""
+  (gate | up; ``[num_held, d, ffn]`` for ``'relu2'`` experts),
+  ``experts_out [num_held, ffn, d]``.  The counter
+  ``moe.ungated_expert_layers`` counts a traced layer of ``'relu2'``
+  experts."""
+  if cfg.expert_form == 'relu2':
+    obs_metrics.inc('moe.ungated_expert_layers')
   tokens = u.shape[0]
   slots, waves = cfg.wave_slots(tokens), cfg.waves(tokens)
   always = cfg.capacity(tokens) // slots

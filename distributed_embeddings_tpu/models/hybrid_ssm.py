@@ -24,6 +24,10 @@ its ``config.json``)::
           y_t = h_t C_t + D x_t
           out = out_proj(rmsnorm(y * silu(z)))
 
+  with ``mamba_n_groups`` G groups, B and C are ``G x N`` wide, head
+  ``h`` reads group ``h // (heads / G)``, and the gated norm normalises
+  each group's ``d_inner / G`` channels apart (``gated_norm``).
+
 PACKED DOCUMENTS ARE INDEPENDENT (``segment_ids``): the state and the
 convolution's window restart at a document's first position and
 attention sees only its own document.
@@ -91,6 +95,7 @@ class HybridSSMConfig:
   mamba_chunk_size: int
   num_attention_heads: int
   num_key_value_heads: int
+  mamba_n_groups: int = 1
   rms_norm_eps: float = 1e-5
   embedding_multiplier: float = 1.0
   residual_multiplier: float = 1.0
@@ -103,9 +108,9 @@ class HybridSSMConfig:
   def from_dict(cls, config: Dict[str, Any], **overrides):
     """From a ``config.json`` of the family; what this class does not
     compute is refused by name."""
-    refused = {'mamba_n_groups': 1, 'mamba_proj_bias': False,
-               'attention_bias': False, 'position_embedding_type': 'nope',
-               'num_local_experts': 0, 'mamba_conv_bias': True}
+    refused = {'mamba_proj_bias': False, 'attention_bias': False,
+               'position_embedding_type': 'nope', 'num_local_experts': 0,
+               'mamba_conv_bias': True}
     for key, only in refused.items():
       if config.get(key, only) != only:
         raise NotImplementedError(
@@ -126,7 +131,7 @@ class HybridSSMConfig:
 
   @property
   def conv_dim(self):
-    return self.d_inner + 2 * self.mamba_d_state
+    return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
   @property
   def head_dim(self):
@@ -141,7 +146,7 @@ def init_params(cfg: HybridSSMConfig, seed: int):
   ``[seed, 5, its number]``, on a few threads (746 M normals at the
   published widths)."""
   rng = np.random.default_rng([int(seed), 5])
-  d, inner, heads = cfg.hidden_size, cfg.d_inner, cfg.mamba_n_heads
+  d = cfg.hidden_size
   kv = cfg.num_key_value_heads * cfg.head_dim
   kernels = []
 
@@ -159,15 +164,7 @@ def init_params(cfg: HybridSSMConfig, seed: int):
   layers = []
   for kind in cfg.layer_types:
     if kind == 'mamba':
-      dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), heads))
-      mixer = {
-          'in_proj': kernel(d, inner + cfg.conv_dim + heads),
-          'conv_kernel': kernel(cfg.mamba_d_conv, cfg.conv_dim),
-          'conv_bias': np.zeros(cfg.conv_dim, np.float32),
-          'dt_bias': (dt + np.log(-np.expm1(-dt))).astype(np.float32),
-          'A_log': np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32),
-          'D': ones(heads), 'gated_norm': ones(inner),
-          'out_proj': kernel(inner, d)}
+      mixer = mamba_params(cfg, rng, kernel)
     else:
       mixer = {'q_proj': kernel(d, d), 'k_proj': kernel(d, kv),
                'v_proj': kernel(d, kv), 'o_proj': kernel(d, d)}
@@ -178,6 +175,25 @@ def init_params(cfg: HybridSSMConfig, seed: int):
   with concurrent.futures.ThreadPoolExecutor(max_workers=8) as pool:
     list(pool.map(draw, range(len(kernels))))
   return {'layers': layers, 'final_norm': ones(d)}
+
+
+def mamba_params(cfg: HybridSSMConfig, rng, kernel):
+  """One Mamba-2 mixer's leaves: ``kernel(*shape)`` makes a kernel that
+  is drawn later; ``dt`` (log-uniform ``[1e-3, 1e-1]``, through the
+  inverse softplus into ``dt_bias``) and ``A_log = log(U[1, 16])`` come
+  from ``rng`` in that order; ``D`` and the gains 1, the convolution's
+  bias 0."""
+  d, inner, heads = cfg.hidden_size, cfg.d_inner, cfg.mamba_n_heads
+  ones = lambda n: np.ones(n, np.float32)
+  dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), heads))
+  return {
+      'in_proj': kernel(d, inner + cfg.conv_dim + heads),
+      'conv_kernel': kernel(cfg.mamba_d_conv, cfg.conv_dim),
+      'conv_bias': np.zeros(cfg.conv_dim, np.float32),
+      'dt_bias': (dt + np.log(-np.expm1(-dt))).astype(np.float32),
+      'A_log': np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32),
+      'D': ones(heads), 'gated_norm': ones(inner),
+      'out_proj': kernel(inner, d)}
 
 
 def count_batch(batch):
@@ -259,20 +275,39 @@ def ssd_scan(x, dt, a_heads, b, c, segment_ids, chunk_size):
   """``y_t = C_t h_t`` of the selective recurrence ``h_t = exp(dt_t A)
   h_{t-1} + dt_t x_t B_t^T`` restarted at every document, in chunks.
   ``x [S, L, H, P]``, ``dt [S, L, H]`` (positive), ``a_heads [H]``
-  (negative), ``b``, ``c`` ``[S, L, N]``, ``segment_ids [S, L]`` counting
-  a sequence's documents from 0.  ``L`` must be a multiple of
-  ``chunk_size``.  The one place that chooses: the kernels of
-  ``ops/pallas_ssd.py`` where the program is compiled for a TPU and the
-  static shapes fit their tiles (the counter ``mixer.scan_kernel_layers``
-  says at trace time that a compiled step holds them), and a
-  ``lax.scan`` over ``_ssd_chunk`` otherwise."""
-  seqs, length = x.shape[:2]
+  (negative), ``b``, ``c`` ``[S, L, G, N]`` in ``G`` groups (head ``h``
+  reads group ``h // (H / G)``; ``G`` is 1 where every head reads the
+  same), ``segment_ids [S, L]`` counting a sequence's documents from 0.
+  ``L`` must be a multiple of ``chunk_size``.  The one place that
+  chooses: the kernels of ``ops/pallas_ssd.py`` where the program is
+  compiled for a TPU and the static shapes fit their tiles (the counter
+  ``mixer.scan_kernel_layers`` says at trace time that a compiled step
+  holds them), and a ``lax.scan`` over ``_ssd_chunk`` otherwise, one
+  group's heads at a time under ``jax.vmap``.  ``mixer.grouped_scan_layers``
+  counts the scans of more than one group."""
+  length = x.shape[1]
   if length % chunk_size:
     raise ValueError(f'ssd_scan: sequence length {length} is no multiple '
                      f'of the chunk size {chunk_size}')
-  if pallas_ssd.takes(x.shape, b.shape[-1], chunk_size):
+  groups = b.shape[2]
+  if groups > 1:
+    obs_metrics.inc('mixer.grouped_scan_layers')
+  if pallas_ssd.takes(x.shape, b.shape[-1], chunk_size, groups):
     obs_metrics.inc('mixer.scan_kernel_layers')
     return pallas_ssd.scan(x, dt, a_heads, b, c, segment_ids, chunk_size)
+  by_group = lambda a: jnp.moveaxis(
+      a.reshape(a.shape[:2] + (groups, -1) + a.shape[3:]), 2, 0)
+  y = jax.vmap(functools.partial(_chunked_scan, segment_ids=segment_ids,
+                                 chunk_size=chunk_size))(
+      by_group(x), by_group(dt), a_heads.reshape(groups, -1),
+      jnp.moveaxis(b, 2, 0), jnp.moveaxis(c, 2, 0))
+  return jnp.moveaxis(y, 0, 2).reshape(x.shape)
+
+
+def _chunked_scan(x, dt, a_heads, b, c, segment_ids, chunk_size):
+  """``ssd_scan`` as a ``lax.scan`` over ``_ssd_chunk``, ``b``, ``c``
+  ``[S, L, N]``."""
+  seqs, length = x.shape[:2]
   chunks = length // chunk_size
   split = lambda a: jnp.swapaxes(
       a.reshape((seqs, chunks, chunk_size) + a.shape[2:]), 0, 1)
@@ -284,9 +319,22 @@ def ssd_scan(x, dt, a_heads, b, c, segment_ids, chunk_size):
   return jnp.swapaxes(y, 0, 1).reshape(x.shape)
 
 
+def gated_norm(cfg: HybridSSMConfig, y, z, gain):
+  """``rmsnorm(y * silu(z))`` with the learned ``gain``, over each
+  group's ``d_inner / mamba_n_groups`` channels apart."""
+  y = y * jax.nn.silu(z)
+  groups = cfg.mamba_n_groups
+  if groups == 1:   # a [.., 1, d_inner] view would move XLA's fusions
+    return rms_norm(y, gain, cfg.rms_norm_eps)
+  grouped = y.reshape(y.shape[:-1] + (groups, -1))
+  return rms_norm(grouped, gain.reshape(groups, -1),
+                  cfg.rms_norm_eps).reshape(y.shape)
+
+
 def mamba_mixer(cfg: HybridSSMConfig, p, u, segment_ids):
   """The Mamba-2 mixer on ``u [S, L, hidden]``."""
-  inner, state = cfg.d_inner, cfg.mamba_d_state
+  inner, groups = cfg.d_inner, cfg.mamba_n_groups
+  state = groups * cfg.mamba_d_state
   heads, d_head = cfg.mamba_n_heads, cfg.mamba_d_head
   with obs_trace.phase('mixer/proj'):
     z, xbc, dt = jnp.split(jnp.matmul(u, p['in_proj']),
@@ -297,13 +345,13 @@ def mamba_mixer(cfg: HybridSSMConfig, p, u, segment_ids):
   with obs_trace.phase('mixer/selective_scan'):
     x, b, c = jnp.split(xbc, [inner, inner + state], axis=-1)
     x = x.reshape(x.shape[:2] + (heads, d_head))
+    b, c = (a.reshape(a.shape[:2] + (groups, -1)) for a in (b, c))
     dt = jax.nn.softplus(dt + p['dt_bias'])
     y = ssd_scan(x, dt, -jnp.exp(p['A_log']), b, c, segment_ids,
                  cfg.mamba_chunk_size)
     y = (y + p['D'][:, None] * x).reshape(z.shape)
   with obs_trace.phase('mixer/proj'):
-    y = rms_norm(y * jax.nn.silu(z), p['gated_norm'], cfg.rms_norm_eps)
-    return jnp.matmul(y, p['out_proj'])
+    return jnp.matmul(gated_norm(cfg, y, z, p['gated_norm']), p['out_proj'])
 
 
 def _attend(scale, q, k, v, seg_q, seg_k, first, key_first=None,

@@ -1,24 +1,27 @@
 """Sparse mixture-of-experts language models: a routed feed-forward layer
 of which this chip holds a share, under a sequence mixer that is windowed
-or full attention or a gated short convolution, over an untied or a tied
-vocabulary.
+or full attention, a gated short convolution or a Mamba-2 mixer, over an
+untied or a tied vocabulary.
 
-A stack of residual blocks given by ``layer_types``
-(``'sliding_attention'``, ``'full_attention'`` or ``'conv'``): the mixer,
-then a feed-forward that is a dense SwiGLU in the first
-``num_dense_layers`` blocks and, in the others, the routed experts held
-here (``layers/routed_experts.py``) beside ``num_shared_experts`` (1 or
-0) shared-expert SwiGLU.  The token table is ONE table behind
-``DistributedEmbedding`` (``combiner=None``, one id per position).
-Untied, only the lookup reads it: it takes the sparse apply's default
-path under ``SparseAdam`` (``make_hybrid_train_step(...,
+A stack of residual blocks given by ``layer_types``.  A block of the
+kinds ``'sliding_attention'``, ``'full_attention'`` and ``'conv'`` holds
+two sub-layers: the mixer, then a feed-forward that is a dense SwiGLU in
+the first ``num_dense_layers`` blocks and, in the others, the routed
+experts held here (``layers/routed_experts.py``) beside
+``num_shared_experts`` (1 or 0) shared experts of the routed experts'
+form.  A block of the kinds ``'mamba'``, ``'attention'`` and ``'moe'``
+holds ONE sub-layer: a Mamba-2 mixer (``hybrid_ssm.mamba_mixer``),
+attention, or the routed feed-forward (``_KINDS``).  The token table is
+ONE table behind ``DistributedEmbedding`` (``combiner=None``, one id per
+position).  Untied, only the lookup reads it: it takes the sparse
+apply's default path under ``SparseAdam`` (``make_hybrid_train_step(...,
 head_reads_tables=())``) and the output head ``lm_head [hidden,
 vocabulary]`` is a dense leaf of its own.  Tied
 (``tie_word_embeddings``), the head multiplies by the table again
 (``head_reads_tables=(0,)``, docs/design.md §25) and there is no
 ``lm_head``.
 
-Two published sigmoid-routed families, told apart by ``model_type``
+Three published sigmoid-routed families, told apart by ``model_type``
 (``MoELMConfig.from_dict``); keys as in their ``config.json``::
 
   afmoe      x0 = row * sqrt(hidden_size)                 (mup_enabled)
@@ -28,12 +31,18 @@ Two published sigmoid-routed families, told apart by ``model_type``
   lfm2_moe   x0 = row
              h  = x + op(rmsnorm(x));  x' = h + ffn(rmsnorm(h))
              logits = rmsnorm(x_last) @ table^T           (tied)
+  nemotron_h x0 = row
+             x' = x + sub(rmsnorm(x))     sub by hybrid_override_pattern:
+                                          M mamba, * attention, E moe
+             logits = rmsnorm(x_last) @ lm_head
 
   attn(u): q = u Wq [Hq x D]; k = u Wk, v = u Wv [Hkv x D]
            q = rmsnorm_D(q), k = rmsnorm_D(k)             per head, learned gain
+           (nemotron_h: no per-head norms)
            rotary(q, k; rope_theta, the whole head, the position in the
            sequence) on the kinds in ``rotary_layers``: afmoe's
-           sliding_attention layers only, every attention layer of lfm2_moe
+           sliding_attention layers only, every attention layer of
+           lfm2_moe, none of nemotron_h
            p_ij = softmax_j(q_i . k_j / sqrt(D)) over j <= i of i's document,
                   and i - j < sliding_window on a sliding_attention layer
            out  = (p v) Wo;  afmoe: ((p v) * sigmoid(u Wg)) Wo
@@ -44,9 +53,12 @@ Two published sigmoid-routed families, told apart by ``model_type``
                                            current position, no bias
            out = y W_out
 
+  mamba(u): hybrid_ssm.mamba_mixer, B and C in mamba_n_groups groups
+
   ffn, dense layers:  swiglu(u) = (silu(u Wgate) * (u Wup)) Wdown
-  ffn, routed layers: [swiglu_shared(u) +] the held experts' part of
-                      sum_{e in top_k} w_e swiglu_e(u)    (routed_experts.py)
+  ffn, routed layers: [expert_shared(u) +] the held experts' part of
+                      sum_{e in top_k} w_e expert_e(u)    (routed_experts.py)
+                      expert: swiglu, or nemotron_h's relu(u Wup)^2 Wdown
 
 PACKED DOCUMENTS ARE INDEPENDENT (``segment_ids``), as in
 ``models/hybrid_ssm.py``, whose ``rms_norm``, ``swiglu``,
@@ -64,9 +76,13 @@ Device phases (inside ``head``): ``attention/window``, ``attention/full``
 (both under ``attention``), ``mixer/short_conv`` (the WHOLE operator,
 projections and gates included, as an attention phase holds its own: a
 fusion carries one name, and XLA fuses the gates into the products beside
-them), ``mlp`` (the dense SwiGLU), ``moe/route``, ``moe/dispatch``,
-``moe/experts``, ``moe/combine``, ``moe/shared``, ``vocab``.  Counters, at
-trace time: ``mixer.short_conv_layers``, ``moe.kept_outputs``.  Gauges,
+them), ``mixer/proj``, ``mixer/conv``, ``mixer/selective_scan`` (a Mamba
+mixer's, as in ``hybrid_ssm``), ``mlp`` (the dense SwiGLU),
+``moe/route``, ``moe/dispatch``, ``moe/experts``, ``moe/combine``,
+``moe/shared``, ``vocab``.  Counters, at trace time:
+``mixer.short_conv_layers``, ``moe.kept_outputs``, and those of the
+layers it calls (``mixer.scan_kernel_layers``,
+``mixer.grouped_scan_layers``, ``moe.ungated_expert_layers``).  Gauges,
 set outside the step by ``record_routing_stats``:
 ``moe.assignments_held``, ``moe.load_max_over_mean``,
 ``moe.overflow_rows``.
@@ -77,7 +93,7 @@ from __future__ import annotations
 import concurrent.futures
 import dataclasses
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +102,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from distributed_embeddings_tpu.layers import routed_experts as routed
 from distributed_embeddings_tpu.models.hybrid_ssm import (
-    blocked_attention, causal_conv, count_batch, rms_norm, swiglu, vocab_loss)
+    HybridSSMConfig, blocked_attention, causal_conv, count_batch,
+    mamba_mixer, mamba_params, rms_norm, swiglu, vocab_loss)
 from distributed_embeddings_tpu.obs import metrics as obs_metrics
 from distributed_embeddings_tpu.obs import trace as obs_trace
 
@@ -94,22 +111,54 @@ __all__ = ['MoELMConfig', 'init_params', 'count_batch', 'forward',
            'make_head_loss_fn', 'selections', 'routing_stats',
            'record_routing_stats']
 
-_KINDS = ('sliding_attention', 'full_attention', 'conv')
+# the sub-layers a block of each kind holds
+_KINDS = {'sliding_attention': ('mixer', 'ffn'),
+          'full_attention': ('mixer', 'ffn'), 'conv': ('mixer', 'ffn'),
+          'mamba': ('mixer',), 'attention': ('mixer',), 'moe': ('ffn',)}
+# a ``hybrid_override_pattern``'s letters
+_PATTERN = {'M': 'mamba', '*': 'attention', 'E': 'moe'}
 # the one array the feed-forward half's checkpoint keeps, where the
 # backward pass reads it: a routed sub-layer's output (``layer``)
 _ROUTED_OUTPUT = 'routed_ffn'
 # What a family's modelling code fixes and no key of its ``config.json``
-# states (``fixed``), and the keys it names otherwise (``keys``), by
-# ``model_type``; a file without one is ``afmoe``'s.
+# states (``fixed``), the keys it names otherwise (``keys``), the values
+# of its own keys this module computes (``refused``: any other is refused
+# by name) and the kinds of block it has (``kinds``), by ``model_type``;
+# a file without one is ``afmoe``'s.
+_TWO_HALVES = ('sliding_attention', 'full_attention', 'conv')
 _FAMILIES = {
-    'afmoe': {'fixed': dict(tie_word_embeddings=False), 'keys': {}},
+    'afmoe': {'fixed': dict(tie_word_embeddings=False), 'keys': {},
+              'kinds': _TWO_HALVES},
     'lfm2_moe': {
+        'kinds': _TWO_HALVES,
         'fixed': dict(mup_enabled=False, sandwich_norms=False,
                       attention_gate=False, rotary_layers=('full_attention',),
                       route_norm_eps=1e-6, num_shared_experts=0,
                       tie_word_embeddings=True, sliding_window=0),
         'keys': {'norm_eps': 'rms_norm_eps',
                  'routed_scaling_factor': 'route_scale'}},
+    'nemotron_h': {
+        'kinds': ('mamba', 'attention', 'moe'),
+        'fixed': dict(mup_enabled=False, sandwich_norms=False,
+                      attention_gate=False, qk_norms=False, rotary_layers=(),
+                      num_dense_layers=0, tie_word_embeddings=False,
+                      expert_form='relu2'),
+        'keys': {'layer_norm_epsilon': 'rms_norm_eps',
+                 'routed_scaling_factor': 'route_scale',
+                 'n_routed_experts': 'num_experts',
+                 'n_shared_experts': 'num_shared_experts',
+                 'moe_shared_expert_intermediate_size':
+                     'shared_intermediate_size',
+                 'mamba_num_heads': 'mamba_n_heads',
+                 'mamba_head_dim': 'mamba_d_head',
+                 'ssm_state_size': 'mamba_d_state',
+                 'n_groups': 'mamba_n_groups', 'conv_kernel': 'mamba_d_conv',
+                 'chunk_size': 'mamba_chunk_size'},
+        'refused': {'mamba_proj_bias': (False,), 'use_conv_bias': (True,),
+                    'use_bias': (False,), 'attention_bias': (False,),
+                    'mlp_bias': (False,), 'mlp_hidden_act': ('relu2',),
+                    'mamba_hidden_act': ('silu',), 'sliding_window': (None,),
+                    'n_shared_experts': (0, 1)}},
 }
 
 
@@ -119,9 +168,12 @@ class MoELMConfig:
   them.  ``num_experts`` counts the experts HELD here, ``first_expert ..``
   of the ``router_width`` the router scores; ``vocab_size`` the rows of
   the token table and the columns of ``lm_head``.  ``sandwich_norms``
-  (a norm after each sub-layer too), ``attention_gate`` and
-  ``rotary_layers`` (the kinds of attention that take rotary) are a
-  family's own."""
+  (a norm after each sub-layer too), ``attention_gate``, ``qk_norms``
+  (per-head norms of queries and keys), ``rotary_layers`` (the kinds of
+  attention that take rotary) and ``expert_form`` are a family's own;
+  ``shared_intermediate_size`` is the shared experts' width where it is
+  not ``moe_intermediate_size``, and ``mamba`` the Mamba mixers' sizes
+  where the stack has any."""
   hidden_size: int
   vocab_size: int
   layer_types: Tuple[str, ...]
@@ -151,6 +203,10 @@ class MoELMConfig:
   sandwich_norms: bool = True
   attention_gate: bool = True
   rotary_layers: Tuple[str, ...] = ('sliding_attention',)
+  qk_norms: bool = True
+  expert_form: str = 'swiglu'
+  shared_intermediate_size: int = 0
+  mamba: Optional[HybridSSMConfig] = None
 
   @classmethod
   def from_dict(cls, config: Dict[str, Any], **overrides):
@@ -165,7 +221,8 @@ class MoELMConfig:
                'n_group': (1,), 'topk_group': (1,),
                'num_shared_experts': (0, 1), 'hidden_act': ('silu',),
                'rope_scaling': (None,), 'conv_bias': (False,),
-               'use_expert_bias': (True,), 'norm_topk_prob': (True,)}
+               'use_expert_bias': (True,), 'norm_topk_prob': (True,),
+               **_FAMILIES[family].get('refused', {})}
     fixed = _FAMILIES[family]['fixed']
     # tied or not is the family's: each was built and tested one way
     refused['tie_word_embeddings'] = (fixed['tie_word_embeddings'],)
@@ -173,18 +230,26 @@ class MoELMConfig:
       if config.get(key, only[0]) not in only:
         raise NotImplementedError(
             f'moe_lm: {key}={config[key]!r} (only {only!r})')
-    unknown = set(config['layer_types']) - set(_KINDS)
+    kinds = (config['layer_types'] if 'layer_types' in config else
+             [_PATTERN.get(c, c) for c in config['hybrid_override_pattern']])
+    unknown = set(kinds) - set(_FAMILIES[family]['kinds'])
     if unknown:
       raise NotImplementedError(f'moe_lm: layer types {sorted(unknown)}')
-    renamed = {_FAMILIES[family]['keys'].get(k, k): v
-               for k, v in config.items()}
+    keys = _FAMILIES[family]['keys']
+    rename = lambda d: {keys.get(k, k): v for k, v in d.items()}
+    renamed = rename(config)
     fields = {f.name for f in dataclasses.fields(cls)}
     picked = {**fixed, **{k: v for k, v in renamed.items() if k in fields}}
-    picked['layer_types'] = tuple(config['layer_types'])
-    picked['router_width'] = int(config.get('published', {}).get(
-        'num_experts', config['num_experts']))
+    picked['layer_types'] = tuple(kinds)
+    picked['router_width'] = int(rename(config.get('published', {})).get(
+        'num_experts', renamed['num_experts']))
     picked.setdefault('head_dim', config['hidden_size']
                       // config['num_attention_heads'])
+    if 'mamba' in kinds:
+      known = {f.name for f in dataclasses.fields(HybridSSMConfig)}
+      picked['mamba'] = HybridSSMConfig(**{
+          **{k: v for k, v in renamed.items() if k in known},
+          'layer_types': ('mamba',), 'intermediate_size': 0})
     if 'rope_parameters' in config:
       picked['rope_theta'] = float(config['rope_parameters']['rope_theta'])
     return cls(**{**picked, **overrides})
@@ -196,22 +261,27 @@ class MoELMConfig:
         experts_per_token=self.num_experts_per_tok,
         num_held=self.num_experts, first_expert=self.first_expert,
         route_scale=self.route_scale, capacity_factor=self.capacity_factor,
-        route_norm_eps=self.route_norm_eps)
+        route_norm_eps=self.route_norm_eps, expert_form=self.expert_form)
 
 
 def init_params(cfg: MoELMConfig, seed: int):
   """The dense parameters as host numpy: kernels ``N(0, 1/fan_in)`` (an
   expert's from its own fan-in, a convolution's from its taps), norm
-  gains 1, ``expert_bias`` 0; no ``lm_head`` under a tied vocabulary.
-  Every kernel is drawn from a stream of its own, ``[seed, 5, its
-  number]``, on a few threads.  (The user's entry, as
-  ``hybrid_ssm.init_params``.  The benchmark draws the same numbers by
+  gains 1, ``expert_bias`` 0, a Mamba mixer's vectors as
+  ``hybrid_ssm.mamba_params`` draws them from ``[seed, 5]``; no
+  ``lm_head`` under a tied vocabulary.  Every kernel is drawn from a
+  stream of its own, ``[seed, 5, its number]``, on a few threads.  (The
+  user's entry, as ``hybrid_ssm.init_params``.  The benchmark draws the
+  same numbers by
   routines of its own, because its references may import nothing of the
   program; ``tests/test_moe_lm.py`` and ``tests/test_lfm2_moe.py`` hold
   the two equal.)"""
   d, heads = cfg.hidden_size, cfg.num_attention_heads * cfg.head_dim
   kv = cfg.num_key_value_heads * cfg.head_dim
   ffn, held = cfg.moe_intermediate_size, cfg.num_experts
+  shared_ffn = cfg.shared_intermediate_size or ffn
+  gated = 2 if cfg.expert_form == 'swiglu' else 1   # gate | up, or up
+  rng = np.random.default_rng([int(seed), 5])
   kernels = []
 
   def kernel(*shape):
@@ -227,31 +297,39 @@ def init_params(cfg: MoELMConfig, seed: int):
   ones = lambda n: np.ones(n, np.float32)
   layers = []
   for i, kind in enumerate(cfg.layer_types):
-    p = {'input_norm': ones(d), 'pre_mlp_norm': ones(d)}
+    mixer, ffn_half = ('mixer' in _KINDS[kind]), ('ffn' in _KINDS[kind])
+    p = {}
+    if mixer:
+      p['input_norm'] = ones(d)
+    if ffn_half:
+      p['pre_mlp_norm'] = ones(d)
     if cfg.sandwich_norms:
       p.update(post_attn_norm=ones(d), post_mlp_norm=ones(d))
     if kind == 'conv':
       p['conv'] = {'in_proj': kernel(d, 3 * d),
                    'conv_kernel': kernel(cfg.conv_L_cache, d),
                    'out_proj': kernel(d, d)}
-    else:
+    elif kind == 'mamba':
+      p['mamba'] = mamba_params(cfg.mamba, rng, kernel)
+    elif mixer:
       p['attention'] = {'q_proj': kernel(d, heads), 'k_proj': kernel(d, kv),
                         'v_proj': kernel(d, kv)}
       if cfg.attention_gate:
         p['attention']['gate_proj'] = kernel(d, heads)
-      p['attention'].update(o_proj=kernel(heads, d),
-                            q_norm=ones(cfg.head_dim),
-                            k_norm=ones(cfg.head_dim))
-    if i < cfg.num_dense_layers:
+      p['attention']['o_proj'] = kernel(heads, d)
+      if cfg.qk_norms:
+        p['attention'].update(q_norm=ones(cfg.head_dim),
+                              k_norm=ones(cfg.head_dim))
+    if ffn_half and i < cfg.num_dense_layers:
       p['mlp_in'] = kernel(d, 2 * cfg.intermediate_size)
       p['mlp_out'] = kernel(cfg.intermediate_size, d)
-    else:
+    elif ffn_half:
       p['moe'] = {'router': kernel(d, cfg.router_width),
                   'expert_bias': np.zeros(cfg.router_width, np.float32)}
       if cfg.num_shared_experts:
-        p['moe']['shared'] = {'mlp_in': kernel(d, 2 * ffn),
-                              'mlp_out': kernel(ffn, d)}
-      p['moe'].update(experts_in=kernel(held, d, 2 * ffn),
+        p['moe']['shared'] = {'mlp_in': kernel(d, gated * shared_ffn),
+                              'mlp_out': kernel(shared_ffn, d)}
+      p['moe'].update(experts_in=kernel(held, d, gated * ffn),
                       experts_out=kernel(held, ffn, d))
     layers.append(p)
   params = {'layers': layers, 'final_norm': ones(d)}
@@ -277,9 +355,9 @@ def rotary(x, theta: float):
 
 def attention(cfg: MoELMConfig, kind: str, p, u, segment_ids):
   """Grouped-query attention on ``u [S, L, hidden]``: per-head norms of
-  queries and keys, rotary on the kinds in ``cfg.rotary_layers``, a
-  window on a ``sliding_attention`` layer, a sigmoid output gate where
-  the family has one."""
+  queries and keys where the family has them, rotary on the kinds in
+  ``cfg.rotary_layers``, a window on a ``sliding_attention`` layer, a
+  sigmoid output gate where the family has one."""
   sliding = kind == 'sliding_attention'
   scope = (obs_trace.phase('attention/window') if sliding
            else obs_trace.phase('attention/full'))
@@ -292,8 +370,9 @@ def attention(cfg: MoELMConfig, kind: str, p, u, segment_ids):
     v = jnp.matmul(u, p['v_proj']).reshape(seqs, length, kv_heads, d)
     if cfg.attention_gate:
       gate = jnp.matmul(u, p['gate_proj'])
-    q = rms_norm(q, p['q_norm'], cfg.rms_norm_eps)
-    k = rms_norm(k, p['k_norm'], cfg.rms_norm_eps)
+    if cfg.qk_norms:
+      q = rms_norm(q, p['q_norm'], cfg.rms_norm_eps)
+      k = rms_norm(k, p['k_norm'], cfg.rms_norm_eps)
     if kind in cfg.rotary_layers:
       q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
     out = blocked_attention(
@@ -320,12 +399,14 @@ def short_conv(p, u, segment_ids):
 
 def routed_ffn(cfg: MoELMConfig, p, u):
   """``(y, sel)``: the held experts' part (beside the shared expert,
-  where the family has one) on ``u [S, L, hidden]``, and the experts
-  each token took ``[S * L, k]``."""
+  where the family has one, of the experts' form) on ``u [S, L,
+  hidden]``, and the experts each token took ``[S * L, k]``."""
   shared = None
   if cfg.num_shared_experts:
     with obs_trace.phase('moe/shared'):
-      shared = swiglu(p['shared'], u)
+      shared = jnp.matmul(routed.interior(
+          cfg.expert_form, jnp.matmul(u, p['shared']['mlp_in'])),
+                          p['shared']['mlp_out'])
   y, sel = routed.routed_experts(cfg.routed, p, u.reshape(-1, u.shape[-1]))
   y = y.reshape(u.shape)
   return (y if shared is None else shared + y), sel
@@ -333,10 +414,11 @@ def routed_ffn(cfg: MoELMConfig, p, u):
 
 def layer(cfg: MoELMConfig, kind: str, p, x, segment_ids):
   """One residual block: ``(x', sel)``, ``sel`` the experts each token
-  took in a routed block and ``None`` in a dense one.  Mixer and
-  feed-forward are rematerialised APART: the backward pass keeps the
-  block's input and the state between the two, and recomputes one half
-  at a time.  (Under one
+  took in a routed block and ``None`` in a dense one.  A block holds the
+  halves its kind names (``_KINDS``): a mixer and a feed-forward, or one
+  of them alone.  Mixer and feed-forward are rematerialised APART: the
+  backward pass keeps the block's input and the state between the two,
+  and recomputes one half at a time.  (Under one
   ``jax.checkpoint`` around the whole block, as ``hybrid_ssm.forward``
   has it, the attention blocks and the waves, which rematerialise
   themselves, kept their buffers alive across the block's recomputation:
@@ -365,8 +447,12 @@ def layer(cfg: MoELMConfig, kind: str, p, x, segment_ids):
   @jax.checkpoint
   def mixer(p, x):
     u = norm(x, p['input_norm'])
-    out = (short_conv(p['conv'], u, segment_ids) if kind == 'conv'
-           else attention(cfg, kind, p['attention'], u, segment_ids))
+    if kind == 'conv':
+      out = short_conv(p['conv'], u, segment_ids)
+    elif kind == 'mamba':
+      out = mamba_mixer(cfg.mamba, p['mamba'], u, segment_ids)
+    else:
+      out = attention(cfg, kind, p['attention'], u, segment_ids)
     return norm(out, p['post_attn_norm']) if cfg.sandwich_norms else out
 
   @functools.partial(
@@ -382,12 +468,16 @@ def layer(cfg: MoELMConfig, kind: str, p, x, segment_ids):
     return (norm(ffn, p['post_mlp_norm']) if cfg.sandwich_norms
             else ffn), sel
 
-  out = mixer(p, x)
-  with obs_trace.phase('residual'):
-    x = x + out
-  ffn, sel = feed_forward(p, x)
-  with obs_trace.phase('residual'):
-    return x + ffn, sel
+  sel = None
+  if 'mixer' in _KINDS[kind]:
+    out = mixer(p, x)
+    with obs_trace.phase('residual'):
+      x = x + out
+  if 'ffn' in _KINDS[kind]:
+    ffn, sel = feed_forward(p, x)
+    with obs_trace.phase('residual'):
+      x = x + ffn
+  return x, sel
 
 
 def _embed(cfg: MoELMConfig, rows):

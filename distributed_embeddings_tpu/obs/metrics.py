@@ -64,6 +64,12 @@ METRIC_TYPES: Dict[str, str] = {
     # Mamba layers whose selective scan runs on the kernels of
     # ops/pallas_ssd.py, one a call at trace time (models/hybrid_ssm.ssd_scan)
     'mixer.scan_kernel_layers': 'counter',
+    # Mamba layers whose B and C come in more than one group of heads,
+    # one a call at trace time (models/hybrid_ssm.ssd_scan)
+    'mixer.grouped_scan_layers': 'counter',
+    # routed layers of ungated squared-ReLU experts, one a call at trace
+    # time (layers/routed_experts.routed_experts)
+    'moe.ungated_expert_layers': 'counter',
     # routed blocks whose output the backward pass keeps for the norm
     # after it, one a block at trace time (models/moe_lm.layer)
     'moe.kept_outputs': 'counter',

@@ -34,6 +34,16 @@ product): the backward kernel returns that product's cotangent, and
 JAX's own rule for the product gives ``A``'s (summed over positions) and
 the rest of ``dt``'s.
 
+GROUPS of ``B`` and ``C``: with ``G`` groups, head ``h`` reads group ``h //
+(H / G)``.  ``B`` and ``C`` stay ``[S, L, G N]`` as the projection gives
+them; a block of heads lies inside one group (``_head_block``), and the
+block specs of ``B``, ``C`` and their transposes select that group's
+``N`` columns by the head block's index, so that no head's copy of them
+is ever made.  The kernels are the same at any ``G``: a head block's
+partial ``dB`` and ``dC`` are written apart, as they are with one group,
+and summed over the blocks of each group afterwards (a group's heads'
+share of the cotangent).
+
 ``ssd_scan`` asks ``takes`` and nothing else: the choice reads static
 shapes and the backend.
 """
@@ -71,24 +81,26 @@ _PRODUCT = dict(preferred_element_type=jnp.float32,
                 precision=lax.Precision.DEFAULT)
 
 
-def _head_block(heads, d_head):
-  """The most heads, at most ``HEADS``, that divide ``heads`` and fill
-  whole lane tiles side by side; None where none do."""
-  return next((n for n in range(min(heads, HEADS), 0, -1)
-               if heads % n == 0 and n * d_head % _LANES == 0), None)
+def _head_block(heads, d_head, groups=1):
+  """The most heads, at most ``HEADS``, that divide a group's ``heads /
+  groups`` and fill whole lane tiles side by side; None where none do."""
+  per_group = heads // groups
+  return next((n for n in range(min(per_group, HEADS), 0, -1)
+               if per_group % n == 0 and n * d_head % _LANES == 0), None)
 
 
-def takes(x_shape, state, chunk) -> bool:
-  """Whether the scan of ``x [S, L, H, P]`` with ``state`` columns in
-  ``B`` and ``C`` and chunks of ``chunk`` positions goes to the kernels:
-  the program is compiled for a TPU (or a hook says so) and the static
-  shapes fit the tiles."""
+def takes(x_shape, state, chunk, groups=1) -> bool:
+  """Whether the scan of ``x [S, L, H, P]`` with ``groups`` groups of
+  ``state`` columns in ``B`` and ``C`` and chunks of ``chunk`` positions
+  goes to the kernels: the program is compiled for a TPU (or a hook says
+  so) and the static shapes fit the tiles."""
   _, length, heads, d_head = x_shape
   engaged = (jax.default_backend() == 'tpu' or ASSUME_TPU
              or FORCE_INTERPRET)
   return (engaged and chunk % _LANES == 0 and length % chunk == 0
           and state % _LANES == 0 and d_head in (64, 128)
-          and _head_block(heads, d_head) is not None)
+          and heads % groups == 0
+          and _head_block(heads, d_head, groups) is not None)
 
 
 def _bf16(a):
@@ -278,16 +290,19 @@ def _backward_kernel(x_ref, dtc_ref, dtr_ref, lac_ref, lar_ref, b_ref, bt_ref,
 class _Layout:
   """The grid ``(sequence, head block, step)`` of one layer's kernels and
   the block specs of its arrays; ``chunk_of(step)`` is the chunk a step
-  holds (the backward kernel walks them from the last)."""
+  holds (the backward kernel walks them from the last), ``group_of(head
+  block)`` the group of ``B`` and ``C`` the block's heads read."""
 
-  def __init__(self, x4, state, chunk, reverse):
+  def __init__(self, x4, state, chunk, reverse, groups=1):
     seqs, length, heads, self.d_head = x4
-    self.heads = _head_block(heads, self.d_head)
+    self.heads = _head_block(heads, self.d_head, groups)
     self.chunk, self.state = chunk, state
     self.chunks = length // chunk
     self.grid = (seqs, heads // self.heads, self.chunks)
     last = self.chunks - 1
     self.chunk_of = (lambda i: last - i) if reverse else (lambda i: i)
+    per_group = self.grid[1] // groups
+    self.group_of = (lambda g: 0) if groups == 1 else (lambda g: g // per_group)
 
   def _spec(self, block, index):
     return pl.BlockSpec(block, lambda s, g, i: index(s, g, self.chunk_of(i)))
@@ -309,14 +324,23 @@ class _Layout:
                       lambda s, g, c: (s, g, 0, c))
 
   def positions(self, width):
-    """``[S, L, width]``: ``B``, ``C``, and the document numbers along
-    the sublanes."""
+    """``[S, L, width]``: the document numbers along the sublanes."""
     return self._spec((None, self.chunk, width), lambda s, g, c: (s, c, 0))
 
   def along(self, width):
-    """``[S, width, L]``: ``B^T``, ``C^T``, and the document numbers
-    along the lanes (8 rows)."""
+    """``[S, width, L]``: the document numbers along the lanes (8
+    rows)."""
     return self._spec((None, width, self.chunk), lambda s, g, c: (s, 0, c))
+
+  def group(self):
+    """``[S, L, G N]``: ``B``, ``C``, the head block's group's columns."""
+    return self._spec((None, self.chunk, self.state),
+                      lambda s, g, c: (s, c, self.group_of(g)))
+
+  def group_along(self):
+    """``[S, G N, L]``: ``B^T``, ``C^T``, the head block's group's rows."""
+    return self._spec((None, self.state, self.chunk),
+                      lambda s, g, c: (s, self.group_of(g), c))
 
   def before(self):
     """``[S, L, 128]``: the 8 positions before the chunk's first (the
@@ -342,9 +366,9 @@ class _Layout:
 
   def inputs(self):
     return [self.lanes(), self.columns(), self.rows(), self.columns(),
-            self.rows(), self.positions(self.state), self.along(self.state),
-            self.positions(self.state), self.along(self.state),
-            self.positions(_LANES), self.along(_SUBLANES), self.before()]
+            self.rows(), self.group(), self.group_along(), self.group(),
+            self.group_along(), self.positions(_LANES),
+            self.along(_SUBLANES), self.before()]
 
   def state_scratch(self):
     return pltpu.VMEM((self.heads, self.state, self.d_head), jnp.float32)
@@ -385,13 +409,27 @@ def _operands(lay, x, dt, la, b, c, seg):
           + (b, t(b), c, t(c), seg['columns'], seg['rows'], seg['columns']))
 
 
+def _per_group(partials, groups, transposed=False):
+  """A head block's partial ``dB`` ``[S, H / heads, L, N]`` (``dC^T``
+  ``[S, H / heads, N, L]`` where ``transposed``) summed over the blocks of
+  each group: ``[S, L, G N]``."""
+  if groups == 1:   # the sum as one reduction, with no view of groups
+    total = jnp.sum(partials, axis=1)
+    return jnp.swapaxes(total, 1, 2) if transposed else total
+  seqs, blocks = partials.shape[:2]
+  total = jnp.sum(partials.reshape(
+      (seqs, groups, blocks // groups) + partials.shape[2:]), axis=2)
+  total = jnp.transpose(total, (0, 3, 1, 2) if transposed else (0, 2, 1, 3))
+  return total.reshape(total.shape[:2] + (-1,))
+
+
 # Each kernel is one jitted function (as ``hybrid_ssm._attend_window`` is):
 # JAX traces and lowers it once for every Mamba layer of a step, where a
 # bare ``pallas_call`` is traced and lowered again at each of its 27
 # calls, which set-up pays on every run, the compile cache's hits too.
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _forward(chunk, interpret, x4, x, dt, la, b, c, seg):
-  lay = _Layout(x4, b.shape[-1], chunk, reverse=False)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _forward(chunk, interpret, x4, groups, x, dt, la, b, c, seg):
+  lay = _Layout(x4, b.shape[-1] // groups, chunk, False, groups)
   seqs = x.shape[0]
   return _call(
       _forward_kernel, lay, lay.inputs(), [lay.lanes(), lay.states()],
@@ -402,9 +440,9 @@ def _forward(chunk, interpret, x4, x, dt, la, b, c, seg):
           *_operands(lay, x, dt, la, b, c, seg))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _backward(chunk, interpret, x4, x, dt, la, b, c, seg, states, dy):
-  lay = _Layout(x4, b.shape[-1], chunk, reverse=True)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _backward(chunk, interpret, x4, groups, x, dt, la, b, c, seg, states, dy):
+  lay = _Layout(x4, b.shape[-1] // groups, chunk, True, groups)
   seqs, length, _ = x.shape
   blocks = lay.grid[1]
   f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
@@ -421,24 +459,24 @@ def _backward(chunk, interpret, x4, x, dt, la, b, c, seg, states, dy):
       interpret, 'ssd_scan_bwd')(
           *_operands(lay, x, dt, la, b, c, seg), states, dy)
   return (dx, _by_position(ddt), _by_position(dla_c, dla_r),
-          jnp.sum(db, axis=1), jnp.swapaxes(jnp.sum(dct, axis=1), 1, 2))
+          _per_group(db, groups), _per_group(dct, groups, transposed=True))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _core(chunk, interpret, x4, x, dt, la, b, c, seg):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _core(chunk, interpret, x4, groups, x, dt, la, b, c, seg):
   """float32 ``x [S, L, H P]``, ``dt`` and ``la = dt A`` ``[S, L, H]``,
-  ``b``, ``c`` ``[S, L, N]`` -> float32 ``y [S, L, H P]``; ``x4`` is
+  ``b``, ``c`` ``[S, L, G N]`` -> float32 ``y [S, L, H P]``; ``x4`` is
   ``x``'s shape as ``[S, L, H, P]``."""
-  return _core_fwd(chunk, interpret, x4, x, dt, la, b, c, seg)[0]
+  return _core_fwd(chunk, interpret, x4, groups, x, dt, la, b, c, seg)[0]
 
 
-def _core_fwd(chunk, interpret, x4, x, dt, la, b, c, seg):
-  y, states = _forward(chunk, interpret, x4, x, dt, la, b, c, seg)
+def _core_fwd(chunk, interpret, x4, groups, x, dt, la, b, c, seg):
+  y, states = _forward(chunk, interpret, x4, groups, x, dt, la, b, c, seg)
   return y, (x, dt, la, b, c, seg, states)
 
 
-def _core_bwd(chunk, interpret, x4, residuals, dy):
-  return _backward(chunk, interpret, x4, *residuals, dy) + (None,)
+def _core_bwd(chunk, interpret, x4, groups, residuals, dy):
+  return _backward(chunk, interpret, x4, groups, *residuals, dy) + (None,)
 
 
 _core.defvjp(_core_fwd, _core_bwd)
@@ -447,15 +485,17 @@ _core.defvjp(_core_fwd, _core_bwd)
 def scan(x, dt, a_heads, b, c, segment_ids, chunk):
   """``ssd_scan``'s contract on the kernels: ``x [S, L, H, P]``, ``dt [S,
   L, H]`` (positive), ``a_heads [H]`` (negative), ``b``, ``c`` ``[S, L,
-  N]``, ``segment_ids [S, L]`` -> float32 ``y [S, L, H, P]``."""
+  G, N]``, ``segment_ids [S, L]`` -> float32 ``y [S, L, H, P]``."""
   seqs, length, heads, d_head = x.shape
+  groups = b.shape[2]
+  b, c = (a.reshape(seqs, length, -1) for a in (b, c))
   ids = segment_ids.astype(jnp.int32)
   # the document numbers twice: a position along the sublanes, and along
   # the lanes, so that no kernel transposes a vector
   seg = dict(
       columns=jnp.broadcast_to(ids[:, :, None], (seqs, length, _LANES)),
       rows=jnp.broadcast_to(ids[:, None, :], (seqs, _SUBLANES, length)))
-  y = _core(chunk, FORCE_INTERPRET, x.shape,
+  y = _core(chunk, FORCE_INTERPRET, x.shape, groups,
             x.reshape(seqs, length, heads * d_head), dt, dt * a_heads, b, c,
             seg)
   return y.reshape(x.shape)

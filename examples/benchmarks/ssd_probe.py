@@ -73,7 +73,8 @@ def main():
               jax.nn.softplus(draw(seqs, length, heads)
                               + jnp.asarray(bias, jnp.float32)),
               -jnp.asarray(rng.uniform(1.0, 16.0, heads), jnp.float32),
-              draw(seqs, length, z['state']), draw(seqs, length, z['state']))
+              draw(seqs, length, 1, z['state']),
+              draw(seqs, length, 1, z['state']))
   seg = jnp.asarray(packed_segments(rng, seqs, length))
   weights = draw(seqs, length, heads, z['d_head'])
 
@@ -98,15 +99,17 @@ def main():
     """ms of the forward kernel and of the backward kernel, each alone."""
     x, dt, a, b, c = operands
     x3 = x.reshape(seqs, length, -1)
+    b, c = (v.reshape(seqs, length, -1) for v in (b, c))
     ids = jnp.asarray(seg, jnp.int32)
     segs = dict(columns=jnp.broadcast_to(ids[:, :, None],
                                          (seqs, length, 128)),
                 rows=jnp.broadcast_to(ids[:, None, :], (seqs, 8, length)))
     interpret = pallas_ssd.FORCE_INTERPRET
-    run = lambda *v: pallas_ssd._forward(z['chunk'], interpret, x.shape, *v)
+    run = lambda *v: pallas_ssd._forward(z['chunk'], interpret, x.shape, 1,
+                                         *v)
     fwd_ms, (_, states) = clock(jax.jit(run), x3, dt, dt * a, b, c, segs)
     back = lambda *v: pallas_ssd._backward(z['chunk'], interpret, x.shape,
-                                           *v)
+                                           1, *v)
     bwd_ms, _ = clock(jax.jit(back), x3, dt, dt * a, b, c, segs, states,
                       weights.reshape(x3.shape))
     return dict(fwd=fwd_ms, bwd=bwd_ms)

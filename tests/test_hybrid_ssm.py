@@ -73,8 +73,9 @@ def test_chunked_scan_matches_the_sequential_recurrence(layout):
   weight = np.random.default_rng(4).standard_normal(
       v['x'].shape).astype(np.float32)
 
-  def chunked(x, dt, a, b, c):
-    return prog.ssd_scan(x, dt, a, b, c, seg, CFG.mamba_chunk_size)
+  def chunked(x, dt, a, b, c):   # B and C as one group
+    return prog.ssd_scan(x, dt, a, b[:, :, None], c[:, :, None], seg,
+                         CFG.mamba_chunk_size)
 
   def sequential(x, dt, a, b, c):
     return ref._sequential_scan(x, dt, a, b, c, seg, matmul)
@@ -144,7 +145,8 @@ def test_a_document_moves_nothing_of_the_next(part):
   f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
   if part == 'scan':
     v = {k: jnp.asarray(a) for k, a in _scan_inputs(5, 1, 24).items()}
-    fn = lambda x: prog.ssd_scan(x, v['dt'], v['a'], v['b'], v['c'], seg,
+    fn = lambda x: prog.ssd_scan(x, v['dt'], v['a'], v['b'][:, :, None],
+                                 v['c'][:, :, None], seg,
                                  CFG.mamba_chunk_size)
     x = v['x']
   elif part == 'conv':
@@ -347,10 +349,11 @@ def test_a_sliced_table_is_refused_by_name():
 
 
 @pytest.mark.parametrize('world', [1, 4])
-@pytest.mark.parametrize('width', [256, 2048])
+@pytest.mark.parametrize('width', [256, 2048, 2688])
 def test_wide_rows_through_lookup_apply_and_checkpoint(width, world,
                                                        tmp_path):
-  """Rows of 1 KiB and 8 KiB on the default path at 64 rows: plan,
+  """Rows of 1 KiB, 8 KiB and 10.5 KiB (21 lane tiles, no power of two)
+  on the default path at 64 rows: plan,
   ``init``, lookup, an Adam apply that moves exactly the rows asked for,
   and a checkpoint round trip of table and moments."""
   mesh = create_mesh(jax.devices()[:world])

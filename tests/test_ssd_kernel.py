@@ -73,10 +73,18 @@ def interpreted(monkeypatch):
   monkeypatch.setattr(pallas_ssd, 'FORCE_INTERPRET', True)
 
 
+def _one_group(scan):
+  """``scan`` on ``b``, ``c`` ``[S, L, N]``, the reference's form: B and
+  C as ``[S, L, 1, N]``, one group that every head reads."""
+  return lambda x, dt, a, b, c, *rest: scan(x, dt, a, b[:, :, None],
+                                            c[:, :, None], *rest)
+
+
 def _three_paths(layout):
   seg = jnp.asarray(np.stack(SEGMENTS[layout]), jnp.int32)
-  kernel = lambda *v: pallas_ssd.scan(*v, seg, CHUNK)
+  kernel = _one_group(lambda *v: pallas_ssd.scan(*v, seg, CHUNK))
 
+  @_one_group
   def chunked(*v):
     with pytest.MonkeyPatch.context() as mp:
       mp.setattr(pallas_ssd, 'FORCE_INTERPRET', False)
@@ -141,8 +149,8 @@ def test_a_document_moves_nothing_of_the_next(interpreted):
   next document's output the same to the bit."""
   seg = jnp.asarray(np.stack(SEGMENTS['inside_a_chunk']), jnp.int32)
   v = _inputs(7)
-  scan = lambda x: pallas_ssd.scan(x, v['dt'], v['a'], v['b'], v['c'], seg,
-                                   CHUNK)
+  scan = lambda x: _one_group(pallas_ssd.scan)(x, v['dt'], v['a'], v['b'],
+                                               v['c'], seg, CHUNK)
   before = np.asarray(scan(v['x']))
   after = np.asarray(scan(jnp.asarray(v['x']).at[0, 20].add(1.0)))
   np.testing.assert_array_equal(before[0, 40:], after[0, 40:])

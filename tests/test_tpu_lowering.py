@@ -846,3 +846,40 @@ def test_scan_kernels_compile_for_v5e_under_their_phase(v5e, ssd_for_tpu):
       'ssd_scan_bwd', 'ssd_scan_fwd', 'ssd_scan_fwd'], names
   assert all(layer.under(xtrace.scope_path(name), 'mixer/selective_scan')
              for name in names), names
+
+
+def test_grouped_scan_kernels_compile_for_v5e_under_their_phase(
+    v5e, ssd_for_tpu):
+  """One Mamba block of ``nemotron-train-packed-8k`` (64 heads of 64, B
+  and C in 8 groups of 128, chunks of 128, 2 x 8,192 positions), a block
+  of one sub-layer under its ``jax.checkpoint``, value and gradients: the
+  grouped kernels compile for a v5e, forward twice and backward once,
+  under ``mixer/selective_scan``."""
+  import json
+  from jax.sharding import SingleDeviceSharding
+  from benchmarks.lib import layer, xtrace
+  from distributed_embeddings_tpu.models import moe_lm
+  sh = SingleDeviceSharding(v5e.devices[0])
+  with open('benchmarks/configs/nemotron-3-nano-30b-a3b.json') as f:
+    cfg = moe_lm.MoELMConfig.from_dict(json.load(f))
+  m = cfg.mamba
+  d, inner, heads = m.hidden_size, m.d_inner, m.mamba_n_heads
+  assert (m.mamba_n_groups, m.conv_dim) == (8, 4096 + 2 * 8 * 128)
+  f32 = lambda *shape: _sds(shape, jnp.float32, sh)
+  p = {'input_norm': f32(d),
+       'mamba': {'in_proj': f32(d, inner + m.conv_dim + heads),
+                 'conv_kernel': f32(m.mamba_d_conv, m.conv_dim),
+                 'conv_bias': f32(m.conv_dim), 'dt_bias': f32(heads),
+                 'A_log': f32(heads), 'D': f32(heads),
+                 'gated_norm': f32(inner), 'out_proj': f32(inner, d)}}
+  block = lambda p, x, seg: moe_lm.layer(cfg, 'mamba', p, x, seg)[0]
+  loss = lambda p, x, seg: jnp.sum(block(p, x, seg) ** 2)
+  assert ssd_for_tpu.takes((2, 8192, heads, m.mamba_d_head),
+                           m.mamba_d_state, m.mamba_chunk_size, 8)
+  compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+      p, f32(2, 8192, d), _sds((2, 8192), jnp.int32, sh)).compile()
+  names = _kernel_calls(compiled.as_text())
+  assert sorted(name.split('/')[-2] for name in names) == [
+      'ssd_scan_bwd', 'ssd_scan_fwd', 'ssd_scan_fwd'], names
+  assert all(layer.under(xtrace.scope_path(name), 'mixer/selective_scan')
+             for name in names), names
