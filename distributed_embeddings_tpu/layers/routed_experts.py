@@ -56,6 +56,25 @@ token 0, are counted into the last expert's group so that every row of
 the buffer is some expert's, and are weighted by exactly 0, forward and
 backward.
 
+The grouped products run at widths rounded up to a multiple of
+``_WIDTH_TILE`` (``padded_width``), with exact zeros: XLA tiles its
+``ragged-dot`` kernel on a TPU by the largest of 512, 256 and 128 that
+divides each width, and the narrow tiles cost rate.  On a v5e, 7,680
+rows over 8 groups, a layer's two products forward and with both
+gradients take 17.96 ms at hidden 2,688 and interior 1,856 (12 and 14%
+of peak) and 6.96 ms at 3,072 and 2,048 (39 and 46%), 1.26 times the
+work (``examples/benchmarks/grouped_product_probe.py``, PERF.md section
+6).  Once a layer, before the waves, the kernels take zero columns
+of the interior (each half of a ``'swiglu'`` kernel apart) and zero
+rows of the interior in ``experts_out``; a zero interior column gives
+``silu(0) * 0 = relu(0)^2 = 0``, which the zero row after it multiplies
+again.  Where the hidden width is rounded too, the tokens' rows and the
+kernels take zero hidden columns and rows, and a wave's output is
+sliced back to the hidden width before it is weighted.  The parameters,
+and their gradients, keep their own shapes: a pad's transpose is a
+slice.  Widths that are multiples already run as they are.  The counter
+``moe.padded_expert_layers`` counts a traced layer that pads.
+
 On one chip there is no exchange, and no code stands in for one.
 
 Device phases (``obs.trace.phase``): ``moe/route``, ``moe/dispatch``
@@ -86,6 +105,11 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # rematerialised on its own, at 13.60: compile-only, PERF.md section 6.)
 _LARGEST_WAVE = 1.25
 _FORMS = ('swiglu', 'relu2')
+# The width multiple at which XLA's ``ragged-dot`` kernel takes its
+# widest tiles on a TPU, and the largest share of a width that rounding
+# it up may add (``padded_width``).
+_WIDTH_TILE = 512
+_MOST_PADDING = 0.25
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,6 +194,29 @@ def interior(form: str, h):
   return jax.nn.silu(gate) * up
 
 
+def padded_width(width: int) -> int:
+  """The width a grouped product takes for ``width``: the next multiple
+  of ``_WIDTH_TILE`` where that adds at most ``_MOST_PADDING`` of it,
+  else ``width`` (more padding than that is not shown to pay: the
+  widths measured grow by 14 and 10%)."""
+  rounded = _WIDTH_TILE * math.ceil(width / _WIDTH_TILE)
+  return rounded if rounded <= (1 + _MOST_PADDING) * width else width
+
+
+def _pad_kernels(form: str, experts_in, experts_out, hidden: int,
+                 ffn: int):
+  """``experts_in [held, d, (2 x) f]`` and ``experts_out [held, f, d]``
+  with zero rows and columns out to ``hidden`` and ``ffn``; a
+  ``'swiglu'`` kernel's gate and up halves each apart."""
+  d, f = experts_out.shape[2], experts_out.shape[1]
+  halves = (jnp.split(experts_in, 2, axis=-1) if form == 'swiglu'
+            else [experts_in])
+  grown = [jnp.pad(h, ((0, 0), (0, hidden - d), (0, ffn - f)))
+           for h in halves]
+  return (jnp.concatenate(grown, axis=-1) if len(grown) > 1 else grown[0],
+          jnp.pad(experts_out, ((0, 0), (0, ffn - f), (0, hidden - d))))
+
+
 def _grouped(lhs, rhs, group_sizes):
   """``lhs [m, k]`` times ``rhs [g, k, n]``, rows ``sum(group_sizes[:e])
   ..`` by ``rhs[e]``: ``jax.lax.ragged_dot`` on float32 operands, which
@@ -185,11 +232,12 @@ def _grouped(lhs, rhs, group_sizes):
   return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
-def _wave(cfg, p, u, flat_weights, ends, lo, at, key):
+def _wave(cfg, width, p, u, flat_weights, ends, lo, at, key):
   """The held experts' output for one wave's window of sorted
-  assignments, summed into token order ``[T, d]``: ``at`` the flat
+  assignments, summed into token order ``[T, width]``: ``at`` the flat
   assignments at the sorted places ``lo ..``, ``key`` their sorted
-  keys."""
+  keys; ``u`` and the kernels ``p`` at the products' widths, of which
+  the first ``width`` hidden columns are the tokens' own."""
   k, held = cfg.experts_per_token, cfg.num_held
   slots = at.shape[0]
   with obs_trace.phase('moe/dispatch'):
@@ -205,9 +253,12 @@ def _wave(cfg, p, u, flat_weights, ends, lo, at, key):
     out = _grouped(interior(cfg.expert_form,
                             _grouped(buffer, p['experts_in'], sizes)),
                    p['experts_out'], sizes)
+    if out.shape[1] != width:
+      out = out[:, :width]
   with obs_trace.phase('moe/combine'):
     weight = jnp.where(valid, flat_weights[at], 0.0)
-    return jnp.zeros_like(u).at[token].add(out * weight[:, None])
+    return jnp.zeros((u.shape[0], width), u.dtype).at[token].add(
+        out * weight[:, None])
 
 
 def dispatch_order(cfg: RoutedExpertsConfig, sel):
@@ -231,12 +282,15 @@ def routed_experts(cfg: RoutedExpertsConfig, p: Dict, u):
   ``routing_stats`` counts).  ``p``: ``router [d, router_width]``,
   ``expert_bias [router_width]``, ``experts_in [num_held, d, 2 x ffn]``
   (gate | up; ``[num_held, d, ffn]`` for ``'relu2'`` experts),
-  ``experts_out [num_held, ffn, d]``.  The counter
-  ``moe.ungated_expert_layers`` counts a traced layer of ``'relu2'``
-  experts."""
+  ``experts_out [num_held, ffn, d]``.  The counters
+  ``moe.ungated_expert_layers`` and ``moe.padded_expert_layers`` count
+  a traced layer of ``'relu2'`` experts and one whose products take
+  padded widths (``padded_width``)."""
   if cfg.expert_form == 'relu2':
     obs_metrics.inc('moe.ungated_expert_layers')
-  tokens = u.shape[0]
+  tokens, width = u.shape
+  ffn = p['experts_out'].shape[1]
+  hidden, wide = padded_width(width), padded_width(ffn)
   slots, waves = cfg.wave_slots(tokens), cfg.waves(tokens)
   always = cfg.capacity(tokens) // slots
   sel, weights = route(cfg, u, p['router'], p['expert_bias'])
@@ -247,15 +301,22 @@ def routed_experts(cfg: RoutedExpertsConfig, p: Dict, u):
                    constant_values=cfg.num_held).reshape(waves, slots)
     order = jnp.pad(order, (0, pad)).reshape(waves, slots)
   kernels = {'experts_in': p['experts_in'], 'experts_out': p['experts_out']}
+  rows = u
+  if (hidden, wide) != (width, ffn):
+    obs_metrics.inc('moe.padded_expert_layers')
+    with obs_trace.phase('moe/experts'):
+      kernels = dict(zip(('experts_in', 'experts_out'), _pad_kernels(
+          cfg.expert_form, p['experts_in'], p['experts_out'], hidden, wide)))
+      rows = jnp.pad(u, ((0, 0), (0, hidden - width)))
   flat_weights = weights.reshape(-1)
   # each wave is rematerialised on its own: the backward pass holds one
   # wave's buffers at a time.  (As one body of a scan the waves that
   # always run compile sooner and hold 1 GiB more, compile-only for a
   # v5e: the scan carries the kernels' cotangents.)
-  wave = jax.checkpoint(functools.partial(_wave, cfg))
-  y = wave(kernels, u, flat_weights, ends, 0, order[0], skey[0])
+  wave = jax.checkpoint(functools.partial(_wave, cfg, width))
+  y = wave(kernels, rows, flat_weights, ends, 0, order[0], skey[0])
   for w in range(1, always):
-    y = y + wave(kernels, u, flat_weights, ends, w * slots, order[w],
+    y = y + wave(kernels, rows, flat_weights, ends, w * slots, order[w],
                  skey[w])
   if waves == always:
     return y, sel
@@ -274,8 +335,9 @@ def routed_experts(cfg: RoutedExpertsConfig, p: Dict, u):
       # stack a wave (six copies of the kernels, compile-only for a v5e)
       return jax.lax.cond(
           ends[-1] > lo,
-          lambda: _wave(cfg, kernels, u, flat_weights, ends, lo, at, key),
-          lambda: jnp.zeros_like(u))
+          lambda: _wave(cfg, width, kernels, u, flat_weights, ends, lo, at,
+                        key),
+          lambda: jnp.zeros((u.shape[0], width), u.dtype))
 
     def body(y, xs):
       return y + maybe(kernels, u, flat_weights, *xs), None
@@ -286,7 +348,7 @@ def routed_experts(cfg: RoutedExpertsConfig, p: Dict, u):
   # one predicate a step where the capacity held everything
   return jax.lax.cond(ends[-1] > always * slots, overflow,
                       lambda args: args[3],
-                      (kernels, u, flat_weights, y)), sel
+                      (kernels, rows, flat_weights, y)), sel
 
 
 def routing_stats(cfg: RoutedExpertsConfig, sel):

@@ -70,6 +70,10 @@ METRIC_TYPES: Dict[str, str] = {
     # routed layers of ungated squared-ReLU experts, one a call at trace
     # time (layers/routed_experts.routed_experts)
     'moe.ungated_expert_layers': 'counter',
+    # routed layers whose grouped products take widths padded to the
+    # kernel's tile multiple, one a call at trace time
+    # (layers/routed_experts.routed_experts)
+    'moe.padded_expert_layers': 'counter',
     # routed blocks whose output the backward pass keeps for the norm
     # after it, one a block at trace time (models/moe_lm.layer)
     'moe.kept_outputs': 'counter',

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.classes import moe_lm as ref
+from distributed_embeddings_tpu import obs
 from distributed_embeddings_tpu.layers import routed_experts as routed
 
 HIDDEN, FFN, WIDTH, PER_TOKEN, TOKENS = 64, 32, 16, 4, 96
@@ -49,15 +50,19 @@ def _tokens(seed):
       (TOKENS, HIDDEN)), jnp.float32)
 
 
-def dense_loop(first, held, p, u):
+def dense_loop(first, held, p, u, form='swiglu'):
   """The held experts' part, an expert at a time over every token."""
   z = dict(router=WIDTH, per_token=PER_TOKEN)
   weights = ref.dense_routing_weights(z, {'route_scale': 2.826}, p, u)
   y = jnp.zeros_like(u)
   for e in range(held):
-    gate, up = jnp.split(matmul(u, p['experts_in'][e]), 2, axis=-1)
-    y = y + weights[:, first + e, None] * matmul(
-        jax.nn.silu(gate) * up, p['experts_out'][e])
+    h = matmul(u, p['experts_in'][e])
+    if form == 'relu2':
+      h = jnp.square(jax.nn.relu(h))
+    else:
+      gate, up = jnp.split(h, 2, axis=-1)
+      h = jax.nn.silu(gate) * up
+    y = y + weights[:, first + e, None] * matmul(h, p['experts_out'][e])
   return y
 
 
@@ -194,3 +199,94 @@ def test_routing_stats_count_held_overflow_and_imbalance():
 def test_a_share_outside_the_router_is_refused_by_name():
   with pytest.raises(ValueError, match='not among the router'):
     _cfg(14, 4)
+
+
+# (hidden, interior) widths against the rounding (``padded_width``):
+# both misaligned, only the interior, only the hidden width (256 would
+# take twice itself, so it stays)
+PADS = {'both': (460, 450), 'interior': (512, 450), 'hidden': (460, 256)}
+
+
+def _wide(form, hidden, ffn, bias=None):
+  """A layer of 16 experts of ``form``, 4 a token, experts 4 to 7
+  held, drawn at ``hidden`` x ``ffn``; with 96 tokens and a
+  cotangent."""
+  rng = np.random.default_rng(11)
+  f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+  mult = 2 if form == 'swiglu' else 1
+  p = {'router': f32(hidden, WIDTH) / np.sqrt(hidden),
+       'expert_bias': (jnp.zeros(WIDTH) if bias is None
+                       else jnp.asarray(bias, jnp.float32)),
+       'experts_in': f32(4, hidden, mult * ffn) / np.sqrt(hidden),
+       'experts_out': f32(4, ffn, hidden) / np.sqrt(ffn)}
+  cfg = _cfg(4, 4, expert_form=form)
+  return cfg, p, f32(TOKENS, hidden), f32(TOKENS, hidden)
+
+
+@pytest.mark.parametrize('pad', sorted(PADS) + ['both_overflowing'])
+@pytest.mark.parametrize('form', ['relu2', 'swiglu'])
+def test_padded_widths_give_the_unpadded_layer(form, pad):
+  """Widths that are no multiple of the kernel's tile run padded with
+  exact zeros: the value and every gradient (the kernels', the router's,
+  the tokens') are the plain loop's at the published widths, to float32
+  rounding, and each gradient has its parameter's published shape;
+  also where every token chooses held experts only, so that the waves
+  past the capacity (the ``cond`` and its ``scan``) run."""
+  hidden, ffn = PADS[pad.replace('_overflowing', '')]
+  assert (routed.padded_width(hidden) > hidden) == (pad != 'interior')
+  assert (routed.padded_width(ffn) > ffn) == (pad != 'hidden')
+  bias = None
+  if pad.endswith('overflowing'):
+    bias = np.zeros(WIDTH, np.float32)
+    bias[4:8] = 10.0
+  cfg, p, u, cot = _wide(form, hidden, ffn, bias=bias)
+  if bias is not None:
+    sel, _ = routed.route(cfg, u, p['router'], p['expert_bias'])
+    held = int(routed.routing_stats(cfg, sel)['assignments_held'])
+    assert held == TOKENS * PER_TOKEN > cfg.capacity(TOKENS)
+  with jax.default_matmul_precision('highest'):
+    got = jax.value_and_grad(
+        lambda p, u: jnp.sum(cot * routed.routed_experts(cfg, p, u)[0]),
+        argnums=(0, 1))(p, u)
+  want = jax.value_and_grad(
+      lambda p, u: jnp.sum(cot * dense_loop(4, 4, p, u, form)),
+      argnums=(0, 1))(p, u)
+  _assert_close(got, want)
+  grads, grad_u = got[1]
+  assert grad_u.shape == u.shape
+  for name, leaf in p.items():
+    assert grads[name].shape == leaf.shape, name
+  assert np.any(np.asarray(grads['experts_out']))
+
+
+def _padded_layers(form, hidden, ffn):
+  cfg, p, u, _ = _wide(form, hidden, ffn)
+  obs.reset()
+  obs.metrics.enable()
+  try:
+    jax.eval_shape(lambda p, u: routed.routed_experts(cfg, p, u), p, u)
+    return obs.metrics.snapshot().get('moe.padded_expert_layers', 0)
+  finally:
+    obs.metrics.disable()
+    obs.reset()
+
+
+@pytest.mark.parametrize('form', ['relu2', 'swiglu'])
+def test_padded_expert_layers_counts_a_misaligned_layer_only(form):
+  """``moe.padded_expert_layers`` counts a traced layer whose products
+  take padded widths; a layer at multiples of the tile (as
+  ``trinity-mini``'s and ``lfm2-24b-a2b``'s are), or too narrow to pay
+  for its padding (the toys'), counts nothing."""
+  assert _padded_layers(form, *PADS['both']) == 1
+  assert _padded_layers(form, *PADS['interior']) == 1
+  assert _padded_layers(form, 512, 512) == 0
+  assert _padded_layers(form, HIDDEN, FFN) == 0
+
+
+def test_padded_width_rounds_to_the_tile_where_it_pays():
+  """Nemotron's widths round up, the other cells' already are multiples
+  of the tile, and a narrow width that would grow by more than a quarter
+  stays as it is."""
+  assert (routed.padded_width(2688), routed.padded_width(1856)) == (3072, 2048)
+  assert all(routed.padded_width(w) == w
+             for w in (1024, 1536, 2048, 3072, 64, 32, 256))

@@ -534,6 +534,58 @@ def test_routed_experts_compile_for_v5e_with_the_kernel_and_no_stacked_wave(
   assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2**30
 
 
+def test_nemotron_routed_layer_runs_its_products_at_tile_widths_for_v5e(
+    v5e):
+  """The routed layer at ``nemotron-train-packed-8k``'s shapes (16,384
+  tokens, hidden 2,688, interior 1,856, 8 held of 128 relu2 experts, 6
+  a token, every slot: 13 waves of 7,680), value and gradients under
+  ``jax.checkpoint``: every ``ragged-dot`` product kernel takes and
+  gives widths that are multiples of the rounding
+  (``routed_experts._WIDTH_TILE``: 2,688 -> 3,072 and 1,856 -> 2,048,
+  exact zeros), keeps its unscoped name, and the padded kernels and
+  their cotangents stay inside the temporaries' bound below."""
+  from jax.sharding import SingleDeviceSharding
+  from distributed_embeddings_tpu.layers import routed_experts as routed
+  cfg = routed.RoutedExpertsConfig(router_width=128, experts_per_token=6,
+                                   num_held=8, route_scale=2.5,
+                                   capacity_factor=16.0, expert_form='relu2')
+  tokens, d, ffn, tile = 16384, 2688, 1856, routed._WIDTH_TILE
+  assert (cfg.wave_slots(tokens), cfg.waves(tokens),
+          cfg.capacity(tokens)) == (7680, 13, 99840)
+  assert (routed.padded_width(d), routed.padded_width(ffn)) == (3072, 2048)
+  sh = SingleDeviceSharding(v5e.devices[0])
+  f32 = lambda *shape: _sds(shape, jnp.float32, sh)
+  p = {'router': f32(d, 128), 'expert_bias': f32(128),
+       'experts_in': f32(8, d, ffn), 'experts_out': f32(8, ffn, d)}
+
+  def loss(p, u):
+    return jnp.sum(jax.checkpoint(
+        lambda p, u: routed.routed_experts(cfg, p, u)[0])(p, u) ** 2)
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+      p, f32(tokens, d)).compile()
+  text = compiled.as_text()
+  products = [line for line in text.splitlines() if re.match(
+      r'\s*%ragged-dot-(?!metadata)[\w\-.]+ = .*custom-call\(', line)]
+  # forward, the wave's recomputation and the backward's four, a wave
+  assert len(products) == 8 * 13, len(products)
+  for line in products:
+    result = re.match(r'\s*\S+ = f32\[([\d,]+)\]', line).group(1)
+    operands = re.search(r'operand_layout_constraints=\{(.*?)\}, ',
+                         line).group(1)
+    for dims in [result] + re.findall(r'f32\[([\d,]+)\]', operands):
+      widths = [int(x) for x in dims.split(',')][-2:]
+      assert all(w % tile == 0 for w in widths), (dims, line[:200])
+  names = {re.search(r'op_name="([^"]*)"', line).group(1)
+           for line in products}
+  assert names and all(n.startswith('ragged-dot') and '/' not in n
+                       for n in names), names
+  assert 'f32[8,2688,1856]' in text      # the gradients' published shape
+  # 4.49 GiB as it stands, 4.35 with the products at the published
+  # widths: the padded kernels and their cotangents add 0.14
+  assert compiled.memory_analysis().temp_size_in_bytes < 4.75 * 2**30
+
+
 @pytest.fixture
 def attention_for_tpu():
   # the AOT trace runs on the CPU backend: ASSUME_TPU makes
